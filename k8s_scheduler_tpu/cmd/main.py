@@ -12,6 +12,8 @@ then run the gRPC shim that the cluster agent talks to.
 from __future__ import annotations
 
 import argparse
+import os
+import shlex
 import signal
 import threading
 
@@ -256,6 +258,16 @@ def new_scheduler_command() -> argparse.ArgumentParser:
     return ap
 
 
+def build_line(fp: dict[str, str]) -> str:
+    """The `build:` line printed at start: the fingerprint's fields as
+    k=v, shell-quoted so a value with spaces (device_kind is "TPU v5
+    lite" on the chip) still splits back with `shlex.split` — which is
+    how chip_smoke.py reads the device its child holds."""
+    return "build: " + " ".join(
+        f"{k}={shlex.quote(v)}" for k, v in sorted(fp.items())
+    )
+
+
 def main(argv: list[str] | None = None) -> int:
     args = new_scheduler_command().parse_args(argv)
     config = (
@@ -362,9 +374,16 @@ def main(argv: list[str] | None = None) -> int:
 
     fp = build_fingerprint()
     gm.set_build_info(fp)
+    print(build_line(fp), flush=True)
+
+    # which snapshot-row encoder serves: the C++ extension, or the numpy
+    # loops native/__init__.py falls back to in silence (the host-side
+    # twin of running on the CPU — an operator should see it at start)
+    from .. import native as _native
+
     print(
-        "build: "
-        + " ".join(f"{k}={v}" for k, v in sorted(fp.items())),
+        f"encoder: native={int(_native.HAVE_FASTASSEMBLE)} "
+        f"pod_rows_into={int(_native.pod_rows_into is not None)}",
         flush=True,
     )
 
@@ -645,7 +664,6 @@ def main(argv: list[str] | None = None) -> int:
             # post-mortem trace: the full ring as one Perfetto-loadable
             # file (same payload as /debug/trace, taken at shutdown)
             import json
-            import os
             import time as _t
 
             from ..core.flight_recorder import to_chrome_trace
@@ -701,4 +719,18 @@ def main(argv: list[str] | None = None) -> int:
             _bb.disarm()
         if lease is not None:
             lease.release()
+        warmer = service.scheduler._warmer
+        if warmer is not None and not warmer.stop(timeout=5.0):
+            # the compile-warmer thread is inside an XLA compile, which
+            # cannot be interrupted (minutes for a 10k x 5k regime), and
+            # finalizing the interpreter under it crashes: the process
+            # died -11 AFTER a clean seal, which a supervisor reads as a
+            # crash. Everything durable is sealed and printed above, so
+            # leave without finalizing.
+            print(
+                "compile warmer still building: exiting without "
+                "interpreter teardown",
+                flush=True,
+            )
+            os._exit(0)
     return 0

@@ -16,7 +16,7 @@ or a backend wedge — ISSUE 5). Three layers attack that cost:
   program recompiles — the cache can cost a compile, never a crash.
   Where the PJRT backend cannot serialize executables, the cache
   degrades to JAX's own persistent compilation-cache directory
-  (utils/compilation_cache.py), pointed inside the same tree.
+  (utils/compilation_cache.py decides where that is).
 
 - **Cache keys** — `models/packing.shape_signature(spec)` (the named
   pad regime: every SIGNATURE_DIMS dimension) + a hash of the full
@@ -170,24 +170,18 @@ class CompileCache:
         )
         self.serialize_unsupported = False
         # fallback for backends without executable serialization: JAX's
-        # own persistent compilation cache, pointed inside this tree so
-        # the state-dir lifecycle covers it too. Only when the process
-        # has no cache dir yet — the CLI and the test conftest configure
-        # a process-wide one at startup, and re-pointing it at every
-        # Scheduler construction would cold-start the shared cache.
-        try:
-            import jax
+        # own persistent compilation cache, at the one place
+        # utils/compilation_cache.py puts it (the environment's
+        # directory, else the fixed in-checkout one) — never under this
+        # tree, whose path changes with every state dir. Only when the
+        # process has none yet: the CLI and the test conftest configure
+        # it at startup.
+        import jax
 
-            if not getattr(
-                jax.config, "jax_compilation_cache_dir", None
-            ):
-                from ..utils.compilation_cache import (
-                    enable_compilation_cache,
-                )
+        if not jax.config.jax_compilation_cache_dir:
+            from ..utils.compilation_cache import enable_compilation_cache
 
-                enable_compilation_cache(os.path.join(directory, "xla"))
-        except Exception as e:  # pragma: no cover — defensive
-            log.warning("compile cache: XLA-dir fallback unavailable: %s", e)
+            enable_compilation_cache()
 
     # ---- entry framing ---------------------------------------------------
 
@@ -339,8 +333,7 @@ class CompileCache:
             log.warning(
                 "compile cache: this backend cannot serialize "
                 "executables (%s); falling back to the JAX persistent "
-                "compilation-cache directory under %s", err,
-                os.path.join(self.dir, "xla"),
+                "compilation cache", err,
             )
 
     def status(self) -> dict:
@@ -411,33 +404,61 @@ def _avals_digest(args: tuple, kwargs: dict) -> str:
 def _args_mesh_desc(args: tuple, kwargs: dict) -> str:
     """Sharding descriptor of a call's argument layout: "none" when
     every leaf is unsharded/single-device, else a short digest over the
-    sorted set of (mesh shape, partition spec) pairs. Feeds the cache
-    key's `mesh` field so sharded and unsharded builds of one program
-    never alias a persistent entry."""
+    (mesh shape, partition spec) of EACH leaf in order ("-" for an
+    unplaced one). Feeds the cache key's `mesh` field so sharded and
+    unsharded builds of one program never alias a persistent entry —
+    and neither do two sharded builds that place different arguments:
+    an executable is compiled for its inputs' shardings and refuses
+    any other at its first call."""
     import jax
 
     leaves, _treedef = jax.tree_util.tree_flatten((args, kwargs))
-    parts: set[str] = set()
+    parts: list[str] = []
     for v in leaves:
         sh = getattr(v, "sharding", None)
+        if sh is None or len(sh.device_set) == 1:
+            parts.append("-")  # unplaced, or on one device
+            continue
         mesh = getattr(sh, "mesh", None)
-        if mesh is None:
-            continue
-        try:
-            shape = tuple(mesh.shape.items())
-        except Exception:  # schedlint: disable=RB001 -- accounting
-            # only: an exotic sharding without a dict-shaped mesh just
-            # stays out of the descriptor (the program name still
-            # disambiguates mesh-closure builds)
-            continue
-        if all(s == 1 for _a, s in shape):
-            continue  # a 1-device mesh is the unsharded layout
-        parts.add(f"{shape}|{getattr(sh, 'spec', None)!r}")
-    if not parts:
+        parts.append(
+            f"{tuple(mesh.shape.items())}|{sh.spec!r}"
+            if mesh is not None else repr(sh)  # a GSPMDSharding
+        )
+    if all(p == "-" for p in parts):
         return "none"
-    return hashlib.sha256(
-        "||".join(sorted(parts)).encode()
-    ).hexdigest()[:10]
+    return hashlib.sha256("||".join(parts).encode()).hexdigest()[:10]
+
+
+def _execution_devices(low) -> list:
+    """The devices `low` was lowered for: one for an unsharded program,
+    the mesh's (in assignment order) for a sharded one. JAX 0.9's
+    `deserialize_and_load` defaults `execution_devices` to EVERY device
+    of the backend, so on a host with more than one a one-device
+    executable would load as an N-way one and fail at its first call
+    ("Expected args to execute_sharded_on_local_devices to have N
+    shards")."""
+    return list(low._lowering._device_list)
+
+
+def _out_avals(low, compiled=None):
+    """The output aval pytree of a lowered program, for chaining the
+    next program's argument avals. A program partitioned over a mesh
+    hands its outputs on SHARDED (the carry's `sbase` lives on
+    'pods'), and a downstream executable compiled for unplaced —
+    hence replicated — inputs refuses them at its first call
+    ("compiled for input shardings that disagree"); so once the
+    executable exists its output shardings ride the avals. One-device
+    programs keep plain avals."""
+    import jax
+
+    if compiled is None or len(_execution_devices(low)) == 1:
+        return jax.tree_util.tree_map(
+            lambda o: jax.ShapeDtypeStruct(o.shape, o.dtype), low.out_info
+        )
+    return jax.tree_util.tree_map(
+        lambda o, s: jax.ShapeDtypeStruct(o.shape, o.dtype, sharding=s),
+        low.out_info, compiled.output_shardings,
+    )
 
 
 def _compile_natively(low):
@@ -517,9 +538,7 @@ def load_or_compile(
             key.name, e,
         )
         return None, "unsupported", 0.0, None
-    out_sds = jax.tree_util.tree_map(
-        lambda o: jax.ShapeDtypeStruct(o.shape, o.dtype), low.out_info
-    )
+    out_sds = _out_avals(low)
     payload = cache.load(key) if cache is not None else None
     if payload is not None:
         memo_key = (key.name, hashlib.sha256(payload).hexdigest())
@@ -528,11 +547,12 @@ def load_or_compile(
         if compiled is not None:
             dt = _time.perf_counter() - t0
             cache.note_hit(dt)
-            return compiled, "cache", dt, out_sds
+            return compiled, "cache", dt, _out_avals(low, compiled)
         try:
             _flat, in_tree = jax.tree_util.tree_flatten(low.args_info)
             compiled = _se.deserialize_and_load(
-                payload, in_tree, low.out_tree
+                payload, in_tree, low.out_tree,
+                execution_devices=_execution_devices(low),
             )
             dt = _time.perf_counter() - t0
             cache.note_hit(dt)
@@ -540,7 +560,7 @@ def load_or_compile(
                 _LOADED[memo_key] = compiled
                 while len(_LOADED) > _LOADED_CAP:
                     _LOADED.pop(next(iter(_LOADED)))
-            return compiled, "cache", dt, out_sds
+            return compiled, "cache", dt, _out_avals(low, compiled)
         except Exception as e:
             log.error(
                 "compile cache: entry %s failed to deserialize (%s); "
@@ -569,6 +589,7 @@ def load_or_compile(
         )
         return None, "unsupported", 0.0, out_sds
     dt = _time.perf_counter() - t0
+    out_sds = _out_avals(low, compiled)
     if cache is not None:
         cache.note_miss()
     if will_store:
@@ -583,7 +604,10 @@ def load_or_compile(
         # a poison entry that every later restart trips over loudly
         try:
             _flat, in_tree = jax.tree_util.tree_flatten(low.args_info)
-            _se.deserialize_and_load(data, in_tree, low.out_tree)
+            _se.deserialize_and_load(
+                data, in_tree, low.out_tree,
+                execution_devices=_execution_devices(low),
+            )
         except Exception as e:
             log.error(
                 "compile cache: NOT storing %s — freshly serialized "
@@ -649,7 +673,10 @@ class CompileWarmer:
     def _run(self) -> None:
         while not self._stop.is_set():
             try:
-                key, thunk = self._q.get(timeout=5.0)
+                job = self._q.get(timeout=5.0)
+                if job is None:  # stop()'s wake-up
+                    return
+                key, thunk = job
             except _queue.Empty:
                 # drained: exit instead of polling forever — a process
                 # that constructs many Schedulers must not accumulate
@@ -696,5 +723,18 @@ class CompileWarmer:
             _time.sleep(0.02)
         return False
 
-    def stop(self) -> None:
+    def stop(self, timeout: float = 0.0) -> bool:
+        """Refuse new jobs and wake the worker; with `timeout`, also
+        wait that long for the build it is in (an XLA compile cannot be
+        interrupted). False while the worker is still building — the
+        caller must then not finalize the interpreter under it
+        (cmd/main.py)."""
         self._stop.set()
+        with self._lock:
+            thread = self._thread
+            self._q.put(None)  # wake a worker blocked on the empty queue
+        if thread is None:
+            return True
+        if timeout > 0:
+            thread.join(timeout)
+        return not thread.is_alive()

@@ -94,6 +94,59 @@ def _pad(n: int, bucket: int = 64) -> int:
     return ((n + bucket - 1) // bucket) * bucket
 
 
+def aot_chain(
+    spec, one, *, cyc, preempt, stable_fn, keeper, diag, placement=None,
+) -> bool:
+    """Walk one regime's programs in dependency order, handing each to
+    `one(kind, fn, args, kwargs) -> out_sds | None` with argument avals
+    derived from the spec alone (the two packed buffers) plus each
+    upstream program's output avals — no device work. `_aot_install`
+    passes a `one` that loads-or-compiles and installs;
+    tests/test_tpu_compile.py passes one that compiles the same chain
+    for a described (not attached) chip. `placement` is the sharding
+    the host-made inputs arrive under (parallel/mesh.replicated when
+    serving sharded, None on one device); every other aval follows from
+    its producer. False when a program the rest of the chain depends on
+    was refused."""
+    import jax
+
+    def host(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=placement)
+
+    w = host((spec.n_words,), np.uint32)
+    b = host((spec.n_bytes,), np.uint8)
+    stable_sds = one("stable", stable_fn, (w, b), None)
+    if stable_sds is None:
+        return False
+    if keeper is not None:
+        carry_sds = one("carry_init", keeper.ci, (w, b, stable_sds), None)
+        if carry_sds is None:
+            return False
+        out_sds = one("cycle", cyc, (w, b, stable_sds, carry_sds), None)
+        idx_sds = host((keeper.bucket,), np.int32)
+        one(
+            "carry_update", keeper._cu(keeper.bucket),
+            (w, b, stable_sds, carry_sds, idx_sds), None,
+        )
+    else:
+        out_sds = one("cycle", cyc, (w, b, stable_sds), None)
+    if out_sds is not None and preempt is not None:
+        one("preempt", preempt, (w, b, out_sds, stable_sds), None)
+    if out_sds is not None and diag is not None:
+        kwargs = {}
+        pv = getattr(out_sds, "pv_claimed", None)
+        if pv is not None:
+            # matches CycleHandle.dispatch_diagnosis's convention
+            kwargs["pv_claimed"] = pv
+        one(
+            "diag", diag,
+            (w, b, stable_sds, out_sds.assignment,
+             out_sds.node_requested),
+            kwargs,
+        )
+    return True
+
+
 class Scheduler:
     def __init__(
         self,
@@ -417,6 +470,11 @@ class Scheduler:
                     f"({pad_bucket}) so sharded arrays split evenly"
                 )
             self._mesh = make_mesh(_jax.devices()[:d])
+        # where host-made inputs (the packed buffers) go: replicated
+        # over the mesh when serving sharded, unplaced on one device
+        from ..parallel.mesh import replicated
+
+        self._host_placement = replicated(self._mesh)
         self.n_devices = d if d > 1 else 1
         self.metrics.shard_devices.set(self.n_devices)
         # per-profile collective payload (bytes/cycle) of the current
@@ -631,17 +689,12 @@ class Scheduler:
         so no device work happens here. Returns "cache" when EVERY
         program loaded from disk, "cold" when any compiled here, None
         when AOT was impossible (the plain jit path remains)."""
-        import jax
 
         from . import compile_cache as cc
 
-        w = jax.ShapeDtypeStruct((spec.n_words,), np.uint32)
-        b = jax.ShapeDtypeStruct((spec.n_bytes,), np.uint8)
         sources: list[str] = []
 
-        def one(kind, fn, args, kwargs=None):
-            if fn is None:
-                return None
+        def one(kind, fn, args, kwargs):
             compiled, source, _dt, out_sds = cc.load_or_compile(
                 fn, self._compile_cache, spec, profile, kind,
                 args=args, kwargs=kwargs,
@@ -654,35 +707,11 @@ class Scheduler:
                 self._probe_payload(profile, compiled)
             return out_sds
 
-        stable_sds = one("stable", stable_fn, (w, b))
-        if stable_sds is None:
+        if not aot_chain(
+            spec, one, cyc=cyc, preempt=preempt, stable_fn=stable_fn,
+            keeper=keeper, diag=diag, placement=self._host_placement,
+        ):
             return None
-        if keeper is not None:
-            carry_sds = one("carry_init", keeper.ci, (w, b, stable_sds))
-            if carry_sds is None:
-                return None
-            out_sds = one("cycle", cyc, (w, b, stable_sds, carry_sds))
-            idx_sds = jax.ShapeDtypeStruct((keeper.bucket,), np.int32)
-            one(
-                "carry_update", keeper._cu(keeper.bucket),
-                (w, b, stable_sds, carry_sds, idx_sds),
-            )
-        else:
-            out_sds = one("cycle", cyc, (w, b, stable_sds))
-        if out_sds is not None and preempt is not None:
-            one("preempt", preempt, (w, b, out_sds, stable_sds))
-        if out_sds is not None and diag is not None:
-            kwargs = {}
-            pv = getattr(out_sds, "pv_claimed", None)
-            if pv is not None:
-                # matches CycleHandle.dispatch_diagnosis's convention
-                kwargs["pv_claimed"] = pv
-            one(
-                "diag", diag,
-                (w, b, stable_sds, out_sds.assignment,
-                 out_sds.node_requested),
-                kwargs,
-            )
         if not sources:
             return None
         return "cache" if all(s == "cache" for s in sources) else "cold"
@@ -1179,12 +1208,15 @@ class Scheduler:
             mut.clear()
             # ONE host->device upload per cycle (device_put copies the
             # arena synchronously); numpy args would re-upload the packed
-            # buffers once per program in the chain below
+            # buffers once per program in the chain below. Sharded
+            # serving uploads them replicated over the mesh, so no
+            # program's partitioning is left to parameter propagation
+            # (parallel/mesh.replicated)
             if do_device_put:
                 import jax as _jax
 
-                wbuf = _jax.device_put(wbuf)
-                bbuf = _jax.device_put(bbuf)
+                wbuf = _jax.device_put(wbuf, self._host_placement)
+                bbuf = _jax.device_put(bbuf, self._host_placement)
             (
                 pcycle, ppreempt, stable_fn, keeper, diag, ext_keeper,
                 pipe,

@@ -187,11 +187,13 @@ cmd/main.py startup stamp):
   core/spans.SPAN_NAMES, machine-checked by schedlint ID010 against
   this docstring and the README span table); spans serve at
   /debug/traces and join /debug/explain verdicts
-- scheduler_build_info{python,jax,jaxlib,backend,git} — constant 1
-  gauge carrying the process's build/runtime fingerprint as labels,
-  set once at startup so dashboards can correlate latency shifts with
-  binary or runtime changes; bench headline artifacts carry the same
-  stamp (build_fingerprint())
+- scheduler_build_info{python,jax,jaxlib,backend,platform,device_kind,
+  device_count,git} — constant 1 gauge carrying the process's
+  build/runtime fingerprint as labels (platform/device_kind/
+  device_count are the devices THIS process holds, as jax reports
+  them), set once at startup so dashboards can correlate latency
+  shifts with binary or runtime changes; bench headline artifacts
+  carry the same stamp (build_fingerprint())
 - scheduler_uptime_seconds — seconds since SchedulerMetrics
   construction (process start for the CLI), evaluated at scrape time;
   joins build_info so restart storms are visible without log access
@@ -613,9 +615,13 @@ class SchedulerMetrics:
         self.build_info = Gauge(
             "scheduler_build_info",
             "Constant 1 gauge carrying the build/runtime fingerprint "
-            "as labels (python | jax | jaxlib | backend | git), set "
-            "once at startup (build_fingerprint()).",
-            ["python", "jax", "jaxlib", "backend", "git"],
+            "as labels (python | jax | jaxlib | backend | platform | "
+            "device_kind | device_count | git), set once at startup "
+            "(build_fingerprint()).",
+            [
+                "python", "jax", "jaxlib", "backend", "platform",
+                "device_kind", "device_count", "git",
+            ],
             registry=r,
         )
         self.uptime = Gauge(
@@ -797,9 +803,12 @@ class SchedulerMetrics:
 def build_fingerprint() -> dict[str, str]:
     """Best-effort build/runtime identity for scheduler_build_info and
     bench headline stamps: python/jax/jaxlib versions, the JAX backend
-    actually serving cycles, and `git describe` of the working tree.
-    Every probe degrades to a placeholder — this must never fail in a
-    wheel install without git or on a box without jax.
+    actually serving cycles with the devices this process holds
+    (platform, device_kind and count as `jax.devices()` reports them —
+    calling this initialises the backend, so only the process that is
+    meant to hold the chip calls it), and `git describe` of the working
+    tree. Every probe degrades to a placeholder — this must never fail
+    in a wheel install without git or on a box without jax.
     """
     import platform
 
@@ -808,6 +817,9 @@ def build_fingerprint() -> dict[str, str]:
         "jax": "unavailable",
         "jaxlib": "unavailable",
         "backend": "unavailable",
+        "platform": "unavailable",
+        "device_kind": "unavailable",
+        "device_count": "0",
         "git": "unknown",
     }
     try:  # schedlint: disable=RB001 -- identity probe, never load-bearing
@@ -815,6 +827,10 @@ def build_fingerprint() -> dict[str, str]:
 
         info["jax"] = str(getattr(jax, "__version__", "unknown"))
         info["backend"] = str(jax.default_backend())
+        devices = jax.devices()
+        info["platform"] = str(devices[0].platform)
+        info["device_kind"] = str(devices[0].device_kind)
+        info["device_count"] = str(len(devices))
     except Exception:  # schedlint: disable=RB001 -- jax optional here
         pass
     try:  # schedlint: disable=RB001 -- identity probe, never load-bearing

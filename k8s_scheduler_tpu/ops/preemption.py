@@ -84,6 +84,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..models import encoding as enc
 from . import argsel
@@ -96,7 +97,7 @@ DEFAULT_BUDGET = 256
 DEFAULT_SCAN_BUDGET = 64
 
 _REL_EPS = 1e-5
-_BIG_I32 = jnp.int32(2**31 - 1)
+_BIG_I32 = np.int32(2**31 - 1)  # numpy: no backend init at import
 
 
 @jax.tree_util.register_dataclass

@@ -78,7 +78,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+import numpy as np
 from jax.sharding import PartitionSpec
 
 from ..models import encoding as enc
@@ -96,9 +96,12 @@ MS_MATCH = 4  # guard-active selectors tracked per pod (overflow = defer)
 # herded every pod's claim onto the same argmax node; integer classes let
 # the per-pod hash spread contending claims across all equally-good nodes.
 TIE_EPS = 0.9375  # hash spread, strictly below the integer quantum
-_PR1 = jnp.uint32(2654435761)
-_PR2 = jnp.uint32(40503)
-_BIG = jnp.int32(2**31 - 1)
+# numpy scalars, not jnp: a jnp scalar is a device array, and making one
+# at import initialises a backend in EVERY process that imports this
+# package — a plain gRPC client (service/client.py) would take the chip
+_PR1 = np.uint32(2654435761)
+_PR2 = np.uint32(40503)
+_BIG = np.int32(2**31 - 1)
 
 # participant role bits (packed into one sort operand)
 _RB_MATCH = 1
@@ -322,11 +325,11 @@ def rounds_commit(
         update, zero collectives inside."""
         if mesh is None:
             return fn
-        return shard_map(
+        return jax.shard_map(
             fn, mesh=mesh,
             in_specs=(PartitionSpec(),) * 5,  # schedlint: disable=SH003 -- shard_map plumbing: the EMPTY spec (replicated) carries no layout rule, it marks these inputs as not-mesh_pin's-business
             out_specs=PartitionSpec(),  # schedlint: disable=SH003 -- same replicated shard_map plumbing as the line above
-            check_rep=False,
+            check_vma=False,
         )
 
     slack = _REL_EPS * snap.node_allocatable + _REL_EPS  # [N, R]

@@ -150,6 +150,11 @@ class SchedulerClient:
 # bind_applier(pod_uid, pod_name, namespace, node_name) -> None; raise = failed
 BindApplier = Callable[[str, str, str, str], None]
 
+# an open batched() request is sent on once it holds this much: the
+# server keeps gRPC's default 4 MiB receive limit (service/server.py
+# sets none), and a 10k-pod re-list or bind confirmation is larger
+MAX_UPDATE_BYTES = 3 * 1024 * 1024
+
 
 class SchedulerAgent:
     """Mirrors cluster objects into the shim and applies its decisions.
@@ -160,8 +165,13 @@ class SchedulerAgent:
 
     def __init__(self, client: SchedulerClient, bind_applier: BindApplier,
                  evict_applier: Callable[[str, str], None] | None = None,
-                 event_applier: Callable[["pb.Event"], None] | None = None) -> None:
+                 event_applier: Callable[["pb.Event"], None] | None = None,
+                 cycle_timeout: float = 120.0) -> None:
         self.client = client
+        # a regime's first Cycle compiles its programs inside the RPC
+        # (minutes for a 10k x 5k cluster on a cold cache): callers that
+        # serve such a cluster raise this
+        self.cycle_timeout = cycle_timeout
         self.bind_applier = bind_applier
         self.evict_applier = evict_applier or (lambda uid, node: None)
         # posts each drained scheduler event as a Kubernetes Event
@@ -178,6 +188,7 @@ class SchedulerAgent:
         self._pending_failures: list[str] = []
         self._boot_id: str | None = None  # shim incarnation last fed state
         self._batch: pb.UpdateRequest | None = None  # open batched() request
+        self._batch_bytes = 0
 
     # ---- informer-side entry points -------------------------------------
 
@@ -264,33 +275,36 @@ class SchedulerAgent:
         if self._pending_failures:
             self._send(pb.UpdateRequest(bind_failures=self._pending_failures))
             self._pending_failures = []
-        resp = self._with_recovery(self.client.cycle)
+        def cycle():
+            return self.client.cycle(timeout=self.cycle_timeout)
+
+        resp = self._with_recovery(cycle)
         if self._boot_changed(resp.boot_id):
             # the shim restarted since we fed it state and the cycle ran
             # against an empty cache — replay everything and re-run
             self.relist()
-            resp = self._with_recovery(self.client.cycle)
-        confirmed = pb.UpdateRequest()
-        for b in resp.bindings:
-            try:
-                self.bind_applier(
-                    b.pod_uid, b.pod_name, b.pod_namespace, b.node_name
-                )
-            except Exception:
-                self._pending_failures.append(b.pod_uid)
-                continue
-            pod, _ = self._pods.get(b.pod_uid, (None, ""))
-            if pod is not None:
-                self._pods[b.pod_uid] = (pod, b.node_name)
-                confirmed.pod_updates.append(
-                    pb.PodEvent(pod=convert.pod_to(pod), bound_node=b.node_name)
-                )
+            resp = self._with_recovery(cycle)
+        with self.batched():  # the confirmations, in requests that fit
+            for b in resp.bindings:
+                try:
+                    self.bind_applier(
+                        b.pod_uid, b.pod_name, b.pod_namespace, b.node_name
+                    )
+                except Exception:
+                    self._pending_failures.append(b.pod_uid)
+                    continue
+                pod, _ = self._pods.get(b.pod_uid, (None, ""))
+                if pod is not None:
+                    self._pods[b.pod_uid] = (pod, b.node_name)
+                    self._send(pb.UpdateRequest(pod_updates=[
+                        pb.PodEvent(
+                            pod=convert.pod_to(pod), bound_node=b.node_name
+                        )
+                    ]))
         for ev in resp.evictions:
             self.evict_applier(ev.pod_uid, ev.node_name)
         for ev in resp.events:
             self.event_applier(ev)
-        if confirmed.pod_updates:
-            self._send(confirmed)
         return resp
 
     # ---- transport + recovery -------------------------------------------
@@ -307,25 +321,34 @@ class SchedulerAgent:
 
     @contextlib.contextmanager
     def batched(self) -> Iterator[None]:
-        """Coalesce every upsert/delete inside the block into ONE Update
-        RPC — the informer re-list path would otherwise pay one round-trip
-        per object (10k pods = 10k RPCs). Nesting reuses the open batch."""
+        """Coalesce the upserts/deletes inside the block into as few
+        Update RPCs as fit the server's message limit (one, up to
+        MAX_UPDATE_BYTES) — the informer re-list path would otherwise pay
+        one round-trip per object (10k pods = 10k RPCs). Nesting reuses
+        the open batch."""
         if self._batch is not None:
             yield
             return
-        self._batch = pb.UpdateRequest()
+        self._batch, self._batch_bytes = pb.UpdateRequest(), 0
         try:
             yield
-            batch, self._batch = self._batch, None
-            if batch.SerializeToString():
-                self._send(batch)
+            if self._batch.ByteSize():
+                self._send_now(self._batch)
         finally:
             self._batch = None
 
     def _send(self, request: pb.UpdateRequest) -> None:
-        if self._batch is not None:
-            self._batch.MergeFrom(request)
+        if self._batch is None:
+            self._send_now(request)
             return
+        self._batch.MergeFrom(request)
+        self._batch_bytes += request.ByteSize()
+        if self._batch_bytes >= MAX_UPDATE_BYTES:
+            full = self._batch
+            self._batch, self._batch_bytes = pb.UpdateRequest(), 0
+            self._send_now(full)
+
+    def _send_now(self, request: pb.UpdateRequest) -> None:
         resp = self._with_recovery(lambda: self.client.update(request))
         if self._boot_changed(resp.boot_id):
             # state before this delta is gone: replay everything (the delta

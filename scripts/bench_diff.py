@@ -3,7 +3,7 @@
 regressions — the CI tripwire the perf rounds read instead of eyeballing
 JSON blobs.
 
-    python scripts/bench_diff.py BENCH_r04.json BENCH_r05.json
+    python scripts/bench_diff.py BENCH_old.json BENCH_new.json
     python scripts/bench_diff.py --json old.json new.json
     python scripts/bench_diff.py --max-p50-rise 10 old.json new.json
 

@@ -381,3 +381,118 @@ def test_lease_release_joins_the_renewer(tmp_path):
     before = os.stat(path).st_mtime_ns
     _t.sleep(0.15)
     assert os.stat(path).st_mtime_ns == before
+
+
+def _start_cli(tmp_path, *extra):
+    """`python -m k8s_scheduler_tpu` as a child on ephemeral ports;
+    returns (proc, lines printed up to the http line, http port)."""
+    import subprocess
+    import sys
+
+    proc = subprocess.Popen(
+        [
+            sys.executable, "-m", "k8s_scheduler_tpu",
+            "--address", "127.0.0.1:0", "--http-port", "0",
+            "--state-dir", str(tmp_path / "state"), *extra,
+        ],
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
+    )
+    lines = []
+    for ln in proc.stdout:
+        lines.append(ln.rstrip("\n"))
+        if ln.startswith("serving /healthz /metrics on port "):
+            return proc, lines, int(ln.rsplit(" ", 1)[1])
+    proc.wait()
+    raise AssertionError(
+        f"CLI exited {proc.returncode} before serving:\n" + "\n".join(lines)
+    )
+
+
+def test_cli_build_line_and_build_info_name_the_device(tmp_path):
+    """ISSUE 22: the `build:` line and `scheduler_build_info` carry the
+    platform, device kind and device count of the process that holds
+    the devices — where `chip_smoke.py` reads the device from (its
+    parent never asks JAX) — the `encoder:` line says which snapshot-row
+    encoder serves, and SIGTERM exits 0 with the state sealed."""
+    import shlex
+    import signal
+
+    import jax
+
+    proc, lines, port = _start_cli(tmp_path)
+    try:
+        build = next(ln for ln in lines if ln.startswith("build: "))
+        fields = dict(
+            kv.split("=", 1) for kv in shlex.split(build[len("build: "):])
+        )
+        dev = jax.devices()[0]
+        assert fields["platform"] == dev.platform == "cpu"
+        assert fields["device_kind"] == dev.device_kind
+        assert int(fields["device_count"]) == len(jax.devices())
+        assert fields["backend"] == "cpu" and fields["jax"] == jax.__version__
+        assert any(ln.startswith("encoder: native=") for ln in lines)
+        with urllib.request.urlopen(
+            f"http://127.0.0.1:{port}/metrics"
+        ) as r:
+            info = next(
+                ln for ln in r.read().decode().splitlines()
+                if ln.startswith("scheduler_build_info{")
+            )
+        for key in ("platform", "device_kind", "device_count"):
+            assert f'{key}="{fields[key]}"' in info, info
+    finally:
+        proc.send_signal(signal.SIGTERM)
+        tail = proc.communicate(timeout=60)[0]
+    assert proc.returncode == 0, tail
+    assert "durable state sealed" in tail
+
+
+def test_build_line_survives_a_device_kind_with_spaces():
+    """device_kind is "TPU v5 lite" on the chip: the line's values are
+    shell-quoted so it still splits back into k=v fields."""
+    import shlex
+
+    from k8s_scheduler_tpu.cmd.main import build_line
+
+    fp = {"device_kind": "TPU v5 lite", "platform": "tpu", "git": "x"}
+    line = build_line(fp)
+    assert line.startswith("build: ")
+    fields = shlex.split(line[len("build: "):])
+    assert dict(kv.split("=", 1) for kv in fields) == fp
+
+
+@pytest.mark.slow
+def test_chip_smoke_rehearsal(tmp_path):
+    """`chip_smoke.py --rehearse` end to end on the CPU at the cut
+    size: both phases pass their checks, the last line is the
+    contract's shape with "ok": false (a rehearsal is never reported
+    as a chip run) — and WITHOUT --rehearse a CPU server is refused,
+    with no contract line."""
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jc"))
+    env.pop("XLA_FLAGS", None)  # one CPU device, as the driver's sandbox
+    out = subprocess.run(
+        [sys.executable, "chip_smoke.py", "--rehearse"], cwd=root, env=env,
+        text=True, capture_output=True, timeout=900,
+    )
+    assert out.returncode == 0, out.stdout + out.stderr
+    rows = [json.loads(ln) for ln in out.stdout.splitlines()]
+    assert rows[-1] == {
+        "ok": False,
+        "device": {"platform": "cpu", "kind": "cpu", "count": 1},
+    }
+    phases = {r["phase"]: r for r in rows if "phase" in r}
+    assert phases["small"]["validator_violations"] == 0
+    assert phases["small"]["first_cycle"]["bound"] == 1000
+    assert phases["full"]["ladder"] == "normal"
+    assert rows[-2]["entries_after"]["aot"] > 0
+    refused = subprocess.run(
+        [sys.executable, "chip_smoke.py"], cwd=root, env=env,
+        text=True, capture_output=True, timeout=300,
+    )
+    assert refused.returncode != 0
+    assert "not a TPU" in refused.stderr and '"ok"' not in refused.stdout

@@ -80,6 +80,125 @@ def test_load_or_compile_roundtrip(tmp_path):
     assert np.asarray(comp2(w, b)["s"]) == first
 
 
+def test_stored_one_device_executable_loads_on_a_multi_device_host(
+    tmp_path,
+):
+    """The JAX 0.9.0 fault, pinned (ISSUE 22): `deserialize_and_load`
+    defaults `execution_devices` to EVERY device of the backend, so on
+    a host with more than one — the 8-device test platform, a four-chip
+    TPU host — a stored one-device executable loaded as an N-way one
+    and failed at its first CALL ("Expected args to
+    execute_sharded_on_local_devices to have 8 shards, got: [1, 1]"),
+    which `_Resilient` and the ladder would then have served around on
+    every warm restart."""
+    assert len(jax.devices()) > 1, "conftest forces 8 virtual devices"
+    spec = _tiny_spec()
+    fn = _fresh_fn("onedev")
+    cc.load_or_compile(
+        fn, cc.CompileCache(str(tmp_path)), spec, "default", "cycle",
+        args=_ARGS,
+    )
+    cc.clear_loaded_memo()  # the next load REALLY deserializes
+    loaded, source, _dt, _out = cc.load_or_compile(
+        _fresh_fn("onedev"), cc.CompileCache(str(tmp_path)), spec,
+        "default", "cycle", args=_ARGS,
+    )
+    assert source == "cache"
+    w = np.arange(16, dtype=np.uint32)
+    b = np.ones(8, np.uint8)
+    out = loaded(w, b)
+    assert int(out["s"]) == int(w.sum()) + 8 and int(out["n"]) == 8
+    assert len(out["s"].sharding.device_set) == 1
+
+
+def test_sharded_program_outputs_chain_sharded_avals(tmp_path):
+    """A program partitioned over a mesh hands its outputs on sharded;
+    the avals `load_or_compile` returns for chaining must say so, or
+    the downstream executable is compiled for replicated inputs and
+    refuses the real (sharded) carry at its first call — found by
+    `chip_smoke.py --chips 4`'s rehearsal, where the ladder absorbed
+    it. Cold and loaded executables must agree, and the loaded one
+    must run on the mesh's devices, not the whole backend's."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    mesh = Mesh(np.array(jax.devices()[:4]), ("pods",))
+    rows = NamedSharding(mesh, PartitionSpec("pods"))
+
+    def make(name):
+        return _jit(
+            lambda x: jax.lax.with_sharding_constraint(x * 2.0, rows),
+            name, disc="sh",
+        )
+
+    spec = _tiny_spec()
+    x_sds = jax.ShapeDtypeStruct((8, 4), np.float32, sharding=rows)
+    _c, source, _dt, cold_out = cc.load_or_compile(
+        make("cc_shard_a"), cc.CompileCache(str(tmp_path)), spec,
+        "default", "carry_init", args=(x_sds,),
+    )
+    assert source == "cold" and cold_out.sharding.is_equivalent_to(rows, 2)
+    cc.clear_loaded_memo()
+    loaded, source, _dt, out = cc.load_or_compile(
+        make("cc_shard_a"), cc.CompileCache(str(tmp_path)), spec,
+        "default", "carry_init", args=(x_sds,),
+    )
+    assert source == "cache" and out.sharding.is_equivalent_to(rows, 2)
+    # the downstream program, compiled against the chained aval, takes
+    # the upstream's real output
+    down, _s, _dt, _o = cc.load_or_compile(
+        make("cc_shard_b"), cc.CompileCache(str(tmp_path)), spec,
+        "default", "cycle", args=(out,),
+    )
+    x = jax.device_put(np.ones((8, 4), np.float32), rows)
+    y = down(loaded(x))
+    assert float(y.sum()) == 8 * 4 * 4.0
+    assert y.sharding.device_set == set(mesh.devices.flat)
+
+
+@pytest.mark.parametrize("placed", ["environment", "unset", "disabled"])
+def test_persistent_cache_is_placed_from_outside(tmp_path, placed):
+    """`JAX_COMPILATION_CACHE_DIR` set: the cache is exactly that
+    directory (no per-backend subdirectory, nothing under ~). Unset: the
+    one fixed directory inside the checkout. Checked in a child, whose
+    environment is what places it — and which must not have initialised
+    a backend by asking."""
+    import os
+    import subprocess
+    import sys
+
+    from k8s_scheduler_tpu.utils import compilation_cache as ucc
+
+    env = {
+        k: v for k, v in os.environ.items()
+        if k not in ("JAX_COMPILATION_CACHE_DIR",
+                     "K8S_TPU_DISABLE_COMPILE_CACHE")
+    }
+    want = {"environment": str(tmp_path / "placed"),
+            "unset": ucc.DEFAULT_CACHE_DIR, "disabled": ""}[placed]
+    if placed == "environment":
+        env["JAX_COMPILATION_CACHE_DIR"] = want
+    if placed == "disabled":
+        env["K8S_TPU_DISABLE_COMPILE_CACHE"] = "1"
+    code = (
+        "import jax\n"
+        "from jax._src import xla_bridge\n"
+        "from k8s_scheduler_tpu.utils.compilation_cache import "
+        "enable_compilation_cache\n"
+        "d = enable_compilation_cache()\n"
+        "assert not xla_bridge.backends_are_initialized()\n"
+        "print(repr((d, jax.config.jax_compilation_cache_dir or '')))\n"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], env=env, text=True, check=True,
+        capture_output=True,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    )
+    assert out.stdout.strip() == repr((want, want))
+    assert os.path.dirname(ucc.DEFAULT_CACHE_DIR) == os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))
+    )
+
+
 def _entry_path(tmp_path):
     files = [p for p in tmp_path.iterdir() if p.name.endswith(".kscc")]
     assert len(files) == 1
@@ -520,3 +639,28 @@ def test_regime_churn_soak_zero_compile_stalls(tmp_path, monkeypatch):
     assert r["hysteresis_flips"] == 1  # held after the first up-step
     assert r["warm_sources"] in ([], ["cache"])
     assert r["compile_seconds"] > r["warm_compile_seconds"]
+
+
+def test_warmer_stop_wakes_idle_worker_and_reports_a_running_build():
+    """`stop(timeout)` is what lets cmd/main.py exit cleanly on SIGTERM:
+    an idle worker is woken and gone at once (True); a worker inside a
+    build — an XLA compile cannot be interrupted — reports False, and
+    the CLI then leaves without finalizing the interpreter under it
+    (the process used to die -11 after sealing its state)."""
+    w = cc.CompileWarmer()
+    assert w.stop(timeout=1.0)  # never started: nothing to wait for
+    w = cc.CompileWarmer()
+    assert w.enqueue_build("quick", lambda: None)
+    assert w.join(10)
+    t0 = time.monotonic()
+    assert w.stop(timeout=10.0)  # idle in q.get(): the sentinel wakes it
+    assert time.monotonic() - t0 < 4.0
+    assert not w.enqueue_build("late", lambda: None)  # stopped: refused
+
+    release = threading.Event()
+    w = cc.CompileWarmer()
+    assert w.enqueue_build("slow", lambda: release.wait(30))
+    time.sleep(0.1)
+    assert not w.stop(timeout=0.2)  # still inside the build
+    release.set()
+    assert w.stop(timeout=10.0)

@@ -352,11 +352,24 @@ def test_bench_diff_gates_sharded_metrics(tmp_path):
         c["metric"] for c in json.loads(reg.stdout)["regressions"]
     }
     assert {"scaling_efficiency", "collective_payload_mb"} <= regressed
-    # backward compatibility: an r05 artifact (no config 8 rows) diffs
+    # backward compatibility: a driver-wrapped round artifact of the
+    # r05 shape ({"tail", "parsed"}, compact rows, no config 8) diffs
     # clean against a new artifact that has them
-    r05 = os.path.join(_REPO, "BENCH_r05.json")
+    r05 = tmp_path / "r05_shape.json"
+    r05.write_text(json.dumps({
+        "n": 5, "cmd": "python bench.py", "rc": 0, "tail": "",
+        "parsed": {
+            "metric": "pod_node_scoring_decisions_per_sec",
+            "value": 304515887.5, "device": "tpu", "errors": [],
+            "configs": [
+                {"c": 4, "dps": 304515888, "p50": 461.9, "p99": 723.9,
+                 "dev": 147.4, "enc": 66.5, "sched": 3313,
+                 "unsched": 6686},
+            ],
+        },
+    }))
     back = subprocess.run(
-        [sys.executable, diff, r05, str(new)],
+        [sys.executable, diff, str(r05), str(new)],
         capture_output=True, text=True,
     )
     assert back.returncode == 0, back.stdout + back.stderr
