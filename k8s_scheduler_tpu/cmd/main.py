@@ -411,6 +411,28 @@ def main(argv: list[str] | None = None) -> int:
             metrics=gm,
         )
 
+    # tracing: armed BEFORE either gRPC server starts, so the first
+    # Update and the first submission can be traced. The rate samples
+    # pods (Submit is where a pod's trace begins); on the agent path
+    # every Update and Cycle RPC is one trace, per RPC and per phase —
+    # about a dozen spans per loop iteration, never one per pod.
+    spans_recorder = None
+    if config.trace_sample_rate > 0:
+        from ..core import spans as _spans
+
+        spans_recorder = _spans.arm(
+            rate=config.trace_sample_rate,
+            counter=(
+                lambda name: gm.trace_spans.labels(name=name).inc()
+            ),
+        )
+        print(
+            "tracing armed: sample rate "
+            f"{config.trace_sample_rate:g} "
+            "(/debug/traces, /debug/explain)",
+            flush=True,
+        )
+
     server, service, port = serve(
         args.address,
         config=config,
@@ -428,7 +450,6 @@ def main(argv: list[str] | None = None) -> int:
     # serialized against any stray Cycle RPC by the service cycle lock.
     front_door = None
     submit_server = None
-    spans_recorder = None
     if args.submit_addr:
         from concurrent import futures as _futures
 
@@ -436,26 +457,6 @@ def main(argv: list[str] | None = None) -> int:
 
         from ..service.admission import self_confirming_front_door
         from ..service.server import add_to_server
-
-        # pod-lifecycle tracing: armed BEFORE the front door starts so
-        # the very first submission can be sampled. Only the front-door
-        # path mints trace contexts (Submit is where a pod's lifecycle
-        # begins), so agent-driven runs skip the armed cost entirely.
-        if config.trace_sample_rate > 0:
-            from ..core import spans as _spans
-
-            spans_recorder = _spans.arm(
-                rate=config.trace_sample_rate,
-                counter=(
-                    lambda name: gm.trace_spans.labels(name=name).inc()
-                ),
-            )
-            print(
-                "tracing armed: sample rate "
-                f"{config.trace_sample_rate:g} "
-                "(/debug/traces, /debug/explain)",
-                flush=True,
-            )
 
         admission = service.enable_front_door()
         submit_server = _grpc.server(

@@ -281,6 +281,11 @@ class FlightRecorder:
 
     # ---- writer side (scheduling loop only) ------------------------------
 
+    @property
+    def next_seq(self) -> int:
+        """The seq the next started record takes."""
+        return self._seq
+
     def start(self, profile: str = "default-scheduler") -> CycleRecord:
         rec = CycleRecord(
             seq=self._seq,

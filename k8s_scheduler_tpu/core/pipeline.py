@@ -58,6 +58,7 @@ change, not a semantic one).
 
 from __future__ import annotations
 
+import contextlib as _contextlib
 import threading as _threading
 import time as _time
 
@@ -68,6 +69,25 @@ import numpy as np
 from . import faults as _faults
 from . import spans as _spans
 from .cycle import CycleDecision, _jit
+
+
+def _dispatch_anchor(anchor, now):
+    """The one thing this program writes into a profiler trace: a
+    `sched.dispatch` host event around the dispatch call, carrying the
+    flight record's `seq` and `t_us`, the pipeline clock (the recorder's
+    and the span ring's: perf_counter) read immediately before it, in
+    microseconds since the recorder's epoch. The event's own start is
+    on the profiler's clock, so one of them gives the offset between
+    that clock and the clock of every span and flight mark, in the same
+    `.xplane.pb` as the device operations. With no profiler session it
+    is an inactive TraceMe; with no `anchor` (tracing unarmed) no
+    annotation object is made."""
+    if anchor is None:
+        return _contextlib.nullcontext()
+    seq, epoch = anchor
+    return jax.profiler.TraceAnnotation(
+        "sched.dispatch", seq=seq, t_us=int((now() - epoch) * 1e6)
+    )
 
 
 class DispatchDeadlineExceeded(RuntimeError):
@@ -914,6 +934,7 @@ class ServingPipeline:
         emask=None,
         escore=None,
         device_put: bool = True,
+        anchor=None,
     ) -> CycleHandle:
         """Upload + dispatch one cycle; returns immediately with a
         CycleHandle (unless forced_sync). Raises if the previous cycle's
@@ -947,18 +968,19 @@ class ServingPipeline:
             # programs would race the next encode's arena rewrite
             # (see _cpu_safe_buffers)
             wbuf, bbuf = _cpu_safe_buffers(wbuf, bbuf)
-        if self._keeper is not None:
-            carry = self._keeper.state(
-                wbuf, bbuf, stable, dirty, carry_key, pin=pin
-            )
-            if emask is not None:
-                result = self._cycle_fn(
-                    wbuf, bbuf, stable, carry, emask, escore
+        with _dispatch_anchor(anchor, self._now):
+            if self._keeper is not None:
+                carry = self._keeper.state(
+                    wbuf, bbuf, stable, dirty, carry_key, pin=pin
                 )
+                if emask is not None:
+                    result = self._cycle_fn(
+                        wbuf, bbuf, stable, carry, emask, escore
+                    )
+                else:
+                    result = self._cycle_fn(wbuf, bbuf, stable, carry)
             else:
-                result = self._cycle_fn(wbuf, bbuf, stable, carry)
-        else:
-            result = self._cycle_fn(wbuf, bbuf, stable)
+                result = self._cycle_fn(wbuf, bbuf, stable)
         if self._slim_fn is None:
             self._slim_fn = build_decision_slim_fn(
                 result.node_requested.shape[0]
@@ -1009,6 +1031,7 @@ class ServingPipeline:
         device_put: bool = True,
         carry0=None,
         speculative: bool = False,
+        anchor=None,
     ) -> MultiCycleHandle:
         """Upload + dispatch one MULTI-CYCLE batch (stacked [K, ...]
         packed snapshots, one device dispatch for up to `n_cycles` inner
@@ -1067,12 +1090,13 @@ class ServingPipeline:
             bbufs = jax.device_put(bbufs)
         else:
             wbufs, bbufs = _cpu_safe_buffers(wbufs, bbufs)
-        if carry0 is not None:
-            result = fn(
-                wbufs, bbufs, stable, np.int32(n_cycles), *carry0
-            )
-        else:
-            result = fn(wbufs, bbufs, stable, np.int32(n_cycles))
+        with _dispatch_anchor(anchor, self._now):
+            if carry0 is not None:
+                result = fn(
+                    wbufs, bbufs, stable, np.int32(n_cycles), *carry0
+                )
+            else:
+                result = fn(wbufs, bbufs, stable, np.int32(n_cycles))
         if self._multi_slim_fn is None:
             self._multi_slim_fn = build_multicycle_slim_rows_fn(
                 result.node_requested.shape[1],

@@ -13,9 +13,10 @@ plus a per-plugin decision-log report (feasible fraction per Filter, score
 stats per Score) — the per-plugin mask statistics from SURVEY.md §5.5.
 
 Run it sampled (Scheduler.profile_cycle, or the CLI's --profile-every
-knob), never in the hot loop. For kernel-level detail beyond this, wrap any
-call in `jax.profiler.trace(log_dir)` and read the trace in TensorBoard or
-Perfetto; `trace_cycle` below does that for one full fused cycle.
+knob), never in the hot loop. For kernel-level detail beyond this, trace
+the serving process from outside with `jax.profiler` (as
+benchmark/traced_server.py does): core/pipeline.py's `sched.dispatch`
+events put the trace on the flight recorder's clock.
 """
 
 from __future__ import annotations
@@ -288,10 +289,3 @@ def overlap_from_records(
         else 0.0,
     }
 
-
-def trace_cycle(cycle_fn, snap: ClusterSnapshot, log_dir: str):
-    """One fused cycle under jax.profiler (TensorBoard/Perfetto trace)."""
-    with jax.profiler.trace(log_dir):
-        out = cycle_fn(snap)
-        jax.block_until_ready(out.assignment)
-    return out

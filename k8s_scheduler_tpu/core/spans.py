@@ -32,9 +32,17 @@ module makes that whole life one trace:
   confirm) and anywhere else all look the context up by uid and land
   in ONE trace. `release(uid)` drops the binding at the pod's
   terminal event (bound / deleted).
+- The agent path — an `Update` or `Cycle` RPC (service/server.py) is
+  one trace of its own (`rpc_context`: its own id, or the caller's
+  when the call carries a `traceparent`): the `rpc.*` span is the root
+  (`record_span(..., root_of=<caller's span or "">)`) and the phases
+  are its children. Stamps are per RPC and per phase, never per pod,
+  and no context is registered; `any_context()` lets the scheduler
+  skip its per-pod stamp sites in every cycle where none is.
 - Export — `spans_to_chrome_events` renders per-trace tracks that
   `to_chrome_trace` merges into the cycle lanes (one Perfetto view
-  shows a pod's spans overlapping the batch that served it), and
+  shows a pod's spans overlapping the batch that served it; the
+  agent RPC spans share one lane beside the host lane), and
   `to_otlp_json` / `export_otlp_dir` produce OTLP-JSON resource spans
   for external ingestion (`--trace-export-dir`, size-rotated).
 
@@ -77,6 +85,19 @@ from typing import Any, Callable, Iterable
 #                   postfilter), bind.confirm (the pod's bind),
 #                   preempt.victim (an eviction this pod's nomination
 #                   caused; attrs name the victim)
+#   agent RPC thread (service/server.py; one trace per RPC, the rpc.*
+#   span its root and the others its children — see AGENT_SPAN_NAMES):
+#                   rpc.update (Update handler entry -> return),
+#                   update.convert (proto -> API objects),
+#                   update.apply (the informer handlers), rpc.cycle
+#                   (Cycle handler entry -> return; attr `seqs` joins
+#                   the flight records committed under it),
+#                   cycle.lock_wait (waiting for the cycle lock),
+#                   cycle.pop (schedule_cycle entry -> the first
+#                   profile's record starts), cycle.snapshot (the
+#                   journal compaction, only when one ran),
+#                   cycle.respond (schedule_cycle returned -> response
+#                   built)
 SPAN_NAMES = (
     "submit.validate",
     "submit.journal",
@@ -90,6 +111,20 @@ SPAN_NAMES = (
     "apply.fold",
     "bind.confirm",
     "preempt.victim",
+    "rpc.update",
+    "update.convert",
+    "update.apply",
+    "rpc.cycle",
+    "cycle.lock_wait",
+    "cycle.pop",
+    "cycle.snapshot",
+    "cycle.respond",
+)
+
+# the agent path's spans (Update / Cycle): per RPC and per phase, never
+# per pod, so they render on one lane instead of a track per trace
+AGENT_SPAN_NAMES = frozenset(
+    n for n in SPAN_NAMES if n.startswith(("rpc.", "update.", "cycle."))
 )
 
 # default head-sampling rate (absent an explicit traceparent): 1/64
@@ -231,18 +266,23 @@ class SpanRecorder:
         ctx: TraceContext,
         t0: float,
         t1: float,
+        *,
+        root_of: "str | None" = None,
         **attrs: Any,
     ) -> Span:
         """Record one completed span under `ctx` (parent = the
         context's root/caller span id). A tenant-scoped context stamps
         its tenant on every span it records — one stamp site, so no
-        emitter can forget the attribution."""
+        emitter can forget the attribution. `root_of` records the
+        context's OWN span instead — the one its children name as
+        parent — as a child of the span id given ("" for a trace
+        that starts here): an agent RPC's root span."""
         if ctx.tenant and "tenant" not in attrs:
             attrs["tenant"] = ctx.tenant
         span = Span(
             trace_id=ctx.trace_id,
-            span_id=new_span_id(),
-            parent=ctx.span_id,
+            span_id=new_span_id() if root_of is None else ctx.span_id,
+            parent=ctx.span_id if root_of is None else root_of,
             name=name,
             t0=t0,
             t1=t1,
@@ -391,6 +431,24 @@ def ctx_for(uid: str) -> "TraceContext | None":
         return _contexts.get(uid)
 
 
+def any_context() -> bool:
+    """Whether any pod is bound to a trace right now, without the
+    lock: the scheduler asks once per cycle and skips its per-pod
+    stamp sites when none is, which is always so on the agent path
+    (only Submit registers contexts)."""
+    return bool(_contexts)
+
+
+def rpc_context(traceparent: str = "") -> "tuple[TraceContext, str]":
+    """One agent RPC is one trace: (the context its phases record
+    under, the root span's parent). A `traceparent` that parses joins
+    the caller's trace, the caller's span the root's parent; otherwise
+    the trace starts here and the root has none."""
+    parsed = parse_traceparent(traceparent) if traceparent else None
+    trace_id, parent = parsed if parsed is not None else (new_trace_id(), "")
+    return TraceContext(trace_id, new_span_id()), parent
+
+
 def release(uid: str) -> None:
     """Drop the uid's trace binding at its terminal event (bound /
     deleted). Recorded spans stay in the ring; only the LIVE join is
@@ -404,6 +462,8 @@ def record_span(
     ctx: TraceContext,
     t0: float,
     t1: float,
+    *,
+    root_of: "str | None" = None,
     **attrs: Any,
 ) -> None:
     """The armed stamp: one span into the module recorder. Callers
@@ -412,7 +472,7 @@ def record_span(
     rec = RECORDER
     if rec is None:
         return
-    rec.record(name, ctx, t0, t1, **attrs)
+    rec.record(name, ctx, t0, t1, root_of=root_of, **attrs)
     cb = _COUNTER
     if cb is not None:
         try:
@@ -426,6 +486,11 @@ def record_span(
 # chrome-trace: span tracks render in their own process group so
 # Perfetto shows them under (and time-aligned with) the cycle lanes
 TRACE_TRACK_PID = 2
+# agent RPC spans: ONE lane in the cycle lanes' own process (pid 1,
+# core/flight_recorder._slice), sorted above its host lane — a track
+# per RPC would be thousands of one-slice tracks
+AGENT_LANE_PID = 1
+AGENT_LANE_TID = 4
 
 
 def spans_to_chrome_events(
@@ -434,13 +499,19 @@ def spans_to_chrome_events(
     """Chrome-trace events for per-trace tracks: one tid per trace_id
     (named by the trace's pod uids), each span an `X` slice whose args
     carry the span/parent ids and attrs — the flight-record `seq` attr
-    included, which is the exemplar join back to the cycle lanes."""
+    included, which is the exemplar join back to the cycle lanes. The
+    agent RPC spans (AGENT_SPAN_NAMES) share one lane beside the host
+    lane; children nest inside their RPC by time."""
     events: "list[dict]" = []
     tids: "dict[str, int]" = {}
     uids: "dict[str, set]" = {}
     tenants: "dict[str, set]" = {}
     spans = list(spans)
+    agent_lane = False
     for s in spans:
+        if s.name in AGENT_SPAN_NAMES:
+            agent_lane = True
+            continue
         tid = tids.setdefault(s.trace_id, len(tids) + 1)
         uid = s.attrs.get("uid")
         if uid:
@@ -448,16 +519,34 @@ def spans_to_chrome_events(
         tn = s.attrs.get("tenant")
         if tn:
             tenants.setdefault(s.trace_id, set()).add(tn)
-    if not tids:
-        return events
-    events.append(
-        {
-            "name": "process_name",
-            "ph": "M",
-            "pid": TRACE_TRACK_PID,
-            "args": {"name": "pod traces"},
-        }
-    )
+    if agent_lane:
+        events.append(
+            {
+                "name": "thread_name",
+                "ph": "M",
+                "pid": AGENT_LANE_PID,
+                "tid": AGENT_LANE_TID,
+                "args": {"name": "agent RPCs (Update/Cycle)"},
+            }
+        )
+        events.append(
+            {
+                "name": "thread_sort_index",
+                "ph": "M",
+                "pid": AGENT_LANE_PID,
+                "tid": AGENT_LANE_TID,
+                "args": {"sort_index": 0},
+            }
+        )
+    if tids:
+        events.append(
+            {
+                "name": "process_name",
+                "ph": "M",
+                "pid": TRACE_TRACK_PID,
+                "args": {"name": "pod traces"},
+            }
+        )
     for trace_id, tid in tids.items():
         pods = ",".join(sorted(uids.get(trace_id, ()))) or "?"
         # tenant-scoped traces lead with the tenant so Perfetto's
@@ -485,12 +574,13 @@ def spans_to_chrome_events(
             }
         )
     for s in spans:
+        agent = s.name in AGENT_SPAN_NAMES
         events.append(
             {
                 "name": s.name,
                 "ph": "X",
-                "pid": TRACE_TRACK_PID,
-                "tid": tids[s.trace_id],
+                "pid": AGENT_LANE_PID if agent else TRACE_TRACK_PID,
+                "tid": AGENT_LANE_TID if agent else tids[s.trace_id],
                 "ts": round((s.t0 - epoch) * 1e6, 3),
                 "dur": round(max(s.t1 - s.t0, 0.0) * 1e6, 3),
                 "cat": "pod-trace",

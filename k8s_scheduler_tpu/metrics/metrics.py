@@ -179,13 +179,16 @@ lifecycle, per-tenant admission, and the batched arena dispatch):
 Tracing / build-identity families (core/spans.py span recorder +
 cmd/main.py startup stamp):
 
-- scheduler_trace_spans_total{name} — pod-lifecycle trace spans
-  recorded, by span name (submit.validate | submit.journal |
-  ack.barrier | mc.buffer_wait | encode.ingest | flush.finalize |
-  dispatch | dispatch.speculative | decision.row | apply.fold |
-  bind.confirm | preempt.victim; the inventory is
+- scheduler_trace_spans_total{name} — trace spans recorded, by span
+  name: a pod's life through the front door (submit.validate |
+  submit.journal | ack.barrier | mc.buffer_wait | encode.ingest |
+  flush.finalize | dispatch | dispatch.speculative | decision.row |
+  apply.fold | bind.confirm | preempt.victim) and the agent path's
+  RPCs, per RPC and per phase (rpc.update | update.convert |
+  update.apply | rpc.cycle | cycle.lock_wait | cycle.pop |
+  cycle.snapshot | cycle.respond); the inventory is
   core/spans.SPAN_NAMES, machine-checked by schedlint ID010 against
-  this docstring and the README span table); spans serve at
+  this docstring and the README span table; spans serve at
   /debug/traces and join /debug/explain verdicts
 - scheduler_build_info{python,jax,jaxlib,backend,platform,device_kind,
   device_count,git} — constant 1 gauge carrying the process's
@@ -607,7 +610,8 @@ class SchedulerMetrics:
         # ---- pod-lifecycle tracing / build identity (core/spans.py) ----
         self.trace_spans = Counter(
             "scheduler_trace_spans_total",
-            "Pod-lifecycle trace spans recorded, by span name (the "
+            "Trace spans recorded, by span name: pod-lifecycle spans "
+            "and the agent path's per-RPC spans (the "
             "core/spans.SPAN_NAMES inventory; serves /debug/traces).",
             ["name"],
             registry=r,

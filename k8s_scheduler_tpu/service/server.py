@@ -36,6 +36,7 @@ from concurrent import futures
 import grpc
 
 from ..config import SchedulerConfiguration
+from ..core import spans as _spans
 from ..core.scheduler import Scheduler
 from ..metrics import SchedulerMetrics
 from ..models.api import PodGroup
@@ -118,23 +119,51 @@ class SchedulerService:
     # ---- RPCs ------------------------------------------------------------
 
     def Update(self, request: pb.UpdateRequest, context) -> pb.UpdateResponse:
+        """Two passes in the request's order: every proto converted to
+        its API object, then the informer handlers applied. Conversion
+        touches no scheduler state, so the order of effects is what one
+        interleaved pass gave; a request with an unparseable object now
+        fails before any of it is applied. Armed, the RPC is one trace
+        (core/spans): `rpc.update` with `update.convert` and
+        `update.apply` as its children, four clock reads in all."""
+        armed = _spans.ARMED
+        if armed:
+            trace, caller = _spans.rpc_context(_traceparent(context))
+            t_in = _spans.now()
         s = self.scheduler
-        for n in request.node_adds:
-            s.on_node_add(convert.node_from(n))
-        for n in request.node_updates:
-            s.on_node_update(convert.node_from(n))
+        node_adds = [convert.node_from(n) for n in request.node_adds]
+        node_updates = [convert.node_from(n) for n in request.node_updates]
+        pod_adds = [
+            (convert.pod_from(ev.pod), ev.bound_node)
+            for ev in request.pod_adds
+        ]
+        pod_updates = [
+            (convert.pod_from(ev.pod), ev.bound_node)
+            for ev in request.pod_updates
+        ]
+        pvcs = [convert.pvc_from(c) for c in request.pvc_upserts]
+        pvs = [convert.pv_from(v) for v in request.pv_upserts]
+        storage_classes = [
+            convert.storage_class_from(sc)
+            for sc in request.storage_class_upserts
+        ]
+        pdbs = [convert.pdb_from(pdb) for pdb in request.pdb_upserts]
+        if armed:
+            t_converted = _spans.now()
+        for node in node_adds:
+            s.on_node_add(node)
+        for node in node_updates:
+            s.on_node_update(node)
         for name in request.node_deletes:
             s.on_node_delete(name)
         for g in request.pod_groups:
             s.add_pod_group(PodGroup(g.name, g.min_member))
-        for ev in request.pod_adds:
-            pod = convert.pod_from(ev.pod)
+        for pod, bound_node in pod_adds:
             self._uid_index[pod.uid] = pod
-            s.on_pod_add(pod, node_name=ev.bound_node)
-        for ev in request.pod_updates:
-            pod = convert.pod_from(ev.pod)
+            s.on_pod_add(pod, node_name=bound_node)
+        for pod, bound_node in pod_updates:
             self._uid_index[pod.uid] = pod
-            s.on_pod_update(pod, node_name=ev.bound_node)
+            s.on_pod_update(pod, node_name=bound_node)
         for uid in request.pod_deletes:
             self._uid_index.pop(uid, None)
             s.on_pod_delete(uid)
@@ -145,32 +174,79 @@ class SchedulerService:
             pod = self._uid_index.get(uid)
             if pod is not None:
                 s.queue.requeue_backoff(pod)
-        for c in request.pvc_upserts:
-            s.on_pvc_upsert(convert.pvc_from(c))
+        for pvc in pvcs:
+            s.on_pvc_upsert(pvc)
         for key in request.pvc_deletes:
             s.on_pvc_delete(key)
-        for v in request.pv_upserts:
-            s.on_pv_upsert(convert.pv_from(v))
+        for pv in pvs:
+            s.on_pv_upsert(pv)
         for name in request.pv_deletes:
             s.on_pv_delete(name)
-        for sc in request.storage_class_upserts:
-            s.on_storage_class_upsert(convert.storage_class_from(sc))
+        for sc in storage_classes:
+            s.on_storage_class_upsert(sc)
         for name in request.storage_class_deletes:
             s.on_storage_class_delete(name)
-        for pdb in request.pdb_upserts:
-            s.on_pdb_upsert(convert.pdb_from(pdb))
+        for pdb in pdbs:
+            s.on_pdb_upsert(pdb)
         for key in request.pdb_deletes:
             s.on_pdb_delete(key)
+        if armed:
+            t_out = _spans.now()
+            converted = (
+                len(node_adds) + len(node_updates) + len(pod_adds)
+                + len(pod_updates) + len(pvcs) + len(pvs)
+                + len(storage_classes) + len(pdbs)
+            )
+            node_events = (
+                len(node_adds) + len(node_updates)
+                + len(request.node_deletes)
+            )
+            _spans.record_span(
+                "update.convert", trace, t_in, t_converted,
+                objects=converted,
+            )
+            _spans.record_span(
+                "update.apply", trace, t_converted, t_out,
+                objects=converted + len(request.node_deletes)
+                + len(request.pod_groups) + len(request.pod_deletes)
+                + len(request.bind_failures) + len(request.pvc_deletes)
+                + len(request.pv_deletes)
+                + len(request.storage_class_deletes)
+                + len(request.pdb_deletes),
+            )
+            _spans.record_span(
+                "rpc.update", trace, t_in, t_out, root_of=caller,
+                pod_adds=len(pod_adds), pod_updates=len(pod_updates),
+                pod_deletes=len(request.pod_deletes),
+                bind_failures=len(request.bind_failures),
+                node_events=node_events,
+            )
         return pb.UpdateResponse(boot_id=self.boot_id)
 
     def Cycle(self, request: pb.CycleRequest, context) -> pb.CycleResponse:
+        """Armed, the RPC is one trace (core/spans): `rpc.cycle` is the
+        root; the servicer stamps `cycle.lock_wait` and `cycle.respond`
+        and the scheduler, handed the trace, stamps `cycle.pop` and
+        `cycle.snapshot` and puts the trace id on the flight records it
+        commits. `rpc.cycle`'s self time (its duration less its
+        children and less the `total` of the records in `seqs`) is what
+        `schedule_cycle` does outside all of them."""
+        armed = _spans.ARMED
+        trace = None
+        if armed:
+            trace, caller = _spans.rpc_context(_traceparent(context))
+            t_in = _spans.now()
         with self._cycle_lock:
+            if armed:
+                t_locked = _spans.now()
             self._bindings = []
             s = self.scheduler
-            stats = s.schedule_cycle()
+            stats = s.schedule_cycle(trace=trace)
             self._cycle_count += 1
             if self.profile_every and self._cycle_count % self.profile_every == 0:
                 s.profile_cycle()
+            if armed:
+                t_scheduled = _spans.now()
             resp = pb.CycleResponse(
                 boot_id=self.boot_id,
                 bindings=list(self._bindings),
@@ -208,6 +284,21 @@ class SchedulerService:
                         pod_name=ev.pod_name,
                         message=ev.message,
                     )
+                )
+            if armed:
+                t_out = _spans.now()
+                n_bind, n_ev = len(resp.bindings), len(resp.events)
+                _spans.record_span(
+                    "cycle.lock_wait", trace, t_in, t_locked
+                )
+                _spans.record_span(
+                    "cycle.respond", trace, t_scheduled, t_out,
+                    bindings=n_bind, events=n_ev,
+                )
+                _spans.record_span(
+                    "rpc.cycle", trace, t_in, t_out, root_of=caller,
+                    seqs=list(s.last_cycle_seqs), bindings=n_bind,
+                    events=n_ev, evictions=len(resp.evictions),
                 )
             return resp
 
@@ -296,12 +387,7 @@ class SchedulerService:
                 grpc.StatusCode.INVALID_ARGUMENT,
                 f"unparseable pod in submission: {e}",
             )
-        traceparent = ""
-        for key, value in context.invocation_metadata() or ():
-            if key == "traceparent":
-                traceparent = value
-                break
-        res = adm.submit(pods, traceparent=traceparent)
+        res = adm.submit(pods, traceparent=_traceparent(context))
         if res.invalid:
             context.abort(
                 grpc.StatusCode.INVALID_ARGUMENT, res.reason
@@ -364,6 +450,17 @@ class SchedulerService:
         return pb.NodeChurnResponse(
             boot_id=self.boot_id, durable=durable
         )
+
+
+def _traceparent(context) -> str:
+    """The W3C `traceparent` of the call's invocation metadata, "" when
+    there is none (or no context: an in-process caller)."""
+    if context is None:
+        return ""
+    for key, value in context.invocation_metadata() or ():
+        if key == "traceparent":
+            return value
+    return ""
 
 
 _RPCS = {

@@ -1,0 +1,371 @@
+"""Spans of the agent path (service/server.py `Update` and `Cycle`,
+core/scheduler.schedule_cycle, core/pipeline's `sched.dispatch` anchor):
+one trace per RPC with the RPC span as root, per phase and never per
+pod, joined both ways to the flight records, on the profiler's clock
+through one anchor per dispatch."""
+
+from __future__ import annotations
+
+import glob
+import os
+import re
+
+import jax
+import pytest
+
+from k8s_scheduler_tpu.config import SchedulerConfiguration
+from k8s_scheduler_tpu.core import pipeline as _pipeline
+from k8s_scheduler_tpu.core import spans as _spans
+from k8s_scheduler_tpu.core.spans import (
+    AGENT_LANE_PID,
+    AGENT_LANE_TID,
+    AGENT_SPAN_NAMES,
+    SPAN_NAMES,
+    format_traceparent,
+    spans_to_chrome_events,
+)
+from k8s_scheduler_tpu.models import MakeNode, MakePod
+from k8s_scheduler_tpu.service import convert
+from k8s_scheduler_tpu.service import scheduler_pb2 as pb
+from k8s_scheduler_tpu.service.server import SchedulerService
+from k8s_scheduler_tpu.state import DurableState
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PACKAGE = os.path.join(REPO, "k8s_scheduler_tpu")
+
+UPDATE_SPANS = {"rpc.update", "update.convert", "update.apply"}
+CYCLE_SPANS = {"rpc.cycle", "cycle.lock_wait", "cycle.pop", "cycle.respond"}
+
+
+class Metadata:
+    """The part of a grpc.ServicerContext the handlers read."""
+
+    def __init__(self, traceparent: str = "") -> None:
+        self.traceparent = traceparent
+
+    def invocation_metadata(self):
+        return (("user-agent", "test"), ("traceparent", self.traceparent))
+
+
+def service(state=None) -> SchedulerService:
+    return SchedulerService(
+        config=SchedulerConfiguration(
+            pod_initial_backoff_seconds=0.05, pod_max_backoff_seconds=0.2
+        ),
+        state=state,
+    )
+
+
+def cluster_request(n_nodes: int = 3, n_pods: int = 5, tag: str = "p"):
+    req = pb.UpdateRequest()
+    for i in range(n_nodes):
+        req.node_adds.append(convert.node_to(
+            MakeNode(f"n{i}").capacity({"cpu": "8"}).obj()))
+    for i in range(n_pods):
+        req.pod_adds.append(pb.PodEvent(pod=convert.pod_to(
+            MakePod(f"{tag}{i}").req({"cpu": "1"}).obj())))
+    return req
+
+
+@pytest.fixture()
+def armed():
+    rec = _spans.arm(rate=1.0)
+    yield rec
+    _spans.disarm()
+
+
+def by_name(spans) -> dict:
+    out: dict = {}
+    for s in spans:
+        out.setdefault(s.name, []).append(s)
+    return out
+
+
+def assert_one_trace(spans, root_name: str, caller: str = ""):
+    """`spans` are one RPC's: one trace id, the rpc.* span the root
+    (child of `caller`), every other span its child and inside it."""
+    assert len({s.trace_id for s in spans}) == 1
+    (root,) = [s for s in spans if s.name == root_name]
+    assert root.parent == caller
+    for s in spans:
+        if s is root:
+            continue
+        assert s.parent == root.span_id, s.name
+        assert root.t0 <= s.t0 <= s.t1 <= root.t1, s.name
+    return root
+
+
+def test_update_and_cycle_give_exactly_the_table_spans(armed, tmp_path):
+    st = DurableState(str(tmp_path), snapshot_interval_seconds=0)
+    svc = service(st)
+    req = cluster_request(n_nodes=3, n_pods=5)
+    req.pod_deletes.append("default/none")
+    svc.Update(req, None)
+    spans = armed.snapshot()
+    assert {s.name for s in spans} == UPDATE_SPANS and len(spans) == 3
+    root = assert_one_trace(spans, "rpc.update")
+    assert {k: root.attrs[k] for k in (
+        "pod_adds", "pod_updates", "pod_deletes", "bind_failures",
+        "node_events")} == {
+        "pod_adds": 5, "pod_updates": 0, "pod_deletes": 1,
+        "bind_failures": 0, "node_events": 3}
+    named = by_name(spans)
+    assert named["update.convert"][0].attrs["objects"] == 8
+    assert named["update.apply"][0].attrs["objects"] == 9
+    # the two phases abut: convert ends where apply starts
+    assert named["update.convert"][0].t1 == named["update.apply"][0].t0
+
+    before = len(spans)
+    resp = svc.Cycle(pb.CycleRequest(), None)
+    spans = armed.snapshot()[before:]
+    # no compaction ran (interval 0 = journal only): no cycle.snapshot
+    assert {s.name for s in spans} == CYCLE_SPANS and len(spans) == 4
+    root = assert_one_trace(spans, "rpc.cycle")
+    assert len(resp.bindings) == 5
+    assert root.attrs["bindings"] == 5
+    assert root.attrs["events"] == len(resp.events) > 0
+    assert root.attrs["evictions"] == len(resp.evictions) == 0
+    named = by_name(spans)
+    assert named["cycle.pop"][0].attrs["pods"] == 5
+    assert named["cycle.respond"][0].attrs == {
+        "bindings": 5, "events": len(resp.events)}
+    # the join runs both ways: the RPC names its flight records, and
+    # each of them names the RPC's trace
+    recs = svc.scheduler.flight.snapshot()
+    assert root.attrs["seqs"] == [r.seq for r in recs] and recs
+    assert all(root.trace_id in r.trace_ids for r in recs)
+    # the phases are disjoint and in order inside the RPC
+    lock, pop, resp_span = (named[n][0] for n in (
+        "cycle.lock_wait", "cycle.pop", "cycle.respond"))
+    assert lock.t1 <= pop.t0 <= pop.t1 <= recs[0].t_start
+    assert recs[-1].t_end <= resp_span.t0
+
+    # a cycle with nothing to pop is still one trace, with no record
+    before = len(armed.snapshot())
+    svc.Cycle(pb.CycleRequest(), None)
+    spans = armed.snapshot()[before:]
+    assert {s.name for s in spans} == CYCLE_SPANS
+    root = assert_one_trace(spans, "rpc.cycle")
+    assert root.attrs["seqs"] == [] and root.attrs["bindings"] == 0
+    st.seal()
+
+
+def test_traceparent_in_the_metadata_is_joined(armed):
+    svc = service()
+    tid, sid = _spans.new_trace_id(), _spans.new_span_id()
+    ctx = Metadata(format_traceparent(tid, sid))
+    svc.Update(cluster_request(), ctx)
+    svc.Cycle(pb.CycleRequest(), ctx)
+    spans = armed.snapshot()
+    assert {s.trace_id for s in spans} == {tid}
+    for root_name, names in (("rpc.update", UPDATE_SPANS),
+                             ("rpc.cycle", CYCLE_SPANS)):
+        assert_one_trace([s for s in spans if s.name in names],
+                         root_name, caller=sid)
+    assert all(tid in r.trace_ids
+               for r in svc.scheduler.flight.snapshot())
+    # a malformed header starts a trace of the RPC's own
+    before = len(spans)
+    svc.Update(pb.UpdateRequest(), Metadata("00-zz-zz-01"))
+    (root,) = [s for s in armed.snapshot()[before:]
+               if s.name == "rpc.update"]
+    assert root.trace_id != tid and root.parent == ""
+
+
+def test_cycle_snapshot_appears_only_in_a_cycle_that_compacted(
+        armed, tmp_path):
+    clock = [0.0]
+    st = DurableState(str(tmp_path), snapshot_interval_seconds=15,
+                      now=lambda: clock[0])
+    svc = service(st)
+    svc.Update(cluster_request(n_pods=2, tag="a"), None)
+    svc.Cycle(pb.CycleRequest(), None)
+    assert "cycle.snapshot" not in {s.name for s in armed.snapshot()}
+    clock[0] = 16.0
+    svc.Update(cluster_request(n_nodes=0, n_pods=2, tag="b"), None)
+    before = len(armed.snapshot())
+    svc.Cycle(pb.CycleRequest(), None)
+    spans = armed.snapshot()[before:]
+    assert {s.name for s in spans} == CYCLE_SPANS | {"cycle.snapshot"}
+    root = assert_one_trace(spans, "rpc.cycle")
+    named = by_name(spans)
+    # after the last record, before the response is built
+    recs = [r for r in svc.scheduler.flight.snapshot()
+            if r.seq in root.attrs["seqs"]]
+    assert recs[-1].t_end <= named["cycle.snapshot"][0].t0
+    assert named["cycle.snapshot"][0].t1 <= named["cycle.respond"][0].t0
+    # an empty cycle compacts too, when its interval has passed
+    clock[0] = 32.0
+    before = len(armed.snapshot())
+    svc.Cycle(pb.CycleRequest(), None)
+    assert {s.name for s in armed.snapshot()[before:]} == (
+        CYCLE_SPANS | {"cycle.snapshot"})
+    st.seal()
+
+
+def test_unarmed_no_span_and_no_annotation_object(monkeypatch):
+    made = []
+    real = jax.profiler.TraceAnnotation
+
+    def counting(*a, **kw):
+        made.append(a)
+        return real(*a, **kw)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", counting)
+    assert not _spans.ARMED
+    # a ring left over from an earlier arm() must stay untouched
+    ring = _spans.RECORDER
+    count = ring.count if ring is not None else 0
+    svc = service()
+    svc.Update(cluster_request(), None)
+    resp = svc.Cycle(pb.CycleRequest(), None)
+    assert len(resp.bindings) == 5
+    assert made == []
+    assert _spans.RECORDER is ring
+    assert (ring.count if ring is not None else 0) == count
+    recs = svc.scheduler.flight.snapshot()
+    assert recs and all(r.trace_ids == () for r in recs)
+    # the seqs are kept either way: they cost one list append a record
+    assert svc.scheduler.last_cycle_seqs == [r.seq for r in recs]
+    # armed, the same cycle makes exactly one: the dispatch's anchor
+    _spans.arm(rate=1.0)
+    try:
+        svc.Update(cluster_request(n_nodes=0, tag="q"), None)
+        svc.Cycle(pb.CycleRequest(), None)
+    finally:
+        _spans.disarm()
+    assert [a[0] for a in made] == ["sched.dispatch"]
+
+
+def test_armed_with_no_context_the_per_pod_sites_do_no_lookup(
+        armed, monkeypatch):
+    calls = []
+    real = _spans.ctx_for
+
+    def counting(uid):
+        calls.append(uid)
+        return real(uid)
+
+    monkeypatch.setattr(_spans, "ctx_for", counting)
+    svc = service()
+    svc.Update(cluster_request(), None)
+    resp = svc.Cycle(pb.CycleRequest(), None)
+    assert len(resp.bindings) == 5
+    assert calls == []  # bind.confirm, dispatch, ...: all skipped
+    assert {s.name for s in armed.snapshot()} <= AGENT_SPAN_NAMES
+    # with one pod bound to a trace the sites run again, for every pod
+    # of the cycle (the flag is per cycle, the lookup per pod)
+    pod = MakePod("traced").req({"cpu": "1"}).obj()
+    assert _spans.register(pod.uid) is not None
+    req = cluster_request(n_nodes=0, n_pods=2, tag="r")
+    req.pod_adds.append(pb.PodEvent(pod=convert.pod_to(pod)))
+    svc.Update(req, None)
+    svc.Cycle(pb.CycleRequest(), None)
+    assert pod.uid in calls and len(set(calls)) == 3
+    mine = {s.name for s in armed.snapshot()
+            if s.attrs.get("uid") == pod.uid}
+    assert {"dispatch", "decision.row", "apply.fold",
+            "bind.confirm"} <= mine
+
+
+def test_dispatch_anchors_agree_on_the_profiler_clock(armed, tmp_path):
+    """A jax.profiler trace around three cycles: every dispatch left a
+    `sched.dispatch` event that carries its record's seq and the
+    recorder clock, and the clock offsets they give agree within 1 ms."""
+    from jax.profiler import ProfileData
+
+    svc = service()
+    svc.Update(cluster_request(n_pods=2, tag="warm"), None)
+    svc.Cycle(pb.CycleRequest(), None)  # compiles, outside the trace
+    n_warm = len(svc.scheduler.flight.snapshot())
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        for k in range(3):
+            svc.Update(cluster_request(n_nodes=0, n_pods=2, tag=f"c{k}-"),
+                       None)
+            assert len(svc.Cycle(pb.CycleRequest(), None).bindings) == 2
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    anchors = [
+        (dict(e.stats), e.start_ns)
+        for plane in ProfileData.from_file(path).planes
+        for line in plane.lines for e in line.events
+        if e.name == "sched.dispatch"
+    ]
+    fr = svc.scheduler.flight
+    recs = fr.snapshot()[n_warm:]
+    assert sorted(a["seq"] for a, _ in anchors) == [r.seq for r in recs]
+    assert len(anchors) == 3
+    offsets_us = [start_ns / 1e3 - a["t_us"] for a, start_ns in anchors]
+    assert max(offsets_us) - min(offsets_us) < 1000.0
+    # t_us is the record's own dispatch mark, on the recorder's epoch
+    for a, _ in anchors:
+        (r,) = [r for r in recs if r.seq == a["seq"]]
+        mark_us = (r.marks["dispatch_start"] - fr.epoch) * 1e6
+        assert 0.0 <= a["t_us"] - mark_us < 50_000.0
+
+
+def test_dispatch_anchor_is_inert_without_an_anchor():
+    import contextlib
+
+    assert isinstance(_pipeline._dispatch_anchor(None, lambda: 0.0),
+                      contextlib.nullcontext)
+    with _pipeline._dispatch_anchor((7, 1.0), lambda: 3.5) as a:
+        assert isinstance(a, jax.profiler.TraceAnnotation)
+
+
+def test_agent_rpcs_render_on_one_lane(armed):
+    svc = service()
+    for k in range(3):
+        svc.Update(cluster_request(n_nodes=3 if k == 0 else 0,
+                                   n_pods=2, tag=f"l{k}-"), None)
+        svc.Cycle(pb.CycleRequest(), None)
+    pod_ctx = _spans.TraceContext(_spans.new_trace_id(),
+                                  _spans.new_span_id())
+    armed.record("bind.confirm", pod_ctx, 1.0, 2.0, uid="default/x")
+    spans = armed.snapshot()
+    events = spans_to_chrome_events(spans, epoch=armed.epoch)
+    slices = [e for e in events if e["ph"] == "X"]
+    agent = [e for e in slices if e["name"] in AGENT_SPAN_NAMES]
+    assert len(agent) == len(spans) - 1
+    assert {(e["pid"], e["tid"]) for e in agent} == {
+        (AGENT_LANE_PID, AGENT_LANE_TID)}
+    # six RPCs, six traces, ONE named lane: not a track per trace
+    lanes = [e for e in events if e["ph"] == "M"
+             and e["name"] == "thread_name"
+             and e["pid"] == AGENT_LANE_PID]
+    assert [e["args"]["name"] for e in lanes] == [
+        "agent RPCs (Update/Cycle)"]
+    # names and args are as for every other span
+    (cyc,) = [e for e in agent if e["name"] == "rpc.cycle"][-1:]
+    (span,) = [s for s in spans if s.span_id == cyc["args"]["span_id"]]
+    assert cyc["args"] == {
+        "trace_id": span.trace_id, "span_id": span.span_id,
+        "parent": span.parent, **span.attrs}
+    # the pod's span keeps its own per-trace track
+    (pod_ev,) = [e for e in slices if e["name"] == "bind.confirm"]
+    assert pod_ev["pid"] == _spans.TRACE_TRACK_PID
+
+
+def test_span_inventory_has_twenty_names():
+    assert len(SPAN_NAMES) == len(set(SPAN_NAMES)) == 20
+    assert AGENT_SPAN_NAMES == UPDATE_SPANS | CYCLE_SPANS | {
+        "cycle.snapshot"}
+
+
+@pytest.mark.parametrize("name", sorted(
+    UPDATE_SPANS | CYCLE_SPANS | {"cycle.snapshot"}))
+def test_each_agent_span_is_stamped_at_one_site(name):
+    sites = []
+    for path in glob.glob(os.path.join(PACKAGE, "**", "*.py"),
+                          recursive=True):
+        with open(path) as f:
+            text = f.read()
+        sites += [path for _ in re.finditer(
+            r"record_span\(\s*\"" + re.escape(name) + "\"", text)]
+    assert len(sites) == 1, sites
