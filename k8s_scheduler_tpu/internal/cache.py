@@ -153,13 +153,24 @@ class SchedulerCache:
                 if self._journal is not None:
                     self._journal("c.finish_binding", now, {"uid": pod_uid})
 
-    def confirm(self, pod_uid: str) -> None:
-        """Bind confirmed by the cluster store (add_pod also confirms)."""
+    def confirm(
+        self, pod_uid: str, node_name: str | None = None
+    ) -> Pod | None:
+        """Bind confirmed by the cluster store (add_pod also confirms):
+        the assumed pod becomes bound, and is returned. Given
+        `node_name`, only an assumption on that node is confirmed; with
+        none held there (unknown uid, already bound, expired, another
+        node) nothing changes and None is returned. The check and the
+        move are one step under the lock, so no TTL sweep falls between
+        them."""
         with self._lock:
-            a = self._assumed.pop(pod_uid, None)
-            if a is not None:
-                self._bound[pod_uid] = (a.pod, a.node_name)
-                self._emit("c.confirm", {"uid": pod_uid})
+            a = self._assumed.get(pod_uid)
+            if a is None or node_name not in (None, a.node_name):
+                return None
+            del self._assumed[pod_uid]
+            self._bound[pod_uid] = (a.pod, a.node_name)
+            self._emit("c.confirm", {"uid": pod_uid})
+            return a.pod
 
     def forget(self, pod_uid: str) -> None:
         with self._lock:

@@ -210,8 +210,24 @@ class SchedulerAgent:
         self._send(pb.UpdateRequest(node_deletes=[name]))
 
     def upsert_pod(self, pod: Pod, bound_node: str = "") -> None:
-        known = pod.uid in self._pods
+        """Send `pod` as pending, or as bound to `bound_node`.
+
+        A bound pod that is THE OBJECT this agent last sent as pending
+        is the confirmation of a binding the shim made itself, and goes
+        as a `BindConfirm(uid, node)`: the shim holds that pod assumed
+        and moves it to bound, nothing is converted or shipped twice.
+        Anything else (an unknown uid, another object, an entry already
+        bound) goes as the whole pod. The rule reads what was stored,
+        so a pod mutated in place and never upserted again is outside
+        it, as it is outside `relist()`, which would send the mutated
+        object: hand the agent a new object for a changed pod."""
+        known = self._pods.get(pod.uid)
         self._pods[pod.uid] = (pod, bound_node)
+        if bound_node and known and known[0] is pod and not known[1]:
+            self._send(pb.UpdateRequest(bind_confirms=[
+                pb.BindConfirm(pod_uid=pod.uid, node_name=bound_node)
+            ]))
+            return
         ev = pb.PodEvent(pod=convert.pod_to(pod), bound_node=bound_node)
         self._send(
             pb.UpdateRequest(
@@ -295,12 +311,7 @@ class SchedulerAgent:
                     continue
                 pod, _ = self._pods.get(b.pod_uid, (None, ""))
                 if pod is not None:
-                    self._pods[b.pod_uid] = (pod, b.node_name)
-                    self._send(pb.UpdateRequest(pod_updates=[
-                        pb.PodEvent(
-                            pod=convert.pod_to(pod), bound_node=b.node_name
-                        )
-                    ]))
+                    self.upsert_pod(pod, bound_node=b.node_name)
         for ev in resp.evictions:
             self.evict_applier(ev.pod_uid, ev.node_name)
         for ev in resp.events:
@@ -355,6 +366,29 @@ class SchedulerAgent:
             # itself was applied to the fresh shim, and relist re-sends the
             # full store including it, which is idempotent)
             self.relist()
+            return  # every pod went with its bound node: nothing to resend
+        # confirmations the shim held no assumption for (expired, already
+        # bound, unknown) come back in `unconfirmed`; one that does not
+        # know the field answers 0 and nothing, and then it is all of
+        # them. They go again at once as whole pods, in requests that fit
+        sent = request.bind_confirms
+        again = set(resp.unconfirmed)
+        if resp.bind_confirms_applied + len(again) != len(sent):
+            again = {c.pod_uid for c in sent}
+        full, size = pb.UpdateRequest(), 0
+        for c in sent:
+            if c.pod_uid not in again or c.pod_uid not in self._pods:
+                continue
+            ev = full.pod_updates.add(
+                pod=convert.pod_to(self._pods[c.pod_uid][0]),
+                bound_node=c.node_name,
+            )
+            size += ev.ByteSize()
+            if size >= MAX_UPDATE_BYTES:
+                self._send_now(full)
+                full, size = pb.UpdateRequest(), 0
+        if size:
+            self._send_now(full)
 
     def _with_recovery(self, call):
         try:

@@ -7,11 +7,14 @@ cluster store conversation. Per the north star, one `Cycle` RPC returns
 pod->node bindings for the WHOLE pending set.
 
 Bind dispatch is optimistic (upstream assume-then-bind-async): a binding
-returned from `Cycle` is assumed in the cache; the agent reports failed
-Binding POSTs in its next `Update(bind_failures=[...])`, which forgets the
-assumption and requeues with backoff. If the confirmation never arrives the
-assumed-pod TTL expires and the pod is requeued (no double-bind either way
-— fault tests in tests/test_service.py).
+returned from `Cycle` is assumed in the cache; the agent confirms the ones
+it applied by reference (`Update(bind_confirms=[(uid, node)])`: the assumed
+pod becomes bound; a bound `pod_update` carrying the whole pod does the
+same) and reports failed Binding POSTs in `Update(bind_failures=[...])`,
+which forgets the assumption and requeues with backoff. If the
+confirmation never arrives the assumed-pod TTL expires and the pod is
+requeued (no double-bind either way — fault tests in
+tests/test_service.py).
 
 The driver underneath runs the split-phase serving pipeline
 (core/pipeline.py): inside `Cycle`, the response's `bindings` are
@@ -38,6 +41,7 @@ import grpc
 from ..config import SchedulerConfiguration
 from ..core import spans as _spans
 from ..core.scheduler import Scheduler
+from ..internal.queue import EVENT_POD_UPDATE
 from ..metrics import SchedulerMetrics
 from ..models.api import PodGroup
 from . import convert
@@ -164,6 +168,27 @@ class SchedulerService:
         for pod, bound_node in pod_updates:
             self._uid_index[pod.uid] = pod
             s.on_pod_update(pod, node_name=bound_node)
+        # bindings of the previous Cycle, confirmed by reference: the pod
+        # the cache holds assumed on that node becomes bound, with what
+        # on_pod_update does for a bound pod and nothing converted,
+        # stored or journaled a second time. Any other uid goes back to
+        # the agent untouched, to be sent in full.
+        unconfirmed = []
+        for c in request.bind_confirms:
+            pod = s.cache.confirm(c.pod_uid, c.node_name)
+            if pod is None:
+                unconfirmed.append(c.pod_uid)
+                continue
+            s.queue.delete(c.pod_uid)
+            if s.flight is not None:
+                s.flight.pod_event(
+                    c.pod_uid, pod.name, "BoundObserved", node=c.node_name
+                )
+        confirmed = len(request.bind_confirms) - len(unconfirmed)
+        if confirmed:
+            # once for the request where the full path moves per pod:
+            # only a cycle fills the unschedulable set
+            s.queue.move_all_to_active_or_backoff(EVENT_POD_UPDATE)
         for uid in request.pod_deletes:
             self._uid_index.pop(uid, None)
             s.on_pod_delete(uid)
@@ -208,7 +233,8 @@ class SchedulerService:
             _spans.record_span(
                 "update.apply", trace, t_converted, t_out,
                 objects=converted + len(request.node_deletes)
-                + len(request.pod_groups) + len(request.pod_deletes)
+                + len(request.pod_groups) + len(request.bind_confirms)
+                + len(request.pod_deletes)
                 + len(request.bind_failures) + len(request.pvc_deletes)
                 + len(request.pv_deletes)
                 + len(request.storage_class_deletes)
@@ -219,9 +245,13 @@ class SchedulerService:
                 pod_adds=len(pod_adds), pod_updates=len(pod_updates),
                 pod_deletes=len(request.pod_deletes),
                 bind_failures=len(request.bind_failures),
-                node_events=node_events,
+                node_events=node_events, bind_confirms=confirmed,
+                confirm_fallbacks=len(unconfirmed),
             )
-        return pb.UpdateResponse(boot_id=self.boot_id)
+        return pb.UpdateResponse(
+            boot_id=self.boot_id, bind_confirms_applied=confirmed,
+            unconfirmed=unconfirmed,
+        )
 
     def Cycle(self, request: pb.CycleRequest, context) -> pb.CycleResponse:
         """Armed, the RPC is one trace (core/spans): `rpc.cycle` is the

@@ -369,3 +369,102 @@ def test_each_agent_span_is_stamped_at_one_site(name):
         sites += [path for _ in re.finditer(
             r"record_span\(\s*\"" + re.escape(name) + "\"", text)]
     assert len(sites) == 1, sites
+
+
+def test_update_counts_confirmations_applied_and_returned(armed):
+    """`rpc.update` says how many binds were confirmed by reference and
+    how many went back to the agent; `update.apply` counts them among
+    its objects; a request without any reads 0 and 0."""
+    svc = service()
+    svc.Update(cluster_request(n_nodes=3, n_pods=4), None)
+    (first,) = [s for s in armed.snapshot() if s.name == "rpc.update"]
+    assert (first.attrs["bind_confirms"],
+            first.attrs["confirm_fallbacks"]) == (0, 0)
+    bindings = svc.Cycle(pb.CycleRequest(), None).bindings
+    assert len(bindings) == 4
+    before = len(armed.snapshot())
+    req = pb.UpdateRequest(bind_confirms=[
+        pb.BindConfirm(pod_uid=b.pod_uid, node_name=b.node_name)
+        for b in bindings
+    ])
+    # one of them again (bound by now) and one the server never saw
+    req.bind_confirms.add(pod_uid=bindings[0].pod_uid,
+                          node_name=bindings[0].node_name)
+    req.bind_confirms.add(pod_uid="default/none", node_name="n0")
+    resp = svc.Update(req, None)
+    assert resp.bind_confirms_applied == 4
+    assert list(resp.unconfirmed) == [bindings[0].pod_uid, "default/none"]
+    named = by_name(armed.snapshot()[before:])
+    root = named["rpc.update"][0]
+    assert {k: root.attrs[k] for k in (
+        "bind_confirms", "confirm_fallbacks", "pod_updates")} == {
+        "bind_confirms": 4, "confirm_fallbacks": 2, "pod_updates": 0}
+    assert named["update.convert"][0].attrs["objects"] == 0
+    assert named["update.apply"][0].attrs["objects"] == 6
+    assert svc.scheduler.cache.counts() == {
+        "nodes": 3, "bound": 4, "assumed": 0}
+    # the pod's timeline shows the confirmation as the full path does
+    last = svc.scheduler.flight.pods.get(bindings[1].pod_uid)["events"][-1]
+    assert (last["kind"], last["node"]) == (
+        "BoundObserved", bindings[1].node_name)
+
+
+PROTO = os.path.join(PACKAGE, "service", "scheduler.proto")
+
+
+def test_generated_module_is_the_proto_compiled(tmp_path):
+    """`scheduler_pb2.py` holds exactly what protoc makes of
+    `scheduler.proto` today: every message, field and number."""
+    import shutil
+    import subprocess
+
+    from google.protobuf import descriptor_pb2
+
+    protoc = shutil.which("protoc")
+    if protoc is None:
+        pytest.skip("no protoc in this image")
+    out = tmp_path / "set.pb"
+    subprocess.run(
+        [protoc, f"--proto_path={os.path.dirname(PROTO)}",
+         f"--descriptor_set_out={out}", PROTO], check=True)
+    (compiled,) = descriptor_pb2.FileDescriptorSet.FromString(
+        out.read_bytes()).file
+    loaded = descriptor_pb2.FileDescriptorProto()
+    pb.DESCRIPTOR.CopyToProto(loaded)
+
+    def strip(messages):
+        # a descriptor set spells out each field's JSON name; the
+        # generated module leaves it to the runtime
+        for m in messages:
+            for f in m.field:
+                f.ClearField("json_name")
+            strip(m.nested_type)
+
+    strip(compiled.message_type)
+    strip(loaded.message_type)
+    assert compiled == loaded
+
+
+@pytest.mark.parametrize("message,field,number,kind", [
+    ("BindConfirm", "pod_uid", 1, "string"),
+    ("BindConfirm", "node_name", 2, "string"),
+    ("UpdateRequest", "bind_confirms", 17, "repeated BindConfirm"),
+    ("UpdateResponse", "bind_confirms_applied", 2, "int32"),
+    ("UpdateResponse", "unconfirmed", 3, "repeated string"),
+])
+def test_proto_text_and_generated_module_agree(message, field, number, kind):
+    with open(PROTO) as f:
+        body = re.search(
+            r"^message " + message + r" \{(.*?)^\}", f.read(), re.M | re.S
+        ).group(1)
+    assert re.search(
+        rf"^\s*{kind} {field} = {number};", body, re.M), (message, field)
+    fd = pb.DESCRIPTOR.message_types_by_name[message].fields_by_name[field]
+    assert fd.number == number
+    assert fd.is_repeated == kind.startswith("repeated ")
+    base = kind.removeprefix("repeated ")
+    if base == "BindConfirm":
+        assert fd.message_type.name == "BindConfirm"
+    else:
+        assert fd.type == {"string": fd.TYPE_STRING,
+                           "int32": fd.TYPE_INT32}[base]

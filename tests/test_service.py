@@ -3,9 +3,16 @@ channel on localhost, plus the fault-tolerance contract from §5.3 —
 shim restart recovers via agent re-list, bind failures forget+backoff,
 and no pod is ever double-bound."""
 
+import copy
+from concurrent import futures
+
 import grpc
 import pytest
 
+from k8s_scheduler_tpu.config import SchedulerConfiguration
+from k8s_scheduler_tpu.core.scheduler import Scheduler
+from k8s_scheduler_tpu.internal.cache import SchedulerCache
+from k8s_scheduler_tpu.internal.queue import SchedulingQueue
 from k8s_scheduler_tpu.models import MakeNode, MakePod
 from k8s_scheduler_tpu.models.api import PodGroup
 from k8s_scheduler_tpu.service import (
@@ -15,6 +22,9 @@ from k8s_scheduler_tpu.service import (
 )
 from k8s_scheduler_tpu.service import convert
 from k8s_scheduler_tpu.service import scheduler_pb2 as pb
+from k8s_scheduler_tpu.service.server import SchedulerService, add_to_server
+from k8s_scheduler_tpu.state import DurableState
+from k8s_scheduler_tpu.state.journal import BATCH_OP, iter_batch, replay_dir
 
 
 # ---- conversion round-trips ------------------------------------------------
@@ -281,3 +291,295 @@ def test_preemption_over_the_wire(shim):
     assert [n.pod_uid for n in resp.nominations] == [urgent.uid]
     assert [e.pod_uid for e in resp.evictions] == [victim.uid]
     assert applier.evicted == [victim.uid]
+
+
+# ---- bind confirmation by reference ----------------------------------------
+
+
+class Clock:
+    def __init__(self):
+        self.t = 100.0
+
+    def __call__(self):
+        return self.t
+
+
+class Shim:
+    """A served scheduler on an injected clock, journaling to `path`."""
+
+    def __init__(self, path):
+        self.clock = Clock()
+        self.path = str(path)
+        self.state = DurableState(
+            self.path, snapshot_interval_seconds=0, now=self.clock
+        )
+        self.service = SchedulerService(scheduler=Scheduler(
+            config=SchedulerConfiguration(), now=self.clock,
+            state=self.state,
+        ))
+        self.server = grpc.server(futures.ThreadPoolExecutor(max_workers=2))
+        add_to_server(self.service, self.server)
+        port = self.server.add_insecure_port("127.0.0.1:0")
+        self.server.start()
+        self.client = SchedulerClient(f"127.0.0.1:{port}")
+        self.applier = Applier()
+        self.agent = SchedulerAgent(
+            self.client, self.applier.bind, self.applier.evict
+        )
+        # every Update the agent makes, as (request, response)
+        self.updates = []
+        inner = self.client.update
+
+        def recording(request, timeout=10.0):
+            resp = inner(request, timeout=timeout)
+            self.updates.append((request, resp))
+            return resp
+
+        self.client.update = recording
+
+    @property
+    def cache(self):
+        return self.service.scheduler.cache
+
+    @property
+    def queue(self):
+        return self.service.scheduler.queue
+
+    def journal_ops(self):
+        """Every logical op journaled so far, batches expanded."""
+        self.state.journal.flush()
+        out = []
+        for op, t, data in replay_dir(self.path):
+            out.extend(iter_batch(data) if op == BATCH_OP else [(op, t, data)])
+        return out
+
+    def restored_bound(self):
+        """uid -> node of the bound set a restore of the journal gives."""
+        self.state.journal.flush()
+        q, c = SchedulingQueue(now=self.clock), SchedulerCache(now=self.clock)
+        st = DurableState(
+            self.path, snapshot_interval_seconds=0, now=self.clock
+        )
+        st.restore_into(q, c)
+        st.journal.close()
+        return {d["pod"]["m"]["u"]: d["node"] for d in c.dump_state()["bound"]}
+
+    def close(self):
+        self.client.close()
+        self.server.stop(grace=None)
+        self.state.journal.close()
+
+
+@pytest.fixture()
+def shims(tmp_path):
+    made = []
+
+    def make(name="a"):
+        made.append(Shim(tmp_path / name))
+        return made[-1]
+
+    yield make
+    for s in made:
+        s.close()
+
+
+def confirm_loop(shim, pods, copies: bool):
+    """The agent loop as the benchmark drives it: a raw `Cycle`, then
+    every binding confirmed through `upsert_pod` and every eviction
+    deleted, in one batch. `copies` hands `upsert_pod` an equal copy of
+    each pod, which is what forces the full `pod_updates` path."""
+    resp = shim.client.cycle()
+    with shim.agent.batched():
+        for b in resp.bindings:
+            pod = pods[b.pod_uid]
+            shim.agent.upsert_pod(
+                copy.deepcopy(pod) if copies else pod, bound_node=b.node_name
+            )
+        for ev in resp.evictions:
+            shim.agent.delete_pod(ev.pod_uid)
+    return resp
+
+
+def waves():
+    """Three cycles' arrivals on three small nodes: plain pods, one pod
+    more with anti-affinity than there are nodes (an update event moves
+    it out of the unschedulable set), a pod too large to ever fit, and a
+    high-priority pod that preempts."""
+    host = "kubernetes.io/hostname"
+    return [
+        [MakePod(f"a{i}").req({"cpu": "1"}).labels({"app": "a"}).obj()
+         for i in range(5)]
+        + [MakePod("huge").req({"cpu": "64"}).obj()]
+        + [MakePod(f"anti{i}").req({"cpu": "1"}).labels({"app": "x"})
+           .pod_affinity(host, {"app": "x"}, anti=True).obj()
+           for i in range(4)],
+        [MakePod(f"b{i}").req({"cpu": "2"}).priority(1).obj()
+         for i in range(6)],
+        [MakePod("urgent").req({"cpu": "6"}).priority(100).obj()],
+    ]
+
+
+@pytest.mark.parametrize("driver", ["upsert_pod", "run_cycle"])
+def test_confirm_by_reference_equals_full_pod_updates(shims, driver):
+    """The same pods and nodes through two servers, one agent confirming
+    its binds by reference and one forced onto full `pod_updates`: the
+    same bindings cycle for cycle, the same cache and queue at the end,
+    the same bound set after a restore; only the journal differs."""
+    ref, full = shims("ref"), shims("full")
+    pods, streams = {}, {ref: [], full: []}
+    # run_cycle hands evictions to the applier: it deletes, as the loop does
+    ref.agent.evict_applier = lambda uid, node: ref.agent.delete_pod(uid)
+    for s in (ref, full):
+        with s.agent.batched():
+            for i in range(3):
+                s.agent.upsert_node(
+                    MakeNode(f"n{i}").capacity({"cpu": "8"}).obj()
+                )
+    n_setup = {s: len(s.journal_ops()) for s in (ref, full)}
+    for wave in waves() + [[], []]:
+        pods.update((p.uid, p) for p in wave)
+        for s in (ref, full):
+            with s.agent.batched():
+                for p in wave:
+                    s.agent.upsert_pod(p)
+            if s is ref and driver == "run_cycle":
+                resp = s.agent.run_cycle()
+            else:
+                resp = confirm_loop(s, pods, copies=s is full)
+            streams[s].append((
+                [(b.pod_uid, b.node_name) for b in resp.bindings],
+                [(e.pod_uid, e.node_name) for e in resp.evictions],
+            ))
+            s.clock.t += 5.0  # past every backoff: the moved pods retry
+    assert streams[ref] == streams[full]
+    bound = {u: n for binds, _ in streams[ref] for u, n in binds}
+    evicted = {u for _, evs in streams[ref] for u, _ in evs}
+    assert len(bound) >= 15 and evicted and "default/huge" not in bound
+    assert "q.move" in [op for op, _, _ in ref.journal_ops()]
+    # the pod the server assumed carries the nomination the server put
+    # on it when it preempted for it; the copy an agent sends back does
+    # not, and nothing reads the field of a bound pod
+    dumps = {s: s.cache.dump_state() for s in (ref, full)}
+    noms = {s: {d["pod"]["m"]["u"]: d["pod"].pop("nom")
+                for d in dumps[s]["bound"] if "nom" in d["pod"]}
+            for s in (ref, full)}
+    assert noms == {ref: {"default/urgent": bound["default/urgent"]},
+                    full: {}}
+    assert dumps[ref] == dumps[full]
+    assert ref.cache.counts()["assumed"] == 0
+    assert ref.queue.dump_state() == full.queue.dump_state()
+
+    # one side sent references, the other whole pods, and both were told
+    sent = {
+        s: (sum(len(r.bind_confirms) for r, _ in s.updates),
+            sum(1 for r, _ in s.updates for e in r.pod_updates
+                if e.bound_node),
+            sum(a.bind_confirms_applied for _, a in s.updates),
+            sum(len(a.unconfirmed) for _, a in s.updates))
+        for s in (ref, full)
+    }
+    assert sent[ref] == (len(bound), 0, len(bound), 0)
+    assert sent[full] == (0, len(bound), 0, 0)
+
+    # the journal records every bind of the window on both sides: as
+    # c.confirm after the c.assume that holds the pod, or as c.add_pod
+    ops = {s: [op for op, _, _ in s.journal_ops()[n_setup[s]:]]
+           for s in (ref, full)}
+    assert ops[ref].count("c.confirm") == len(bound)
+    assert ops[ref].count("c.add_pod") == 0
+    assert ops[full].count("c.add_pod") == len(bound)
+    assert ops[full].count("c.confirm") == 0
+    for s in (ref, full):
+        assert ops[s].count("c.assume") == len(bound)
+        assert ops[s].count("q.delete") == ops[ref].count("q.delete")
+    live = {u: n for u, n in bound.items() if u not in evicted}
+    assert ref.restored_bound() == full.restored_bound() == live
+
+
+def spoil_unknown(shim, pod, node):
+    # the server forgets the pod behind the agent's back
+    shim.service.Update(pb.UpdateRequest(pod_deletes=[pod.uid]), None)
+    return node
+
+
+def spoil_expired(shim, pod, node):
+    # the assumption outlives its TTL and the next cycle's sweep
+    # requeues the pod, with backoff
+    shim.clock.t += 31.0
+    assert not shim.client.cycle().bindings
+    assert not shim.cache.has_pod(pod.uid)
+    assert shim.queue.pending_counts()["backoff"] == 1
+    return node
+
+
+def spoil_wrong_node(shim, pod, node):
+    return "n1" if node == "n0" else "n0"  # where the agent bound it
+
+
+def spoil_already_bound(shim, pod, node):
+    # another informer stream delivered the bound pod first
+    shim.service.Update(pb.UpdateRequest(pod_updates=[
+        pb.PodEvent(pod=convert.pod_to(pod), bound_node=node)
+    ]), None)
+    return node
+
+
+def spoil_old_server(shim, pod, node):
+    # a server from before field 17 drops it and answers 0 and nothing
+    inner = shim.client.update
+
+    def old(request, timeout=10.0):
+        known = pb.UpdateRequest()
+        known.CopyFrom(request)
+        known.ClearField("bind_confirms")
+        resp = inner(known, timeout=timeout)
+        assert not resp.bind_confirms_applied and not resp.unconfirmed
+        return resp
+
+    shim.client.update = old
+    return node
+
+
+@pytest.mark.parametrize("spoil", [
+    spoil_unknown, spoil_expired, spoil_wrong_node, spoil_already_bound,
+    spoil_old_server,
+], ids=lambda f: f.__name__[6:])
+def test_a_confirmation_the_server_cannot_apply_falls_back(shims, spoil):
+    """No assumption on that node (or no server that knows the field):
+    the uid comes back, or the counts do not add up, and the agent
+    sends the whole pod before `batched()` returns: bound exactly
+    once, nothing pending, and a re-list still carries whole pods."""
+    shim = shims()
+    for i in range(2):
+        shim.agent.upsert_node(MakeNode(f"n{i}").capacity({"cpu": "8"}).obj())
+    pod = MakePod("p").req({"cpu": "1"}).obj()
+    shim.agent.upsert_pod(pod)
+    (b,) = shim.client.cycle().bindings
+    assert shim.cache.is_assumed(pod.uid)
+    node = spoil(shim, pod, b.node_name)
+    del shim.updates[:]
+    with shim.agent.batched():
+        shim.agent.upsert_pod(pod, bound_node=node)
+    first, second = shim.updates
+    assert [c.pod_uid for c in first[0].bind_confirms] in ([pod.uid], [])
+    assert not first[0].pod_updates and not first[1].bind_confirms_applied
+    if spoil is not spoil_old_server:
+        assert list(first[1].unconfirmed) == [pod.uid]
+    (ev,) = second[0].pod_updates
+    assert (ev.pod.metadata.uid, ev.bound_node) == (pod.uid, node)
+    assert not second[0].bind_confirms
+
+    assert shim.cache.counts() == {"nodes": 2, "bound": 1, "assumed": 0}
+    assert {d["pod"]["m"]["u"]: d["node"]
+            for d in shim.cache.dump_state()["bound"]} == {pod.uid: node}
+    assert sum(shim.queue.pending_counts().values()) == 0
+    shim.clock.t += 60.0
+    assert not shim.client.cycle().bindings  # and never bound again
+    assert shim.restored_bound() == {pod.uid: node}
+
+    del shim.updates[:]
+    shim.agent.relist()
+    ((req, _),) = shim.updates
+    assert [(e.pod.metadata.uid, e.bound_node) for e in req.pod_adds] == [
+        (pod.uid, node)]
+    assert not req.bind_confirms and not req.pod_updates
