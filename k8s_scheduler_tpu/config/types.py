@@ -85,7 +85,12 @@ class Extender:
 @dataclass
 class SchedulerConfiguration:
     profiles: list[Profile] = field(default_factory=lambda: [Profile()])
-    percentage_of_nodes_to_score: int = 0  # 0 = adaptive/all (upstream default)
+    # upstream's default, 0, is adaptive (50 - nodes/125 percent, floored
+    # at 5; every node under 100 nodes). Under 100, a pod is scored on the
+    # first k FEASIBLE nodes of a walk from its start offset, never
+    # refused while a node admits it; the start is a fixed rotation per
+    # pod and cycle, not upstream's one advancing index (ops/sampling.py)
+    percentage_of_nodes_to_score: int = 0
     pod_initial_backoff_seconds: float = 1.0
     pod_max_backoff_seconds: float = 10.0
     # gang scheduling (Coscheduling PodGroup CRD analogue, SURVEY.md C14)

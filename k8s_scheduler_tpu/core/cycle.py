@@ -32,6 +32,7 @@ from ..models.encoding import ClusterSnapshot
 from ..parallel.mesh import mesh_pin
 from ..ops import commit as commit_ops
 from ..ops import rounds as rounds_ops
+from ..ops import sampling as sampling_ops
 from ..ops import volumes as volumes_ops
 from . import faults as _faults
 
@@ -61,6 +62,23 @@ class CycleResult:
     # per commit round (zeros in scan mode) — convergence diagnostics
     diag_per_round: jnp.ndarray  # i32 [max_rounds, 3] (live claims,
     # capacity rejections, guard rejections) per round, summed over passes
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class SampledCycleResult(CycleResult):
+    """What a cycle program returns when it samples nodes
+    (`node_sample`): a CycleResult plus the two counts the flight record
+    and the `rpc.cycle` span carry. A class of its own, not two optional
+    fields: a program that takes a whole result as an argument (the
+    preemption program) has the result's tree in its cache key, and the
+    keys of programs that never sample must not move. The latency subset
+    (CycleDecision, and the multi-cycle loop built on it) samples the
+    same way and carries no counts: it is exactly what a bind needs."""
+
+    sample_k: jnp.ndarray  # i32 [] the k in force; 0 = every node considered
+    sample_narrowed_pods: jnp.ndarray  # i32 [] pods with more than k
+    # feasible nodes when first judged (the sample cost them a candidate)
 
 
 @jax.tree_util.register_dataclass
@@ -150,40 +168,40 @@ def multicycle_unsupported_reason(snap: ClusterSnapshot) -> str | None:
     return None
 
 
-def sampling_mask(snap: ClusterSnapshot, pct: int) -> jnp.ndarray:
-    """percentageOfNodesToScore: restrict each pod to a rotating window of
-    candidate nodes (bool [P, N]).
+def node_sample(snap: ClusterSnapshot, pct: int):
+    """percentageOfNodesToScore for one snapshot: None when every node is
+    a candidate by construction (the key at 100 or more, or a node pad
+    under upstream's 100-node floor), so such programs trace nothing of
+    it; else `(off i32 [P], k i32 [])` for `ops.sampling.sample_feasible`,
+    which the commit engines apply to each pod's feasibility in the state
+    it is judged in. `k` comes from the REAL node count."""
+    if pct >= 100 or snap.N < sampling_ops.MIN_FEASIBLE_NODES:
+        return None
+    return (
+        sampling_ops.start_offsets(snap),
+        sampling_ops.num_feasible_nodes_to_find(snap.num_nodes, pct),
+    )
 
-    Upstream numFeasibleNodesToFind semantics: clusters of <100 nodes (or
-    pct >= 100) consider everything; otherwise the candidate count is
-    numAllNodes * pct / 100 (adaptive pct = 50 - numAllNodes/125, floor 5,
-    when the knob is 0), floored at 100 nodes. Upstream stops SCANNING
-    after finding that many feasible nodes from a rotating start index;
-    the batched analogue samples that many CANDIDATE nodes per pod from a
-    deterministic per-pod rotation — a documented deviation (data-
-    dependent early exit is anti-TPU), strictly more selective, and the
-    sample rotates with the pod's queue rank exactly so different pods
-    spread load over different nodes."""
-    n = snap.num_nodes.astype(jnp.int32)  # real node count (traced)
-    if pct >= 100:
-        return jnp.ones((snap.P, snap.N), bool)
-    if pct <= 0:
-        adaptive = jnp.maximum(50 - n // 125, 5)
-    else:
-        adaptive = jnp.int32(pct)
-    k = jnp.maximum(n * adaptive // 100, 100)  # min-feasible floor
-    # rotate per pod rank AND per cycle: a pod whose feasible nodes fall
-    # outside this cycle's window gets a different window next cycle, so
-    # sampling delays but never permanently starves (upstream's rotating
-    # global scan index has the same property)
-    off = (
-        snap.pod_order.astype(jnp.int32) * 75347
-        + snap.cycle_index.astype(jnp.int32) * 31337
-    ) % jnp.maximum(n, 1)
-    col = jax.lax.broadcasted_iota(jnp.int32, (snap.P, snap.N), 1)
-    win = (col - off[:, None]) % jnp.maximum(n, 1)
-    # clusters under the floor consider every node (win < k always)
-    return win < k
+
+def _with_sample_counts(result: CycleResult, snap, sample, narrowed):
+    """`result` with the sample's two counts, where the program sampled."""
+    if sample is None:
+        return result
+    k = sample[1]
+    return SampledCycleResult(
+        **{f.name: getattr(result, f.name)
+           for f in dataclasses.fields(CycleResult)},
+        sample_k=jnp.where(k < snap.num_nodes.astype(jnp.int32), k, 0),
+        sample_narrowed_pods=narrowed,
+    )
+
+
+def _sample_mark(pct: int) -> str:
+    """Appended to the key's value in a program name. A program that
+    samples says HOW in its name: the executable store keys on names,
+    and an entry built when the sample was a window of node indices
+    must never load."""
+    return "" if pct >= 100 else ":feasible"
 
 
 def _unique(fn, base: str, disc: str = ""):
@@ -577,11 +595,9 @@ def _make_cycle_body(
             # filters; rejections are attributed to the base mask)
             smask = smask & snap.pod_extender_mask
             sscore = sscore + snap.pod_extender_score
-        smask_all_nodes = smask  # pre-sampling (preemption gate base)
-        if percentage_of_nodes_to_score < 100:
-            # 0 = adaptive percentage, like upstream's default; the <100-
-            # node floor inside sampling_mask keeps small clusters exact
-            smask = smask & sampling_mask(snap, percentage_of_nodes_to_score)
+        # 0 = adaptive percentage, like upstream's default. The static
+        # mask stays whole: the engines sample each pod's FEASIBLE nodes
+        sample = node_sample(snap, percentage_of_nodes_to_score)
         if snap.has_inter_pod_affinity or snap.has_topology_spread:
             # materialize the shared match tables at CYCLE scope: the scan
             # body would otherwise compute-and-cache them inside its own
@@ -621,8 +637,10 @@ def _make_cycle_body(
                 max_rounds=max_rounds,
                 score_anchor_fn=lambda nr: fw.score_anchor(ctx, nr),
                 pv_choice_fn=_make_pv_choice_fn(ctx),
+                sample=sample,
                 **(rounds_kw or {}),
             )
+            narrowed = rres.sample_narrowed
             # Final-state work (dynamic reject attribution + the NodePorts
             # part of the preemption gate) only matters for pods that never
             # placed — computed on a COMPACTED view instead of a full
@@ -699,7 +717,9 @@ def _make_cycle_body(
                 dyn_fn=dyn_fn,
                 extra=extra,
                 update_fn=update_fn,
+                sample=sample,
             )
+            narrowed = result.sample_narrowed
         dropped = jnp.zeros_like(snap.pod_valid)
         if gang_scheduling:
             result, dropped = _gang_unwind(snap, result)
@@ -709,14 +729,14 @@ def _make_cycle_body(
             return CycleDecision(
                 result.assignment, result.node_requested, unsched, dropped
             )
-        return CycleResult(
+        return _with_sample_counts(CycleResult(
             result.assignment, result.node_requested, unsched, dropped,
             srejects + result.dyn_aux,
             _pv_claimed_after_unwind(
                 snap, ctx, result.extra, result.assignment, dropped
             ),
             rounds_used, accepted_per_round, diag_per_round,
-        )
+        ), snap, sample, narrowed)
 
     return cycle
 
@@ -775,7 +795,8 @@ def build_cycle_fn(
         cycle, "cycle",
         disc=(
             f"{commit_mode}|{gang_scheduling}|{max_rounds}|"
-            f"{percentage_of_nodes_to_score}|{outputs}|"
+            f"{percentage_of_nodes_to_score}"
+            f"{_sample_mark(percentage_of_nodes_to_score)}|{outputs}|"
             f"{sorted((rounds_kw or {}).items())!r}|{_fw_disc(fw)}"
         ),
     )
@@ -804,6 +825,7 @@ def build_packed_cycle_fn(spec, **kw):
         packed, "packed_cycle",
         disc=(
             repr(spec.key()) + repr(sorted(scalars.items()))
+            + _sample_mark(kw.get("percentage_of_nodes_to_score", 0))
             + _fw_disc(kw.get("framework"))
         ),
     )
@@ -857,6 +879,7 @@ def build_arena_cycle_fn(spec, **kw):
         arena, "arena_cycle",
         disc=(
             repr(spec.key()) + repr(sorted(scalars.items()))
+            + _sample_mark(kw.get("percentage_of_nodes_to_score", 0))
             + _fw_disc(kw.get("framework"))
         ),
     )
@@ -1040,7 +1063,8 @@ def build_packed_multicycle_fn(
         multicycle, "multicycle",
         disc=(
             f"k{k}|{commit_mode}|{gang_scheduling}|{max_rounds}|"
-            f"{percentage_of_nodes_to_score}|"
+            f"{percentage_of_nodes_to_score}"
+            f"{_sample_mark(percentage_of_nodes_to_score)}|"
             f"{sorted((rounds_kw or {}).items())!r}|carry{int(carry_in)}|"
             + repr(spec.key()) + _fw_disc(fw)
         ),
@@ -1346,26 +1370,20 @@ def build_packed_cycle_carry_fn(
         ctx = CycleContext(snap)
         ctx._cache.update(stable)
         ctx._cache["matched_pending"] = carry["mp"]
-        sbase_all = carry["sbase"]
+        sbase = carry["sbase"]
         if extender_args:
             # merge exactly like the fallback path merges the snapshot's
             # extender fields (rejections land in the base mask)
-            sbase_all = jnp.where(
-                emask, sbase_all + escore, rounds_ops.NEG_INF
+            sbase = jnp.where(
+                emask, sbase + escore, rounds_ops.NEG_INF
             )
         elif snap.has_extender:
-            sbase_all = jnp.where(
-                snap.pod_extender_mask,
-                sbase_all + snap.pod_extender_score,
-                rounds_ops.NEG_INF,
-            )
-        sbase = sbase_all
-        if percentage_of_nodes_to_score < 100:
             sbase = jnp.where(
-                sampling_mask(snap, percentage_of_nodes_to_score),
-                sbase_all,
+                snap.pod_extender_mask,
+                sbase + snap.pod_extender_score,
                 rounds_ops.NEG_INF,
             )
+        sample = node_sample(snap, percentage_of_nodes_to_score)
         extra = fw.extra_init(ctx)
 
         def view_ctx(vsnap, vmp):
@@ -1389,6 +1407,7 @@ def build_packed_cycle_carry_fn(
             score_anchor_fn=lambda nr: fw.score_anchor(ctx, nr),
             pv_choice_fn=_make_pv_choice_fn(ctx),
             mesh=mesh,
+            sample=sample,
             **(rounds_kw or {}),
         )
         result = commit_ops.CommitResult(
@@ -1401,19 +1420,20 @@ def build_packed_cycle_carry_fn(
         if gang_scheduling:
             result, dropped = _gang_unwind(snap, result)
         unsched = snap.pod_valid & (result.assignment < 0)
-        return CycleResult(
+        return _with_sample_counts(CycleResult(
             result.assignment, result.node_requested, unsched, dropped,
             result.dyn_aux,
             _pv_claimed_after_unwind(
                 snap, ctx, rres.extra, result.assignment, dropped
             ),
             rres.rounds_used, rres.accepted_per_round, rres.diag_per_round,
-        )
+        ), snap, sample, rres.sample_narrowed)
 
     return _jit(
         cycle, "carry_cycle",
         disc=(
-            f"{gang_scheduling}|{percentage_of_nodes_to_score}|"
+            f"{gang_scheduling}|{percentage_of_nodes_to_score}"
+            f"{_sample_mark(percentage_of_nodes_to_score)}|"
             f"{max_rounds}|ext{int(extender_args)}|"
             f"{sorted((rounds_kw or {}).items())!r}|"
             f"mesh{_mesh_desc(mesh)}|"

@@ -254,6 +254,12 @@ class CycleHandle:
         self._pipe = pipe
         self.result = result  # CycleResult/CycleDecision device futures
         self._slim = slim  # (i16|i32 [P], u8 [P]) device futures
+        # a program that samples nodes (cycle.SampledCycleResult) hands
+        # back two more scalars, fetched in the same transfer
+        self._sample = (
+            (result.sample_k, result.sample_narrowed_pods)
+            if hasattr(result, "sample_k") else None
+        )
         self._wbuf = wbuf
         self._bbuf = bbuf
         self._stable = stable
@@ -274,8 +280,8 @@ class CycleHandle:
             t0 = now()
             self._pipe.stats["t_decision_start"] = t0
             try:
-                a, flags = self._pipe.fetch_decisions(
-                    lambda: jax.device_get(self._slim)
+                (a, flags), sample = self._pipe.fetch_decisions(
+                    lambda: jax.device_get((self._slim, self._sample))
                 )
             except Exception as e:
                 # a failed fetch consumes the cycle: no bind can come of
@@ -298,6 +304,10 @@ class CycleHandle:
             st["decision_wait_ms"] = (self._t_decisions - t0) * 1e3
             st["t_decision_end"] = self._t_decisions
             st["fetch_bytes"] = int(a.nbytes + flags.nbytes)
+            if sample is not None:
+                st["fetch_bytes"] += sum(int(v.nbytes) for v in sample)
+                st["sample_k"] = int(sample[0])
+                st["sample_narrowed_pods"] = int(sample[1])
             # what the un-slimmed fetch of the same fields would move
             st["fetch_bytes_full"] = int(a.shape[0] * (4 + 1 + 1))
             self._pipe._fetch_bytes_total += st["fetch_bytes"]
@@ -431,6 +441,7 @@ class CycleHandle:
         """Drop every device reference so the slot's arena blocks free
         (the allocator then recycles them for the next upload)."""
         self.result = self._slim = self._diag = self._pre = None
+        self._sample = None
         self._wbuf = self._bbuf = self._stable = self._emask = None
 
 

@@ -215,6 +215,9 @@ class Scheduler:
         self._pod_spans = False
         self._cycle_trace = None
         self.last_cycle_seqs: list[int] = []
+        # percentageOfNodesToScore's two counts over the same records
+        # (empty when no program of the cycle sampled): the RPC span's
+        self.last_cycle_sample: dict[str, int] = {}
         # durable state (state/ package): restore-then-journal. Attach
         # happens here — after queue/cache exist, before any cycle — so
         # a standby that just won the FileLease resumes with the exact
@@ -966,6 +969,7 @@ class Scheduler:
         self._cycle_fault = False
         self._cycle_trace = trace
         self.last_cycle_seqs = []
+        self.last_cycle_sample = {}
         t_entry = _spans.now() if trace is not None else 0.0
         # the per-pod stamp sites run only while some pod is bound to a
         # trace (Submit registers them; the agent path never does)
@@ -2705,6 +2709,17 @@ class Scheduler:
             # | none), one sample per speculation — feeds the
             # observer's speculation_thrash abandon-rate EWMA
             rec.speculation = speculation
+        if "sample_k" in st:
+            # the cycle program sampled nodes (core/cycle.node_sample):
+            # the k in force and the pods it cost a candidate, as fetched
+            # with the decisions (core/pipeline.CycleHandle)
+            k, narrowed = st["sample_k"], st["sample_narrowed_pods"]
+            rec.counts.update(sample_k=k, sample_narrowed_pods=narrowed)
+            seen = self.last_cycle_sample
+            seen["sample_k"] = k
+            seen["sample_narrowed_pods"] = (
+                seen.get("sample_narrowed_pods", 0) + narrowed
+            )
         qc = self.queue.pending_counts()
         sb, ub, bb, pb, vb = before
         rec.counts.update(

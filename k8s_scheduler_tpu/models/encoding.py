@@ -215,9 +215,10 @@ class ClusterSnapshot:
     num_pending: np.ndarray
     num_existing: np.ndarray
     num_domains: np.ndarray
-    # monotone per-encoder cycle counter (0-d i32): rotates the node-
-    # sampling windows across cycles so percentageOfNodesToScore can never
-    # permanently starve a pod whose feasible nodes sit outside one window
+    # monotone per-encoder cycle counter (0-d i32): turns the start of
+    # each pod's walk over the nodes from cycle to cycle, so
+    # percentageOfNodesToScore samples another stretch of its feasible
+    # nodes each time (ops/sampling.start_offsets)
     cycle_index: np.ndarray
 
     # --- nodes [N...] ---

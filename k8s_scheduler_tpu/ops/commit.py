@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from . import argsel
+from . import sampling
 
 NEG_INF = -1e9
 
@@ -58,6 +59,8 @@ class CommitResult:
     node_requested: jnp.ndarray  # f32 [N, R] post-commit
     extra: Any  # final hook state (e.g. running domain counts)
     dyn_aux: Any = None  # per-pod stacked dyn_fn aux (None w/ 2-tuple dyn_fn)
+    sample_narrowed: jnp.ndarray | None = None  # i32 [] pods whose
+    # feasible nodes outnumbered the sample's k (None: no sampling)
 
 
 def greedy_commit(
@@ -73,6 +76,9 @@ def greedy_commit(
     dyn_fn: DynFn,
     extra: Any = None,
     update_fn: UpdateFn | None = None,
+    sample=None,  # (off i32 [P], k i32 []) | None — percentageOfNodesTo-
+    # Score: a pod is scored on the first k nodes feasible in the state
+    # it is scheduled in, walking from off[p] (ops/sampling.py)
 ) -> CommitResult:
     P, N = static_mask.shape
 
@@ -86,7 +92,12 @@ def greedy_commit(
         # normalize-over-feasible scoring); AND it again here so a dyn_fn
         # that ignores its 4th arg can never bypass static filters
         feasible = feasible & static_mask[p]
-        score = jnp.where(feasible, static_score[p] + dyn_score, NEG_INF)
+        scored = feasible
+        if sample is not None:
+            off, k = sample
+            scored, narrowed = sampling.sample_feasible(feasible, off[p], k)
+            aux = (aux, narrowed & pod_valid[p])
+        score = jnp.where(scored, static_score[p] + dyn_score, NEG_INF)
         # A nominated node (set by a previous preemption) is honored when
         # feasible, regardless of score — upstream evaluates the nominated
         # node first and keeps it if it passes filters.
@@ -109,12 +120,18 @@ def greedy_commit(
     (node_req_final, extra_final), (pods, assigned, auxs) = jax.lax.scan(
         step, (node_requested, extra), jnp.arange(P, dtype=jnp.int32)
     )
+    narrowed = None
+    if sample is not None:
+        auxs, narrow = auxs
+        narrowed = jnp.sum(narrow, dtype=jnp.int32)
     assignment = jnp.zeros(P, jnp.int32).at[pods].set(assigned)
     # ys arrive in rank order; re-scatter to pod order like `assignment`
     dyn_aux = jax.tree_util.tree_map(
         lambda a: jnp.zeros_like(a).at[pods].set(a), auxs
     )
-    return CommitResult(assignment, node_req_final, extra_final, dyn_aux)
+    return CommitResult(
+        assignment, node_req_final, extra_final, dyn_aux, narrowed
+    )
 
 
 def unwind_assignments(

@@ -85,6 +85,7 @@ from ..models import encoding as enc
 from ..parallel.mesh import MESH_AXES, mesh_pin
 from . import argsel
 from . import interpod as interpod_ops
+from . import sampling
 
 NEG_INF = -1e9
 _REL_EPS = 1e-5  # mirrors ops/resources.py fit slack
@@ -124,6 +125,9 @@ class RoundsResult:
     diag_per_round: jnp.ndarray  # i32 [max_rounds, 3] summed over passes:
     # (live claims, capacity rejections, guard rejections) — convergence
     # diagnostics, negligible cost
+    sample_narrowed: jnp.ndarray | None = None  # i32 [] pods whose
+    # feasible nodes outnumbered the sample's k in round 1 (None: the
+    # cycle does not sample)
 
 
 def compact_window(P: int, compact: int = 8) -> int:
@@ -266,6 +270,15 @@ def rounds_commit(
     # all-reducing a replicated [B, N] (the single largest collective in
     # AUDIT_SHARDED_r05: 23.6 MB of 43.2 MB total). None (the default,
     # and every single-device build) changes nothing.
+    sample=None,  # (off i32 [P], k i32 []) | None — percentageOfNodesTo-
+    # Score (core/cycle.node_sample): each round, a pod's candidates are
+    # the first k nodes FEASIBLE FOR IT IN THAT ROUND'S STATE, in its
+    # rotation order from off (ops/sampling.py); all of them when there
+    # are fewer. A pod whose sample fills up in-round meets a fresh
+    # sample of what is still feasible next round, so a zero-accept
+    # round still means every active pod's mask was empty: the
+    # "unplaced => infeasible against the final state" invariant holds
+    # as without sampling.
 ) -> RoundsResult:
     P, N = (sbase if sbase is not None else static_mask).shape
     S = m_pending.shape[0]
@@ -581,6 +594,19 @@ def rounds_commit(
             vsnap, vmp, node_req, ext, vsmask
         )
         mask = mask & vsmask & act_v[:, None]
+        narrowed = None
+        if sample is not None:
+            off, k = sample
+            sampled, narrowed = sampling.sample_feasible(
+                mask, off if identity_gid else off[gid], k
+            )
+            # upstream evaluates the nominated node before the walk: a
+            # feasible one stays claimable wherever the sample ended
+            # (pod_nominated is -1 where there is none: no column)
+            col = jax.lax.broadcasted_iota(jnp.int32, (1, N), 1)
+            mask = sampled | (
+                mask & (col == vsnap.pod_nominated[:, None])
+            )
         base = vsbase + score  # un-rounded; claim ranking re-rounds with
         # the per-pass anchor delta applied (see score_node_anchor)
         tie = _tie_break(gid, N)
@@ -866,11 +892,11 @@ def rounds_commit(
         ext = local_update_fn(update_batched_view_fn)(
             vsnap, vmp, ext, acc, jnp.where(acc, acc_node, 0)
         )
-        return acc, acc_node, node_req, ext, diag
+        return acc, acc_node, node_req, ext, diag, narrowed
 
     # ---- round 1: full pending set ----
     gid0 = jnp.arange(P, dtype=jnp.int32)
-    acc0, node0, node_req, extra, diag0 = one_round(
+    acc0, node0, node_req, extra, diag0, narrowed0 = one_round(
         gid0, snap.pod_valid, snap.node_requested, extra, passes_round0,
         identity_gid=True,
     )
@@ -904,7 +930,7 @@ def rounds_commit(
         start = jnp.minimum(skip, jnp.maximum(P - B, 0))
         gid = jax.lax.dynamic_slice(order, (start,), (B,))
         act_v = active[gid]
-        accepted, node_of, node_req, ext, diag = one_round(
+        accepted, node_of, node_req, ext, diag, _ = one_round(
             gid, act_v, node_req, ext, passes
         )
         placed = placed.at[gid].set(jnp.where(accepted, node_of, placed[gid]))
@@ -939,4 +965,9 @@ def rounds_commit(
         rounds_used=rounds_used,
         accepted_per_round=acc_hist,
         diag_per_round=diag_hist,
+        # round 1 judged every valid pod; an invalid row's mask is empty
+        sample_narrowed=(
+            None if narrowed0 is None
+            else jnp.sum(narrowed0, dtype=jnp.int32)
+        ),
     )

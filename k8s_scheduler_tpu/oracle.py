@@ -834,6 +834,54 @@ def feasible_nodes(pod: Pod, state: OracleState, filters) -> list[int]:
     return feasible
 
 
+# --------------------------------------------------------------------------
+# percentageOfNodesToScore: the filter pass's early stop, as a plain walk
+#
+# Written from upstream's description (numFeasibleNodesToFind and
+# findNodesThatPassFilters in pkg/scheduler/schedule_one.go), from memory.
+# The device computes the same set with a prefix count (ops/sampling.py);
+# tests/test_sampling.py holds the two equal.
+# --------------------------------------------------------------------------
+
+
+def num_feasible_nodes_to_find(n: int, pct: int) -> int:
+    """How many feasible nodes end the filter pass on a cluster of `n`
+    nodes: all of them under 100 nodes (minFeasibleNodesToFind) or at
+    100%; else n * percentage / 100, floored at 100, where percentage is
+    the key, or when it is 0 (the default) `50 - n/125`, floored at 5
+    (minFeasibleNodesPercentageToFind)."""
+    if n < 100 or pct >= 100:
+        return n
+    if pct <= 0:
+        pct = max(50 - n // 125, 5)
+    return max(n * pct // 100, 100)
+
+
+def sample_start(rank: int, cycle_index: int, n: int) -> int:
+    """Where a pod's walk starts. DEPARTURE from upstream, which keeps one
+    nextStartNodeIndex and advances it by the nodes each pod visited: a
+    batch schedules its pods together, so the start is a fixed rotation
+    of the pod's queue rank and the encoder's cycle index instead."""
+    return (rank * 75347 + cycle_index * 31337) % max(n, 1)
+
+
+def sampled_candidates(feasible_row: Sequence[bool], start: int,
+                       k: int) -> list[int]:
+    """The nodes a pod is scored on: walk the node indices from `start`,
+    wrap at the end, keep each feasible one, stop at `k`. `feasible_row`
+    is judged in the state the pod is scheduled in. Fewer than `k`
+    feasible nodes: all of them."""
+    n = len(feasible_row)
+    found: list[int] = []
+    for step in range(n):
+        i = (start + step) % n
+        if feasible_row[i]:
+            found.append(i)
+            if len(found) == k:
+                break
+    return found
+
+
 @dataclasses.dataclass
 class _CrossNodeRaws:
     """Raw scores needing cross-node normalization over the feasible set
@@ -1421,12 +1469,19 @@ def schedule(
     pvcs: Sequence = (),
     pvs: Sequence = (),
     storage_classes: Sequence = (),
+    percentage_of_nodes_to_score: int = 100,
+    cycle_index: int = 0,
 ) -> list[OracleDecision]:
     """Sequential greedy scheduling in (priority desc, creation asc) order —
-    the reference's queue order (PrioritySort QueueSort plugin)."""
+    the reference's queue order (PrioritySort QueueSort plugin). Under
+    `percentage_of_nodes_to_score` < 100 a pod is scored on
+    `sampled_candidates` only (`cycle_index` is the snapshot's: it turns
+    the start); cross-node normalisation stays over all feasible nodes,
+    as on the device."""
     state = OracleState.build(nodes, existing, pvcs, pvs, storage_classes)
+    k = num_feasible_nodes_to_find(len(nodes), percentage_of_nodes_to_score)
     decisions: dict[int, int] = {}
-    for pi in queue_order(pending):
+    for rank, pi in enumerate(queue_order(pending)):
         pod = pending[pi]
         feasible = feasible_nodes(pod, state, filters)
         if not feasible:
@@ -1434,6 +1489,12 @@ def schedule(
             continue
         best, best_score = -1, -float("inf")
         cn = _CrossNodeRaws.compute(pod, state, feasible, weights)
+        if k < len(feasible):
+            row = [False] * len(nodes)
+            for i in feasible:
+                row[i] = True
+            feasible = sorted(sampled_candidates(
+                row, sample_start(rank, cycle_index, len(nodes)), k))
         for i in feasible:
             s = _score_pod(pod, state, i, weights, cn)
             if s > best_score:

@@ -329,6 +329,7 @@ class SchedulerService:
                     "rpc.cycle", trace, t_in, t_out, root_of=caller,
                     seqs=list(s.last_cycle_seqs), bindings=n_bind,
                     events=n_ev, evictions=len(resp.evictions),
+                    **s.last_cycle_sample,
                 )
             return resp
 

@@ -468,3 +468,27 @@ def test_proto_text_and_generated_module_agree(message, field, number, kind):
     else:
         assert fd.type == {"string": fd.TYPE_STRING,
                            "int32": fd.TYPE_INT32}[base]
+
+
+@pytest.mark.parametrize("pct,n_nodes,want", [
+    (0, 160, {"sample_k": 100, "sample_narrowed_pods": 5}),
+    (0, 60, {}),  # under upstream's 100-node floor: nothing is traced
+    (100, 160, {}),
+])
+def test_sample_counts_on_the_record_and_on_rpc_cycle(
+        armed, pct, n_nodes, want):
+    """A cycle program that samples nodes (percentageOfNodesToScore
+    under 100 on a cluster of 100 nodes or more) hands back the k in
+    force and the pods it cost a candidate: both are on the cycle's
+    flight record and on `rpc.cycle`. A program that does not sample
+    stamps neither."""
+    svc = SchedulerService(config=SchedulerConfiguration(
+        percentage_of_nodes_to_score=pct))
+    svc.Update(cluster_request(n_nodes=n_nodes, n_pods=5), None)
+    assert len(svc.Cycle(pb.CycleRequest(), None).bindings) == 5
+    (root,) = [s for s in armed.snapshot() if s.name == "rpc.cycle"]
+    keys = ("sample_k", "sample_narrowed_pods")
+    assert {k: root.attrs[k] for k in keys if k in root.attrs} == want
+    (rec,) = [r for r in svc.scheduler.flight.snapshot()
+              if r.seq in root.attrs["seqs"]]
+    assert {k: rec.counts[k] for k in keys if k in rec.counts} == want
