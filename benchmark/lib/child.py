@@ -144,6 +144,16 @@ class Server:
             raise self.fail(f"/debug/flightrecorder answered {status}")
         return json.loads(body)["cycles"]
 
+    def trace_events(self, last: int) -> list[dict] | None:
+        """The Chrome-trace events of `/debug/traces`: the last `last`
+        cycles' lanes and every span the ring still holds. None from a
+        program that does not serve them: its span metrics are left
+        out, the run is not failed."""
+        status, body = self.http(f"/debug/traces?last={last}")
+        if status != 200:
+            return None
+        return json.loads(body).get("traceEvents")
+
     def health(self) -> dict:
         status, body = self.http("/healthz")
         if status != 200:

@@ -2,14 +2,20 @@
 pending pods, and the probe pools — every size from the configuration's
 file, every draw from the seed.
 
-The shapes of `utils/synth.make_cluster`/`make_pods` are kept (zone,
-region and node-type labels by node index; one `app` label per pod and
+The shapes of `utils/synth.make_cluster`/`make_pods` are kept (zone
+and region labels by node index; one `app` label per pod and
 constraints that select the pod's own app), copied here so the yardstick
-does not move with the program's test fixtures. Two things differ, both
+does not move with the program's test fixtures. Three things differ, all
 for steadiness: node and pod sizes come from the configuration (synth's
-make a full cluster), and a constraint is carried by an exact count of
+make a full cluster); a constraint is carried by an exact count of
 pods in every block of `BLOCK`, shuffled by the seed, not by a coin per
-pod — every seed offers the same multiset in another order.
+pod — every seed offers the same multiset in another order; and every
+node type stands in every zone (synth's `i % 3` beside `i % 6` puts a
+type in two zones of six, and a pod with a selector AND a zone spread
+whose two zones are not the app's emptiest fits no node, ever: 1.4% of
+all pods offered, a backlog that grows all through a run — PERF.md
+section 6, PR 29). What a cell refuses is said in the configuration
+(`unschedulable`), a fixed count, not left to the labels.
 """
 
 from __future__ import annotations
@@ -57,6 +63,25 @@ class Deployment:
             buf[0] += BLOCK
         out, buf[1] = buf[1][:count], buf[1][count:]
         return out
+
+    def unschedulable(self) -> list[Pod]:
+        """The configuration's `unschedulable` pods: scheduler_perf's
+        Unschedulable workload, pods that ask for more CPU than a node
+        has and stay pending beside the measured ones. A fixed count,
+        the oldest of the lowest priority; they ride the warm-up batch
+        (named `warm-`, so not among the pods a run attempts), so that
+        the programs that serve a refusal are warm before the window.
+        The program's queue parks them after that refusal."""
+        uc = self.cfg.get("unschedulable") or {}
+        return [
+            MakePod(f"warm-unschedulable-{i}")
+            .req({"cpu": uc["cpu"], "memory": uc["memory"]})
+            .labels({"app": "unschedulable"})
+            .priority(0)
+            .created(-2.0 + i * 1e-6)
+            .obj()
+            for i in range(int(uc.get("count", 0)))
+        ]
 
     def probe(self, pool: ProbePool) -> Pod:
         """A best-effort pod that only its pool admits: a selector and a
@@ -149,7 +174,8 @@ def deployment(cfg: dict, seed: int, cut: dict | None = None) -> Deployment:
         ).labels({
             ZONE_KEY: ZONES[i % len(ZONES)],
             "topology.kubernetes.io/region": REGIONS[i % len(REGIONS)],
-            "node-type": NODE_TYPES[i % 3],
+            # every type in every zone (see the module's note)
+            "node-type": NODE_TYPES[(i // len(ZONES)) % len(NODE_TYPES)],
         })
         if i < n - n_pool:
             if tainted[i]:

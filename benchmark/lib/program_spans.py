@@ -5,12 +5,14 @@ split by which of them was open.
 Three sources, all taken under `--trace 1` only:
 
 - the `X` events of `/debug/traces` (`core/spans.spans_to_chrome_events`
-  and `core/flight_recorder.to_chrome_trace`): the spans `rpc.update`,
+  and `core/flight_recorder.to_chrome_trace`): every span the export
+  carries, which is an event with `args.span_id` (`rpc.update`,
   `update.convert`, `update.apply`, `rpc.cycle`, `cycle.lock_wait`,
-  `cycle.pop`, `cycle.snapshot`, `cycle.respond`, with `ts` and `dur` in
-  microseconds of the recorder's clock since its epoch, `args.span_id`,
-  `args.parent`, and on `rpc.cycle` `args.seqs`, the flight records it
-  committed; and the `cycle[<seq>]` slices, those records' `total`;
+  `cycle.pop`, `cycle.snapshot`, `cycle.respond` today; whatever a later
+  PR stamps, under its own name), with `ts` and `dur` in microseconds
+  of the recorder's clock since its epoch, `args.parent`, and on
+  `rpc.cycle` `args.seqs`, the flight records it committed; and the
+  `cycle[<seq>]` slices, those records' `total`;
 - the `.xplane.pb`: the device operations, and the `sched.dispatch`
   host events `core/pipeline` wraps around every dispatch, whose stats
   carry `t_us`, the recorder's clock at the event's start. The event's
@@ -24,12 +26,14 @@ A program without these spans (the parent of the PR that added them)
 gives no `rpc.*` event: `collect` then returns None and every metric
 read from it is left out of the line.
 
-`run.py` does not call this yet: `program_spans.wiring.txt` is the
-edit that makes it, and `benchmark/wired_copy.py` a copy that has it.
+A per-layer metric over a span is a layer file with `"source_kind":
+"program_span"`, the span's name under `select` and `median` or `mean`
+under `reduce`, and a `per_layer` entry: data only.
 """
 
 from __future__ import annotations
 
+import bisect
 import re
 import statistics
 
@@ -37,9 +41,6 @@ from .xplane import DEVICE_PLANE, OPS_LINE
 
 ANCHOR = "sched.dispatch"
 ANCHOR_SPREAD_US = 1000.0
-UPDATE_CHILDREN = ("update.convert", "update.apply")
-CYCLE_CHILDREN = ("cycle.lock_wait", "cycle.pop", "cycle.snapshot",
-                  "cycle.respond")
 _RECORD = re.compile(r"^cycle\[(\d+)\]$")
 
 
@@ -161,45 +162,72 @@ def idle_partition(spans: dict, offset_us: float, planes: list,
 
 def cycle_rows(spans: dict, record_ms: dict, lo_us: float,
                hi_us: float) -> list[dict]:
-    """One row per `rpc.cycle` span that began in [lo_us, hi_us], in
-    milliseconds: the span, its children, the `total` of its flight
-    records (`records`, None when one is missing from the export) and
-    its self time; and the `Update`s that began since the previous
-    `rpc.cycle` ended, summed: `rpc.update`, its two children and its
-    self time."""
+    """One row per `rpc.cycle` span that began in [lo_us, hi_us]: an
+    iteration, in milliseconds. A row holds, under its own name, the
+    summed duration of EVERY span name the window's spans carry (0.0
+    where the iteration has none), so a span stamped by a later PR needs
+    no edit here. A span belongs to the iteration whose `rpc.cycle` or
+    `rpc.update` it descends from by `args.parent`; one with no such
+    ancestor goes, with what descends from it, to the iteration in which
+    it began (the previous `rpc.cycle`'s end to this one's end), as an
+    `rpc.update` itself does whatever called it. Beside
+    the names: `updates` (count), `records` (the `total` of the flight
+    records in `seqs`, None when one is missing from the export),
+    `cycle.self` (the span less its records and its direct children)
+    and `update.self` (the `Update`s less their direct children)."""
     cycles = sorted(spans.get("rpc.cycle", ()), key=lambda s: s["ts"])
-    updates = sorted(spans.get("rpc.update", ()), key=lambda s: s["ts"])
-    children: dict = {}
-    for name in UPDATE_CHILDREN + CYCLE_CHILDREN:
-        for s in spans.get(name, ()):
-            by = children.setdefault(s["args"]["parent"], {})
-            by[name] = by.get(name, 0.0) + s["dur"] / 1e3
-    rows, u = [], 0
-    for c in cycles:
-        mine = []  # began since the previous cycle ended
-        while u < len(updates) and updates[u]["ts"] < c["ts"] + c["dur"]:
-            mine.append(updates[u])
-            u += 1
-        if not lo_us <= c["ts"] <= hi_us:
-            continue
-        row = {"rpc.cycle": c["dur"] / 1e3, "updates": len(mine)}
-        own = children.get(c["args"]["span_id"], {})
-        for name in CYCLE_CHILDREN:
-            row[name] = own.get(name, 0.0)
-        seqs = c["args"].get("seqs", [])
+    index = {c["args"]["span_id"]: i for i, c in enumerate(cycles)}
+    # the cycle lock serialises `Cycle`s, so the ends are sorted too
+    ends = [c["ts"] + c["dur"] for c in cycles]
+    by_id = {s["args"]["span_id"]: s
+             for group in spans.values() for s in group}
+    owner: dict = {}  # span_id -> index into `cycles`, or None
+
+    def iteration(s) -> int | None:
+        sid = s["args"]["span_id"]
+        if sid not in owner:
+            owner[sid] = None  # a parent loop ends here
+            parent = by_id.get(s["args"].get("parent"))
+            if s["name"] == "rpc.cycle":
+                owner[sid] = index[sid]
+            elif parent is not None and s["name"] != "rpc.update":
+                owner[sid] = iteration(parent)
+            else:
+                i = bisect.bisect_right(ends, s["ts"])
+                owner[sid] = i if i < len(ends) else None
+        return owner[sid]
+
+    sums: list[dict] = [{} for _ in cycles]
+    updates = [0] * len(cycles)
+    # per iteration, the direct children of its `rpc.cycle` and of its
+    # `rpc.update`s (a child is in its parent's iteration), for self time
+    under = {"rpc.cycle": [0.0] * len(cycles),
+             "rpc.update": [0.0] * len(cycles)}
+    for name, group in spans.items():
+        for s in group:
+            i = iteration(s)
+            if i is None:
+                continue
+            ms = s["dur"] / 1e3
+            sums[i][name] = sums[i].get(name, 0.0) + ms
+            updates[i] += name == "rpc.update"
+            parent = by_id.get(s["args"].get("parent"))
+            if parent is not None and parent["name"] in under:
+                under[parent["name"]][i] += ms
+    window = [i for i, c in enumerate(cycles) if lo_us <= c["ts"] <= hi_us]
+    names = sorted({k for i in window for k in sums[i]})
+    rows = []
+    for i in window:
+        row = {k: sums[i].get(k, 0.0) for k in names}
+        row["updates"] = updates[i]
+        seqs = cycles[i]["args"].get("seqs", [])
         row["records"] = (
             sum(record_ms[q] for q in seqs)
             if all(q in record_ms for q in seqs) else None)
         row["cycle.self"] = None if row["records"] is None else (
-            row["rpc.cycle"] - row["records"]
-            - sum(row[name] for name in CYCLE_CHILDREN))
-        row["rpc.update"] = sum(s["dur"] for s in mine) / 1e3
-        for name in UPDATE_CHILDREN:
-            row[name] = sum(
-                children.get(s["args"]["span_id"], {}).get(name, 0.0)
-                for s in mine)
-        row["update.self"] = row["rpc.update"] - sum(
-            row[name] for name in UPDATE_CHILDREN)
+            row["rpc.cycle"] - row["records"] - under["rpc.cycle"][i])
+        row["update.self"] = (
+            row.get("rpc.update", 0.0) - under["rpc.update"][i])
         rows.append(row)
     return rows
 
@@ -216,7 +244,7 @@ def collect(events, records: list, wall0: float, wall1: float,
         if ev.get("ph") != "X":
             continue
         name = ev["name"]
-        if name.startswith(("rpc.", "update.", "cycle.")):
+        if ev.get("args", {}).get("span_id"):  # a span, not a lane slice
             spans.setdefault(name, []).append(ev)
         else:
             m = _RECORD.match(name)
@@ -239,10 +267,8 @@ def collect(events, records: list, wall0: float, wall1: float,
     # the self-time table PERF.md prints, beside what the clocks and the
     # idle gave: medians, and means, which add up (a compaction runs in
     # one cycle of several, so its median is 0)
-    keys = ("rpc.update", *UPDATE_CHILDREN, "update.self", "rpc.cycle",
-            *CYCLE_CHILDREN, "records", "cycle.self")
     columns = {
-        k: vals for k in keys
+        k: vals for k in (rows[0] if rows else ()) if k != "updates"
         if (vals := [r[k] for r in rows if r[k] is not None])
     }
     table = {
@@ -258,16 +284,19 @@ def collect(events, records: list, wall0: float, wall1: float,
 
 def read(spec: dict, src: dict):
     """The `program_span` reader. `select` names row keys (span names)
-    summed per `rpc.cycle`, reduced over the window's cycles by `median`
-    or `mean`; or, with `idle_pct`, the one part of the idle partition."""
+    summed per iteration and reduced over the window's by `median` or
+    `mean`; or, with `idle_pct`, the one part of the idle partition.
+    None, and never an error, where the program gave no spans or no
+    span of the window carries a selected name: the parent of the PR
+    that stamps a span prints its line without that span's metric."""
     program = src.get("program")
     if not program:
         return None
+    select = spec["select"]
     if spec["reduce"] == "idle_pct":
-        idle = program["idle"]
-        return None if idle is None else idle[spec["select"][0]]
-    series = [sum(row[k] for k in spec["select"])
-              for row in program["cycles"]]
+        return (program["idle"] or {}).get(select[0])
+    series = [sum(row[k] for k in select) for row in program["cycles"]
+              if all(row.get(k) is not None for k in select)]
     if not series:
         return None
     if spec["reduce"] == "median":

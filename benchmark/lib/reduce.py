@@ -1,17 +1,22 @@
 """From spans, flight records and the trace to numbers.
 
 A per-layer metric is one file, `benchmark/layers/<name>.json`: the kind
-of source it reads (`client_span`, `flight_phase`, `trace_ops`), what
-it selects there, and how the selection is reduced.
+of source it reads (`client_span`, `client_latency`, `flight_phase`,
+`trace_ops`, `program_span`: the program's own spans,
+`program_spans.py`), what it
+selects there, and how the selection is reduced.
 Adding a metric over an existing kind of source is adding a file and a
-`BENCHMARK.json` entry. A reader that finds nothing to read returns
-None and the harness leaves the metric out of the line.
+`BENCHMARK.json` entry: over a span a later PR stamps, too. A reader
+that finds nothing to read returns None and the harness leaves the
+metric out of the line.
 """
 
 from __future__ import annotations
 
 import math
 import statistics
+
+from . import program_spans
 
 
 def percentile(values, q: float) -> float:
@@ -30,6 +35,8 @@ def _reduce(series, how: str):
         return None
     if how == "median":
         return statistics.median(series)
+    if how == "p95":
+        return percentile(series, 95)
     raise ValueError(f"unknown reduction {how!r}")
 
 
@@ -40,6 +47,12 @@ def read_client_span(spec: dict, src: dict):
          for s in src["spans"] if s.offered),
         spec["reduce"],
     )
+
+
+def read_client_latency(spec: dict, src: dict):
+    """Due -> binding received over the pods due in the window, as the
+    open loop stamps them; nothing to read in a closed loop."""
+    return _reduce(src.get("latency_ms") or (), spec["reduce"])
 
 
 def flight_phases(record: dict) -> dict[str, float]:
@@ -98,8 +111,10 @@ def read_trace_ops(spec: dict, src: dict):
 
 READERS = {
     "client_span": read_client_span,
+    "client_latency": read_client_latency,
     "flight_phase": read_flight_phase,
     "trace_ops": read_trace_ops,
+    "program_span": program_spans.read,
 }
 
 

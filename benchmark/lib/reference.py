@@ -378,6 +378,9 @@ class Verdict:
     problems: list  # human-readable, first few of each kind
     counts: dict  # every number compared, beside its limit
     wrongly_refused: int = 0
+    # refusals whose own diagnosis left nodes open: where and what they
+    # said, so that a run over REFUSED_OPEN_LIMIT names its cause
+    open_refusals: dict = dataclasses.field(default_factory=dict)
 
     @property
     def ok(self) -> bool:
@@ -400,6 +403,10 @@ def check_run(nodes, init, pods: dict, cycles: list[Cycle],
     n_bind = n_refused = n_allowed = n_checked = wrongly = 0
     bound_ever: set = set()
     probe_nodes: list[int] = []  # where each probe was bound
+    open_by_cycle: dict[int, int] = {}
+    open_said: dict[str, int] = {}
+    open_uids: set = set()
+    open_samples: list[str] = []
     for ci, cyc in enumerate(cycles):
         seen = set()
         fresh = []
@@ -436,6 +443,15 @@ def check_run(nodes, init, pods: dict, cycles: list[Cycle],
             n_refused += 1
             if rejected < counted:
                 n_allowed += 1  # the program itself leaves nodes open
+                open_by_cycle[ci] = open_by_cycle.get(ci, 0) + 1
+                open_said[said] = open_said.get(said, 0) + 1
+                open_uids.add(uid)
+                if len(open_samples) < 8:
+                    open_samples.append(
+                        f"cycle {ci}: {uid} ({said!r}); the reference "
+                        f"finds {int(cl.feasible(pods[uid]).sum())} nodes "
+                        f"open at the cycle's end; pod: "
+                        f"{describe(pods[uid])}")
                 continue
             n_checked += 1
             open_nodes = np.flatnonzero(cl.feasible(pods[uid]))
@@ -479,7 +495,13 @@ def check_run(nodes, init, pods: dict, cycles: list[Cycle],
         "probe_score_gap_max": [gap, PROBE_GAP_LIMIT],
     }
     problems = [f"{k}: {v[0]} (+{len(v) - 1} more)" for k, v in bad.items()]
-    return Verdict(problems, counts, wrongly)
+    opened = {
+        "by_cycle": open_by_cycle, "of_cycles": len(cycles),
+        "distinct_pods": len(open_uids),
+        "said": sorted(open_said.items(), key=lambda kv: -kv[1])[:6],
+        "samples": open_samples,
+    } if n_allowed else {}
+    return Verdict(problems, counts, wrongly, opened)
 
 
 def describe(pod) -> str:

@@ -79,6 +79,14 @@ def reduce_trace(path: str, window_s: float | None = None) -> dict | None:
     if not per_plane:
         return None
     busy = [union_seconds(ops) / 1e9 for ops in per_plane]
+    # the child times its window from start_trace's RETURN, and event
+    # times count from where the trace began, inside that call: an
+    # operation can end past the timed length. The window is at least
+    # as long as its last operation says, so busy never counts time the
+    # window does not hold (and `program_spans.idle_partition`, given
+    # this window, sums to the same idle share)
+    if window_s is not None:
+        window_s = max(window_s, hi / 1e9)
     # idle gaps of the first device plane, longest first, as (start,
     # length) in seconds since the trace began (event times count from
     # there). With the traced window's length given, the idle stretches

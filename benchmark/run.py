@@ -35,11 +35,14 @@ import statistics  # noqa: E402
 import sys  # noqa: E402
 import threading  # noqa: E402
 
+import yaml  # noqa: E402
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, ROOT)
 
-from benchmark.lib import agent, generate, reduce, reference, xplane  # noqa: E402
+from benchmark.lib import (  # noqa: E402
+    agent, generate, program_spans, reduce, reference, xplane)
 from benchmark.lib.child import BenchError, Server, counter_total  # noqa: E402
 
 SCRATCH = os.path.join(ROOT, ".bench")  # git-ignored; caches outlast a run
@@ -76,6 +79,12 @@ def server_yaml(cfg: dict, rehearse: bool, workdir: str) -> str:
     return path
 
 
+def pad_existing(yaml_path: str) -> int:
+    """`padExisting` as the server was given it (JSON is YAML)."""
+    with open(yaml_path) as f:
+        return int(yaml.safe_load(f)["padExisting"])
+
+
 def run_cell(bench: dict, cell: dict, args, rehearse: bool) -> dict:
     traffic = load_json("workloads", cell["name"] + ".json")
     cfg = load_json("configs", cell["config"] + ".json")
@@ -102,8 +111,10 @@ def run_cell(bench: dict, cell: dict, args, rehearse: bool) -> dict:
                 {k: v for k, v in (cut or {}).items() if k != "server"},
             )
             depth = dep.cfg["depth"]
+            served_yaml = server_yaml(cfg, rehearse, workdir)
+            pad = pad_existing(served_yaml)
             server = Server(
-                ROOT, workdir, server_yaml(cfg, rehearse, workdir),
+                ROOT, workdir, served_yaml,
                 aot_dir=os.path.join(cache, "aot"),
                 jax_cache_dir=os.path.join(cache, "jax"),
                 traced=bool(args.trace),
@@ -127,8 +138,10 @@ def run_cell(bench: dict, cell: dict, args, rehearse: bool) -> dict:
                                 int(traffic.get("burst", 1)))
                 window_pods = dep.pending(len(due), "pod")
             t_warm = time.monotonic()
-            # the warm-up batch of `depth` pods pins the P pad for the run
-            drv.warm(dep.pending(depth, "warm"))
+            # the warm-up batch of `depth` pods pins the P pad for the
+            # run; the configuration's unschedulable pods are of it
+            stay = dep.unschedulable()
+            drv.warm(dep.pending(depth - len(stay), "warm") + stay)
             warm_s = time.monotonic() - t_warm
             m_start = server.metrics()
             compiled_in_setup = counter_total(
@@ -178,8 +191,11 @@ def run_cell(bench: dict, cell: dict, args, rehearse: bool) -> dict:
             if wall0 <= r["wall_start"] <= wall1
         ]
         health = server.health()
-        trace = None
+        trace = program = None
         if args.trace:
+            # the program's spans, while the child lives to serve them
+            program_events = server.trace_events(
+                min(len(drv.spans), 60000))
             done = os.path.join(server.trace_dir, "trace.done")
             limit = time.monotonic() + 90.0
             while not os.path.exists(done) and time.monotonic() < limit:
@@ -197,6 +213,9 @@ def run_cell(bench: dict, cell: dict, args, rehearse: bool) -> dict:
             ) if path else None
             if trace:
                 trace["t0_wall"] = trace_meta["wall_start"]
+            program = program_spans.collect(
+                program_events, records, wall0, wall1, path,
+                trace["window_s"] if trace else None)
         device_line = next(
             (json.loads(ln[len("bench_device: "):])
              for ln in server.log().splitlines()
@@ -228,7 +247,14 @@ def run_cell(bench: dict, cell: dict, args, rehearse: bool) -> dict:
     loaded = counter_total(m_end, "scheduler_compile_cache_hits_total") \
         - counter_total(m_start, "scheduler_compile_cache_hits_total")
     rung = health["degradation"]
+    # the agent completes no pods, so the server's existing set is what
+    # was loaded and everything it ever bound, less what it evicted. Past
+    # the E pad the encoder leaves the delta path and the next regime
+    # compiles inside the window: said here in plain words
+    existing_at_end = len(dep.init) + len(drv.bound_at) - sum(
+        len(c.evictions) for c in drv.cycles)
     served = {
+        "existing_over_pad": [max(0, existing_at_end - pad), 0],
         "programs_compiled_in_window": [compiled, 0],
         "programs_loaded_in_window": [loaded, 0],
         "ladder_degradations": [
@@ -253,16 +279,19 @@ def run_cell(bench: dict, cell: dict, args, rehearse: bool) -> dict:
         sent = {u for c in drv.cycles[n_warm_cycles:] for u in c.offered}
         lat = [(drv.bound_at[u] - t0) * 1e3 for u in in_window if u in sent]
         unbound = len(drv.pending)
+    compared = {**verdict.counts, **served}
     say(
-        compared={**verdict.counts, **served},
+        compared=compared,
         problems=verdict.problems[:8], check_s=round(check_s, 3),
         check_covers="every cycle, every binding, every refusal",
     )
+    if verdict.open_refusals:
+        say(refusals_left_open=verdict.open_refusals,
+            warm_cycles=n_warm_cycles,
+            window_records=[{"seq": r["seq"], **r["counts"]}
+                            for r in records])
     correct = verdict.ok and all(v[0] <= v[1] for v in served.values())
     e2e = {"pods_bound_per_s": len(in_window) / window, "setup_s": setup_s}
-    if due is not None and lat:
-        e2e["bind_latency_p50_ms"] = statistics.median(lat)
-        e2e["bind_latency_p95_ms"] = reduce.percentile(lat, 95)
     say(
         facts={
             "window_s": window, "cycles": len(spans),
@@ -284,9 +313,15 @@ def run_cell(bench: dict, cell: dict, args, rehearse: bool) -> dict:
                 spans[-1].offered if spans else 0,
             ],
             "flight_records": len(records),
+            "existing_at_end": existing_at_end, "pad_existing": pad,
+            "pad_headroom_share": 1.0 - existing_at_end / pad,
         },
     )
-    src = {"spans": spans, "flight": records, "trace": trace}
+    src = {"spans": spans, "flight": records, "trace": trace,
+           "program": program,
+           "latency_ms": lat if due is not None else None}
+    if program:
+        say(program_spans=program["table"])
     names = bench["per_layer"] if args.trace else bench["end_to_end"]
     metrics = {}
     for m in names:
@@ -320,6 +355,9 @@ def run_cell(bench: dict, cell: dict, args, rehearse: bool) -> dict:
                 for start, secs in trace["gaps"]
             ],
         }
+    # each number compared beside its limit, last in the line
+    line["compared"] = {
+        k: v for k, v in compared.items() if isinstance(v, list)}
     return line
 
 
@@ -388,6 +426,9 @@ def main() -> int:
             print("benchmark: FAILED: the harness initialised a JAX "
                   "backend", file=sys.stderr, flush=True)
             return 1
+    for name, (value, limit) in line["compared"].items():
+        print(f"compared: {name} {value} limit {limit}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
 

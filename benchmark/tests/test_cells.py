@@ -4,8 +4,11 @@ with the rest of `benchmark/tests`):
 - every configuration, cell and per-layer metric `BENCHMARK.json` names has
   its files, and they say what the entry says;
 - `sp5000-default` is `sp5000-mixed` with `percentageOfNodesToScore` left
-  out and the pad its own rule gives, and its cell and seven metrics are
-  the saturated cell's under their own names;
+  out and nothing else, and its cell and sixteen metrics are the
+  saturated cell's under their own names;
+- every configuration's E pad is at or over what its own `pad_rule` and
+  sizes give, and a run that outgrows its pad says so: `existing_over_pad`
+  over 0, `correct: false`;
 - every per-layer metric's reader returns None, and never raises, over a
   run that has nothing for it: no loop iteration, flight records without
   phases, no trace. A program that lacks what a metric reads (the parent
@@ -15,6 +18,7 @@ with the rest of `benchmark/tests`):
 
 import json
 import os
+import shutil
 import subprocess
 import sys
 
@@ -31,6 +35,9 @@ from benchmark.lib import reduce  # noqa: E402
 DEFAULT_METRICS = (
     "update_rpc_ms", "cycle_rpc_ms", "encode_ms", "apply_ms",
     "device_wait_ms", "device_busy_ms", "device_idle_pct",
+    "update_servicer_ms", "update_convert_ms", "update_apply_ms",
+    "cycle_servicer_ms", "cycle_respond_ms", "cycle_snapshot_ms",
+    "idle_in_update_pct", "idle_in_cycle_pct", "idle_outside_rpc_pct",
 )
 
 
@@ -58,6 +65,37 @@ def test_configuration_files(entry):
     assert cells, "a configuration that no cell runs is never measured"
 
 
+def pad_by_rule(cfg: dict, run_seconds: int) -> tuple[float, int]:
+    """(what a run may hold, the power of two above it): the rule of
+    `sp5000-mixed.json`'s `assumed`, from the file's own numbers."""
+    rule = cfg["pad_rule"]
+    holds = (
+        cfg["init_pods"]
+        + cfg["probe"]["pools"] * cfg["probe"]["nodes_per_pool"]
+        + cfg["depth"]
+        + rule["factor"] * rule["rate_ref_pods_per_s"]
+        * (run_seconds + rule["iteration_s"]))
+    return holds, 1 << int(holds).bit_length()
+
+
+@pytest.mark.parametrize(
+    "entry", BENCHMARK["configs"], ids=lambda e: e["name"])
+def test_the_existing_pad_is_what_the_configurations_rule_gives(entry):
+    cfg = load("configs", entry["name"] + ".json")
+    holds, pad = pad_by_rule(cfg, BENCHMARK["run_seconds"])
+    server = load("configs", cfg["server_config"])
+    assert server["padExisting"] == pad, (holds, pad)
+
+
+def test_the_mixed_pad_rule_in_numbers():
+    cfg = load("configs", "sp5000-mixed.json")
+    holds, pad = pad_by_rule(cfg, 40)
+    assert holds == pytest.approx(12000 + 64 + 10000 + 2 * 2163 * 44.7)
+    assert round(holds) == 215436 and pad == 262144
+    assert any(a.startswith("padExisting: 262144") and "215,436" in a
+               for a in cfg["assumed"])
+
+
 @pytest.mark.parametrize(
     "cell", BENCHMARK["workloads"], ids=lambda c: c["name"])
 def test_cell_files(cell):
@@ -82,13 +120,19 @@ def test_layer_files_and_their_readers_on_an_empty_run(entry):
     cells = {w["name"] for w in BENCHMARK["workloads"]}
     assert set(entry["workloads"]) <= cells
     # a window in which nothing ran, a program whose records carry no
-    # mark or phase, a server that wrote no trace, a trace in which the
-    # program the metric names was never launched
+    # mark or phase, a server that wrote no trace, a program that gave
+    # no span (or none in the window, or no anchors), a trace in which
+    # the program the metric names was never launched
     bare = {"t_start_s": 0.0, "t_end_s": 1.0}
     for src in (
-        {"spans": [], "flight": [], "trace": None},
+        {"spans": [], "flight": [], "trace": None, "program": None},
         {"spans": [], "flight": [bare, dict(bare, marks_s={}, phases_ms={})],
-         "trace": None},
+         "trace": None,
+         "program": {"cycles": [], "idle": None, "table": {}}},
+        {"spans": [], "flight": [],
+         "program": {"cycles": [{"another.span": 1.0, "updates": 0,
+                                 "records": None, "cycle.self": None}],
+                     "idle": {}, "table": {}}},
         {"spans": [], "flight": []},
     ):
         assert reduce.read_layer(spec, src) is None
@@ -127,9 +171,8 @@ def test_sp5000_default_is_sp5000_mixed_at_the_stock_percentage():
                 for n in ("sp5000-mixed", "sp5000-default"))
     assert y_m.pop("percentageOfNodesToScore") == 100
     assert "percentageOfNodesToScore" not in y_d
-    # the power of two above init + depth + 1.5 x ~2,100 pods/s x 40 s
-    assert y_d.pop("padExisting") == 262144 > 148000 > y_m.pop("padExisting")
-    assert y_d == y_m
+    # the two YAMLs differ in exactly that one key, the pad included
+    assert y_d == y_m and y_d["padExisting"] == 262144
     entry = next(c for c in BENCHMARK["configs"]
                  if c["name"] == "sp5000-default")
     assert "numFeasibleNodesToFind" in entry["source"]
@@ -159,6 +202,40 @@ def test_sp5000_default_sat_is_the_saturated_cell_under_its_own_names():
     assert mine_in_bench == [m + ".default" for m in DEFAULT_METRICS]
 
 
+def test_a_run_that_outgrows_its_pad_says_so(tmp_path):
+    """A copy of the benchmark whose rehearsal pad (1,024) is under what
+    a 4 s saturated rehearsal binds: the line reads `correct: false`
+    and `existing_over_pad` says why, beside whatever the program did
+    about it (regimes compiled inside the window)."""
+    copy = tmp_path / "checkout"
+    shutil.copytree(BENCH, copy / "benchmark",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), copy)
+    os.symlink(os.path.join(ROOT, "k8s_scheduler_tpu"),
+               copy / "k8s_scheduler_tpu")
+    path = copy / "benchmark" / "configs" / "sp5000-mixed.json"
+    cfg = json.loads(path.read_text())
+    cfg["rehearse"]["server"]["padExisting"] = 1024
+    path.write_text(json.dumps(cfg))
+    out = subprocess.run(
+        [sys.executable, str(copy / "benchmark" / "run.py"), "--rehearse",
+         "--workload", "sp5000-mixed.sat", "--trace", "0",
+         "--seed", "3000000019", "--seconds", "4"],
+        capture_output=True, text=True, timeout=1700,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert out.returncode == 0, out.stderr[-2000:]
+    said = [json.loads(ln) for ln in out.stdout.splitlines()
+            if ln.startswith("{")]
+    facts = next(d["facts"] for d in said if "facts" in d)
+    line = next(d["would_print"] for d in said if "would_print" in d)
+    over, limit = line["compared"]["existing_over_pad"]
+    assert facts["pad_existing"] == 1024 and limit == 0
+    assert over == facts["existing_at_end"] - 1024 > 0
+    assert facts["pad_headroom_share"] < 0
+    assert line["correct"] is False
+    assert list(line)[-1] == "compared"
+
+
 def test_the_rehearsal_passes_in_every_cell_traced_and_untraced():
     out = subprocess.run(
         [sys.executable, os.path.join(BENCH, "run.py"), "--rehearse",
@@ -175,6 +252,7 @@ def test_the_rehearsal_passes_in_every_cell_traced_and_untraced():
     for (cell, trace), line in lines.items():
         assert line["correct"] is True, (cell, trace)
         assert line["failed"] == 0
+        assert line["compared"]["existing_over_pad"] == [0, 0]
         if trace == 0:
             assert {"pods_bound_per_s", "setup_s"} <= set(line["metrics"])
         else:

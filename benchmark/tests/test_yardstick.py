@@ -89,6 +89,20 @@ def test_percentile_known_series():
     assert reduce.spread([95, 98, 100, 100, 102, 105]) == pytest.approx(0.055)
 
 
+def test_client_latency_reader_on_a_known_series():
+    """The steady cell's latencies, per layer since PR 29: the same
+    arithmetic as when they stood end to end; nothing in a closed loop."""
+    lat = [float(v) for v in range(1, 101)]
+    src = {"spans": [], "flight": [], "latency_ms": lat}
+    for q, want in (("p50", 50.5), ("p95", 95.0)):
+        with open(os.path.join(ROOT, "benchmark", "layers",
+                               f"bind_latency_{q}_ms.steady.json")) as f:
+            spec = json.load(f)
+        assert reduce.read_layer(spec, src) == want
+        assert reduce.read_layer(spec, {**src, "latency_ms": None}) is None
+        assert reduce.read_layer(spec, {**src, "latency_ms": []}) is None
+
+
 def test_union_of_intervals():
     assert xplane.union_seconds([(0, 10), (5, 15), (20, 30)]) == 25
     assert xplane.union_seconds([]) == 0
@@ -116,6 +130,48 @@ def test_reference_passes_a_sound_run(seed):
     assert v.counts["probe_score_gap_max"][0] == 0.0
 
 
+@pytest.mark.parametrize("size", ["cell", "rehearsal"])
+def test_every_node_type_stands_in_every_zone(size):
+    """A type that stands in two zones of six makes a pod with its
+    selector and a zone spread infeasible for good whenever neither zone
+    is its app's emptiest: a backlog that grows all through a run (PR
+    29's refusal, PERF.md section 6)."""
+    cfg, cut = config()
+    dep = generate.deployment(cfg, 3, cut if size == "rehearsal" else None)
+    zones_of = {}
+    for n in dep.nodes:
+        if generate.POOL_KEY in n.metadata.labels:
+            continue
+        zones_of.setdefault(n.metadata.labels["node-type"], set()).add(
+            n.metadata.labels[generate.ZONE_KEY])
+    assert set(zones_of) == set(generate.NODE_TYPES)
+    assert all(z == set(generate.ZONES) for z in zones_of.values())
+
+
+@pytest.mark.parametrize("name", ["sp5000-mixed", "sp5000-default"])
+def test_what_a_cell_refuses_is_the_configurations_count(name):
+    """The mix refuses nothing (the reference places every pod of it);
+    the configuration's unschedulable pods fit no node, are named as
+    warm-up pods, and sort first among the lowest priority."""
+    cfg, cut = config(name)
+    dep, _by_uid, cycle = sound_run(6, n_pods=600)
+    assert cycle.refused == []
+    dep = generate.deployment(cfg, 6, cut)
+    stay = dep.unschedulable()
+    assert len(stay) == cut["unschedulable"]["count"] > 0
+    assert cfg["unschedulable"]["count"] * 5 < cfg["depth"] // 8
+    cl = reference.Cluster(dep.nodes)
+    for pod, node in dep.init:
+        cl.add(pod, cl.index[node])
+    first = dep.pending(1, "warm")[0]
+    for pod in stay:
+        assert not cl.feasible(pod).any()
+        assert pod.uid.split("/")[-1].startswith("warm-")
+        assert pod.spec.priority == 0
+        assert (pod.metadata.creation_timestamp
+                < first.metadata.creation_timestamp)
+
+
 def test_reference_fails_an_over_commit():
     dep, by_uid, cycle = sound_run(4, n_pods=100)
     # 100m pods on a 4-CPU node: the 41st does not fit
@@ -139,6 +195,10 @@ def test_reference_fails_a_wrongly_refused_pod():
     v = verdict(dep, by_uid, cycle)
     assert v.ok
     assert v.counts["refused_with_nodes_left_open_by_the_program"][0] == 1
+    # ... and says where they were and how many nodes IT finds open
+    assert v.open_refusals["by_cycle"] == {0: 1}
+    assert v.open_refusals["distinct_pods"] == 1
+    assert uid in v.open_refusals["samples"][0]
     # ... up to what sound runs make, and no further: the program's own
     # diagnosis may not decide which refusals are looked at
     for _ in range(reference.REFUSED_OPEN_LIMIT):
@@ -254,3 +314,17 @@ def test_broken_timed_path_comes_out_not_correct(monkeypatch):
     line = run.run_cell(bench, cell, Args, rehearse=True)
     assert state["done"]
     assert line["correct"] is False
+
+
+def test_the_traced_window_is_at_least_as_long_as_its_last_operation():
+    """The child times its window from start_trace's return; an
+    operation that ends past that length stretches the window instead
+    of being counted as busy time of a window that does not hold it."""
+    path = os.path.join(HERE, "data", "recorded.xplane.pb")
+    whole = xplane.reduce_trace(path)
+    short = xplane.reduce_trace(path, whole["window_s"] / 2)
+    long = xplane.reduce_trace(path, 5.0)
+    assert short["busy_s"] == long["busy_s"] == whole["busy_s"]
+    assert short["window_s"] >= whole["window_s"] > whole["window_s"] / 2
+    assert short["busy_s"] <= short["window_s"]
+    assert long["window_s"] == 5.0
