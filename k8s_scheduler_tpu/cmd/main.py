@@ -607,6 +607,20 @@ def main(argv: list[str] | None = None) -> int:
             flush=True,
         )
 
+    # the cyclic collector's policy, installed LAST: its first freeze
+    # takes everything imported and restored above out of the
+    # collector's sight. Only the CLI installs it (core/collector.py).
+    from ..core.collector import CollectorPolicy
+
+    collector = CollectorPolicy(service.scheduler.census, metrics=gm)
+    service.collector = collector
+    collector.install()
+    print(
+        "collector policy installed: a freeze after every cycle "
+        "(core/collector.py)",
+        flush=True,
+    )
+
     stop = threading.Event()
 
     def _shutdown(signum, frame):

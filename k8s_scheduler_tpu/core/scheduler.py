@@ -917,6 +917,15 @@ class Scheduler:
     def add_pod_group(self, group: PodGroup) -> None:
         self._groups[group.name] = group
 
+    def census(self) -> tuple[int, int]:
+        """(pods and nodes resident now, pods and nodes that have left
+        since the process began): what core/collector sweeps by."""
+        c = self.cache.counts()
+        return (
+            c["nodes"] + c["bound"] + c["assumed"] + len(self.queue),
+            self.cache.departed + self.queue.departed,
+        )
+
     # ---- volume objects (VolumeBinding inputs) ---------------------------
 
     def on_pvc_upsert(self, pvc) -> None:

@@ -98,6 +98,13 @@ from typing import Any, Callable, Iterable
 #                   journal compaction, only when one ran),
 #                   cycle.respond (schedule_cycle returned -> response
 #                   built)
+#   whichever thread the collector runs on (core/collector.py; a trace
+#   of its own each, no parent): gc.pass — a placed operation (attr
+#                   `kind`: freeze | sweep) on the thread that ran
+#                   `cycle_done`, gRPC's serving thread after a `Cycle`
+#                   or the front door's loop; a generation-2 pass the
+#                   interpreter started itself (`auto_full`) on the
+#                   thread whose allocation set it off
 SPAN_NAMES = (
     "submit.validate",
     "submit.journal",
@@ -119,12 +126,17 @@ SPAN_NAMES = (
     "cycle.pop",
     "cycle.snapshot",
     "cycle.respond",
+    "gc.pass",
 )
 
 # the agent path's spans (Update / Cycle): per RPC and per phase, never
-# per pod, so they render on one lane instead of a track per trace
+# per pod, so they render on one lane instead of a track per trace. The
+# collector's passes share the lane: a pass holds the interpreter lock,
+# so it lies inside the RPC it delayed or between two, never across an
+# edge
 AGENT_SPAN_NAMES = frozenset(
-    n for n in SPAN_NAMES if n.startswith(("rpc.", "update.", "cycle."))
+    n for n in SPAN_NAMES
+    if n.startswith(("rpc.", "update.", "cycle.", "gc."))
 )
 
 # default head-sampling rate (absent an explicit traceparent): 1/64

@@ -74,6 +74,9 @@ class SchedulerCache:
         self._nodes: dict[str, Node] = {}
         self._bound: dict[str, tuple[Pod, str]] = {}  # uid -> (pod, node)
         self._assumed: dict[str, _AssumedPod] = {}
+        # pods and nodes dropped since the process began: what
+        # core/collector sweeps by (not state: never journaled)
+        self.departed = 0
 
     def set_journal(
         self, journal: Callable[[str, float, dict], None] | None
@@ -106,6 +109,7 @@ class SchedulerCache:
     def remove_node(self, node_name: str) -> None:
         with self._lock:
             if self._nodes.pop(node_name, None) is not None:
+                self.departed += 1
                 self._emit("c.remove_node", {"name": node_name})
 
     # ---- pod events (bound pods observed via informer) -------------------
@@ -126,6 +130,7 @@ class SchedulerCache:
             b = self._bound.pop(pod_uid, None)
             a = self._assumed.pop(pod_uid, None)
             if b is not None or a is not None:
+                self.departed += 1
                 self._emit("c.remove_pod", {"uid": pod_uid})
 
     # ---- assume lifecycle ------------------------------------------------
@@ -175,6 +180,7 @@ class SchedulerCache:
     def forget(self, pod_uid: str) -> None:
         with self._lock:
             if self._assumed.pop(pod_uid, None) is not None:
+                self.departed += 1
                 self._emit("c.forget", {"uid": pod_uid})
 
     def is_assumed(self, pod_uid: str) -> bool:
@@ -201,6 +207,7 @@ class SchedulerCache:
             for u in gone:
                 a = self._assumed.pop(u)
                 out.append((a.pod, a.node_name))
+            self.departed += len(out)
             if out and self._journal is not None:
                 # gated: this sweep runs every cycle — an idle scheduler
                 # must not grow the journal with no-op records. Emits the

@@ -132,6 +132,9 @@ class SchedulingQueue:
         self._unschedulable: dict[str, _QueuedPod] = {}
         self._in_flight: dict[str, _QueuedPod] = {}
         self._deleted_in_flight: set[str] = set()
+        # pending pods deleted since the process began: with the cache's
+        # count, what core/collector sweeps by (not state)
+        self.departed = 0
 
     def set_journal(
         self, journal: Callable[[str, float, dict], None] | None
@@ -197,6 +200,7 @@ class SchedulingQueue:
             for tier in (self._active, self._backoff, self._unschedulable):
                 if tier.pop(pod_uid, None) is not None:
                     changed = True
+                    self.departed += 1
             if pod_uid in self._in_flight:
                 # mark so the cycle's requeue discards instead of
                 # resurrecting a deleted pod

@@ -186,7 +186,8 @@ cmd/main.py startup stamp):
   apply.fold | bind.confirm | preempt.victim) and the agent path's
   RPCs, per RPC and per phase (rpc.update | update.convert |
   update.apply | rpc.cycle | cycle.lock_wait | cycle.pop |
-  cycle.snapshot | cycle.respond); the inventory is
+  cycle.snapshot | cycle.respond) and the collector's passes
+  (gc.pass); the inventory is
   core/spans.SPAN_NAMES, machine-checked by schedlint ID010 against
   this docstring and the README span table; spans serve at
   /debug/traces and join /debug/explain verdicts
@@ -200,6 +201,12 @@ cmd/main.py startup stamp):
 - scheduler_uptime_seconds — seconds since SchedulerMetrics
   construction (process start for the CLI), evaluated at scrape time;
   joins build_info so restart storms are visible without log access
+- scheduler_gc_young_passes_total — automatic generation-0 and
+  generation-1 passes of CPython's cyclic collector under the server's
+  policy (core/collector.py, installed by cmd/main.py only; thousands a
+  minute, so counted here and not stamped as spans; placed operations
+  and full passes are `gc.pass` spans), carried over at each cycle's end
+- scheduler_gc_young_pass_seconds_total — seconds spent in them
 - scheduler_alerts_total{rule,severity} — declarative alert-rule
   firings from the in-process watchtower (metrics/rules.py; one
   increment per ok->firing transition, never per evaluation); the
@@ -641,6 +648,20 @@ class SchedulerMetrics:
         # (GET vs HEAD Content-Length must agree)
         self.uptime.set_function(
             lambda: float(int(_time.monotonic() - _t0))
+        )
+        # ---- the collector's automatic young passes (core/collector.py)
+        self.gc_young_passes = Counter(
+            "scheduler_gc_young_passes_total",
+            "Automatic generation-0 and generation-1 passes of the "
+            "cyclic collector under the server's policy "
+            "(core/collector.py).",
+            registry=r,
+        )
+        self.gc_young_pass_seconds = Counter(
+            "scheduler_gc_young_pass_seconds_total",
+            "Seconds spent in automatic generation-0 and generation-1 "
+            "passes of the cyclic collector.",
+            registry=r,
         )
         self.alerts = Counter(
             "scheduler_alerts_total",

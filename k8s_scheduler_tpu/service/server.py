@@ -85,6 +85,9 @@ class SchedulerService:
         # enable_front_door() — the Submit/NodeChurn RPCs answer
         # FAILED_PRECONDITION while disabled
         self.admission = None
+        # core/collector.CollectorPolicy, set by cmd/main.main() and by
+        # nothing else: None leaves the interpreter's collector alone
+        self.collector = None
 
     def enable_front_door(self, **kwargs):
         """Attach an AdmissionController (idempotent) so the Submit /
@@ -108,7 +111,23 @@ class SchedulerService:
             self._bindings = []
             stats = self.scheduler.schedule_cycle()
             self._bindings = []
-            return stats
+        self._cycle_left_standing(None)
+        return stats
+
+    def _cycle_left_standing(self, context) -> None:
+        """The collector's turn (core/collector), where no caller waits
+        for it: once gRPC has sent `Cycle`'s response and closed the
+        call, which is when it runs a context's callbacks. A pass holds
+        the interpreter lock, so one placed before the return would be
+        paid by the agent inside `Cycle`; after it, it overlaps with the
+        agent building its next `Update` in another process. The front
+        door's loop has no caller, and an in-process one (no context) no
+        such moment: there it runs at once."""
+        collector = self.collector
+        if collector is None:
+            return
+        if context is None or not context.add_callback(collector.cycle_done):
+            collector.cycle_done()
 
     def _collect_binding(self, pod, node_name: str) -> None:
         self._bindings.append(
@@ -331,7 +350,8 @@ class SchedulerService:
                     events=n_ev, evictions=len(resp.evictions),
                     **s.last_cycle_sample,
                 )
-            return resp
+        self._cycle_left_standing(context)
+        return resp
 
     def Health(self, request: pb.HealthRequest, context) -> pb.HealthResponse:
         return pb.HealthResponse(ok=True, status="ok", boot_id=self.boot_id)

@@ -352,10 +352,11 @@ def test_agent_rpcs_render_on_one_lane(armed):
     assert pod_ev["pid"] == _spans.TRACE_TRACK_PID
 
 
-def test_span_inventory_has_twenty_names():
-    assert len(SPAN_NAMES) == len(set(SPAN_NAMES)) == 20
+def test_span_inventory_has_twenty_one_names():
+    assert len(SPAN_NAMES) == len(set(SPAN_NAMES)) == 21
+    # the collector's passes (core/collector.py) share the agent's lane
     assert AGENT_SPAN_NAMES == UPDATE_SPANS | CYCLE_SPANS | {
-        "cycle.snapshot"}
+        "cycle.snapshot", "gc.pass"}
 
 
 @pytest.mark.parametrize("name", sorted(
