@@ -31,7 +31,6 @@ from .registry import PassBase
 SCRIPT_ALLOWLIST = frozenset({
     "scripts/alerts_check.py",    # clean-soak alert-rule CI gate
     "scripts/audit_sharded.py",   # compile-only collective-budget gate
-    "scripts/bench_diff.py",      # BENCH artifact CI tripwire
     "scripts/blackbox_read.py",   # crash black-box bundle reader
     "scripts/fuzz_scheduler.py",  # scenario-fuzzer differential soak
     "scripts/lint_metrics.py",    # metric-inventory shim (tests)

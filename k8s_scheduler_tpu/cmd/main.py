@@ -262,7 +262,8 @@ def build_line(fp: dict[str, str]) -> str:
     """The `build:` line printed at start: the fingerprint's fields as
     k=v, shell-quoted so a value with spaces (device_kind is "TPU v5
     lite" on the chip) still splits back with `shlex.split` — which is
-    how chip_smoke.py reads the device its child holds."""
+    how a parent process reads the device its child holds without
+    asking JAX itself."""
     return "build: " + " ".join(
         f"{k}={shlex.quote(v)}" for k, v in sorted(fp.items())
     )
@@ -369,7 +370,7 @@ def main(argv: list[str] | None = None) -> int:
 
     # build identity: one constant-1 gauge stamped at startup so
     # dashboards can correlate latency shifts with binary/runtime
-    # changes (bench headlines carry the same fingerprint)
+    # changes (the `build:` line below carries the same fingerprint)
     from ..metrics.metrics import build_fingerprint
 
     fp = build_fingerprint()
@@ -432,6 +433,17 @@ def main(argv: list[str] | None = None) -> int:
             "(/debug/traces, /debug/explain)",
             flush=True,
         )
+
+    # the handlers stand BEFORE the first line a supervisor can take as
+    # "ready": a SIGTERM during the rest of start-up is kept until
+    # stop.wait() below, and the journal is sealed on the way out
+    stop = threading.Event()
+
+    def _shutdown(signum, frame):
+        stop.set()
+
+    signal.signal(signal.SIGTERM, _shutdown)
+    signal.signal(signal.SIGINT, _shutdown)
 
     server, service, port = serve(
         args.address,
@@ -621,13 +633,6 @@ def main(argv: list[str] | None = None) -> int:
         flush=True,
     )
 
-    stop = threading.Event()
-
-    def _shutdown(signum, frame):
-        stop.set()
-
-    signal.signal(signal.SIGTERM, _shutdown)
-    signal.signal(signal.SIGINT, _shutdown)
     try:
         stop.wait()
     finally:

@@ -19,7 +19,6 @@ from .observe import (  # noqa: F401
     PHASES,
     CycleObserver,
     SloEngine,
-    classify_latency_series,
     phase_seconds,
 )
 from .pipeline import ServingPipeline, build_decision_slim_fn  # noqa: F401

@@ -1,8 +1,9 @@
 """Persistent compiled-program cache + speculative pre-compilation.
 
-Every BENCH_r05 config pays 8.8-16.8 s of `compile_seconds` per program,
-and a pad-regime flip re-pays it mid-serve (historically up to ~100 s,
-or a backend wedge — ISSUE 5). Three layers attack that cost:
+A cold start pays seconds to minutes of XLA compile per program (~200 s
+for the six programs of the 10,000 x 5,000 regime on a v5e's host), and
+a pad-regime flip re-pays it mid-serve (historically up to ~100 s, or a
+backend wedge — ISSUE 5). Three layers attack that cost:
 
 - **`CompileCache`** — an on-disk executable store under
   `<state-dir>/compile_cache/` (PR 3's durable-state directory; a

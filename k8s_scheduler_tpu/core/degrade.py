@@ -105,7 +105,7 @@ class DegradationLadder:
         self._events = events
         self._observer = observer
         self._on_transition = on_transition
-        # transition log (soaks and bench config 7 read it for MTTR):
+        # transition log (the soaks read it for MTTR):
         # each entry carries both clocks so recovery time is measurable
         # in wall seconds. A bounded ring (ISSUE 11 satellite): a
         # process degrading every cycle for weeks must not grow one
@@ -259,8 +259,8 @@ class DegradationLadder:
 
     def recovery_episodes_ms(self) -> list[float]:
         """Wall milliseconds of each completed recovery episode (left
-        rung 0 -> returned to rung 0) — the MTTR series bench config 7
-        and soak_chaos report."""
+        rung 0 -> returned to rung 0) — the MTTR series soak_chaos
+        reports."""
         out: list[float] = []
         down_t: "float | None" = None
         # snapshot under the lock: iterating the live deque while the

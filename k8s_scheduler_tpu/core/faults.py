@@ -5,9 +5,9 @@ release on a failed fetch, journal-death stateless degrade, warm-standby
 failover — was verified against faults the RIG happened to produce. This
 module makes each of them reproducible on demand: a seeded `FaultPlan`
 fires scripted faults at named injection points threaded through the
-real serving/durability code, so `scripts/soak_chaos.py`, bench config 7
-(`fault_storm`), and the tier-1 tests can PROVE each ladder rung works
-instead of waiting for the tunnel to misbehave.
+real serving/durability code, so `scripts/soak_chaos.py` and the tier-1
+tests can PROVE each ladder rung works instead of waiting for the
+transport to misbehave.
 
 Injection points (`POINTS`; each hook sits on the exact code path the
 real fault class strikes):

@@ -177,27 +177,6 @@ def build_decision_slim_fn(num_nodes: int):
     return _jit(slim, "decision_slim", disc=f"narrow{int(narrow)}")
 
 
-def build_multicycle_slim_fn(num_nodes: int):
-    """Multi-cycle variant of the decision slimming: stacked [K, P]
-    decisions in, (assignment i16|i32 [K, P], flags u8 [K, P],
-    cycles_run i32) out. Flag bits: 0 = unschedulable, 1 = gang_dropped,
-    2 = attempted (the pod was valid in that inner cycle — the host
-    needs it to tell "not this cycle's pod" from "placed at node 0")."""
-    narrow = num_nodes < (1 << 15)
-
-    def slim(assignment, unschedulable, gang_dropped, attempted,
-             cycles_run):
-        a = assignment.astype(jnp.int16) if narrow else assignment
-        flags = (
-            unschedulable.astype(jnp.uint8)
-            | (gang_dropped.astype(jnp.uint8) << 1)
-            | (attempted.astype(jnp.uint8) << 2)
-        )
-        return a, flags, cycles_run
-
-    return _jit(slim, "multicycle_slim", disc=f"narrow{int(narrow)}")
-
-
 def build_multicycle_slim_rows_fn(num_nodes: int, k: int):
     """STREAMED variant of the multi-cycle decision slimming: the same
     i16|u8 diet, but split K ways so each inner cycle's row is its own
@@ -207,8 +186,9 @@ def build_multicycle_slim_rows_fn(num_nodes: int, k: int):
     alone, so the apply loop can bind inner cycle i's winners while
     rows i+1…K-1 are still in flight (and, under depth-2 speculative
     dispatch, while the NEXT batch is still running on device). Flag
-    bits match build_multicycle_slim_fn: 0 = unschedulable, 1 =
-    gang_dropped, 2 = attempted."""
+    bits: 0 = unschedulable, 1 = gang_dropped, 2 = attempted (the pod
+    was valid in that inner cycle — the host needs it to tell "not
+    this cycle's pod" from "placed at node 0")."""
     narrow = num_nodes < (1 << 15)
 
     def slim(assignment, unschedulable, gang_dropped, attempted,

@@ -2755,8 +2755,8 @@ class Scheduler:
             queue_active=qc.get("active", 0),
             queue_backoff=qc.get("backoff", 0),
             queue_unschedulable=qc.get("unschedulable", 0),
-            # current degradation rung (0 = normal): bench config 7 and
-            # soak_chaos count records with rung > 0 as degraded cycles
+            # current degradation rung (0 = normal): soak_chaos counts
+            # records with rung > 0 as degraded cycles
             rung=self.ladder.rung,
             # multi-chip serving: mesh width this cycle dispatched over
             # and the regime's probed per-cycle collective payload

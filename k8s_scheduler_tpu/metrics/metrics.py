@@ -196,8 +196,8 @@ cmd/main.py startup stamp):
   build/runtime fingerprint as labels (platform/device_kind/
   device_count are the devices THIS process holds, as jax reports
   them), set once at startup so dashboards can correlate latency
-  shifts with binary or runtime changes; bench headline artifacts
-  carry the same stamp (build_fingerprint())
+  shifts with binary or runtime changes; the CLI's `build:` line
+  carries the same stamp (build_fingerprint())
 - scheduler_uptime_seconds — seconds since SchedulerMetrics
   construction (process start for the CLI), evaluated at scrape time;
   joins build_info so restart storms are visible without log access
@@ -827,7 +827,7 @@ class SchedulerMetrics:
 
 def build_fingerprint() -> dict[str, str]:
     """Best-effort build/runtime identity for scheduler_build_info and
-    bench headline stamps: python/jax/jaxlib versions, the JAX backend
+    the CLI's `build:` line: python/jax/jaxlib versions, the JAX backend
     actually serving cycles with the devices this process holds
     (platform, device_kind and count as `jax.devices()` reports them —
     calling this initialises the backend, so only the process that is

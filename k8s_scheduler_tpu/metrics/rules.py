@@ -381,37 +381,3 @@ class RuleEngine:
             "fired_total": self.fired_total,
             "evaluations": self.evaluations,
         }
-
-
-def replay_alerts(samples_s: Iterable[float],
-                  rules: Iterable[Rule] | None = None) -> dict:
-    """Replays a bench per-cycle latency series through the production
-    classifier AND the built-in rule pack (mirror of
-    core/observe.classify_latency_series): each cycle advances a
-    virtual wall clock by one second — so a 60 s rule window reads as a
-    60-cycle window — feeds the observer's cumulative anomaly counters
-    into a throwaway TSDB, and evaluates the pack. Returns
-    {"alerts_fired": n, "fired_rules": [...]} for the bench headline."""
-    from ..core.observe import CycleObserver  # lazy: avoids cycles
-    from .tsdb import MetricsTSDB
-
-    tsdb = MetricsTSDB(raw_cap=256)
-    engine = RuleEngine(rules if rules is not None else builtin_rules(),
-                        tsdb)
-    obs = CycleObserver(metrics=None)
-    fired_rules: set[str] = set()
-    for i, t in enumerate(samples_s):
-        obs.observe_phases(
-            {"total": t, "device": t, "decision_fetch": t},
-            profile="bench", seq=i,
-        )
-        now = float(i + 1)
-        for cls, n in obs.anomaly_counts.items():
-            tsdb.append("scheduler_anomalies_total",
-                        (("class", cls),), float(n), t=now)
-        engine.evaluate(now)
-        for rule in engine.rules:
-            if engine._states[rule.name].stage == "firing":
-                fired_rules.add(rule.name)
-    return {"alerts_fired": engine.fired_total,
-            "fired_rules": sorted(fired_rules)}

@@ -108,10 +108,11 @@ def replicated(mesh):
     """The every-device-holds-all layout on `mesh`; None without one (a
     one-device run places nothing). The packed snapshot buffers are
     uploaded under it when serving sharded: an UNPLACED input lets XLA
-    propagate a sharding onto the parameter, and at bench cell 4's
+    propagate a sharding onto the parameter, and at the 10,000 x 5,000
     regime the partitioning it then picks for the preemption program
     aborts this libtpu's compiler (a check failure in the all-reduce
-    fusion emitter — chip_smoke.py --chips 4, PR 22). Placed inputs
+    fusion emitter, met on four v5e chips in PR 22 and reproduced by
+    tests/test_tpu_compile.py's four-chip case). Placed inputs
     leave it no such choice, on the jit path and the AOT path alike."""
     if mesh is None:
         return None

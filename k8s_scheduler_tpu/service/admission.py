@@ -649,7 +649,7 @@ class FrontDoor:
         self._cycle_fn = cycle_fn or self.scheduler.schedule_cycle
         self._idle_sleep = idle_sleep
         # runs on the loop thread after every cycle — the in-process
-        # drives (bench config 9, loadgen, soak overload) use it to
+        # drives (scripts/loadgen.py, the soak's overload phase) use it to
         # play the informer back (bind confirmations), which a real
         # deployment's agent does via Update; without confirmation an
         # assumed pod expires on the 30 s TTL and re-binds

@@ -18,7 +18,7 @@ Where the cache lives is decided OUTSIDE the program:
 Nothing here initialises a backend: the process that calls this has not
 necessarily decided to hold the chip yet.
 
-Called from the CLI entrypoint, the bench suite, and tests' conftest.
+Called from the CLI entrypoint and tests' conftest.
 """
 
 from __future__ import annotations

@@ -10,9 +10,9 @@ silently throttling the generator. Rates are pods/minute to match the
 Two modes:
 
 - **inproc** (default) — spins the whole front door in this process on
-  `bench_suite.front_door_drive` (the same harness bench config 9 and
-  the soak_chaos overload phase use): exact per-pod submit->bind
-  latency from the binder's own timestamps, BENCH-diffable JSON out.
+  `front_door_drive` below (the same harness the soak_chaos overload
+  phase uses): exact per-pod submit->bind latency from the binder's
+  own timestamps, one JSON object out.
 
       JAX_PLATFORMS=cpu python scripts/loadgen.py --rate 30000 --duration 10
 
@@ -31,6 +31,7 @@ Two modes:
 from __future__ import annotations
 
 import argparse
+import collections
 import json
 import os
 import sys
@@ -39,17 +40,214 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-# the ONE percentile implementation (bench_suite's module level is
-# stdlib-only): the load tool and the bench must never disagree on
-# quantile indexing
-from bench_suite import _percentile as _pctl  # noqa: E402
+
+def _pctl(xs: list[float], q: float) -> float:
+    ys = sorted(xs)
+    if not ys:
+        return 0.0
+    k = min(len(ys) - 1, max(0, int(round(q / 100.0 * (len(ys) - 1)))))
+    return ys[k]
+
+
+def front_door_drive(
+    duration_s: float,
+    rate_pps: float,
+    queue_depth: int = 0,
+    n_nodes: int = 16,
+    batch: int = 4,
+    state_dir: str = "",
+    fault_spec: str = "",
+    deadline_ms: float = 0.0,
+    multi_cycle_k: int = 4,
+    drain_timeout_s: float = 60.0,
+    promote_cycles: int = 4,
+    name_prefix: str = "ld",
+    release_after_bind: bool = True,
+    on_tick=None,
+) -> dict:
+    """The shared open-loop front-door harness (ISSUE 14): one real
+    Scheduler behind an AdmissionController + FrontDoor serve loop; the
+    caller's thread plays the open-loop client — submissions fire at
+    wall-clock arrival times derived from `rate_pps` REGARDLESS of how
+    fast binds complete (arrival-rate-driven, never closed-loop), so
+    overload actually overloads instead of self-throttling. Used by
+    this tool's in-process mode and scripts/soak_chaos.py's overload
+    phase, so the load tool and the soak can never assert different
+    invariants of the same front door.
+
+    Returns raw facts: `sched`/`admission` (live handles), `acked`
+    (uid -> submit wall time), `binds` (uid -> (count, bind wall
+    time)), `ack_lat_s`, `shed`/`accepted` counts, `max_depth` (the
+    deepest queue_depth any ack/shed reported), `duplicate_binds`,
+    `lost` (acked pods that neither bound nor remain tracked),
+    `drained`. Leaves any fault plan ARMED (caller disarms), exactly
+    like soak_chaos.chaos_serve_drive."""
+    from k8s_scheduler_tpu.config import SchedulerConfiguration
+    from k8s_scheduler_tpu.core.scheduler import Scheduler
+    from k8s_scheduler_tpu.service.admission import (
+        AdmissionController,
+        FrontDoor,
+    )
+    from k8s_scheduler_tpu.utils.synth import make_cluster, make_pods
+
+    state = None
+    if state_dir:
+        from k8s_scheduler_tpu.state import DurableState
+
+        state = DurableState(state_dir, snapshot_interval_seconds=0)
+    cfg_obj = SchedulerConfiguration(
+        admission_queue_depth=queue_depth,
+        multi_cycle_k=multi_cycle_k,
+        multi_cycle_max_wait_ms=5.0,
+        dispatch_deadline_ms=deadline_ms,
+        degrade_promote_cycles=promote_cycles,
+        fault_spec=fault_spec,
+        pod_initial_backoff_seconds=0.05,
+        pod_max_backoff_seconds=0.2,
+        # pre-sized pads: regime flips mid-drive would bill compile
+        # time to submit->bind latency
+        pad_existing=2048,
+        pad_pods_per_node=512,
+        compile_cache_dir="off",
+        speculative_compile=False,
+    )
+    binds: dict[str, tuple[int, float]] = {}
+    confirm_q: "collections.deque" = collections.deque()
+
+    def binder(p, n):
+        c, t = binds.get(p.uid, (0, 0.0))
+        binds[p.uid] = (c + 1, time.perf_counter())
+        confirm_q.append((p, n))
+
+    sched = Scheduler(config=cfg_obj, binder=binder, state=state)
+    admission = AdmissionController(sched)
+    for nd in make_cluster(n_nodes):
+        admission.node_churn(adds=[nd])
+
+    def confirm_binds():
+        # informer playback on the loop thread (a real deployment's
+        # agent confirms via Update): without it an assumed pod
+        # expires on the TTL and re-binds, which the duplicate-bind
+        # invariant would — correctly — flag. With
+        # `release_after_bind` the confirmed pod is then deleted (a
+        # fast-jobs workload): node capacity recycles, so the drive
+        # measures SERVING throughput instead of filling n_nodes and
+        # stalling on cluster capacity
+        while confirm_q:
+            p, n = confirm_q.popleft()
+            sched.on_pod_add(p, n)
+            if release_after_bind:
+                sched.on_pod_delete(p.uid)
+
+    fd = FrontDoor(admission, post_cycle=confirm_binds)
+    fd.start()
+    acked: dict[str, float] = {}
+    ack_lat: list[float] = []
+    shed = 0
+    max_depth = 0
+    seq = 0
+    t_start = time.perf_counter()
+    t0 = t_start  # reassigned when the open-loop window opens
+    try:
+        # warmup OUTSIDE the timed window: the first dispatch compiles
+        warm = make_pods(batch, seed=999, name_prefix=f"{name_prefix}w-")
+        r = admission.submit(warm)
+        assert r.ok, f"warmup submission rejected: {r.reason}"
+        # warmup pods are NOT recorded in `acked`: their bind time
+        # embeds the first-dispatch compile, and joining them into the
+        # submit->bind latencies would make the gated p99 report
+        # compile noise instead of the steady-state SLO (they are
+        # asserted fully bound right here, so the lost/dup accounting
+        # does not need them)
+        while len(binds) < len(warm):
+            if time.perf_counter() - t_start > 120:
+                raise AssertionError("warmup never bound (compile hang?)")
+            time.sleep(0.01)
+
+        # the open-loop window: arrival i is DUE at t0 + i/rate; send
+        # every batch that is due, sleep only until the next arrival
+        t0 = time.perf_counter()
+        interval = batch / rate_pps
+        n_batches = max(int(duration_s / interval), 1)
+        for i in range(n_batches):
+            due = t0 + i * interval
+            now = time.perf_counter()
+            if now < due:
+                time.sleep(due - now)
+            seq += 1
+            pods = make_pods(
+                batch, seed=10_000 + seq,
+                name_prefix=f"{name_prefix}{seq}-",
+            )
+            t_sub = time.perf_counter()
+            res = admission.submit(pods)
+            if res.queue_depth > max_depth:
+                max_depth = res.queue_depth
+            if res.ok:
+                ack_lat.append(time.perf_counter() - t_sub)
+                for p in pods:
+                    acked[p.uid] = t_sub
+            else:
+                shed += res.shed
+            if on_tick is not None:
+                # mid-burst probe hook: soak_chaos's overload phase
+                # evaluates the real /healthz closure in here
+                on_tick(sched, admission, res)
+        # drain: every acked pod resolves (bound, or parked in a tier),
+        # and — when a fault plan degraded the ladder — rung 0 returns.
+        # While the ladder sits below rung 0 a probe trickle keeps
+        # flowing (promotion counts clean DISPATCHING cycles: a silent
+        # queue earns no recovery evidence; this is the recovery-tail
+        # role the fuzz chaos traces generate explicitly)
+        deadline = time.perf_counter() + drain_timeout_s
+        while (
+            (any(u not in binds for u in acked) or sched.ladder.rung > 0)
+            and time.perf_counter() < deadline
+        ):
+            if sched.ladder.rung > 0:
+                seq += 1
+                probe = make_pods(
+                    1, seed=90_000 + seq,
+                    name_prefix=f"{name_prefix}rt{seq}-",
+                )
+                r = admission.submit(probe)
+                if r.ok:
+                    acked[probe[0].uid] = time.perf_counter()
+            time.sleep(0.05)
+    finally:
+        drained = fd.stop()
+    tracked = {p.uid for p in sched.queue.all_pending()}
+    bind_ts = [t for _c, t in binds.values() if t >= t0]
+    return {
+        "sched": sched,
+        "admission": admission,
+        "state": state,
+        "acked": acked,
+        "binds": binds,
+        "ack_lat_s": ack_lat,
+        "accepted": len(acked),
+        "shed": shed,
+        "max_depth": max_depth,
+        "wall_s": time.perf_counter() - t_start,
+        # serving rate over the open-loop window (warmup excluded):
+        # binds landed after t0, divided by the window they landed in —
+        # the capacity estimate soak_chaos's overload phase calibrates on
+        "bind_rate_pps": (
+            len(bind_ts) / max(max(bind_ts) - t0, 1e-6)
+            if bind_ts else 0.0
+        ),
+        "duplicate_binds": sum(
+            1 for c, _t in binds.values() if c > 1
+        ),
+        "lost": sorted(set(acked) - set(binds) - tracked),
+        "drained": drained,
+        "cycles": fd.cycles,
+    }
 
 
 def run_inproc(args) -> dict:
-    import bench_suite
-
     rate_pps = args.rate / 60.0
-    d = bench_suite.front_door_drive(
+    d = front_door_drive(
         duration_s=args.duration,
         rate_pps=rate_pps,
         queue_depth=args.queue_depth,
@@ -66,7 +264,6 @@ def run_inproc(args) -> dict:
     ack_ms = [v * 1e3 for v in d["ack_lat_s"]]
     total = d["accepted"] + d["shed"]
     out = {
-        "config": 9,
         "name": "front_door",
         "mode": "inproc",
         "rate_pods_per_min": args.rate,
@@ -173,7 +370,6 @@ def run_tenants(args) -> dict:
     arena = host.arena
     total = accepted + shed
     return {
-        "config": 9,
         "name": "tenant_front_door",
         "mode": "inproc",
         "tenants": args.tenants,
@@ -255,7 +451,6 @@ def run_grpc(args) -> dict:
             os.fsync(log_f.fileno())
     total = accepted + shed
     out = {
-        "config": 9,
         "name": "front_door",
         "mode": "grpc",
         "addr": args.addr,
@@ -300,7 +495,7 @@ def main() -> int:
     ap.add_argument(
         "--tenants", type=int, default=0,
         help="inproc: drive N virtual clusters through the tenant "
-        "arena front door (0 = single-cluster bench_suite path)",
+        "arena front door (0 = the single-cluster front door)",
     )
     ap.add_argument(
         "--tenant-dist", choices=("roundrobin", "zipf"),

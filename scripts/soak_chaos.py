@@ -21,7 +21,7 @@ Four phases (each selectable; default = all):
       crashes on the torn entry nor misses every entry.
 - **overload** — chaos fusion for the edge (ISSUE 14): arrivals at
   >= 2x measured capacity through the REAL submission API
-  (bench_suite.front_door_drive, the bench-config-9 harness) with a
+  (loadgen.front_door_drive, the load tool's own harness) with a
   fetch_hang mid-burst. Asserts bounded admission-queue depth,
   shed-not-lost (every acked pod binds exactly once), /healthz
   degraded DURING the burst, and ladder recovery to rung 0 after it.
@@ -55,7 +55,8 @@ import sys
 import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, REPO)
+# the repo for the package, scripts/ for loadgen's front_door_drive
+sys.path[:0] = [REPO, os.path.join(REPO, "scripts")]
 
 
 def _mean(xs):
@@ -82,6 +83,93 @@ SERVE_PLAN = (
 )
 
 
+def chaos_serve_drive(
+    fault_spec: str,
+    cycles: int,
+    deadline_ms: float,
+    pods_per_cycle: int = 4,
+    n_nodes: int = 16,
+    cache_dir: str = "off",
+    promote_cycles: int = 4,
+    drain_timeout_s: float = 30.0,
+) -> dict:
+    """The chaos-serve drive (ISSUE 9): one real Scheduler (dispatch
+    watchdog + ladder + pre-sized pads so no regime flip pollutes the
+    timing) serves a steady arrival stream under `fault_spec`, then
+    drains until every added pod bound and the ladder promoted home
+    (or `drain_timeout_s` expires).
+
+    Returns raw facts — `sched` (live handle), `added`, `binds`
+    (uid -> bind count), per-cycle `walls`, `degraded_cycles` (flight
+    records with rung > 0), `episodes_ms` (completed recovery episodes),
+    `duplicate_binds`, `lost` — and leaves the fault plan ARMED so the
+    caller can probe `faults.plan()`; the caller must
+    `faults.disarm()` when done."""
+    from k8s_scheduler_tpu.config import SchedulerConfiguration
+    from k8s_scheduler_tpu.core.scheduler import Scheduler
+    from k8s_scheduler_tpu.utils.synth import make_cluster, make_pods
+
+    cfg_obj = SchedulerConfiguration(
+        dispatch_deadline_ms=deadline_ms,
+        degrade_promote_cycles=promote_cycles,
+        fault_spec=fault_spec,
+        # backoff short so DispatchFailed pods retry within the drive
+        pod_initial_backoff_seconds=0.05,
+        pod_max_backoff_seconds=0.2,
+        # pre-sized pads: the oscillation-free workload must not flip
+        # regimes, so the deadline assertions are compile-free
+        pad_existing=2048,
+        pad_pods_per_node=512,
+        compile_cache_dir=cache_dir,
+        speculative_compile=False,
+    )
+    binds: dict[str, int] = {}
+    added: set[str] = set()
+    sched = Scheduler(
+        config=cfg_obj,
+        binder=lambda p, n: binds.__setitem__(
+            p.uid, binds.get(p.uid, 0) + 1
+        ),
+    )
+    for nd in make_cluster(n_nodes):
+        sched.on_node_add(nd)
+    walls: dict[int, float] = {}
+    t_run = time.perf_counter()
+    for i in range(1, cycles + 1):
+        for p in make_pods(
+            pods_per_cycle, seed=5000 + i, name_prefix=f"cz{i}-"
+        ):
+            sched.on_pod_add(p)
+            added.add(p.uid)
+        t0 = time.perf_counter()
+        sched.schedule_cycle()
+        walls[i] = time.perf_counter() - t0
+    # drain tail: requeued pods bind, ladder promotes home
+    drain_deadline = time.perf_counter() + drain_timeout_s
+    while (
+        len(binds) < len(added) or sched.ladder.rung > 0
+    ) and time.perf_counter() < drain_deadline:
+        sched.schedule_cycle()
+        time.sleep(0.02)
+    recs = sched.flight.snapshot(last=4096)
+    return {
+        "sched": sched,
+        "added": added,
+        "binds": binds,
+        "walls": walls,
+        "wall_s": time.perf_counter() - t_run,
+        "degraded_cycles": sum(
+            1 for r in recs if r.counts.get("rung", 0) > 0
+        ),
+        "episodes_ms": sched.ladder.recovery_episodes_ms(),
+        "duplicate_binds": sum(1 for n in binds.values() if n > 1),
+        "lost": sorted(
+            added - set(binds)
+            - {p.uid for p in sched.queue.all_pending()}
+        ),
+    }
+
+
 def run_serve_phase(
     cycles: int = 48,
     deadline_ms: float = 300.0,
@@ -90,17 +178,13 @@ def run_serve_phase(
     cache_dir: str = "",
     verbose: bool = True,
 ) -> dict:
-    # the drive itself is bench_suite.chaos_serve_drive — shared with
-    # bench config 7 (fault_storm), so the soak and the bench can never
-    # assert different invariants of the same storm; this phase adds
-    # the wider fault plan (cache/clock classes) and the warm-restart
-    # check over the chaos-written compile cache
-    import bench_suite
-
+    # chaos_serve_drive under the wider fault plan (cache/clock
+    # classes), then the warm-restart check over the chaos-written
+    # compile cache
     from k8s_scheduler_tpu.core import faults
 
     try:
-        d = bench_suite.chaos_serve_drive(
+        d = chaos_serve_drive(
             fault_spec=SERVE_PLAN.format(hang_ms=hang_ms),
             cycles=cycles,
             deadline_ms=deadline_ms,
@@ -193,8 +277,8 @@ def run_serve_phase(
 
 def run_overload_phase(verbose: bool = True) -> dict:
     """Arrival rate >= 2x measured capacity through the REAL front
-    door (bench_suite.front_door_drive — the same harness bench
-    config 9 asserts, so bench and soak can never drift), with a
+    door (loadgen.front_door_drive — the same harness the load tool
+    drives, so tool and soak can never drift), with a
     fetch_hang firing MID-BURST so the degradation ladder engages
     while the door is already shedding. Invariants:
 
@@ -206,7 +290,7 @@ def run_overload_phase(verbose: bool = True) -> dict:
     - the ladder recovers to rung 0 after the burst, with the hang
       step deadline-classified (the watchdog ended it, not the hang).
     """
-    import bench_suite
+    from loadgen import front_door_drive
 
     from k8s_scheduler_tpu.cmd.httpserver import staleness_healthz
     from k8s_scheduler_tpu.core import faults
@@ -214,7 +298,7 @@ def run_overload_phase(verbose: bool = True) -> dict:
     depth_bound = 64
     deadline_ms, hang_ms = 300.0, 2500.0
     try:
-        cal = bench_suite.front_door_drive(
+        cal = front_door_drive(
             duration_s=1.0, rate_pps=400.0, queue_depth=depth_bound,
             name_prefix="oc",
         )
@@ -236,7 +320,7 @@ def run_overload_phase(verbose: bool = True) -> dict:
             if detail.get("degraded"):
                 degraded_seen["burst"] = True
 
-        d = bench_suite.front_door_drive(
+        d = front_door_drive(
             duration_s=6.0,
             rate_pps=cap * 2.5,
             queue_depth=depth_bound,

@@ -5,7 +5,9 @@ The tests run on the CPU: the driver launches them with
 `jax.config`, both before the first backend use) so a bare `pytest`
 cannot reach for an accelerator. Multi-chip sharding is exercised on
 the 8 virtual CPU devices. The chip is reached only through
-`python chip_smoke.py`; the one test file that loads the TPU compiler
+`python benchmark/run.py` on a machine that holds one (here its
+`--rehearse` mode runs on the CPU: tests/test_benchmark_rehearsal.py);
+the one test file that loads the TPU compiler
 (tests/test_tpu_compile.py) compiles for a chip that is described, not
 attached.
 """
@@ -62,18 +64,15 @@ _SLOW_TESTS = {
     "test_kill9_failover_digest_matches_pre_kill",
     "test_soak_failover_smoke",
     # multi-cycle heavyweights: the 3-seed scheduler-level equivalence
-    # drive (~40 s/seed: two full Schedulers + WAL per seed), the
-    # 15-cycle burst/lull trace, and the bench K-sweeps (wall-clock
-    # perf bounds — kept out of the functional tier so machine load
-    # can't flake it; the device-level equivalence cases stay fast)
+    # drive (~40 s/seed: two full Schedulers + WAL per seed) and the
+    # 15-cycle burst/lull trace (the device-level equivalence cases
+    # stay fast)
     "test_scheduler_multicycle_matches_sequential",
     "test_mixed_burst_lull_traffic_no_false_fold_miss",
-    "test_bench_multicycle_sweep_amortizes_dispatch",
-    "test_bench_multicycle_sweep_respects_envelope",
     # compile-regime management end-to-end proofs (ISSUE 8): each
     # drives real Schedulers through cold XLA compiles of whole
     # program sets (warm-restart zero-cold-compile, speculation-won
-    # flip, and the three-phase regime_churn bench soak)
+    # flip, and the three-phase regime-churn soak)
     "test_warm_restart_compiles_zero_programs",
     "test_speculative_precompile_wins_the_flip",
     "test_regime_churn_soak_zero_compile_stalls",
@@ -92,20 +91,19 @@ _SLOW_TESTS = {
     # depth-2 speculative dispatch (ISSUE 13) heavyweights: the
     # 3-scheduler equivalence ladder and the 2-scheduler mismatch
     # drive (~40 s of Scheduler+WAL each), the speculative fuzz
-    # differential (TWO engine replays per trace), the chaos
+    # differential (TWO engine replays per trace) and the chaos
     # mid-speculation replay (a real 15 s injected hang bounded by
-    # the watchdog), and the scheduler-driven bench sweep point —
-    # the device-level chain/pipeline/record/sentinel cases stay fast
+    # the watchdog) — the device-level chain/pipeline/record/sentinel
+    # cases stay fast
     "test_scheduler_speculative_matches_sequential",
     "test_mismatch_abandons_redispatches_bit_identical",
     "test_fuzz_differential_speculative_seed",
     "test_fuzz_chaos_fetch_hang_mid_speculation",
-    "test_bench_sweep_reports_first_bind_and_hit_rate",
     # admission-time incremental encode (ISSUE 16) heavyweights: the
     # incremental fuzz differential (TWO engine replays per trace,
     # same class as its sibling seeds above) and the two table-growth
     # drives (each compiles a fresh K=4 packed program set) — the
-    # journal batch-record and bench_diff gate cases stay fast
+    # journal batch-record cases stay fast
     "test_fuzz_differential_incremental_seed",
     "test_multicycle_table_growth_within_padding_rebinds",
     "test_multicycle_growth_reencode_reuses_interned_entries",

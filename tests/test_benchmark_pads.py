@@ -1,0 +1,79 @@
+"""What the benchmark's data files hold the program to, read with no
+process started.
+
+- Per configuration under `benchmark/configs/`, the served YAML's
+  `padExisting` is the power of two above what the JSON's own `pad_rule`
+  inputs give, and holds `init_pods` + `depth` (ROADMAP S9 c; the
+  arithmetic of `benchmark/tests/test_cells.py`, which is run by hand).
+  Past the pad the encoder leaves the delta path and programs compile
+  inside the window: the run reads `existing_over_pad` > 0 and
+  `correct: false`.
+- Every span name a `program_span` layer file selects is in
+  `core/spans.SPAN_NAMES`, and every phase a `flight_phase` layer file
+  selects is in `core/observe.PHASES`: one case a name, so that a
+  rename fails in under a second with the name in the test's id.
+"""
+
+import glob
+import json
+import os
+
+import pytest
+import yaml
+
+from k8s_scheduler_tpu.core.observe import PHASES
+from k8s_scheduler_tpu.core.spans import SPAN_NAMES
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmark")
+
+
+def load(*parts):
+    with open(os.path.join(ROOT, *parts)) as f:
+        return yaml.safe_load(f) if parts[-1].endswith(".yaml") \
+            else json.load(f)
+
+
+BENCHMARK = load("BENCHMARK.json")
+
+
+@pytest.mark.parametrize(
+    "entry", BENCHMARK["configs"], ids=lambda e: e["name"])
+def test_the_existing_pad_is_what_the_configurations_rule_gives(entry):
+    cfg = load(entry["file"])
+    rule = cfg["pad_rule"]
+    holds = (
+        cfg["init_pods"]
+        + cfg["probe"]["pools"] * cfg["probe"]["nodes_per_pool"]
+        + cfg["depth"]
+        + rule["factor"] * rule["rate_ref_pods_per_s"]
+        * (BENCHMARK["run_seconds"] + rule["iteration_s"]))
+    served = load("benchmark", "configs", cfg["server_config"])
+    assert served["padExisting"] == 1 << int(holds).bit_length(), holds
+    assert served["padExisting"] >= cfg["init_pods"] + cfg["depth"]
+    # the rehearsal's cut server is the same program under a smaller pad
+    assert cfg["rehearse"]["server"]["padExisting"] < served["padExisting"]
+
+
+def selected(source_kind: str) -> list[str]:
+    """The distinct names the layer files of one kind select."""
+    names = set()
+    for path in glob.glob(os.path.join(BENCH, "layers", "*.json")):
+        with open(path) as f:
+            spec = json.load(f)
+        if spec["source_kind"] == source_kind:
+            names.update(spec["select"])
+    return sorted(names)
+
+
+# `outside` is the reader's own word (benchmark/lib/program_spans.py):
+# the device's idle time under no rpc.* span
+@pytest.mark.parametrize(
+    "name", [n for n in selected("program_span") if n != "outside"])
+def test_a_selected_span_is_one_the_program_stamps(name):
+    assert name in SPAN_NAMES
+
+
+@pytest.mark.parametrize("name", selected("flight_phase"))
+def test_a_selected_phase_is_one_the_flight_recorder_keeps(name):
+    assert name in PHASES

@@ -1,0 +1,72 @@
+"""The benchmark's own rehearsal, in every cell, traced and untraced.
+
+`benchmark/run.py` (`BENCHMARK.json` `command`) judges every PR on the
+chip. Its `--rehearse` mode runs the same harness against the same
+served program on the CPU at cut sizes: the server child starts, prints
+its `build:` and `encoder:` lines, serves `Update` / `Cycle`, answers
+`/debug/traces` and `/debug/flightrecorder`, and exits sealed on
+SIGTERM; every binding is checked against `benchmark/lib/reference.py`.
+A product change that breaks one of those contacts (a span or a flight
+phase renamed, a `/debug` field, a YAML key, a log line) fails here and
+not in the driver's check a PR later. No number of a rehearsal is a
+measurement, and none is asserted.
+
+The cells come from `BENCHMARK.json`, so a cell a later PR adds is
+covered without an edit. This file is one xdist worker's (`--dist
+loadfile`): the cases share `.bench/` (git-ignored: `cache-cpu` and the
+server logs, ~10 MB) and run one after another.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
+    BENCHMARK = json.load(_f)
+
+# read from spans and flight marks in every window, so printed by every
+# traced rehearsal (`cycle_snapshot_ms` is left out where no compaction
+# ran; the CPU has no device plane for `device_busy_ms` and the idle
+# shares)
+ALWAYS_READ = (
+    "update_servicer_ms", "update_convert_ms", "update_apply_ms",
+    "cycle_servicer_ms", "cycle_respond_ms", "encode_ms", "apply_ms",
+    "device_wait_ms", "gc_pass_ms",
+)
+
+
+@pytest.mark.parametrize("trace", (0, 1), ids=("untraced", "traced"))
+@pytest.mark.parametrize(
+    "cell", [w["name"] for w in BENCHMARK["workloads"]])
+def test_rehearsal(cell, trace):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)  # one CPU device, as the cell's one chip
+    out = subprocess.run(
+        [sys.executable, "benchmark/run.py", "--rehearse",
+         "--workload", cell, "--trace", str(trace),
+         "--seed", "3000000019"],
+        cwd=ROOT, env=env, text=True, capture_output=True, timeout=600,
+    )
+    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-4000:]
+    rows = [json.loads(ln) for ln in out.stdout.splitlines()]
+    (row,) = [r for r in rows if "would_print" in r]
+    assert (row["rehearsal"], row["trace"]) == (cell, trace)
+    line = row["would_print"]
+    assert line["correct"] is True, line["compared"]
+    assert line["failed"] == 0
+    for name, (value, limit) in line["compared"].items():
+        assert value <= limit, (name, value, limit)
+    printed = set(line["metrics"])
+    if not trace:
+        assert {"pods_bound_per_s", "setup_s"} <= printed
+        return
+    of_cell = {m["name"] for m in BENCHMARK["per_layer"]
+               if cell in m["workloads"]}
+    assert printed <= of_cell, printed - of_cell
+    owed = {n for n in of_cell if n.split(".")[0] in ALWAYS_READ}
+    assert len(owed) == len(ALWAYS_READ), owed
+    assert owed <= printed, owed - printed

@@ -383,9 +383,13 @@ def test_lease_release_joins_the_renewer(tmp_path):
     assert os.stat(path).st_mtime_ns == before
 
 
-def _start_cli(tmp_path, *extra):
+HTTP_LINE = "serving /healthz /metrics on port "
+
+
+def _start_cli(tmp_path, *extra, until=HTTP_LINE):
     """`python -m k8s_scheduler_tpu` as a child on ephemeral ports;
-    returns (proc, lines printed up to the http line, http port)."""
+    returns (proc, lines printed up to the `until` line, the port that
+    line ends in: the http port by default)."""
     import subprocess
     import sys
 
@@ -401,7 +405,7 @@ def _start_cli(tmp_path, *extra):
     lines = []
     for ln in proc.stdout:
         lines.append(ln.rstrip("\n"))
-        if ln.startswith("serving /healthz /metrics on port "):
+        if ln.startswith(until):
             return proc, lines, int(ln.rsplit(" ", 1)[1])
     proc.wait()
     raise AssertionError(
@@ -412,9 +416,10 @@ def _start_cli(tmp_path, *extra):
 def test_cli_build_line_and_build_info_name_the_device(tmp_path):
     """ISSUE 22: the `build:` line and `scheduler_build_info` carry the
     platform, device kind and device count of the process that holds
-    the devices — where `chip_smoke.py` reads the device from (its
-    parent never asks JAX) — the `encoder:` line says which snapshot-row
-    encoder serves, and SIGTERM exits 0 with the state sealed."""
+    the devices — where `benchmark/run.py` reads the device from (the
+    harness never asks JAX) — the `encoder:` line says which
+    snapshot-row encoder serves, and SIGTERM exits 0 with the state
+    sealed."""
     import shlex
     import signal
 
@@ -448,6 +453,30 @@ def test_cli_build_line_and_build_info_name_the_device(tmp_path):
     assert "durable state sealed" in tail
 
 
+@pytest.mark.parametrize(
+    "ready", ["scheduler shim listening on port ", HTTP_LINE],
+    ids=["grpc_line", "http_line"],
+)
+def test_sigterm_at_a_ready_line_still_seals_the_state(tmp_path, ready):
+    """ROADMAP D0: the handlers stand before the first line a
+    supervisor can take as "ready", so a SIGTERM sent the moment such a
+    line appears (the rest of start-up still ahead: the black box, the
+    http server, the collector's install) exits 0 with the journal
+    sealed, not killed by the default action."""
+    import signal
+
+    proc, lines, _port = _start_cli(tmp_path, until=ready)
+    try:
+        proc.send_signal(signal.SIGTERM)
+        tail = proc.communicate(timeout=60)[0]
+    finally:
+        if proc.poll() is None:
+            proc.kill()
+            proc.wait()
+    assert proc.returncode == 0, "\n".join(lines) + tail
+    assert "durable state sealed" in tail
+
+
 def test_build_line_survives_a_device_kind_with_spaces():
     """device_kind is "TPU v5 lite" on the chip: the line's values are
     shell-quoted so it still splits back into k=v fields."""
@@ -460,39 +489,3 @@ def test_build_line_survives_a_device_kind_with_spaces():
     assert line.startswith("build: ")
     fields = shlex.split(line[len("build: "):])
     assert dict(kv.split("=", 1) for kv in fields) == fp
-
-
-@pytest.mark.slow
-def test_chip_smoke_rehearsal(tmp_path):
-    """`chip_smoke.py --rehearse` end to end on the CPU at the cut
-    size: both phases pass their checks, the last line is the
-    contract's shape with "ok": false (a rehearsal is never reported
-    as a chip run) — and WITHOUT --rehearse a CPU server is refused,
-    with no contract line."""
-    import subprocess
-    import sys
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jc"))
-    env.pop("XLA_FLAGS", None)  # one CPU device, as the driver's sandbox
-    out = subprocess.run(
-        [sys.executable, "chip_smoke.py", "--rehearse"], cwd=root, env=env,
-        text=True, capture_output=True, timeout=900,
-    )
-    assert out.returncode == 0, out.stdout + out.stderr
-    rows = [json.loads(ln) for ln in out.stdout.splitlines()]
-    assert rows[-1] == {
-        "ok": False,
-        "device": {"platform": "cpu", "kind": "cpu", "count": 1},
-    }
-    phases = {r["phase"]: r for r in rows if "phase" in r}
-    assert phases["small"]["validator_violations"] == 0
-    assert phases["small"]["first_cycle"]["bound"] == 1000
-    assert phases["full"]["ladder"] == "normal"
-    assert rows[-2]["entries_after"]["aot"] > 0
-    refused = subprocess.run(
-        [sys.executable, "chip_smoke.py"], cwd=root, env=env,
-        text=True, capture_output=True, timeout=300,
-    )
-    assert refused.returncode != 0
-    assert "not a TPU" in refused.stderr and '"ok"' not in refused.stdout

@@ -115,10 +115,11 @@ def test_sharded_program_outputs_chain_sharded_avals(tmp_path):
     """A program partitioned over a mesh hands its outputs on sharded;
     the avals `load_or_compile` returns for chaining must say so, or
     the downstream executable is compiled for replicated inputs and
-    refuses the real (sharded) carry at its first call — found by
-    `chip_smoke.py --chips 4`'s rehearsal, where the ladder absorbed
-    it. Cold and loaded executables must agree, and the loaded one
-    must run on the mesh's devices, not the whole backend's."""
+    refuses the real (sharded) carry at its first call — found by a
+    four-device rehearsal of the served path, where the ladder
+    absorbed it. Cold and loaded executables must agree, and the
+    loaded one must run on the mesh's devices, not the whole
+    backend's."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
     mesh = Mesh(np.array(jax.devices()[:4]), ("pods",))
@@ -622,23 +623,73 @@ def test_speculative_precompile_wins_the_flip(tmp_path):
     )
 
 
-def test_regime_churn_soak_zero_compile_stalls(tmp_path, monkeypatch):
-    """Acceptance (bench-shaped): the pad-bucket-crossing churn soak
-    records zero compile-attributed stall cycles after the first
-    traversal of each regime, a warm start with zero cold compiles,
-    and hysteresis holding the oscillation to a single flip."""
-    import bench_suite
+def _churn_drive(cache_dir: str, hysteresis_pct: float, cycles: int = 8):
+    """One real Scheduler over `cache_dir`, its pending count flipping
+    60 <-> 80 across the P=64/128 pad boundary every cycle (E and MPN
+    pre-sized, so P is the one dimension that moves)."""
+    # manual clock: a cold compile takes real seconds, and an assumed
+    # pod's TTL expiring mid-drive would requeue it and move P off the
+    # scripted oscillation
+    clk = [0.0]
+    sched = Scheduler(
+        config=SchedulerConfiguration(
+            compile_cache_dir=cache_dir,
+            pad_existing=4096,
+            pad_pods_per_node=1024,
+            pad_hysteresis_pct=hysteresis_pct,
+            speculative_compile=False,  # would race the oscillation
+        ),
+        binder=lambda p, n: None,
+        now=lambda: clk[0],
+    )
+    for nd in make_cluster(16):
+        sched.on_node_add(nd)
+    for i in range(cycles):
+        for p in make_pods(
+            80 if i % 2 else 60, seed=9000 + i, name_prefix=f"rc{i}-"
+        ):
+            sched.on_pod_add(p)
+        sched.schedule_cycle()
+        clk[0] += 0.05
+    recs = sched.flight.snapshot()
+    # a stall = a cycle that paid > 50 ms of program build for a
+    # regime the scheduler had already been through
+    seen: set = set()
+    stalls = 0
+    for r in recs:
+        if r.phases.get("compile_ms", 0.0) > 50.0 and r.sig in seen:
+            stalls += 1
+        seen.add(r.sig)
+    return {
+        "flips": sum(1 for a, b in zip(recs, recs[1:]) if a.sig != b.sig),
+        "stalls": stalls,
+        "compile_s": sum(r.phases.get("compile_ms", 0.0) for r in recs) / 1e3,
+        "sources": {
+            r.compile_source for r in recs
+            if r.counts.get("regime_flip") and r.compile_source
+        },
+        "cache": sched._compile_cache.status(),
+    }
 
-    monkeypatch.setenv("BENCH_COMPILE_CACHE_DIR", str(tmp_path))
-    r = bench_suite.run_config(6, snapshots=8)
-    assert r["name"] == "regime_churn"
-    assert r["stall_cycles"] == 0
-    assert r["cache_misses"] == 0  # warm phase compiled nothing cold
-    assert r["compile_cache_hit_rate"] == 1.0
-    assert r["regime_flips"] >= 7  # the workload really oscillated
-    assert r["hysteresis_flips"] == 1  # held after the first up-step
-    assert r["warm_sources"] in ([], ["cache"])
-    assert r["compile_seconds"] > r["warm_compile_seconds"]
+
+def test_regime_churn_soak_zero_compile_stalls(tmp_path):
+    """Acceptance: the pad-bucket-crossing churn soak records zero
+    compile-attributed stall cycles after the first traversal of each
+    regime, a warm start with zero cold compiles, and hysteresis
+    holding the oscillation to a single flip."""
+    cold = _churn_drive(str(tmp_path), 0.0)
+    hyst = _churn_drive(str(tmp_path), 20.0)
+    # a fresh process would start without the loaded-executable memo:
+    # the warm drive must really deserialize
+    cc.clear_loaded_memo()
+    warm = _churn_drive(str(tmp_path), 0.0)
+    assert cold["flips"] >= 7  # the workload really oscillated
+    assert hyst["flips"] == 1  # held after the first up-step
+    assert cold["stalls"] + hyst["stalls"] + warm["stalls"] == 0
+    assert warm["cache"]["misses"] == 0  # compiled nothing cold
+    assert warm["cache"]["hits"] > 0
+    assert warm["sources"] <= {"cache"}
+    assert cold["compile_s"] > warm["compile_s"]
 
 
 def test_warmer_stop_wakes_idle_worker_and_reports_a_running_build():

@@ -27,7 +27,6 @@ from k8s_scheduler_tpu.core.observe import (
     CycleObserver,
     SloEngine,
     StreamHist,
-    classify_latency_series,
     phase_seconds,
 )
 from k8s_scheduler_tpu.metrics import SchedulerMetrics
@@ -423,19 +422,6 @@ def test_slo_config_plumbs_to_observer():
     # recorder disabled -> no records to observe -> no observer
     cfg_off = load_config("flightRecorderSize: 0")
     assert Scheduler(config=cfg_off).observer is None
-
-
-# ---- bench classifier ----------------------------------------------------
-
-
-def test_classify_latency_series_counts_stalls():
-    clean = [0.1] * 100
-    assert classify_latency_series(clean) == {}
-    with_stall = clean + [28.0]
-    counts = classify_latency_series(with_stall)
-    assert counts == {"tunnel_stall": 1}
-    # every reported class is a member of the canonical inventory
-    assert set(counts) <= set(ANOMALY_CLASSES)
 
 
 # ---- debug endpoints -----------------------------------------------------

@@ -24,10 +24,6 @@ here is fast-tier except where marked.
 """
 
 import hashlib
-import json
-import os
-import subprocess
-import sys
 
 import jax
 import numpy as np
@@ -316,81 +312,6 @@ def test_flight_record_payload_digest_stable():
     assert total == sum(c.bytes for c in colls)
     digest = hashlib.sha256(str(total).encode()).hexdigest()
     assert len(digest) == 64  # parser output is deterministic
-
-
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def test_bench_diff_gates_sharded_metrics(tmp_path):
-    """bench_diff gates config 8 directionally: scaling_efficiency may
-    not drop, collective_payload_mb may not rise; artifacts predating
-    config 8 (r05) still diff clean against new ones."""
-    base = {
-        "config": 8, "name": "sharded_scale",
-        "scaling_efficiency": 0.8, "collective_payload_mb": 3.7,
-        "per_device_ms": 50.0, "p50_ms": 0.0,
-    }
-    old = tmp_path / "old.json"
-    new = tmp_path / "new.json"
-    old.write_text(json.dumps(base))
-    worse = dict(base)
-    worse["scaling_efficiency"] = 0.4  # -50% efficiency
-    worse["collective_payload_mb"] = 40.0  # the diet regressed
-    new.write_text(json.dumps(worse))
-    diff = os.path.join(_REPO, "scripts", "bench_diff.py")
-    same = subprocess.run(
-        [sys.executable, diff, str(old), str(old)],
-        capture_output=True, text=True,
-    )
-    assert same.returncode == 0, same.stdout + same.stderr
-    reg = subprocess.run(
-        [sys.executable, diff, "--json", str(old), str(new)],
-        capture_output=True, text=True,
-    )
-    assert reg.returncode == 1, reg.stdout + reg.stderr
-    regressed = {
-        c["metric"] for c in json.loads(reg.stdout)["regressions"]
-    }
-    assert {"scaling_efficiency", "collective_payload_mb"} <= regressed
-    # backward compatibility: a driver-wrapped round artifact of the
-    # r05 shape ({"tail", "parsed"}, compact rows, no config 8) diffs
-    # clean against a new artifact that has them
-    r05 = tmp_path / "r05_shape.json"
-    r05.write_text(json.dumps({
-        "n": 5, "cmd": "python bench.py", "rc": 0, "tail": "",
-        "parsed": {
-            "metric": "pod_node_scoring_decisions_per_sec",
-            "value": 304515887.5, "device": "tpu", "errors": [],
-            "configs": [
-                {"c": 4, "dps": 304515888, "p50": 461.9, "p99": 723.9,
-                 "dev": 147.4, "enc": 66.5, "sched": 3313,
-                 "unsched": 6686},
-            ],
-        },
-    }))
-    back = subprocess.run(
-        [sys.executable, diff, str(r05), str(new)],
-        capture_output=True, text=True,
-    )
-    assert back.returncode == 0, back.stdout + back.stderr
-
-
-@pytest.mark.slow
-def test_bench_sharded_scale_smoke(monkeypatch):
-    """Bench config 8 end-to-end at a smoke grid: sweeps the virtual
-    devices, asserts the invariance contract internally, and reports
-    the headline keys bench_diff gates."""
-    import bench_suite
-
-    monkeypatch.setenv("BENCH_SHARDED_GRID", "512x128")
-    monkeypatch.setenv("BENCH_SHARDED_DEVICES", "1,2")
-    r = bench_suite.run_sharded_scale_config(snapshots=2)
-    assert r["config"] == 8 and r["name"] == "sharded_scale"
-    assert "scaling_efficiency" in r and r["scaling_efficiency"] > 0
-    assert r["collective_payload_mb"] >= 0
-    assert r["grid"][0]["devices"]["2"]["per_device_ms"] > 0
-    # the 100k x 50k target grid stays documented in CONFIG_SHAPES
-    assert bench_suite.CONFIG_SHAPES[8] == (100000, 50000)
 
 
 def test_budget_checker_flags_unknown_class_and_overrun():

@@ -1,16 +1,13 @@
 """Pod-lifecycle tracing (core/spans.py + wiring): span ring
 semantics, W3C traceparent propagation, deterministic head sampling,
 the cross-thread trace join (submit thread -> serve thread -> bind),
-the unarmed-overhead bound, chrome/OTLP export, the /debug/traces +
-/debug/explain endpoints with the deprecated /debug/trace alias, and
-the bench_diff --max-trace-overhead ceiling."""
+the unarmed-overhead bound, chrome/OTLP export, and the /debug/traces +
+/debug/explain endpoints with the deprecated /debug/trace alias."""
 
 from __future__ import annotations
 
 import json
 import os
-import subprocess
-import sys
 import threading
 import time
 import urllib.request
@@ -631,73 +628,3 @@ def test_new_endpoints_head_and_mutations_405():
                 assert headers["Allow"] == "GET, HEAD"
     finally:
         server.shutdown()
-
-
-# ---------------------------------------------------------------------------
-# bench_diff: the --max-trace-overhead ceiling
-# ---------------------------------------------------------------------------
-
-
-def _bench_diff(tmp_path, old_row, new_row, *extra):
-    for name, row in (("old.json", old_row), ("new.json", new_row)):
-        (tmp_path / name).write_text(json.dumps({"configs": [row]}))
-    return subprocess.run(
-        [
-            sys.executable,
-            os.path.join(REPO, "scripts", "bench_diff.py"),
-            *extra,
-            str(tmp_path / "old.json"), str(tmp_path / "new.json"),
-        ],
-        capture_output=True, text=True,
-    )
-
-
-def test_trace_overhead_pct_absorbs_fsync_bimodality():
-    sys.path.insert(0, REPO)
-    try:
-        import bench_suite
-    finally:
-        sys.path.remove(REPO)
-    f = bench_suite.trace_overhead_pct
-    # the measured rig flip: untraced stage lands the lucky fsync mode
-    # (0.34 ms ack p99), traced stage the slow one (4.5 ms) — same
-    # code, same disk. The naive p99 ratio reads +1219%; the floored
-    # axis must not count it (bind p50 barely moves)
-    assert f(0.341, 4.5, 18385.0, 18553.0) < 5.0
-    # and the reverse flip clamps at 0, never negative
-    assert f(4.361, 0.341, 18500.0, 18400.0) == 0.0
-    # a catastrophic ack regression (far past the jitter floor) still
-    # trips a 50% ceiling regardless of which mode the base landed in
-    assert f(0.341, 30.0, 18385.0, 18553.0) > 50.0
-    assert f(4.361, 30.0, 18385.0, 18553.0) > 50.0
-    # a serve-loop-serializing bug shows on the bind p50 axis plainly
-    assert f(4.0, 4.0, 10000.0, 40000.0) == pytest.approx(300.0)
-
-
-def test_bench_diff_trace_overhead_ceiling(tmp_path):
-    base = {"config": 9, "submit_ack_p99_ms": 5.0}
-    # under the ceiling: clean (the old side has no trov at all —
-    # pre-PR artifacts must keep diffing against traced ones)
-    r = _bench_diff(
-        tmp_path, dict(base), dict(base, trace_overhead_pct=12.0),
-        "--max-trace-overhead", "50",
-    )
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "trace_overhead_ceiling" in r.stdout
-    # over the ceiling: the absolute gate trips on the NEW artifact
-    r = _bench_diff(
-        tmp_path, dict(base), dict(base, trace_overhead_pct=80.0),
-        "--max-trace-overhead", "50",
-    )
-    assert r.returncode == 1
-    assert "REGRESSED" in r.stdout
-    # 0 disables the gate entirely
-    r = _bench_diff(
-        tmp_path, dict(base), dict(base, trace_overhead_pct=80.0),
-        "--max-trace-overhead", "0",
-    )
-    assert r.returncode == 0, r.stdout + r.stderr
-    # artifacts without the metric (both sides pre-PR) diff clean
-    r = _bench_diff(tmp_path, dict(base), dict(base))
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "trace_overhead_ceiling" not in r.stdout

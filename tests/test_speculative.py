@@ -559,81 +559,6 @@ def test_scheduler_consults_the_thrash_hold(tmp_path):
     assert sched.speculation_ledger()["adopted"] == 0
 
 
-# ---- bench: the K-sweep acceptance shape ---------------------------------
-
-
-def test_bench_sweep_reports_first_bind_and_hit_rate():
-    """ISSUE 13 bench acceptance (CPU smoke): with depth-2 + streamed
-    fetch on, the K-sweep reports first_bind_p50_ms and
-    speculation_hit_rate; first bind lands within ~1 inner cycle (the
-    `<= 2x a single inner cycle` criterion, with sched_effective_p50
-    = flush wall / K as the inner-cycle yardstick) instead of waiting
-    the whole K-cycle batch, and a clean drive adopts every
-    speculation."""
-    import bench_suite
-
-    for attempt in range(2):
-        out = bench_suite.run_multicycle_config(
-            1, k_values=(1, 4), batches=3
-        )
-        assert "skipped" not in out
-        assert out["speculation_hit_rate"] == 1.0
-        pt = out["per_k"]["4"]
-        assert pt["speculation_ledger"]["adopted"] >= 1
-        fb = out["first_bind_p50_ms"]
-        if (
-            fb <= 2 * pt["sched_effective_p50_ms"]
-            and fb < pt["sched_batch_p50_ms"]
-        ):
-            break
-    else:
-        assert fb <= 2 * pt["sched_effective_p50_ms"]
-        assert fb < pt["sched_batch_p50_ms"]
-
-
-def test_bench_diff_gates_the_new_metrics(tmp_path):
-    """bench_diff: first_bind_p50_ms higher = regressed,
-    speculation_hit_rate drop = regressed — and both stay
-    backward-compatible with artifacts predating the sweep (r05)."""
-    import json
-    import subprocess
-    import sys
-    import os
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    old = {"configs": [{
-        "config": 2, "p50_ms": 10.0,
-        "first_bind_p50_ms": 5.0, "speculation_hit_rate": 1.0,
-    }]}
-    new = {"configs": [{
-        "config": 2, "p50_ms": 10.0,
-        "first_bind_p50_ms": 20.0, "speculation_hit_rate": 0.4,
-    }]}
-    r05 = {"configs": [{"config": 2, "p50_ms": 10.0}]}
-    p_old = tmp_path / "old.json"
-    p_new = tmp_path / "new.json"
-    p_r05 = tmp_path / "r05.json"
-    p_old.write_text(json.dumps(old))
-    p_new.write_text(json.dumps(new))
-    p_r05.write_text(json.dumps(r05))
-
-    def diff(a, b):
-        proc = subprocess.run(
-            [sys.executable, os.path.join(repo, "scripts", "bench_diff.py"),
-             "--json", str(a), str(b)],
-            capture_output=True, text=True,
-        )
-        return proc.returncode, json.loads(proc.stdout)
-
-    rc, res = diff(p_old, p_new)
-    assert rc == 1
-    regressed = {c["metric"] for c in res["regressions"]}
-    assert {"first_bind_p50_ms", "speculation_hit_rate"} <= regressed
-    # r05-era artifact without the metrics: skipped, not crashed
-    rc, res = diff(p_r05, p_new)
-    assert rc == 0, res
-
-
 # ---- config / CLI plumbing ----------------------------------------------
 
 

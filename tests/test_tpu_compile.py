@@ -3,7 +3,7 @@ and not attached (ISSUE 22 steps 2 and 6).
 
 No Pallas kernel exists in this repo, so "the kernels compile" means the
 cycle's XLA programs — `stable`, `carry_init`, `cycle`, `carry_update`,
-`preempt`, `diag` — at the pad regime `chip_smoke.py` serves: bench
+`preempt`, `diag` — at the pad regime PR 22 first served on the chip:
 cell 4 (10,000 pending x 5,000 nodes, 12,000 bound) under
 `padExisting: 32768`, `padPodsPerNode: 32`. The TPU compiler installed
 here compiles them for `v5e:2x2` without a chip; what it refuses here
@@ -36,7 +36,7 @@ import jax
 import numpy as np
 import pytest
 
-# chip_smoke.py's YAML: the sticky pads that keep cell 4 in one regime
+# the sticky pads that keep cell 4 in one regime
 SMOKE_PADS = dict(pad_existing=32768, pad_pods_per_node=32)
 HBM_BYTES = 16 * 1024**3  # one v5e chip
 
@@ -66,9 +66,41 @@ def no_persistent_cache():
     jcc.reset_cache()
 
 
+def make_cell(cfg: int) -> tuple:
+    """(nodes, pending, existing) of cell `cfg`: 2 = 1,000 pods x 100
+    nodes with node affinity and taints, 3 = 5,000 x 1,000 with
+    inter-pod (anti-)affinity, 4 = 10,000 x 5,000 with the full default
+    plugin set over 12,000 low-priority bound pods on small nodes, so
+    that preemption has real work."""
+    from k8s_scheduler_tpu.utils.synth import make_cluster, make_pods
+
+    mix = dict(affinity_fraction=0.3, anti_affinity_fraction=0.2,
+               spread_fraction=0.2, num_apps=500)
+    if cfg == 2:
+        return (
+            make_cluster(100, taint_fraction=0.3),
+            make_pods(1000, seed=0, selector_fraction=0.5,
+                      toleration_fraction=0.4),
+            [],
+        )
+    if cfg == 3:
+        return make_cluster(1000), make_pods(5000, seed=0, **mix), []
+    assert cfg == 4, cfg
+    bound = make_pods(12000, seed=991, name_prefix="run",
+                      affinity_fraction=0.1, spread_fraction=0.1,
+                      num_apps=500)
+    return (
+        make_cluster(5000, taint_fraction=0.1, cpu_choices=(4, 8, 16)),
+        make_pods(10000, seed=0, selector_fraction=0.3,
+                  toleration_fraction=0.1, priorities=(0, 0, 10, 100),
+                  **mix),
+        [(p, f"node-{i % 5000}") for i, p in enumerate(bound)],
+    )
+
+
 def lower_regime(cfg: int, default, mesh_devices=()) -> tuple:
-    """Encode bench cell `cfg` through a real Scheduler's encoder under
-    chip_smoke.py's pads, and lower its program chain
+    """Encode cell `cfg` through a real Scheduler's encoder under
+    SMOKE_PADS, and lower its program chain
     (core/scheduler.aot_chain — the walk `_aot_install` serves from)
     with the host-made inputs placed by `default`, as served; chained
     avals that carry no sharding of their own get it too (a described
@@ -80,14 +112,11 @@ def lower_regime(cfg: int, default, mesh_devices=()) -> tuple:
     program is COMPILED as it is reached, because the next one's avals
     carry its output shardings (compile_cache._out_avals, as served):
     returns (spec, {kind: (compiled, seconds)})."""
-    import bench_suite
     from k8s_scheduler_tpu.config import SchedulerConfiguration
     from k8s_scheduler_tpu.core import compile_cache as cc
     from k8s_scheduler_tpu.core import scheduler as sched_mod
 
-    nodes, pending, existing, _groups = bench_suite.make_config_workload(
-        cfg, seed=0
-    )
+    nodes, pending, existing = make_cell(cfg)
     config = SchedulerConfiguration(**SMOKE_PADS)
     config.compile_cache_dir = "off"
     config.speculative_compile = False
@@ -174,8 +203,8 @@ def report(label: str, spec, seconds: float, mem) -> int:
 #   stable 15.5/12.7/0.2  carry_init 4.0/2.7/1.0  cycle 114.9/57.0/13.6
 #   carry_update 8.2/5.9/0.8  preempt 92.8/17.7/1.5  diag 45.9/33.2/1.3
 # Tier-1 keeps what is under ~20 s each and ~60 s together: the three
-# cell-4 programs that are, and cell 2's (the smoke's small phase) for
-# the three kinds that are not; the rest are `slow`.
+# cell-4 programs that are, and cell 2's for the three kinds that are
+# not; the rest are `slow`.
 FAST = [
     (4, "stable"), (4, "carry_init"), (4, "carry_update"),
     (2, "cycle"), (2, "preempt"), (2, "diag"),

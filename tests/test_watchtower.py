@@ -41,7 +41,6 @@ from k8s_scheduler_tpu.metrics.rules import (
     RuleEngine,
     builtin_rules,
     load_rules_file,
-    replay_alerts,
     scale_rules,
 )
 from k8s_scheduler_tpu.metrics.tsdb import MetricsTSDB
@@ -440,14 +439,6 @@ def test_faultplan_stall_burst_fires_after_hold_and_resolves():
         (("rule", "tunnel_stall_burst"), ("severity", "critical")),
     ) in vals else "scheduler_alerts_total",
         (("rule", "tunnel_stall_burst"), ("severity", "critical")))] == 1.0
-
-
-def test_replay_alerts_headline():
-    clean = replay_alerts([0.5] * 40)
-    assert clean == {"alerts_fired": 0, "fired_rules": []}
-    stormy = replay_alerts([0.5] * 10 + [28.0] * 25 + [0.5] * 5)
-    assert stormy["alerts_fired"] >= 1
-    assert "tunnel_stall_burst" in stormy["fired_rules"]
 
 
 # ---- black box ------------------------------------------------------------
