@@ -401,6 +401,9 @@ class Scheduler:
         # flush-time phase stamps awaiting inner record 0
         self._ingest_s: dict[str, float] = {}
         self._flush_phases: dict[str, dict] = {}
+        # per profile, the encoder's fold_fallback_pods as of the last
+        # committed flight record (_commit_record stamps the difference)
+        self._fold_fallback_seen: dict[str, int] = {}
         if self.extenders:
             # extender verdicts are consulted per HOST cycle; inner
             # device cycles cannot re-consult a webhook, so batching is
@@ -848,7 +851,7 @@ class Scheduler:
         # stable precomputes.
         enc = encoder or self._encoder
         enc_st = getattr(enc, "_stable", None)
-        key = (spec.key(), id(enc_st), getattr(enc, "fold_hits", 0))
+        key = (spec.key(), id(enc_st), enc.fold_hits)
         hit = self._dev_stable.get(key)
         if hit is None or hit[0] is not enc_st:
             hit = (enc_st, stable_fn(wbuf, bbuf))
@@ -2729,6 +2732,17 @@ class Scheduler:
             seen["sample_narrowed_pods"] = (
                 seen.get("sample_narrowed_pods", 0) + narrowed
             )
+        # rows the existing-set fold built in Python since the last
+        # record: bound pods the native row writer does not cover
+        # (volumes / nodeAffinity), folded per pod instead of sending
+        # the whole cycle to the full encode
+        fb_total = int(encoder.fold_fallback_pods)
+        fold_fallback = fb_total - self._fold_fallback_seen.get(
+            rec.profile, 0
+        )
+        if fold_fallback:
+            self._fold_fallback_seen[rec.profile] = fb_total
+            self.metrics.fold_fallback_pods.inc(fold_fallback)
         qc = self.queue.pending_counts()
         sb, ub, bb, pb, vb = before
         rec.counts.update(
@@ -2747,7 +2761,8 @@ class Scheduler:
             # fall off the delta/fold encode path)
             full_encodes=int(encoder.full_encodes),
             delta_hits=int(encoder.delta_hits),
-            fold_hits=int(getattr(encoder, "fold_hits", 0)),
+            fold_hits=int(encoder.fold_hits),
+            fold_fallback_pods=fold_fallback,
             # admission-time incremental encode: dirty slots whose
             # flush-time parse was skipped (a staged ingest row was
             # waiting) — the bench's encode_hidden evidence

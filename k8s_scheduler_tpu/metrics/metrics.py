@@ -105,6 +105,11 @@ round trip):
 - scheduler_encode_finalize_seconds — flush-time residue of the
   incremental encode: folding staged rows into the packed arena when
   the multi-cycle buffer flushes (what is left of the old O(P) rebuild)
+- scheduler_encode_fold_fallback_pods_total — newly bound pods whose
+  existing-set row the incremental fold built in Python because the
+  native row writer does not cover them (volumes / nodeAffinity): the
+  per-pod fallback that keeps such a pod from costing a full encode
+  (counted as each cycle's flight record commits)
 
 Multi-chip serving families (shardDevices + parallel/audit.py — the
 sharded carry path with shard-invariant tie-breaking):
@@ -386,6 +391,13 @@ class SchedulerMetrics:
             "scheduler_decision_fetch_bytes_total",
             "Bytes moved device->host by the blocking per-cycle decision "
             "fetch (slimmed payload: i16 assignment + u8 flags per pod).",
+            registry=r,
+        )
+        self.fold_fallback_pods = Counter(
+            "scheduler_encode_fold_fallback_pods_total",
+            "Newly bound pods whose existing-set row the incremental "
+            "fold built in Python (the native row writer does not cover "
+            "volumes / nodeAffinity); counted at flight-record commit.",
             registry=r,
         )
         # ---- admission-time incremental encode (models/encoding.py) ----

@@ -487,6 +487,11 @@ class SnapshotEncoder:
         # observability: how many encode_packed calls hit the delta path
         self.delta_hits = 0
         self.full_encodes = 0
+        # existing-set folds that succeeded (_try_fold_existing), and the
+        # appended rows they built in Python because the native writer
+        # does not cover the pod
+        self.fold_hits = 0
+        self.fold_fallback_pods = 0
         # per-segment ms of the LAST delta encode (see _encode_delta)
         self.delta_profile: dict[str, float] = {}
         # admission-time incremental encode (ingest/finalize split, PR 16):
@@ -1936,7 +1941,10 @@ class SnapshotEncoder:
             ):
                 # ONLY the existing set changed — the per-cycle event of
                 # real serving (bindings fold in; a completion batch
-                # drops the tail). Try the incremental stable fold.
+                # drops the tail). Try the incremental stable fold: it
+                # answers False for a middle-of-list removal, a row over
+                # the sticky dims or a grown interning table, and no
+                # longer for a bound pod the native parser does not cover.
                 import time as _time
 
                 _ft = _time.perf_counter()
@@ -2011,11 +2019,21 @@ class SnapshotEncoder:
         existing set changed by a pure APPEND (pods bound since the last
         cycle) or a pure TAIL REMOVAL (un-folding a completion batch of
         recently bound pods). Anything else — middle-of-list removals,
-        node/volume/PDB changes, dict growth, arena-dim overflow, pods the
-        native parser does not cover — returns False and the caller takes
-        the full encode (which rebuilds the stable cache from scratch, so
-        partial st mutations on a failed fold are discarded wholesale
-        along with the stale _stable_key).
+        node/volume/PDB changes, an interning table that grew, a row
+        wider than the sticky dims (MPL / MA / the existing-pod port
+        width / R), affinity terms while flag_aff is off, the E pad
+        exhausted, a node outgrowing its used-port or victim-table width
+        — returns False and the caller takes the full encode (which
+        rebuilds the stable cache from scratch, so partial st mutations
+        on a failed fold are discarded wholesale along with the stale
+        _stable_key).
+
+        An appended pod the native row writer does not cover (volumes /
+        nodeAffinity / exotic operators) does NOT fail the fold: its row
+        comes from the Python row builder and is written through
+        apply_rows, per pod, under the same guards as the native rows
+        (`fold_fallback_pods` counts them). One such pod among the newly
+        bound used to cost a full encode of the whole resident set.
 
         Exactness contract: after a successful fold, every st array is
         byte-identical to what a from-scratch assembly over the new
@@ -2062,6 +2080,7 @@ class SnapshotEncoder:
         ca = st["exist_creation_abs"]
         affected_nodes: set[int] = set()
         port_nodes: set[int] = set()
+        n_fallback = 0  # appended rows built in Python (counted on commit)
 
         if n_new < n_old:  # ---- tail removal ----
             sl = np.arange(L, n_old)
@@ -2139,10 +2158,33 @@ class SnapshotEncoder:
                 [p for p, _ in app], self._native_ctx(), slots, specs,
                 limits,
             )
-            if not guard_ok or any(r is None for r in res):
-                return False  # dims overflow / unsupported pod
+            if not guard_ok:
+                return False  # a native row overflowed the sticky dims
+            # per-pod fallback, as _encode_delta's fb_slots: a pod the
+            # native writer does not cover (volumes / nodeAffinity /
+            # exotic operators) gets its row from the Python builder (a
+            # _pod_cache hit when it was pending the cycle before) and
+            # only the fold's own guards decide about the full path
+            fb = [j for j, r in enumerate(res) if r is None]
+            fb_rows = [ds["pod_rowdata"](app[j][0]) for j in fb]
+            for d in fb_rows:
+                if (
+                    len(d["lab_k"]) > limits["MPL"]
+                    or d["n_aff"] > limits["MA"]
+                    or len(d["ports"]) > limits["MPorts"]
+                    or len(d["reqvec"]) > limits["R"]
+                    or (not flag_aff and d["n_aff"] > 0)
+                ):
+                    return False  # same guards as the native rows
             if self._table_lens() != lens0:
                 return False  # interning grew: finalize tables stale
+            if fb:
+                idx = slots[fb]
+                # apply_rows writes no f64 column: creation goes beside
+                # it, as on the pending side
+                native.apply_rows(specs[:-1], idx, fb_rows)
+                ca[idx] = [d["creation"] for d in fb_rows]
+                n_fallback = len(fb)
             nidx = ds["node_index"]
             en_new = np.array(
                 [nidx.get(nm, -1) for _, nm in app], np.int32
@@ -2270,7 +2312,8 @@ class SnapshotEncoder:
         # every port-bearing pending slot this cycle
         if port_nodes:
             ds["fold_port_dirty"] = True
-        self.fold_hits = getattr(self, "fold_hits", 0) + 1
+        self.fold_hits += 1
+        self.fold_fallback_pods += n_fallback
         return True
 
     def _encode_delta(self, ds, pending, pod_groups, mutated_ids):

@@ -52,6 +52,7 @@ class Driver:
             nodes, pending, existing, groups, mutated_ids=mutated, **kw
         )
         ref = self.b.encode(nodes, pending, existing, groups, **kw)
+        self.ref = ref  # the reference's snapshot of this step
         got = packing.unpack(np.asarray(w), np.asarray(bb), spec)
         assert_same_snapshot(got, ref)
         # the view snapshot must alias the arena (same data, same ids)
@@ -352,6 +353,162 @@ def test_fold_unfold_float_exactness_under_inexact_requests():
         existing = existing[:12]  # completion batch
         d.step(nodes, pods, existing)
     assert d.a.fold_hits >= 6
+
+
+def assert_fold_exact(d):
+    """The fold's exactness contract, byte for byte: every `st` array of
+    the folding encoder against the reference encoder's from-scratch
+    assembly over the same lists, and both arena buffers against the
+    reference's freshly packed snapshot."""
+    sa, sb = d.a._stable, d.b._stable
+    for k, vb in sb.items():
+        if isinstance(vb, np.ndarray):
+            va = sa[k]
+            assert va.dtype == vb.dtype and va.shape == vb.shape, k
+            assert va.tobytes() == vb.tobytes(), f"st[{k}] differs"
+    assert sa["start_base"] == sb["start_base"]
+    assert sa["e_real"] == sb["e_real"]
+    ref_w, ref_b = packing.pack(d.ref, d.a._arena_spec)
+    assert d.a._arena_w.tobytes() == np.asarray(ref_w).tobytes()
+    assert d.a._arena_b.tobytes() == np.asarray(ref_b).tobytes()
+
+
+_DICT_PATH_PODS = {
+    # what the benchmark's score-fidelity probes carry
+    "preferred_node_affinity": lambda name: (
+        MakePod(name).req({"cpu": "100m"})
+        .node_affinity_preferred(10, "zone", ["zone-0"])
+    ),
+    "required_node_affinity": lambda name: (
+        MakePod(name).req({"cpu": "100m"})
+        .node_affinity_in("zone", ["zone-0", "zone-1"])
+    ),
+    "volume": lambda name: (
+        MakePod(name).req({"cpu": "100m"}).volume("claim-0")
+    ),
+}
+
+
+def _fold_fixture(kind, n_dict=3):
+    """A warmed Driver whose pending set holds `n_dict` pods the native
+    row writer refuses (they were pending, so their interning is done:
+    what the served path sees when such a pod binds)."""
+    from k8s_scheduler_tpu import native
+    from k8s_scheduler_tpu.models.api import (
+        PersistentVolume,
+        PersistentVolumeClaim,
+    )
+
+    if native.pod_rows_into is None:
+        pytest.skip("native extension not built")
+    nodes = make_cluster(8)
+    d = Driver()
+    pods = make_pods(30, seed=41, affinity_fraction=0.2, num_apps=5)
+    odd = [
+        _DICT_PATH_PODS[kind](f"odd-{i}").labels({"app": f"app-{i}"})
+        .created(50.0 + i).obj()
+        for i in range(n_dict)
+    ]
+    assert all(
+        r is None for r in (
+            native.pod_row(p, SnapshotEncoder()._native_ctx()) for p in odd
+        )
+    ), "fixture pods must be ones the native parser does not cover"
+    pods[10:10 + n_dict] = odd
+    existing = [
+        (p, f"node-{i % 8}")
+        for i, p in enumerate(
+            make_pods(20, seed=42, name_prefix="run", num_apps=5)
+        )
+    ]
+    kw = dict(
+        pvcs=[PersistentVolumeClaim("claim-0", volume_name="pv-0")],
+        pvs=[PersistentVolume("pv-0", claim_ref="default/claim-0")],
+    )
+    d.step(nodes, pods, existing, **kw)
+    d.step(nodes, pods, existing, **kw)  # warm the delta path
+    return d, nodes, pods, odd, existing, kw
+
+
+@pytest.mark.parametrize("kind", sorted(_DICT_PATH_PODS))
+def test_fold_falls_back_per_pod_for_rows_the_native_writer_refuses(kind):
+    """One newly bound pod with a volume or node affinity used to fail
+    the fold for the whole cycle (a full encode of the resident set).
+    The append now builds those rows in Python, beside the native ones,
+    and their tail removal un-folds them: both byte-identical to a
+    from-scratch assembly, with no full encode."""
+    d, nodes, pods, odd, existing, kw = _fold_fixture(kind)
+    folds0, fulls0 = d.a.fold_hits, d.a.full_encodes
+    fb0 = d.a.fold_fallback_pods
+
+    # native and dict-path pods bind in one cycle, interleaved
+    bound_idx = [0, 10, 1, 11, 2, 12, 3]
+    bound = [(pods[i], f"node-{i % 8}") for i in bound_idx]
+    existing2 = existing + bound
+    pending2 = [p for i, p in enumerate(pods) if i not in bound_idx]
+    d.step(nodes, pending2, existing2, **kw)
+    assert d.a.full_encodes == fulls0
+    assert d.a.fold_hits == folds0 + 1
+    assert d.a.fold_fallback_pods == fb0 + len(odd)
+    assert_fold_exact(d)
+
+    # the completion batch: the appended tail leaves again
+    d.step(nodes, pending2, existing2[: len(existing)], **kw)
+    assert d.a.full_encodes == fulls0
+    assert d.a.fold_hits == folds0 + 2
+    assert d.a.fold_fallback_pods == fb0 + len(odd)  # parses no pod
+    assert_fold_exact(d)
+
+
+@pytest.mark.parametrize("guard", ["labels_outgrow_MPL", "interning_grows"])
+def test_fold_fallback_rows_keep_the_folds_guards(guard):
+    """The per-pod fallback is safe because its rows pass the guards the
+    native rows pass: a dict-path pod wider than the sticky dims, or one
+    whose parse grows an interning table, still takes the full path."""
+    d, nodes, pods, _odd, existing, kw = _fold_fixture(
+        "preferred_node_affinity"
+    )
+    if guard == "labels_outgrow_MPL":
+        # every string is interned already (two pending pods carry the
+        # labels between them, the affinity term is the fixture's), so
+        # the ONLY thing wrong with this pod is its label row's width
+        keys = [f"k{i}" for i in range(12)]
+        halves = [
+            MakePod(f"half-{h}").req({"cpu": "100m"})
+            .labels({k: "v" for k in keys[h * 6:(h + 1) * 6]}).obj()
+            for h in range(2)
+        ]
+        pods = pods[:-2] + halves
+        d.step(nodes, pods, existing, **kw)
+        d.step(nodes, pods, existing, **kw)
+        newcomer = (
+            MakePod("wide").req({"cpu": "100m"})
+            .labels({k: "v" for k in keys})
+            .node_affinity_preferred(10, "zone", ["zone-0"]).obj()
+        )
+        assert len(keys) + 1 > d.a._delta_state["dims"]["MPL"]
+    else:
+        # never pending: its affinity expression is new to the tables
+        newcomer = (
+            MakePod("stranger").req({"cpu": "100m"})
+            .node_affinity_preferred(7, "rack", ["rack-9"]).obj()
+        )
+    folds0, fulls0 = d.a.fold_hits, d.a.full_encodes
+    fb0 = d.a.fold_fallback_pods
+    lens0 = d.a._table_lens()
+    d.step(nodes, pods, existing + [(newcomer, "node-2")], **kw)
+    assert d.a.full_encodes == fulls0 + 1
+    assert d.a.fold_hits == folds0
+    assert d.a.fold_fallback_pods == fb0
+    assert (d.a._table_lens() == lens0) == (guard == "labels_outgrow_MPL")
+    assert_fold_exact(d)
+    # and the grown arena folds again afterwards
+    d.step(
+        nodes, pods[1:],
+        existing + [(newcomer, "node-2"), (pods[0], "node-1")], **kw
+    )
+    assert d.a.fold_hits == folds0 + 1
+    assert_fold_exact(d)
 
 
 def test_pad_ma_mc_presize_keeps_regime_stable():

@@ -583,3 +583,74 @@ def test_a_confirmation_the_server_cannot_apply_falls_back(shims, spoil):
     assert [(e.pod.metadata.uid, e.bound_node) for e in req.pod_adds] == [
         (pod.uid, node)]
     assert not req.bind_confirms and not req.pod_updates
+
+
+def test_a_bound_pod_with_node_affinity_costs_no_full_encode():
+    """The agent path with the rehearsal's pads: after the warm-up, every
+    cycle binds a pod with a preferred node-affinity term (what the
+    benchmark's score-fidelity probes carry, and what the native row
+    writer does not cover) beside plain pods. The next cycle's fold
+    builds that pod's row in Python: `full_encodes` stays flat in the
+    flight records, `fold_fallback_pods` counts the pod, and the
+    observer raises no `fold_miss`."""
+    from k8s_scheduler_tpu import native
+
+    if native.pod_rows_into is None:
+        pytest.skip("native extension not built")
+    server, service, port = serve(
+        "127.0.0.1:0",
+        config=SchedulerConfiguration(
+            pad_existing=256, pad_pods_per_node=32, pad_hysteresis_pct=100
+        ),
+    )
+    client = SchedulerClient(f"127.0.0.1:{port}")
+    try:
+        applier = Applier()
+        agent = SchedulerAgent(client, applier.bind, applier.evict)
+        for i in range(8):
+            agent.upsert_node(
+                MakeNode(f"n{i}").capacity({"cpu": "16"})
+                .labels({"pool": f"pool-{i % 2}"}).obj()
+            )
+
+        def wave(c):
+            with agent.batched():
+                for j in range(3):
+                    agent.upsert_pod(
+                        MakePod(f"plain-{c}-{j}").req({"cpu": "100m"})
+                        .labels({"app": "a"}).obj()
+                    )
+                agent.upsert_pod(
+                    MakePod(f"probe-{c}").req({"cpu": "100m"})
+                    .labels({"app": "a"})
+                    .node_affinity_preferred(10, "pool", ["pool-1"]).obj()
+                )
+            return agent.run_cycle()
+
+        warm, cycles = 3, 5
+        for c in range(warm + cycles):
+            assert wave(c).stats.scheduled == 4
+        assert applier.bound[f"default/probe-{warm}"] in {
+            "n1", "n3", "n5", "n7"}
+
+        sched = service.scheduler
+        recs = [r for r in sched.flight.snapshot() if r.counts.get("pods")]
+        assert len(recs) == warm + cycles
+        after = recs[warm:]
+        # each of these cycles folds in the previous one's four binds,
+        # one of them on the dict path
+        assert {r.counts["full_encodes"] for r in after} == {
+            recs[warm - 1].counts["full_encodes"]}
+        assert [r.counts["fold_fallback_pods"] for r in after] == [1] * cycles
+        assert (after[-1].counts["fold_hits"]
+                - recs[warm - 1].counts["fold_hits"]) == cycles
+        assert not [
+            a for a in sched.observer.anomalies()
+            if a["class"] == "fold_miss" and a["seq"] >= after[0].seq
+        ]
+        assert (b"scheduler_encode_fold_fallback_pods_total %.1f"
+                % sum(r.counts["fold_fallback_pods"] for r in recs)
+                ) in client.metrics_text()
+    finally:
+        client.close()
+        server.stop(grace=None)
