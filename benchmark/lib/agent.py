@@ -1,10 +1,17 @@
 """The agent loop: what a cluster-side agent does with the scheduler,
 timed from the client's side.
 
-One loop, as `service/client.py` has it: upsert every pod that is due,
+One loop, as `service/client.py` has it: complete resident pods down to
+the configuration's `resident_target`, upsert every pod that is due,
 `Cycle`, stamp each returned binding with the time the response arrived,
 confirm the bindings and apply the evictions as `run_cycle` does, repeat.
-The traffic file says when a pod is due:
+Pods finish (the traffic file's `completions` block): before every cycle
+the agent deletes `max(0, resident - resident_target)` bound pods, drawn
+uniformly from those not on a probe-pool node by a generator of its own,
+seeded from `--seed`, in the same batched `Update` as the upserts. So a
+run's resident set is held at the size the configuration states, and does
+not grow with what the program binds. The traffic file says when a pod is
+due:
 
 - `closed_depth`: before every cycle the server's pending set is topped
   up to `depth` (saturation; judged on pods bound per second);
@@ -21,6 +28,8 @@ import dataclasses
 import re
 import time
 
+import numpy as np
+
 from k8s_scheduler_tpu.service.client import SchedulerAgent, SchedulerClient
 
 from .child import BenchError
@@ -28,6 +37,9 @@ from .reference import Cycle
 
 WARM_CYCLE_TIMEOUT_S = 1100.0
 CYCLE_TIMEOUT_S = 120.0
+# the completion draw's stream of `--seed`: never the deployment's, so
+# the pods a seed offers do not move with what a run completes
+COMPLETIONS_STREAM = 1
 _DIAGNOSIS = re.compile(r"^0/(\d+) nodes are available: (.*)\.$")
 
 
@@ -72,8 +84,23 @@ def parse_diagnosis(message: str) -> tuple[int, int]:
 class Driver:
     """The agent-side truth: what was sent, what came back, when."""
 
-    def __init__(self, port: int, dep) -> None:
+    def __init__(self, port: int, dep, completions: dict | None = None,
+                 seed: int = 0) -> None:
+        """`completions` is the traffic file's block; without one the
+        agent completes nothing and the resident set is all it bound."""
         self.dep = dep
+        if completions and completions["order"] != "uniform":
+            raise BenchError(
+                f"completions in {completions['order']!r} order: this "
+                "agent draws uniformly from the resident pods")
+        self.resident_target = (
+            int(dep.cfg["resident_target"]) if completions else None)
+        self._draw = np.random.default_rng((seed, COMPLETIONS_STREAM))
+        self._pool_nodes = {
+            dep.nodes[i].name for pool in dep.pools for i in pool.nodes}
+        self._held: set[str] = set()  # bound pods the server holds
+        self._finishable: list[str] = []  # those not on a pool node
+        self._slot: dict[str, int] = {}  # uid -> index in _finishable
         self.client = SchedulerClient(f"127.0.0.1:{port}")
         self.agent = StrictAgent(
             self.client, bind_applier=lambda *_: None,
@@ -96,6 +123,7 @@ class Driver:
                 self.agent.upsert_node(n)
             for pod, node in self.dep.init:
                 self.agent.upsert_pod(pod, bound_node=node)
+                self._placed(pod.uid, node)
 
     def warm(self, pods) -> None:
         """One batch of `depth` pods: compiles or loads the regime and
@@ -109,6 +137,46 @@ class Driver:
             if not self.pending or not self.step([], probes=False).bound:
                 break
         self.agent.cycle_timeout = CYCLE_TIMEOUT_S
+
+    # ---- the resident set -------------------------------------------
+
+    @property
+    def resident(self) -> int:
+        """Bound pods the server holds, by what was sent to it."""
+        return len(self._held)
+
+    def _placed(self, uid: str, node: str) -> None:
+        self._held.add(uid)
+        if node not in self._pool_nodes:  # probes and their loads stay
+            self._slot[uid] = len(self._finishable)
+            self._finishable.append(uid)
+
+    def _left(self, uid: str) -> None:
+        """A resident pod is gone (completed or evicted): O(1), the last
+        of the list takes its slot."""
+        self._held.discard(uid)
+        i = self._slot.pop(uid, None)
+        if i is None:
+            return
+        last = self._finishable.pop()
+        if last != uid:
+            self._finishable[i] = last
+            self._slot[last] = i
+
+    def completions_due(self) -> list[str]:
+        """The pods that finish before the next cycle: as many as the
+        resident set stands over `resident_target`, drawn without
+        replacement from the resident pods not on a pool node."""
+        if self.resident_target is None:
+            return []
+        n = min(max(self.resident - self.resident_target, 0),
+                len(self._finishable))
+        picks = self._draw.choice(len(self._finishable), size=n,
+                                  replace=False)
+        done = [self._finishable[i] for i in picks]
+        for uid in done:
+            self._left(uid)
+        return done
 
     # ---- one iteration ----------------------------------------------
 
@@ -132,7 +200,10 @@ class Driver:
         t0 = time.monotonic()
         if probes:
             due = list(due) + self._probes_due(t0)
+        completed = self.completions_due()
         with self.agent.batched():
+            for uid in completed:
+                self.agent.delete_pod(uid)
             for pod in due:
                 if pod.uid in self.pods:
                     raise BenchError(f"duplicate pod uid {pod.uid}")
@@ -150,14 +221,17 @@ class Driver:
                 self.pending.discard(uid)
                 if uid in self.pods:
                     self.agent.upsert_pod(self.pods[uid], bound_node=node)
+                    self._placed(uid, node)
             for ev in resp.evictions:
                 self.agent.delete_pod(ev.pod_uid)
+                self._left(ev.pod_uid)
         t3 = time.monotonic()
         st = resp.stats
         if st.bind_errors or st.scheduled != len(bindings):
             raise BenchError(f"the cycle's own accounting is off: {st}")
         self.cycles.append(Cycle(
             offered=offered,
+            completed=completed,
             bindings=bindings,
             evictions=[(ev.pod_uid, ev.node_name) for ev in resp.evictions],
             refused=[

@@ -71,7 +71,9 @@ class Deployment:
         the oldest of the lowest priority; they ride the warm-up batch
         (named `warm-`, so not among the pods a run attempts), so that
         the programs that serve a refusal are warm before the window.
-        The program's queue parks them after that refusal."""
+        The program's queue parks them after a refusal; a completion
+        (`PodDelete`, a queueing hint of NodeResourcesFit) un-parks
+        them, so they are judged again as their backoff runs out."""
         uc = self.cfg.get("unschedulable") or {}
         return [
             MakePod(f"warm-unschedulable-{i}")

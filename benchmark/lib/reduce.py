@@ -2,7 +2,7 @@
 
 A per-layer metric is one file, `benchmark/layers/<name>.json`: the kind
 of source it reads (`client_span`, `client_latency`, `flight_phase`,
-`trace_ops`, `program_span`: the program's own spans,
+`flight_count`, `trace_ops`, `program_span`: the program's own spans,
 `program_spans.py`), what it
 selects there, and how the selection is reduced.
 Adding a metric over an existing kind of source is adding a file and a
@@ -92,6 +92,22 @@ def read_flight_phase(spec: dict, src: dict):
     return _reduce(series, spec["reduce"])
 
 
+def read_flight_count(spec: dict, src: dict):
+    """The window's rise of one running count of the flight records
+    (`full_encodes`: the encoder's total when the record was committed)
+    over the cycles it rose through: last less first, over the records
+    between them. None where fewer than two records carry the count (a
+    program that keeps no such count, a window of one cycle)."""
+    if spec["reduce"] != "rise_per_cycle":
+        raise ValueError(f"unknown reduction {spec['reduce']!r}")
+    (name,) = spec["select"]
+    series = [r["counts"][name] for r in src["flight"]
+              if name in r.get("counts", {})]
+    if len(series) < 2:
+        return None
+    return (series[-1] - series[0]) / (len(series) - 1)
+
+
 def read_trace_ops(spec: dict, src: dict):
     """From the reduced device trace: `busy_per_launch` (busy seconds
     over launches of the program named in `per`) or `idle_pct`."""
@@ -113,6 +129,7 @@ READERS = {
     "client_span": read_client_span,
     "client_latency": read_client_latency,
     "flight_phase": read_flight_phase,
+    "flight_count": read_flight_count,
     "trace_ops": read_trace_ops,
     "program_span": program_spans.read,
 }

@@ -4,6 +4,16 @@ numpy and Python integers only. It reads pods and nodes through the API
 types (`models/api.py` dataclasses) and imports no scheduling code of
 the program. It decides `correct` from what the client was SENT:
 
+Pods finish: a cycle's `completed` uids (what the agent deleted before
+it) leave the replayed cluster before anything of that cycle is read, so
+(b), (c)'s start-of-cycle counts and (d) see the room and the peers a
+correct server sees. A completed uid that was not resident is a fault of
+the harness (`bad_completions`, limit 0); a resident set that stands over
+the configuration's `resident_target` at a cycle's start means the
+completions stopped (`resident_over_target`, limit 0). A server that lost
+a delete keeps a node fuller than it is, and refuses a pod the reference
+finds room for: (d).
+
 (a) every binding names a known node and a pod that was pending, once;
 (b) per node, CPU (milli), memory (bytes) and pod count within
     allocatable, in exact integers, after every cycle;
@@ -371,6 +381,8 @@ class Cycle:
     evictions: list  # (uid, node name)
     # (uid, nodes the diagnosis rejected, nodes it counted, its message)
     refused: list
+    # uids the agent deleted as finished before this cycle ran
+    completed: list = dataclasses.field(default_factory=list)
 
 
 @dataclasses.dataclass
@@ -381,6 +393,11 @@ class Verdict:
     # refusals whose own diagnosis left nodes open: where and what they
     # said, so that a run over REFUSED_OPEN_LIMIT names its cause
     open_refusals: dict = dataclasses.field(default_factory=dict)
+    # bound pods in the replayed cluster, per cycle: at its start (its
+    # completions gone) and after its confirmations (bindings in,
+    # evictions out)
+    resident_at_start: list = dataclasses.field(default_factory=list)
+    resident_after: list = dataclasses.field(default_factory=list)
 
     @property
     def ok(self) -> bool:
@@ -388,10 +405,12 @@ class Verdict:
 
 
 def check_run(nodes, init, pods: dict, cycles: list[Cycle],
-              pools, probe_rounds: int) -> Verdict:
+              pools, probe_rounds: int,
+              resident_target: int | None = None) -> Verdict:
     """Replay the run cycle by cycle. `pods` maps uid -> Pod for every
     pod ever offered; `probe_rounds` is how many times a probe per pool
-    fell due."""
+    fell due; `resident_target` is what the configuration holds the
+    resident set to at a cycle's start (None: nothing completes)."""
     cl = Cluster(nodes)
     for pod, node in init:
         cl.add(pod, cl.index[node])
@@ -407,7 +426,17 @@ def check_run(nodes, init, pods: dict, cycles: list[Cycle],
     open_said: dict[str, int] = {}
     open_uids: set = set()
     open_samples: list[str] = []
+    n_completed = 0
+    at_start: list[int] = []
+    after: list[int] = []
     for ci, cyc in enumerate(cycles):
+        for uid in cyc.completed:  # gone before the cycle read anything
+            n_completed += 1
+            if uid in cl.where:
+                cl.remove(uid)
+            else:
+                note("completion", f"cycle {ci}: {uid} was not resident")
+        at_start.append(len(cl.where))
         seen = set()
         fresh = []
         for uid, node in cyc.bindings:  # (a)
@@ -467,6 +496,12 @@ def check_run(nodes, init, pods: dict, cycles: list[Cycle],
                 note("eviction", f"cycle {ci}: {uid} not on {node}")
             else:
                 cl.remove(uid)
+        after.append(len(cl.where))
+    over_target = 0 if resident_target is None else max(
+        0, max(at_start, default=0) - resident_target)
+    if over_target:
+        note("resident", f"{over_target} pods over the resident target "
+             f"{resident_target} at a cycle's start: completions stopped")
     gap, n_probe = probe_gaps(cl, pools, probe_nodes)
     if gap > PROBE_GAP_LIMIT:
         note("probe", f"a probe sits {gap:.4f} score points under its "
@@ -485,6 +520,9 @@ def check_run(nodes, init, pods: dict, cycles: list[Cycle],
         "nodes_over_allocatable": [len(bad.get("capacity", [])), 0],
         "constraint_breaches": [len(bad.get("constraint", [])), 0],
         "bad_evictions": [len(bad.get("eviction", [])), 0],
+        "completed": n_completed,
+        "bad_completions": [len(bad.get("completion", [])), 0],
+        "resident_over_target": [over_target, 0],
         "refused": n_refused,
         "refused_with_nodes_left_open_by_the_program": [
             n_allowed, REFUSED_OPEN_LIMIT],
@@ -501,7 +539,7 @@ def check_run(nodes, init, pods: dict, cycles: list[Cycle],
         "said": sorted(open_said.items(), key=lambda kv: -kv[1])[:6],
         "samples": open_samples,
     } if n_allowed else {}
-    return Verdict(problems, counts, wrongly, opened)
+    return Verdict(problems, counts, wrongly, opened, at_start, after)
 
 
 def describe(pod) -> str:

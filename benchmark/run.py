@@ -123,7 +123,8 @@ def run_cell(bench: dict, cell: dict, args, rehearse: bool) -> dict:
                                    chips=cell["chips"])
             say(server=build, workload=cell["name"], seed=args.seed,
                 start=start)
-            drv = agent.Driver(server.grpc_port, dep)
+            drv = agent.Driver(server.grpc_port, dep,
+                               traffic.get("completions"), args.seed)
             t_load = time.monotonic()
             drv.load()
             load_s = time.monotonic() - t_load
@@ -240,21 +241,31 @@ def run_cell(bench: dict, cell: dict, args, rehearse: bool) -> dict:
     t_check = time.monotonic()
     verdict = reference.check_run(
         dep.nodes, dep.init, drv.pods, drv.cycles, dep.pools,
-        drv.probe_rounds)
+        drv.probe_rounds, drv.resident_target)
     check_s = time.monotonic() - t_check
     compiled = counter_total(m_end, "scheduler_compile_cache_misses_total") \
         - counter_total(m_start, "scheduler_compile_cache_misses_total")
     loaded = counter_total(m_end, "scheduler_compile_cache_hits_total") \
         - counter_total(m_start, "scheduler_compile_cache_hits_total")
     rung = health["degradation"]
-    # the agent completes no pods, so the server's existing set is what
-    # was loaded and everything it ever bound, less what it evicted. Past
-    # the E pad the encoder leaves the delta path and the next regime
-    # compiles inside the window: said here in plain words
-    existing_at_end = len(dep.init) + len(drv.bound_at) - sum(
-        len(c.evictions) for c in drv.cycles)
+    # the server's existing set is what was loaded and everything it
+    # bound, less what it evicted and what the agent completed: the
+    # reference's replay counts it after every cycle's confirmations,
+    # and the largest is what the E pad has to hold. Past the pad the
+    # encoder leaves the delta path and the next regime compiles inside
+    # the window: said here in plain words
+    existing_peak = max(verdict.resident_after, default=len(dep.init))
+    at_start = verdict.resident_at_start[n_warm_cycles:]
+    # ... and the server's own count of the pods it holds (bound and
+    # assumed, stamped at the last cycle's end) is the replay's: a
+    # delete or a confirmation that never reached it shows here
+    held = m_end.get('scheduler_cache_size{type="pods"}')
+    if held is None:
+        raise BenchError("/metrics holds no scheduler_cache_size{type=pods}")
     served = {
-        "existing_over_pad": [max(0, existing_at_end - pad), 0],
+        "existing_over_pad": [max(0, existing_peak - pad), 0],
+        "server_resident_drift": [
+            abs(int(held) - verdict.resident_after[-1]), 0],
         "programs_compiled_in_window": [compiled, 0],
         "programs_loaded_in_window": [loaded, 0],
         "ladder_degradations": [
@@ -313,8 +324,12 @@ def run_cell(bench: dict, cell: dict, args, rehearse: bool) -> dict:
                 spans[-1].offered if spans else 0,
             ],
             "flight_records": len(records),
-            "existing_at_end": existing_at_end, "pad_existing": pad,
-            "pad_headroom_share": 1.0 - existing_at_end / pad,
+            "existing_peak": existing_peak, "pad_existing": pad,
+            "pad_headroom_share": 1.0 - existing_peak / pad,
+            "completed": verdict.counts["completed"],
+            "resident_target": drv.resident_target,
+            "resident_at_start": [min(at_start, default=None),
+                                  max(at_start, default=None)],
         },
     )
     src = {"spans": spans, "flight": records, "trace": trace,

@@ -4,11 +4,12 @@ with the rest of `benchmark/tests`):
 - every configuration, cell and per-layer metric `BENCHMARK.json` names has
   its files, and they say what the entry says;
 - `sp5000-default` is `sp5000-mixed` with `percentageOfNodesToScore` left
-  out and nothing else, and its cell and sixteen metrics are the
+  out and nothing else, and its cell and eighteen metrics are the
   saturated cell's under their own names;
-- every configuration's E pad is at or over what its own `pad_rule` and
-  sizes give, and a run that outgrows its pad says so: `existing_over_pad`
-  over 0, `correct: false`;
+- every configuration's E pad is the power of two above the resident
+  set it states (`resident_target` + `depth` + the probe loads: no rate
+  input since PR 35), and a run that outgrows its pad says so:
+  `existing_over_pad` over 0, `correct: false`;
 - every per-layer metric's reader returns None, and never raises, over a
   run that has nothing for it: no loop iteration, flight records without
   phases, no trace. A program that lacks what a metric reads (the parent
@@ -38,6 +39,7 @@ DEFAULT_METRICS = (
     "update_servicer_ms", "update_convert_ms", "update_apply_ms",
     "cycle_servicer_ms", "cycle_respond_ms", "cycle_snapshot_ms",
     "idle_in_update_pct", "idle_in_cycle_pct", "idle_outside_rpc_pct",
+    "gc_pass_ms", "full_encodes_per_cycle",
 )
 
 
@@ -65,35 +67,43 @@ def test_configuration_files(entry):
     assert cells, "a configuration that no cell runs is never measured"
 
 
-def pad_by_rule(cfg: dict, run_seconds: int) -> tuple[float, int]:
+def pad_by_rule(cfg: dict) -> tuple[int, int]:
     """(what a run may hold, the power of two above it): the rule of
-    `sp5000-mixed.json`'s `assumed`, from the file's own numbers."""
-    rule = cfg["pad_rule"]
+    `sp5000-mixed.json`'s `assumed`, from the file's own sizes. The
+    resident set at a cycle's start, what one cycle can bind on top of
+    it, and the probe pools' load pods; no rate and no run length."""
     holds = (
-        cfg["init_pods"]
-        + cfg["probe"]["pools"] * cfg["probe"]["nodes_per_pool"]
-        + cfg["depth"]
-        + rule["factor"] * rule["rate_ref_pods_per_s"]
-        * (run_seconds + rule["iteration_s"]))
-    return holds, 1 << int(holds).bit_length()
+        cfg["resident_target"] + cfg["depth"]
+        + cfg["probe"]["pools"] * cfg["probe"]["nodes_per_pool"])
+    return holds, 1 << holds.bit_length()
 
 
 @pytest.mark.parametrize(
     "entry", BENCHMARK["configs"], ids=lambda e: e["name"])
 def test_the_existing_pad_is_what_the_configurations_rule_gives(entry):
     cfg = load("configs", entry["name"] + ".json")
-    holds, pad = pad_by_rule(cfg, BENCHMARK["run_seconds"])
+    holds, pad = pad_by_rule(cfg)
     server = load("configs", cfg["server_config"])
     assert server["padExisting"] == pad, (holds, pad)
+    # the window opens under the target and the warm-up batch fills it
+    assert cfg["init_pods"] + cfg["depth"] <= cfg["resident_target"] + 64
+    # the rate inputs are no inputs: at 0 the old formula, which tier 1
+    # (tests/test_benchmark_pads.py) still computes, gives the same pad
+    rule = cfg["pad_rule"]
+    assert (rule["rate_ref_pods_per_s"], rule["factor"],
+            rule["iteration_s"]) == (0, 0.0, 0.0)
+    cut = cfg["rehearse"]
+    assert cut["resident_target"] + cut["depth"] < cut["server"]["padExisting"]
+    assert cut["init_pods"] < cut["resident_target"]
 
 
 def test_the_mixed_pad_rule_in_numbers():
     cfg = load("configs", "sp5000-mixed.json")
-    holds, pad = pad_by_rule(cfg, 40)
-    assert holds == pytest.approx(12000 + 64 + 10000 + 2 * 2163 * 44.7)
-    assert round(holds) == 215436 and pad == 262144
-    assert any(a.startswith("padExisting: 262144") and "215,436" in a
+    holds, pad = pad_by_rule(cfg)
+    assert holds == 140000 + 10000 + 64 == 150064 and pad == 262144
+    assert any(a.startswith("padExisting: 262144") and "150,064" in a
                for a in cfg["assumed"])
+    assert cfg["reduced"] == {}
 
 
 @pytest.mark.parametrize(
@@ -105,6 +115,9 @@ def test_cell_files(cell):
     assert traffic["why"] == cell["why"]
     assert cell["name"] == f"{cell['config']}.{cell['traffic']}"
     assert traffic["loop"] in ("closed_depth", "open_rate")
+    # pods finish in every cell, in the one order the agent draws
+    assert traffic["completions"] == {"order": "uniform"}
+    assert any(a.startswith("completions:") for a in traffic["assumed"])
     reported = [m for m in BENCHMARK["per_layer"]
                 if cell["name"] in m.get("workloads", [cell["name"]])]
     assert reported, "every cell reports a per-layer metric"
@@ -146,13 +159,14 @@ def test_layer_files_and_their_readers_on_an_empty_run(entry):
 def test_sp5000_default_is_sp5000_mixed_at_the_stock_percentage():
     mixed, default = (load("configs", n + ".json")
                       for n in ("sp5000-mixed", "sp5000-default"))
-    for key in ("nodes", "pods", "init_pods", "depth", "probe", "plugins",
-                "precision", "reduced"):
+    for key in ("nodes", "pods", "init_pods", "resident_target", "depth",
+                "probe", "plugins", "precision", "reduced", "pad_rule"):
         assert default[key] == mixed[key], key
     assert default["nodes"] == {
         "count": 5000, "cpu": "4", "memory": "32Gi", "pods": 110,
         "taint_fraction": 0.1}
-    assert (default["init_pods"], default["depth"]) == (12000, 10000)
+    assert (default["init_pods"], default["resident_target"],
+            default["depth"]) == (130000, 140000, 10000)
     assert (default["pods"]["cpu"], default["pods"]["memory"],
             default["pods"]["num_apps"]) == ("100m", "500Mi", 500)
     assert default["guarantees"][:4] == mixed["guarantees"]
@@ -203,10 +217,11 @@ def test_sp5000_default_sat_is_the_saturated_cell_under_its_own_names():
 
 
 def test_a_run_that_outgrows_its_pad_says_so(tmp_path):
-    """A copy of the benchmark whose rehearsal pad (1,024) is under what
-    a 4 s saturated rehearsal binds: the line reads `correct: false`
-    and `existing_over_pad` says why, beside whatever the program did
-    about it (regimes compiled inside the window)."""
+    """A copy of the benchmark whose rehearsal pad (512) is under the
+    resident set its rehearsal holds (520 + a cycle's 248): the line
+    reads `correct: false` and `existing_over_pad` says why, beside
+    whatever the program did about it (regimes compiled inside the
+    window)."""
     copy = tmp_path / "checkout"
     shutil.copytree(BENCH, copy / "benchmark",
                     ignore=shutil.ignore_patterns("__pycache__"))
@@ -215,7 +230,7 @@ def test_a_run_that_outgrows_its_pad_says_so(tmp_path):
                copy / "k8s_scheduler_tpu")
     path = copy / "benchmark" / "configs" / "sp5000-mixed.json"
     cfg = json.loads(path.read_text())
-    cfg["rehearse"]["server"]["padExisting"] = 1024
+    cfg["rehearse"]["server"]["padExisting"] = 512
     path.write_text(json.dumps(cfg))
     out = subprocess.run(
         [sys.executable, str(copy / "benchmark" / "run.py"), "--rehearse",
@@ -229,8 +244,8 @@ def test_a_run_that_outgrows_its_pad_says_so(tmp_path):
     facts = next(d["facts"] for d in said if "facts" in d)
     line = next(d["would_print"] for d in said if "would_print" in d)
     over, limit = line["compared"]["existing_over_pad"]
-    assert facts["pad_existing"] == 1024 and limit == 0
-    assert over == facts["existing_at_end"] - 1024 > 0
+    assert facts["pad_existing"] == 512 and limit == 0
+    assert over == facts["existing_peak"] - 512 > 0
     assert facts["pad_headroom_share"] < 0
     assert line["correct"] is False
     assert list(line)[-1] == "compared"
@@ -249,10 +264,26 @@ def test_the_rehearsal_passes_in_every_cell_traced_and_untraced():
              for d in said if "would_print" in d}
     assert sorted(lines) == sorted(
         (w["name"], t) for w in BENCHMARK["workloads"] for t in (0, 1))
+    # pods finish in every cell's rehearsal: the delete path runs, the
+    # resident set is flat at the cut target from the window's first
+    # cycle to its last, and the replay and the server agree on it
+    all_facts = [d["facts"] for d in said if "facts" in d]
+    assert len(all_facts) == len(lines)
+    for facts in all_facts:
+        assert facts["completed"] > 0
+        assert facts["resident_at_start"] == [facts["resident_target"]] * 2
+        assert facts["existing_peak"] <= facts["resident_target"] + 256
     for (cell, trace), line in lines.items():
         assert line["correct"] is True, (cell, trace)
         assert line["failed"] == 0
         assert line["compared"]["existing_over_pad"] == [0, 0]
+        for name in ("bad_completions", "resident_over_target",
+                     "server_resident_drift"):
+            assert line["compared"][name] == [0, 0], (cell, trace, name)
+        if trace:
+            (full,) = [v["value"] for k, v in line["metrics"].items()
+                       if k.startswith("full_encodes_per_cycle.")]
+            assert 0.0 <= full <= 1.0
         if trace == 0:
             assert {"pods_bound_per_s", "setup_s"} <= set(line["metrics"])
         else:
