@@ -62,6 +62,10 @@ class CycleResult:
     # per commit round (zeros in scan mode) — convergence diagnostics
     diag_per_round: jnp.ndarray  # i32 [max_rounds, 3] (live claims,
     # capacity rejections, guard rejections) per round, summed over passes
+    rounds_parked: jnp.ndarray  # i32 [] pods the commit rounds parked:
+    # refused whatever else the cycle placed, and kept out of the
+    # compacted window from the round that judged them (ops/rounds.py;
+    # 0 in scan mode)
 
 
 @jax.tree_util.register_dataclass
@@ -196,12 +200,13 @@ def _with_sample_counts(result: CycleResult, snap, sample, narrowed):
     )
 
 
-def _sample_mark(pct: int) -> str:
+def _engine_marks(pct: int) -> str:
     """Appended to the key's value in a program name. A program that
     samples says HOW in its name: the executable store keys on names,
     and an entry built when the sample was a window of node indices
-    must never load."""
-    return "" if pct >= 100 else ":feasible"
+    must never load. Every program that takes this mark embeds the
+    commit engines, so the rounds engine's own mark rides with it."""
+    return rounds_ops.ENGINE_MARK + ("" if pct >= 100 else ":feasible")
 
 
 def _unique(fn, base: str, disc: str = ""):
@@ -639,48 +644,60 @@ def _make_cycle_body(
                 score_anchor_fn=lambda nr: fw.score_anchor(ctx, nr),
                 pv_choice_fn=_make_pv_choice_fn(ctx),
                 sample=sample,
+                closed_for_cycle_fn=lambda vs, vmp, vsm, pf: (
+                    fw.closed_for_cycle(view_ctx(vs, vmp), vsm, pf)
+                ),
                 **(rounds_kw or {}),
             )
             narrowed = rres.sample_narrowed
-            # Final-state work (dynamic reject attribution + the NodePorts
-            # part of the preemption gate) only matters for pods that never
-            # placed — computed on a COMPACTED view instead of a full
-            # [P, N] dyn pass. PREEMPTION-ELIGIBLE unplaced pods fill the
-            # window first (by rank), so the window can never be exhausted
-            # by preemptionPolicy:Never pods ahead of eligible preemptors
-            # (the window is >= the preemption budget, so every pod the
-            # PostFilter would consider gets real gate rows); other
-            # unplaced pods follow and get attribution on a best-effort
-            # basis — beyond the window: empty gate rows and zero dyn
-            # attribution, retried next cycle. The latency program skips
-            # all of it (the diagnosis program owns attribution there).
+            # Final-state work (dynamic reject attribution) only matters
+            # for pods that never placed — computed on COMPACTED views
+            # of them, window after window in rank order, instead of a
+            # full [P, N] dyn pass: the cost follows the number of
+            # unplaced pods, not P, and none is left without its dynamic
+            # counts however many there are (a refusal without them
+            # reads as if nodes were left open). The latency program
+            # skips all of it (the diagnosis program owns attribution
+            # there, the same way).
             if lean:
                 dyn_aux = jnp.zeros(
                     (snap.P, len(fw.filters)), jnp.int32
                 )
             else:
                 unplaced = snap.pod_valid & (rres.assignment < 0)
+                n_un = jnp.sum(unplaced, dtype=jnp.int32)
                 B_attr = rounds_ops.compact_window(snap.P)
-                rank32 = snap.pod_order.astype(jnp.int32)
-                ucan = unplaced & snap.pod_can_preempt
-                ukey = jnp.where(
-                    ucan, rank32,
-                    jnp.where(unplaced, rank32 + jnp.int32(1 << 24),
-                              jnp.int32(2**31 - 1)),
-                )
-                ugid = jnp.argsort(ukey)[:B_attr].astype(jnp.int32)
-                uact = unplaced[ugid]
-                uvsnap = rounds_ops._pod_view(snap, ugid)
-                uvmp = ctx.matched_pending[:, ugid]
-                uvsmask = smask[ugid]
-                _um, _us, upf = dyn_batched_view_fn(
-                    uvsnap, uvmp, rres.node_requested, rres.extra, uvsmask
-                )
-                urejects = fw.attribute_rejects(uvsmask, upf, rows=uact)
-                dyn_aux = (
-                    jnp.zeros((snap.P, len(fw.filters)), jnp.int32)
-                    .at[ugid]
-                    .add(jnp.where(uact[:, None], urejects, 0))
+                uorder = jnp.argsort(jnp.where(
+                    unplaced, snap.pod_order.astype(jnp.int32),
+                    jnp.int32(2**31 - 1),
+                )).astype(jnp.int32)
+
+                def attr_body(carry):
+                    aux, w = carry
+                    start = jnp.minimum(w * B_attr, snap.P - B_attr)
+                    ugid = jax.lax.dynamic_slice(
+                        uorder, (start,), (B_attr,)
+                    )
+                    uact = unplaced[ugid]
+                    uvsmask = smask[ugid]
+                    _um, _us, upf = dyn_batched_view_fn(
+                        rounds_ops._pod_view(snap, ugid),
+                        ctx.matched_pending[:, ugid],
+                        rres.node_requested, rres.extra, uvsmask,
+                    )
+                    urejects = fw.attribute_rejects(
+                        uvsmask, upf, rows=uact
+                    )
+                    # the last window is clamped and may overlap the
+                    # one before: a pod's counts are the same in both
+                    return aux.at[ugid].max(
+                        jnp.where(uact[:, None], urejects, 0)
+                    ), w + 1
+
+                dyn_aux, _ = jax.lax.while_loop(
+                    lambda c: c[1] * B_attr < n_un, attr_body,
+                    (jnp.zeros((snap.P, len(fw.filters)), jnp.int32),
+                     jnp.int32(0)),
                 )
             result = commit_ops.CommitResult(
                 assignment=rres.assignment,
@@ -691,6 +708,7 @@ def _make_cycle_body(
             rounds_used = rres.rounds_used
             accepted_per_round = rres.accepted_per_round
             diag_per_round = rres.diag_per_round
+            rounds_parked = rres.parked
         else:
             def dyn_fn(p, node_req, ext, static_row):
                 out = fw.dyn(ctx, p, node_req, ext, static_row)
@@ -702,7 +720,7 @@ def _make_cycle_body(
             def update_fn(ext, p, node, ok):
                 return fw.extra_update(ctx, ext, p, node, ok)
 
-            rounds_used = jnp.int32(0)
+            rounds_used = rounds_parked = jnp.int32(0)
             accepted_per_round = jnp.zeros((max_rounds,), jnp.int32)
             diag_per_round = jnp.zeros((max_rounds, 3), jnp.int32)
             order = jnp.argsort(snap.pod_order)
@@ -737,6 +755,7 @@ def _make_cycle_body(
                 snap, ctx, result.extra, result.assignment, dropped
             ),
             rounds_used, accepted_per_round, diag_per_round,
+            rounds_parked,
         ), snap, sample, narrowed)
 
     return cycle
@@ -797,7 +816,7 @@ def build_cycle_fn(
         disc=(
             f"{commit_mode}|{gang_scheduling}|{max_rounds}|"
             f"{percentage_of_nodes_to_score}"
-            f"{_sample_mark(percentage_of_nodes_to_score)}|{outputs}|"
+            f"{_engine_marks(percentage_of_nodes_to_score)}|{outputs}|"
             f"{sorted((rounds_kw or {}).items())!r}|{_fw_disc(fw)}"
         ),
     )
@@ -826,7 +845,7 @@ def build_packed_cycle_fn(spec, **kw):
         packed, "packed_cycle",
         disc=(
             repr(spec.key()) + repr(sorted(scalars.items()))
-            + _sample_mark(kw.get("percentage_of_nodes_to_score", 0))
+            + _engine_marks(kw.get("percentage_of_nodes_to_score", 0))
             + _fw_disc(kw.get("framework"))
         ),
     )
@@ -880,7 +899,7 @@ def build_arena_cycle_fn(spec, **kw):
         arena, "arena_cycle",
         disc=(
             repr(spec.key()) + repr(sorted(scalars.items()))
-            + _sample_mark(kw.get("percentage_of_nodes_to_score", 0))
+            + _engine_marks(kw.get("percentage_of_nodes_to_score", 0))
             + _fw_disc(kw.get("framework"))
         ),
     )
@@ -1065,7 +1084,7 @@ def build_packed_multicycle_fn(
         disc=(
             f"k{k}|{commit_mode}|{gang_scheduling}|{max_rounds}|"
             f"{percentage_of_nodes_to_score}"
-            f"{_sample_mark(percentage_of_nodes_to_score)}|"
+            f"{_engine_marks(percentage_of_nodes_to_score)}|"
             f"{sorted((rounds_kw or {}).items())!r}|carry{int(carry_in)}|"
             + repr(spec.key()) + _fw_disc(fw)
         ),
@@ -1409,6 +1428,9 @@ def build_packed_cycle_carry_fn(
             pv_choice_fn=_make_pv_choice_fn(ctx),
             mesh=mesh,
             sample=sample,
+            closed_for_cycle_fn=lambda vs, vmp, vsm, pf: (
+                fw.closed_for_cycle(view_ctx(vs, vmp), vsm, pf)
+            ),
             **(rounds_kw or {}),
         )
         result = commit_ops.CommitResult(
@@ -1428,13 +1450,14 @@ def build_packed_cycle_carry_fn(
                 snap, ctx, rres.extra, result.assignment, dropped
             ),
             rres.rounds_used, rres.accepted_per_round, rres.diag_per_round,
+            rres.parked,
         ), snap, sample, rres.sample_narrowed)
 
     return _jit(
         cycle, "carry_cycle",
         disc=(
             f"{gang_scheduling}|{percentage_of_nodes_to_score}"
-            f"{_sample_mark(percentage_of_nodes_to_score)}|"
+            f"{_engine_marks(percentage_of_nodes_to_score)}|"
             f"{max_rounds}|ext{int(extender_args)}|"
             f"{sorted((rounds_kw or {}).items())!r}|"
             f"mesh{_mesh_desc(mesh)}|"

@@ -69,6 +69,7 @@ TRACE_LANE_FOR_PHASE = {
     "decision_fetch": (LANE_HOST, "decision_wait"),
     "bind": (LANE_HOST, "bind winners"),
     "postfilter": (LANE_HOST, "postfilter"),
+    "losers": (LANE_HOST, "losers"),
     "device": (LANE_DEVICE, "device cycle[seq]"),
     "diag_lag": (LANE_DIAG, "diag lag[seq]"),
     # multi-cycle batched decomposition: an inner cycle's host-side
@@ -535,8 +536,14 @@ def to_chrome_trace(
                 )
             )
         if t_post is not None:
+            # to the mark the loser loop stamps when it had a loser,
+            # else to the record's end
             events.append(
-                _slice("losers", LANE_HOST, t_post, rec.t_end, epoch)
+                _slice(
+                    TRACE_LANE_FOR_PHASE["losers"][1],
+                    TRACE_LANE_FOR_PHASE["losers"][0],
+                    t_post, m.get("losers_end", rec.t_end), epoch,
+                )
             )
 
         # device lane: dispatched program in flight until the slimmed

@@ -8,8 +8,9 @@ rebuilt TPU-natively on top of the recorder:
 
 - **Phase attribution** (`phase_seconds`): every committed CycleRecord
   is decomposed into the named phase windows in `PHASES` (encode, fold,
-  dispatch, device, decision_fetch, bind, postfilter, diag_lag, compile,
-  total) and fed into fixed-bucket streaming histograms, exported as the
+  dispatch, device, decision_fetch, bind, postfilter, losers, diag_lag,
+  compile, total) and fed into fixed-bucket streaming histograms,
+  exported as the
   `scheduler_cycle_phase_seconds{phase=...}` histogram family plus
   per-phase p50/p99 gauges evaluated at scrape time. The windows are
   measurement lenses, not a strict partition: `device` (dispatch return
@@ -78,6 +79,8 @@ PHASES = (
     "decision_fetch", # the ONE blocking device->host wait
     "bind",           # winner bind loop
     "postfilter",     # preemption force between winners and losers
+    "losers",         # loser loop: diagnosis fetch, messages, events,
+    # parks and their journal records (only in a cycle with a loser)
     "diag_lag",       # deferred FailedScheduling attribution lag
     "compile",        # packed-program (re)build on a regime flip
     # multi-cycle batched decomposition (core/scheduler.py
@@ -182,6 +185,9 @@ def phase_seconds(rec) -> dict[str, float]:
     p1 = m.get("postfilter_end")
     if a1 is not None and p1 is not None and p1 >= a1:
         out["postfilter"] = p1 - a1
+    l1 = m.get("losers_end")
+    if p1 is not None and l1 is not None and l1 >= p1:
+        out["losers"] = l1 - p1
     if "diag_lag_ms" in ph:
         out["diag_lag"] = ph["diag_lag_ms"] / 1e3
     if "compile_ms" in ph:

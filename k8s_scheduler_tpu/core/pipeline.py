@@ -240,6 +240,12 @@ class CycleHandle:
             (result.sample_k, result.sample_narrowed_pods)
             if hasattr(result, "sample_k") else None
         )
+        # ... and a program that reports its commit rounds (a
+        # CycleResult; the latency subset carries none) two more
+        self._rounds = (
+            (result.rounds_used, result.rounds_parked)
+            if hasattr(result, "rounds_parked") else None
+        )
         self._wbuf = wbuf
         self._bbuf = bbuf
         self._stable = stable
@@ -260,8 +266,10 @@ class CycleHandle:
             t0 = now()
             self._pipe.stats["t_decision_start"] = t0
             try:
-                (a, flags), sample = self._pipe.fetch_decisions(
-                    lambda: jax.device_get((self._slim, self._sample))
+                (a, flags), sample, rounds = self._pipe.fetch_decisions(
+                    lambda: jax.device_get(
+                        (self._slim, self._sample, self._rounds)
+                    )
                 )
             except Exception as e:
                 # a failed fetch consumes the cycle: no bind can come of
@@ -288,6 +296,10 @@ class CycleHandle:
                 st["fetch_bytes"] += sum(int(v.nbytes) for v in sample)
                 st["sample_k"] = int(sample[0])
                 st["sample_narrowed_pods"] = int(sample[1])
+            if rounds is not None:
+                st["fetch_bytes"] += sum(int(v.nbytes) for v in rounds)
+                st["commit_rounds"] = int(rounds[0])
+                st["rounds_parked"] = int(rounds[1])
             # what the un-slimmed fetch of the same fields would move
             st["fetch_bytes_full"] = int(a.shape[0] * (4 + 1 + 1))
             self._pipe._fetch_bytes_total += st["fetch_bytes"]

@@ -404,6 +404,12 @@ class Scheduler:
         # per profile, the encoder's fold_fallback_pods as of the last
         # committed flight record (_commit_record stamps the difference)
         self._fold_fallback_seen: dict[str, int] = {}
+        # running totals every flight record carries (_commit_record):
+        # the commit rounds the cycle programs used, the pods those
+        # rounds parked (ops/rounds.py) and the pods refused
+        self._round_totals = {
+            "commit_rounds": 0, "rounds_parked": 0, "refusals": 0,
+        }
         if self.extenders:
             # extender verdicts are consulted per HOST cycle; inner
             # device cycles cannot re-consult a webhook, so batching is
@@ -2732,6 +2738,16 @@ class Scheduler:
             seen["sample_narrowed_pods"] = (
                 seen.get("sample_narrowed_pods", 0) + narrowed
             )
+        tot = self._round_totals
+        if "commit_rounds" in st:
+            # fetched with the decisions too: the rounds this cycle's
+            # program ran and the pods it parked as refused for the
+            # cycle. The records keep running totals, as `full_encodes`
+            tot["commit_rounds"] += st["commit_rounds"]
+            tot["rounds_parked"] += st["rounds_parked"]
+            self.metrics.commit_rounds.inc(st["commit_rounds"])
+            self.metrics.rounds_parked_pods.inc(st["rounds_parked"])
+        tot["refusals"] += stats.unschedulable - before[1]
         # rows the existing-set fold built in Python since the last
         # record: bound pods the native row writer does not cover
         # (volumes / nodeAffinity), folded per pod instead of sending
@@ -2763,6 +2779,7 @@ class Scheduler:
             delta_hits=int(encoder.delta_hits),
             fold_hits=int(encoder.fold_hits),
             fold_fallback_pods=fold_fallback,
+            **tot,
             # admission-time incremental encode: dirty slots whose
             # flush-time parse was skipped (a staged ingest row was
             # waiting) — the bench's encode_hidden evidence
@@ -3159,16 +3176,34 @@ class Scheduler:
             t_winners = self._now()
             if rec is not None:
                 rec.mark("winners_end", fr.now())
+            # under a `Cycle` RPC's trace a cycle that has a loser
+            # stamps the wait for the preemption program and the loser
+            # loop as two spans (one each per cycle, never per pod)
+            trace = self._cycle_trace if len(lose_idx) else None
+            t_sp_winners = _spans.now() if trace is not None else 0.0
             nominated, victims = force_pre()
             t_post = self._now()
             if rec is not None:
                 rec.mark("postfilter_end", fr.now())
+            if trace is not None:
+                t_sp_post = _spans.now()
+                _spans.record_span(
+                    "cycle.postfilter", trace, t_sp_winners, t_sp_post,
+                    losers=len(lose_idx),
+                    nominated=(
+                        int((nominated >= 0).sum())
+                        if nominated is not None else 0
+                    ),
+                    victims=(
+                        int(victims.sum()) if victims is not None else 0
+                    ),
+                )
             self.metrics.cycle_duration.labels(
                 phase="postfilter"
             ).observe(t_post - t_winners)
 
             rej_mat = None
-            n_unsched = 0
+            n_unsched = n_diagnosed = 0
             reason_incs: dict[str, int] = {}
             for i in lose_idx:
                 i = int(i)
@@ -3212,6 +3247,7 @@ class Scheduler:
                     message = failed_scheduling_message(
                         len(nodes), per_plugin
                     )
+                    n_diagnosed += 1
                 for r in reasons:
                     reason_incs[r] = reason_incs.get(r, 0) + 1
                 _pev(
@@ -3232,6 +3268,17 @@ class Scheduler:
                 self.metrics.observe_attempts(
                     "unschedulable", per_pod_s(), profile, n_unsched
                 )
+            if len(lose_idx):
+                # the last loser is requeued: the diagnosis fetch, the
+                # messages, the events, the parks and their journal
+                # records lie between `postfilter_end` and here
+                if rec is not None:
+                    rec.mark("losers_end", fr.now())
+                if trace is not None:
+                    _spans.record_span(
+                        "cycle.losers", trace, t_sp_post, _spans.now(),
+                        losers=len(lose_idx), diagnosed=n_diagnosed,
+                    )
 
             if victims is not None and victims.any():
                 # victims belong to the preemptor nominated onto their

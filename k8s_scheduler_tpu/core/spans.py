@@ -96,6 +96,12 @@ from typing import Any, Callable, Iterable
 #                   cycle.pop (schedule_cycle entry -> the first
 #                   profile's record starts), cycle.snapshot (the
 #                   journal compaction, only when one ran),
+#                   cycle.postfilter (winners bound -> the preemption
+#                   program's output in hand: the host's wait for
+#                   `packed_preempt`) and cycle.losers (from there to
+#                   the last loser requeued: the diagnosis fetch, the
+#                   messages, the events, the parks and their journal
+#                   records), both only in a cycle that refused a pod,
 #                   cycle.respond (schedule_cycle returned -> response
 #                   built)
 #   whichever thread the collector runs on (core/collector.py; a trace
@@ -125,6 +131,8 @@ SPAN_NAMES = (
     "cycle.lock_wait",
     "cycle.pop",
     "cycle.snapshot",
+    "cycle.postfilter",
+    "cycle.losers",
     "cycle.respond",
     "gc.pass",
 )

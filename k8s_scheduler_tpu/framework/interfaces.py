@@ -129,6 +129,17 @@ class PluginBase:
         counts-by-node table) so co-enabled plugins don't recompute them."""
         return None
 
+    def dyn_mask_reopens(self, ctx: CycleContext) -> jnp.ndarray | None:
+        """bool [P]: the pods for which a placement made LATER IN THE
+        SAME CYCLE can open a node this plugin's dynamic mask closed, or
+        None where it can for none. Within a cycle placements only
+        consume room and add pods, so a dynamic mask that counts
+        capacity, ports, claimed volumes or anti-affinity only ever
+        closes (the default). The rounds engine parks a pod whose every
+        node is closed by masks that cannot reopen for it
+        (Framework.closed_for_cycle)."""
+        return None
+
     def dyn_score_batched(self, ctx: CycleContext, node_requested, extra,
                           feasible, shared: dict) -> jnp.ndarray | None:
         """`feasible` is the full [P, N] feasibility (static & dynamic)

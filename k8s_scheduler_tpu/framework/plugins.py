@@ -13,6 +13,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
+from ..models import encoding as enc
 from ..ops import images as images_ops
 from ..ops import interpod as interpod_ops
 from ..ops import labels as labels_ops
@@ -407,6 +408,13 @@ class InterPodAffinity(PluginBase):
             ctx.snap, state, ctx.matched_pending, cbn
         )
 
+    def dyn_mask_reopens(self, ctx: CycleContext):
+        # a required affinity term is met once a matching peer is placed
+        # in the domain; anti-affinity, both ways, only ever closes
+        if not ctx.snap.has_inter_pod_affinity:
+            return None
+        return jnp.any(ctx.snap.pod_aff_terms[..., 0] >= 0, axis=1)
+
     def dyn_score_batched(self, ctx: CycleContext, node_requested, extra,
                           feasible, shared):
         if not ctx.snap.has_inter_pod_affinity:
@@ -489,6 +497,17 @@ class PodTopologySpread(PluginBase):
             shared["spread_minc"] = interpod_ops.spread_minc(ctx.snap, state)
         return interpod_ops.spread_mask_batched(
             ctx.snap, state, cbn, shared["spread_minc"]
+        )
+
+    def dyn_mask_reopens(self, ctx: CycleContext):
+        # a DoNotSchedule constraint lets a domain in again once the
+        # minimum over the domains has risen
+        if not ctx.snap.has_topology_spread:
+            return None
+        tsc = ctx.snap.pod_tsc
+        return jnp.any(
+            (tsc[..., 0] >= 0) & (tsc[..., 2] == enc.WHEN_DO_NOT_SCHEDULE),
+            axis=1,
         )
 
     def dyn_score_batched(self, ctx: CycleContext, node_requested, extra,

@@ -220,6 +220,26 @@ class Framework:
                 score = score + w * v
         return mask, score, per_filter
 
+    def closed_for_cycle(self, ctx: CycleContext, static_mask,
+                         per_filter):
+        """bool [P]: the pods that no placement made later in this cycle
+        can give a node, from one round's masks (`per_filter` as
+        `dyn_batched` returns it). A filter's mask counts as open for
+        the pods it can reopen for (PluginBase.dyn_mask_reopens:
+        required affinity, DoNotSchedule spread) and as it stands for
+        every other pod; static masks never move within a cycle. A pod
+        with no node left under that reading is refused whatever else
+        the cycle places, so the rounds engine parks it."""
+        open_ = static_mask
+        for f, m in zip(self.filters, per_filter):
+            if m is None:
+                continue
+            reopens = f.dyn_mask_reopens(ctx)
+            open_ = open_ & (
+                m if reopens is None else m | reopens[:, None]
+            )
+        return ~jnp.any(open_, axis=1)
+
     def attribute_rejects(self, base_mask, per_filter, rows=None):
         """First-rejector attribution over a filter-mask chain: returns
         i32 [P, F] where column i counts the nodes newly rejected by

@@ -56,7 +56,7 @@ streaming consumer of every flight record):
 - scheduler_cycle_phase_seconds{phase} — streaming per-phase latency
   attribution of every committed cycle record; phases: total, encode,
   fold, encode_ingest, encode_finalize, dispatch, device,
-  decision_fetch, bind, postfilter, diag_lag,
+  decision_fetch, bind, postfilter, losers, diag_lag,
   compile, batch_wait, device_share, first_bind, submit_bind
   (encode_ingest / encode_finalize are the admission-time incremental
   encode split: the per-group ingest cost paid in the ack path's
@@ -110,6 +110,13 @@ round trip):
   native row writer does not cover them (volumes / nodeAffinity): the
   per-pod fallback that keeps such a pod from costing a full encode
   (counted as each cycle's flight record commits)
+- scheduler_commit_rounds_total — commit rounds the cycle programs ran
+  (ops/rounds.py `rounds_used`, fetched with the decisions; a program
+  that returns only the latency subset reports none)
+- scheduler_rounds_parked_pods_total — pods the commit rounds parked:
+  no placement later in the cycle could have given them a node, so
+  they were refused for the cycle the round that judged them and kept
+  out of the rounds' compacted window
 
 Multi-chip serving families (shardDevices + parallel/audit.py — the
 sharded carry path with shard-invariant tie-breaking):
@@ -191,7 +198,8 @@ cmd/main.py startup stamp):
   apply.fold | bind.confirm | preempt.victim) and the agent path's
   RPCs, per RPC and per phase (rpc.update | update.convert |
   update.apply | rpc.cycle | cycle.lock_wait | cycle.pop |
-  cycle.snapshot | cycle.respond) and the collector's passes
+  cycle.snapshot | cycle.postfilter | cycle.losers | cycle.respond)
+  and the collector's passes
   (gc.pass); the inventory is
   core/spans.SPAN_NAMES, machine-checked by schedlint ID010 against
   this docstring and the README span table; spans serve at
@@ -393,6 +401,19 @@ class SchedulerMetrics:
             "fetch (slimmed payload: i16 assignment + u8 flags per pod).",
             registry=r,
         )
+        self.commit_rounds = Counter(
+            "scheduler_commit_rounds_total",
+            "Commit rounds the cycle programs ran (rounds_used, fetched "
+            "with the decisions).",
+            registry=r,
+        )
+        self.rounds_parked_pods = Counter(
+            "scheduler_rounds_parked_pods_total",
+            "Pods the commit rounds parked: refused for the cycle the "
+            "round that judged them, because no later placement could "
+            "have given them a node.",
+            registry=r,
+        )
         self.fold_fallback_pods = Counter(
             "scheduler_encode_fold_fallback_pods_total",
             "Newly bound pods whose existing-set row the incremental "
@@ -453,7 +474,8 @@ class SchedulerMetrics:
             "scheduler_cycle_phase_seconds",
             "Per-phase latency attribution of every committed cycle "
             "record (phases: total, encode, fold, dispatch, device, "
-            "decision_fetch, bind, postfilter, diag_lag, compile).",
+            "decision_fetch, bind, postfilter, losers, diag_lag, "
+            "compile).",
             ["phase"],
             buckets=phase_buckets,
             registry=r,

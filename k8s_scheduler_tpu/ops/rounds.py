@@ -50,7 +50,9 @@ lowest-rank `P/compact` still-active pods, re-gathered each round — since
 round 1 typically places the large majority, and [B, N] work shrinks
 proportionally. The compacted view is a real ClusterSnapshot whose
 pod-axis arrays are gathered at the active ids, so every plugin kernel
-runs unchanged.
+runs unchanged. A pod that no acceptance of this cycle can give a node
+is PARKED the round it is judged and never holds a row of that view
+again (see `rounds_commit`).
 
 Semantics contract (documented deviation from the strict scan):
   - Every accepted placement satisfies every filter against the state at
@@ -104,6 +106,12 @@ _PR1 = np.uint32(2654435761)
 _PR2 = np.uint32(40503)
 _BIG = np.int32(2**31 - 1)
 
+# In the name of every program that embeds this engine (core/cycle.py
+# appends it to the name's discriminator): the executable store keys on
+# names and call conventions, not on code, and an entry built by an
+# engine that did not park (see rounds_commit) must never load.
+ENGINE_MARK = ":parks"
+
 # participant role bits (packed into one sort operand)
 _RB_MATCH = 1
 _RB_ANTI = 2
@@ -125,6 +133,8 @@ class RoundsResult:
     diag_per_round: jnp.ndarray  # i32 [max_rounds, 3] summed over passes:
     # (live claims, capacity rejections, guard rejections) — convergence
     # diagnostics, negligible cost
+    parked: jnp.ndarray  # i32 [] pods parked for the rest of the cycle
+    # (see rounds_commit)
     sample_narrowed: jnp.ndarray | None = None  # i32 [] pods whose
     # feasible nodes outnumbered the sample's k in round 1 (None: the
     # cycle does not sample)
@@ -226,6 +236,10 @@ def rounds_commit(
     dyn_batched_view_fn: Callable,  # (vsnap, vmp, node_req, ext, vsmask)
     #   -> (mask [B,N], score [B,N], per_filter)
     update_batched_view_fn: Callable,  # (vsnap, vmp, ext, accepted, node_of)
+    closed_for_cycle_fn: Callable,  # (vsnap, vmp, vsmask, per_filter)
+    #   -> bool [B]: pods no acceptance of this cycle can give a node
+    #   (Framework.closed_for_cycle), from the per-filter masks the
+    #   round computed anyway
     extra: Any,
     max_rounds: int = 64,
     compact: int = 8,
@@ -280,6 +294,31 @@ def rounds_commit(
     # "unplaced => infeasible against the final state" invariant holds
     # as without sampling.
 ) -> RoundsResult:
+    """Commit the pending set in rounds (the module docstring has the
+    round itself). What the loop over rounds guarantees:
+
+    **At the end of a cycle every unplaced valid pod has had a full-mask
+    check against a state no later acceptance changed, or is parked by a
+    reason no acceptance can lift; `max_rounds` is reached only where
+    acceptances themselves keep coming.**
+
+    Within one cycle acceptances only consume room and add pods. A node
+    closed to a pod by a static filter, by NodeResourcesFit, NodePorts,
+    a claimed PV or required anti-affinity therefore stays closed; only
+    required affinity (a peer arrives) and DoNotSchedule spread (the
+    minimum rises) can OPEN one. `closed_for_cycle_fn` says, from the
+    masks of the round that judged it, whether a pod has no node left
+    once the filters that can reopen FOR THAT POD are taken as open. Such
+    a pod is PARKED: it leaves `active` for the rest of the cycle, so it
+    never fills a row of the compacted window, never counts towards the
+    sweep below and is returned unplaced. Every other active pod is
+    judged as before. Without parking, about B pods that fit nowhere,
+    ranked before the feasible leftovers of round 1, held the window:
+    a round accepted a handful or the cap ended the cycle, and pods that
+    fit hundreds of nodes were refused (PERF.md section 6, PR 36).
+
+    On inputs where no pod parks, the window, the scores, the tie-break,
+    the guards and every placement are bit for bit what they were."""
     P, N = (sbase if sbase is not None else static_mask).shape
     S = m_pending.shape[0]
     D = snap.domain_key.shape[0]
@@ -590,8 +629,13 @@ def rounds_commit(
             vovf = overflow_g[gid]
         vsmask = vsbase > NEG_INF * 0.5
 
-        mask, score, _pf = dyn_batched_view_fn(
+        mask, score, per_filter = dyn_batched_view_fn(
             vsnap, vmp, node_req, ext, vsmask
+        )
+        # judged on the round-start state, before the sample narrows the
+        # mask: what is closed for the cycle is closed on every node
+        parked = act_v & vsnap.pod_valid & closed_for_cycle_fn(
+            vsnap, vmp, vsmask, per_filter
         )
         mask = mask & vsmask & act_v[:, None]
         narrowed = None
@@ -892,16 +936,16 @@ def rounds_commit(
         ext = local_update_fn(update_batched_view_fn)(
             vsnap, vmp, ext, acc, jnp.where(acc, acc_node, 0)
         )
-        return acc, acc_node, node_req, ext, diag, narrowed
+        return acc, acc_node, node_req, ext, diag, narrowed, parked
 
     # ---- round 1: full pending set ----
     gid0 = jnp.arange(P, dtype=jnp.int32)
-    acc0, node0, node_req, extra, diag0, narrowed0 = one_round(
+    acc0, node0, node_req, extra, diag0, narrowed0, parked0 = one_round(
         gid0, snap.pod_valid, snap.node_requested, extra, passes_round0,
         identity_gid=True,
     )
     placed = jnp.where(acc0, node0, -1)
-    active = snap.pod_valid & ~acc0
+    active = snap.pod_valid & ~acc0 & ~parked0
     acc_hist = jnp.zeros((max_rounds,), jnp.int32).at[0].set(
         jnp.sum(acc0, dtype=jnp.int32)
     )
@@ -920,42 +964,48 @@ def rounds_commit(
     # every active pod a genuine full-mask check against what is then
     # the final state — the validity invariant "unplaced => infeasible"
     # holds exactly. Any acceptance resets the sweep to the lowest
-    # ranks.
+    # ranks. A pod parked in a round leaves `active` with its verdict
+    # final (see the docstring), so the sweep steps on by the rows that
+    # STAY active: the pods ranked after the window move up by as many
+    # places as it parked, and none of them is passed over.
     B = compact_window(P, compact)
 
     def body(carry):
-        node_req, ext, placed, active, rnd, skip, hist, dhist = carry
+        (node_req, ext, placed, active, rnd, skip, hist, dhist,
+         n_parked) = carry
         key = jnp.where(active, rank_g, _BIG)
         order = jnp.argsort(key).astype(jnp.int32)
         start = jnp.minimum(skip, jnp.maximum(P - B, 0))
         gid = jax.lax.dynamic_slice(order, (start,), (B,))
         act_v = active[gid]
-        accepted, node_of, node_req, ext, diag, _ = one_round(
+        accepted, node_of, node_req, ext, diag, _, parked = one_round(
             gid, act_v, node_req, ext, passes
         )
         placed = placed.at[gid].set(jnp.where(accepted, node_of, placed[gid]))
-        active = active.at[gid].set(act_v & ~accepted)
+        active = active.at[gid].set(act_v & ~accepted & ~parked)
         n_acc = jnp.sum(accepted, dtype=jnp.int32)
+        n_out = jnp.sum(parked, dtype=jnp.int32)
         hist = hist.at[jnp.minimum(rnd, max_rounds - 1)].set(n_acc)
         dhist = dhist.at[jnp.minimum(rnd, max_rounds - 1)].set(diag)
-        skip = jnp.where(n_acc > 0, jnp.int32(0), skip + jnp.int32(B))
+        skip = jnp.where(
+            n_acc > 0, jnp.int32(0), skip + jnp.int32(B) - n_out
+        )
         return (node_req, ext, placed, active, rnd + 1, skip, hist,
-                dhist)
+                dhist, n_parked + n_out)
 
     def cond(carry):
-        _, _, _, active, rnd, skip, _, _ = carry
+        _, _, _, active, rnd, skip, _, _, _ = carry
         n_act = jnp.sum(active, dtype=jnp.int32)
         return (skip < n_act) & (rnd < max_rounds)
 
     # round 0 was full-width: if it accepted nothing, every pod already
     # had its full-mask check and the sweep is complete (skip = P)
     skip0 = jnp.where(jnp.any(acc0), jnp.int32(0), jnp.int32(P))
-    node_req, extra, placed, active, rounds_used, _, acc_hist, diag_hist = (
-        jax.lax.while_loop(
-            cond, body,
-            (node_req, extra, placed, active, jnp.int32(1), skip0,
-             acc_hist, diag_hist),
-        )
+    (node_req, extra, placed, active, rounds_used, _, acc_hist, diag_hist,
+     n_parked) = jax.lax.while_loop(
+        cond, body,
+        (node_req, extra, placed, active, jnp.int32(1), skip0,
+         acc_hist, diag_hist, jnp.sum(parked0, dtype=jnp.int32)),
     )
 
     return RoundsResult(
@@ -965,6 +1015,7 @@ def rounds_commit(
         rounds_used=rounds_used,
         accepted_per_round=acc_hist,
         diag_per_round=diag_hist,
+        parked=n_parked,
         # round 1 judged every valid pod; an invalid row's mask is empty
         sample_narrowed=(
             None if narrowed0 is None

@@ -985,7 +985,6 @@ def validate_rounds_assignment(
     pending: Sequence[Pod],
     assignment: Sequence[int],
     existing: Sequence[tuple[Pod, str]] = (),
-    round_cap_hit: bool = False,
     allow_feasible_unplaced: Sequence[int] = (),
     pvcs: Sequence = (),
     pvs: Sequence = (),
@@ -1003,9 +1002,11 @@ def validate_rounds_assignment(
       - required affinity with the bootstrap allowance (a pod matching its
         own selector may stand alone);
       - DoNotSchedule spread as final skew <= maxSkew.
-    Unplaced pods must be infeasible against the final state, unless the
-    round cap was hit or they are listed in `allow_feasible_unplaced`
-    (gang-dropped pods). Returns human-readable violations."""
+    Unplaced pods must be infeasible against the final state, unless
+    they are listed in `allow_feasible_unplaced` (gang-dropped pods).
+    There is no excuse for a round cap: the engine parks what no
+    acceptance can help, so the cap is reached only while acceptances
+    keep coming (PR 36). Returns human-readable violations."""
     final = OracleState.build(nodes, existing, pvcs, pvs, storage_classes)
     placed: list[tuple[Pod, int]] = []
     # placed pods enter in QUEUE ORDER so their static-PV claims fold
@@ -1102,17 +1103,16 @@ def validate_rounds_assignment(
                     f"{node.name}"
                 )
 
-    if not round_cap_hit:
-        allowed = set(allow_feasible_unplaced)
-        for pi, pod in enumerate(pending):
-            if assignment[pi] >= 0 or pi in allowed:
-                continue
-            feas = feasible_nodes(pod, final, DEFAULT_FILTERS)
-            if feas:
-                errors.append(
-                    f"{pod.name}: unplaced but feasible on {feas[:5]} "
-                    f"in the final state"
-                )
+    allowed = set(allow_feasible_unplaced)
+    for pi, pod in enumerate(pending):
+        if assignment[pi] >= 0 or pi in allowed:
+            continue
+        feas = feasible_nodes(pod, final, DEFAULT_FILTERS)
+        if feas:
+            errors.append(
+                f"{pod.name}: unplaced but feasible on {feas[:5]} "
+                f"in the final state"
+            )
     return errors
 
 

@@ -16,6 +16,7 @@ import pytest
 from k8s_scheduler_tpu.config import SchedulerConfiguration
 from k8s_scheduler_tpu.core import pipeline as _pipeline
 from k8s_scheduler_tpu.core import spans as _spans
+from k8s_scheduler_tpu.core.observe import phase_seconds
 from k8s_scheduler_tpu.core.spans import (
     AGENT_LANE_PID,
     AGENT_LANE_TID,
@@ -35,6 +36,8 @@ PACKAGE = os.path.join(REPO, "k8s_scheduler_tpu")
 
 UPDATE_SPANS = {"rpc.update", "update.convert", "update.apply"}
 CYCLE_SPANS = {"rpc.cycle", "cycle.lock_wait", "cycle.pop", "cycle.respond"}
+# only in a cycle that refuses a pod
+LOSER_SPANS = {"cycle.postfilter", "cycle.losers"}
 
 
 class Metadata:
@@ -203,6 +206,57 @@ def test_cycle_snapshot_appears_only_in_a_cycle_that_compacted(
     st.seal()
 
 
+def test_the_loser_loop_is_two_spans_a_mark_and_three_running_counts(
+        armed):
+    """A cycle that refuses a pod stamps the wait for the preemption
+    program and the loser loop, children of `rpc.cycle`, in that order
+    between the last bind and the response; a cycle that refuses none
+    stamps neither. Every flight record carries the running totals the
+    benchmark's `flight_count` reads, and `/metrics` the first two."""
+    svc = service()
+    req = cluster_request(n_nodes=3, n_pods=4)
+    for i in range(3):  # 9 CPU on 8-CPU nodes: no node, no victim helps
+        req.pod_adds.append(pb.PodEvent(pod=convert.pod_to(
+            MakePod(f"big{i}").req({"cpu": "9"}).obj())))
+    svc.Update(req, None)
+    resp = svc.Cycle(pb.CycleRequest(), None)
+    assert len(resp.bindings) == 4
+    spans = armed.snapshot()[3:]
+    assert {s.name for s in spans} == CYCLE_SPANS | LOSER_SPANS
+    root = assert_one_trace(spans, "rpc.cycle")
+    named = by_name(spans)
+    (post,), (losers,) = named["cycle.postfilter"], named["cycle.losers"]
+    assert post.attrs == {"losers": 3, "nominated": 0, "victims": 0}
+    assert losers.attrs == {"losers": 3, "diagnosed": 3}
+    assert post.t1 == losers.t0 <= losers.t1 <= named["cycle.respond"][0].t0
+    (rec,) = [r for r in svc.scheduler.flight.snapshot()
+              if r.seq in root.attrs["seqs"]]
+    marks = rec.marks
+    assert (marks["winners_end"] <= marks["postfilter_end"]
+            <= marks["losers_end"] <= rec.t_end)
+    assert phase_seconds(rec)["losers"] == (
+        marks["losers_end"] - marks["postfilter_end"])
+    first = {k: rec.counts[k] for k in (
+        "commit_rounds", "rounds_parked", "refusals")}
+    assert first["rounds_parked"] == first["refusals"] == 3
+    assert first["commit_rounds"] >= 1
+    text = svc.scheduler.metrics.expose()
+    assert b"scheduler_rounds_parked_pods_total 3.0" in text
+    assert (b"scheduler_commit_rounds_total %.1f"
+            % first["commit_rounds"]) in text
+    # a cycle with no loser: neither span, no mark, the totals stand
+    svc.Update(cluster_request(n_nodes=0, n_pods=2, tag="q"), None)
+    before = len(armed.snapshot())
+    assert len(svc.Cycle(pb.CycleRequest(), None).bindings) == 2
+    spans = armed.snapshot()[before:]
+    assert {s.name for s in spans} == CYCLE_SPANS
+    rec2 = svc.scheduler.flight.snapshot()[-1]
+    assert "losers_end" not in rec2.marks
+    assert "losers" not in phase_seconds(rec2)
+    assert rec2.counts["refusals"] == rec2.counts["rounds_parked"] == 3
+    assert rec2.counts["commit_rounds"] > first["commit_rounds"]
+
+
 def test_unarmed_no_span_and_no_annotation_object(monkeypatch):
     made = []
     real = jax.profiler.TraceAnnotation
@@ -352,15 +406,15 @@ def test_agent_rpcs_render_on_one_lane(armed):
     assert pod_ev["pid"] == _spans.TRACE_TRACK_PID
 
 
-def test_span_inventory_has_twenty_one_names():
-    assert len(SPAN_NAMES) == len(set(SPAN_NAMES)) == 21
+def test_span_inventory_has_twenty_three_names():
+    assert len(SPAN_NAMES) == len(set(SPAN_NAMES)) == 23
     # the collector's passes (core/collector.py) share the agent's lane
-    assert AGENT_SPAN_NAMES == UPDATE_SPANS | CYCLE_SPANS | {
+    assert AGENT_SPAN_NAMES == UPDATE_SPANS | CYCLE_SPANS | LOSER_SPANS | {
         "cycle.snapshot", "gc.pass"}
 
 
 @pytest.mark.parametrize("name", sorted(
-    UPDATE_SPANS | CYCLE_SPANS | {"cycle.snapshot"}))
+    UPDATE_SPANS | CYCLE_SPANS | LOSER_SPANS | {"cycle.snapshot"}))
 def test_each_agent_span_is_stamped_at_one_site(name):
     sites = []
     for path in glob.glob(os.path.join(PACKAGE, "**", "*.py"),

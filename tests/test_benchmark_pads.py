@@ -2,12 +2,14 @@
 process started.
 
 - Per configuration under `benchmark/configs/`, the served YAML's
-  `padExisting` is the power of two above what the JSON's own `pad_rule`
-  inputs give, and holds `init_pods` + `depth` (ROADMAP S9 c; the
-  arithmetic of `benchmark/tests/test_cells.py`, which is run by hand).
-  Past the pad the encoder leaves the delta path and programs compile
-  inside the window: the run reads `existing_over_pad` > 0 and
-  `correct: false`.
+  `padExisting` is the power of two above the resident set the JSON
+  states: `resident_target` + `depth` + the probe pools' load pods (the
+  arithmetic of `benchmark/tests/test_cells.py`, which is run by hand;
+  the pad follows the resident set, not the rate, since PR 35). The
+  three rate inputs of the rule before that are read only where a
+  configuration still carries them, and must then stand at 0. Past the
+  pad the encoder leaves the delta path and programs compile inside the
+  window: the run reads `existing_over_pad` > 0 and `correct: false`.
 - Every span name a `program_span` layer file selects is in
   `core/spans.SPAN_NAMES`, and every phase a `flight_phase` layer file
   selects is in `core/observe.PHASES`: one case a name, so that a
@@ -41,18 +43,26 @@ BENCHMARK = load("BENCHMARK.json")
     "entry", BENCHMARK["configs"], ids=lambda e: e["name"])
 def test_the_existing_pad_is_what_the_configurations_rule_gives(entry):
     cfg = load(entry["file"])
-    rule = cfg["pad_rule"]
     holds = (
-        cfg["init_pods"]
-        + cfg["probe"]["pools"] * cfg["probe"]["nodes_per_pool"]
-        + cfg["depth"]
-        + rule["factor"] * rule["rate_ref_pods_per_s"]
-        * (BENCHMARK["run_seconds"] + rule["iteration_s"]))
+        cfg["resident_target"] + cfg["depth"]
+        + cfg["probe"]["pools"] * cfg["probe"]["nodes_per_pool"])
     served = load("benchmark", "configs", cfg["server_config"])
-    assert served["padExisting"] == 1 << int(holds).bit_length(), holds
-    assert served["padExisting"] >= cfg["init_pods"] + cfg["depth"]
-    # the rehearsal's cut server is the same program under a smaller pad
-    assert cfg["rehearse"]["server"]["padExisting"] < served["padExisting"]
+    assert served["padExisting"] == 1 << holds.bit_length(), holds
+    # the window opens under the target and the warm-up batch fills it
+    assert cfg["init_pods"] + cfg["depth"] <= cfg["resident_target"] + 64
+    # no rate is an input: a file that still names the old rule's three
+    # (until a benchmark PR deletes the keys) holds them at 0
+    rule = cfg["pad_rule"]
+    assert "resident_target + depth" in rule["holds"]
+    assert all(rule[k] == 0 for k in (
+        "rate_ref_pods_per_s", "factor", "iteration_s") if k in rule)
+    # the rehearsal's cut server is the same program under a smaller
+    # pad, which holds the cut resident set the same way
+    cut = cfg["rehearse"]
+    assert cut["server"]["padExisting"] < served["padExisting"]
+    assert cut["resident_target"] + cut["depth"] < (
+        cut["server"]["padExisting"])
+    assert cut["init_pods"] < cut["resident_target"]
 
 
 def selected(source_kind: str) -> list[str]:
@@ -77,3 +87,12 @@ def test_a_selected_span_is_one_the_program_stamps(name):
 @pytest.mark.parametrize("name", selected("flight_phase"))
 def test_a_selected_phase_is_one_the_flight_recorder_keeps(name):
     assert name in PHASES
+
+
+def test_the_loser_loop_is_a_phase_beside_its_span():
+    """`loser_loop_ms.*` reads the span (a mean: the median of a phase
+    that is 0 in every other cycle says nothing); the recorder's
+    histograms see the same window as the phase `losers`."""
+    assert {"cycle.postfilter", "cycle.losers"} <= set(
+        selected("program_span"))
+    assert "losers" in PHASES and "postfilter" in PHASES

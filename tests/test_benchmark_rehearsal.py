@@ -37,6 +37,14 @@ ALWAYS_READ = (
     "cycle_servicer_ms", "cycle_respond_ms", "encode_ms", "apply_ms",
     "device_wait_ms", "gc_pass_ms",
 )
+# ... and from the loser loop's two spans (PR 36): every cell's queue
+# holds pods that fit nowhere, and their backoff (2 s after the warm-up
+# has refused them twice) runs out inside the rehearsal's 4 s window.
+# The three counts beside them (`commit_rounds_per_cycle`,
+# `rounds_parked_per_cycle`, `refusals_per_cycle`) need two records in
+# the window to rise through, like `full_encodes_per_cycle`: printed,
+# not owed
+ALWAYS_READ += ("loser_loop_ms", "postfilter_ms")
 
 
 @pytest.mark.parametrize("trace", (0, 1), ids=("untraced", "traced"))
@@ -60,6 +68,17 @@ def test_rehearsal(cell, trace):
     assert line["failed"] == 0
     for name, (value, limit) in line["compared"].items():
         assert value <= limit, (name, value, limit)
+    # pods finish: the delete path ran, the resident set is held at the
+    # cut target (a cycle of the open loop that follows one which bound
+    # little may start a pod under it; none starts over it:
+    # `resident_over_target`), and the replay and the server agree on
+    # what is resident
+    (facts,) = [r["facts"] for r in rows if "facts" in r]
+    assert facts["completed"] > 0
+    assert facts["resident_at_start"][1] == facts["resident_target"]
+    for name in ("bad_completions", "resident_over_target",
+                 "server_resident_drift"):
+        assert line["compared"][name] == [0, 0], name
     printed = set(line["metrics"])
     if not trace:
         assert {"pods_bound_per_s", "setup_s"} <= printed
