@@ -401,9 +401,10 @@ class Scheduler:
         # flush-time phase stamps awaiting inner record 0
         self._ingest_s: dict[str, float] = {}
         self._flush_phases: dict[str, dict] = {}
-        # per profile, the encoder's fold_fallback_pods as of the last
-        # committed flight record (_commit_record stamps the difference)
-        self._fold_fallback_seen: dict[str, int] = {}
+        # per profile, the encoder's (fold_fallback_pods,
+        # fold_removed_pods) as of the last committed flight record
+        # (_commit_record counts the differences)
+        self._fold_seen: dict[str, tuple[int, int]] = {}
         # running totals every flight record carries (_commit_record):
         # the commit rounds the cycle programs used, the pods those
         # rounds parked (ops/rounds.py) and the pods refused
@@ -2752,13 +2753,17 @@ class Scheduler:
         # record: bound pods the native row writer does not cover
         # (volumes / nodeAffinity), folded per pod instead of sending
         # the whole cycle to the full encode
+        # ... and the rows it compacted away: resident pods that left
+        # from anywhere in the list without costing a full encode. Of
+        # these the record keeps the encoder's running total, as
+        # `full_encodes`
         fb_total = int(encoder.fold_fallback_pods)
-        fold_fallback = fb_total - self._fold_fallback_seen.get(
-            rec.profile, 0
-        )
-        if fold_fallback:
-            self._fold_fallback_seen[rec.profile] = fb_total
-            self.metrics.fold_fallback_pods.inc(fold_fallback)
+        removed_total = int(encoder.fold_removed_pods)
+        fb_seen, removed_seen = self._fold_seen.get(rec.profile, (0, 0))
+        self._fold_seen[rec.profile] = (fb_total, removed_total)
+        fold_fallback = fb_total - fb_seen
+        self.metrics.fold_fallback_pods.inc(fold_fallback)
+        self.metrics.fold_removed_pods.inc(removed_total - removed_seen)
         qc = self.queue.pending_counts()
         sb, ub, bb, pb, vb = before
         rec.counts.update(
@@ -2779,6 +2784,7 @@ class Scheduler:
             delta_hits=int(encoder.delta_hits),
             fold_hits=int(encoder.fold_hits),
             fold_fallback_pods=fold_fallback,
+            fold_removed_pods=removed_total,
             **tot,
             # admission-time incremental encode: dirty slots whose
             # flush-time parse was skipped (a staged ingest row was

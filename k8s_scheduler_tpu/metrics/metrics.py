@@ -110,6 +110,10 @@ round trip):
   native row writer does not cover them (volumes / nodeAffinity): the
   per-pod fallback that keeps such a pod from costing a full encode
   (counted as each cycle's flight record commits)
+- scheduler_encode_fold_removed_pods_total — resident pods that left
+  the existing set and whose rows the incremental fold compacted away
+  in place, wherever in the list they stood (a completion no longer
+  costs a full encode; counted as each cycle's flight record commits)
 - scheduler_commit_rounds_total — commit rounds the cycle programs ran
   (ops/rounds.py `rounds_used`, fetched with the decisions; a program
   that returns only the latency subset reports none)
@@ -419,6 +423,13 @@ class SchedulerMetrics:
             "Newly bound pods whose existing-set row the incremental "
             "fold built in Python (the native row writer does not cover "
             "volumes / nodeAffinity); counted at flight-record commit.",
+            registry=r,
+        )
+        self.fold_removed_pods = Counter(
+            "scheduler_encode_fold_removed_pods_total",
+            "Resident pods that left the existing set and whose rows the "
+            "incremental fold compacted away in place; counted at "
+            "flight-record commit.",
             registry=r,
         )
         # ---- admission-time incremental encode (models/encoding.py) ----

@@ -492,6 +492,7 @@ class SnapshotEncoder:
         # does not cover the pod
         self.fold_hits = 0
         self.fold_fallback_pods = 0
+        self.fold_removed_pods = 0
         # per-segment ms of the LAST delta encode (see _encode_delta)
         self.delta_profile: dict[str, float] = {}
         # admission-time incremental encode (ingest/finalize split, PR 16):
@@ -1160,7 +1161,6 @@ class SnapshotEncoder:
             exist_valid = np.zeros(E, bool)
             exist_valid[:e_real] = True
 
-            used_ports: list[list[int]] = [[] for _ in range(N)]
             # existing pods' own (non-anti) required affinity is not re-checked
             # against incoming pods (upstream symmetry applies to anti-affinity
             # and preferred terms only), so required-affinity terms are dropped
@@ -1212,34 +1212,23 @@ class SnapshotEncoder:
             np.add.at(
                 node_requested, en[placed_mask], exist_req[:e_real][placed_mask]
             )
-            for i, d in enumerate(exist_rows):
-                if len(d["ports"]) and exist_node[i] >= 0:
-                    used_ports[int(exist_node[i])].extend(
-                        int(x) for x in d["ports"]
-                    )
-
+            used_ports = _used_ports_by_node(
+                exist_node, exist_ports_arr, e_real
+            )
             MUP = self._stick(
-                "MUP", _pad_dim(max([len(u) for u in used_ports] + [1]), 4)
+                "MUP",
+                _pad_dim(
+                    max([len(u) for u in used_ports.values()] + [1]), 4
+                ),
             )
             node_used_ports = np.full((N, MUP), -1, np.int32)
-            for i, u in enumerate(used_ports):
-                if u:
-                    node_used_ports[i, : len(u)] = u
+            for i, u in used_ports.items():
+                node_used_ports[i, : len(u)] = u
 
-            # node_pods [N, MPN]: existing indices per node, ascending priority
-            # (ties: higher index first — same key the per-node sort used)
+            # node_pods [N, MPN]: existing indices per node (_victim_table)
             e_ids = np.flatnonzero(placed_mask)
             if e_ids.size:
-                order_v = np.lexsort(
-                    (-e_ids, exist_prio[:e_real][e_ids], en[e_ids])
-                )
-                se = e_ids[order_v].astype(np.int32)
-                sn = en[se]
-                starts = np.r_[True, sn[1:] != sn[:-1]]
-                group_start = np.maximum.accumulate(
-                    np.where(starts, np.arange(sn.size), 0)
-                )
-                col = np.arange(sn.size) - group_start
+                sn, col, se = _victim_table(en, exist_prio, e_ids)
                 # the pad folds INTO the bucket-of-8 (like E into its
                 # pow2 bucket): a non-multiple-of-8 pad must not leave
                 # MPN below the bucket a grown depth would demand
@@ -1940,11 +1929,14 @@ class SnapshotEncoder:
                 ds, nodes, pvcs, pvs, storage_classes, pdbs
             ):
                 # ONLY the existing set changed — the per-cycle event of
-                # real serving (bindings fold in; a completion batch
-                # drops the tail). Try the incremental stable fold: it
-                # answers False for a middle-of-list removal, a row over
-                # the sticky dims or a grown interning table, and no
-                # longer for a bound pod the native parser does not cover.
+                # real serving (bindings fold in at the tail; pods that
+                # finished leave from anywhere in the list). Try the
+                # incremental stable fold: it compacts the rows over the
+                # holes and appends the tail, and answers False for a row
+                # over the sticky dims, a grown interning table or a list
+                # of which more changed than stayed — not for a removal
+                # in the middle, nor for a bound pod the native parser
+                # does not cover.
                 import time as _time
 
                 _ft = _time.perf_counter()
@@ -2015,15 +2007,28 @@ class SnapshotEncoder:
 
     def _try_fold_existing(self, ds, existing) -> bool:
         """Incremental existing-set fold (SURVEY §4 realism; VERDICT r4
-        item 3): bring the cached stable side up to date IN PLACE when the
-        existing set changed by a pure APPEND (pods bound since the last
-        cycle) or a pure TAIL REMOVAL (un-folding a completion batch of
-        recently bound pods). Anything else — middle-of-list removals,
-        node/volume/PDB changes, an interning table that grew, a row
-        wider than the sticky dims (MPL / MA / the existing-pod port
-        width / R), affinity terms while flag_aff is off, the E pad
-        exhausted, a node outgrowing its used-port or victim-table width
-        — returns False and the caller takes the full encode (which
+        item 3): bring the cached stable side up to date IN PLACE when
+        the new existing list is an order-preserving SUBSEQUENCE of the
+        old one followed by an APPENDED TAIL — pods left from anywhere
+        in the list (completions come in no particular order) and pods
+        bound since the last cycle arrived at its end, both in one fold.
+        The surviving rows are compacted over the holes (one vectorised
+        move per per-slot array, no per-pod Python), the vacated slots
+        get the full path's pad values back, and the tail is appended
+        behind them. A pure append is the fold with no hole and a pure
+        tail removal the fold with no tail: the same code, with nothing
+        to move. A reordered list reads as removals plus appends of the
+        same pods, and is as exact. Slot ORDER is kept because it is
+        semantic (the victim table breaks priority ties by slot,
+        node_requested is an f32 sum in ascending slot order).
+
+        Anything else — node/volume/PDB changes, an interning table that
+        grew, a row wider than the sticky dims (MPL / MA / the
+        existing-pod port width / R), affinity terms while flag_aff is
+        off, the E pad exhausted, a node outgrowing its used-port or
+        victim-table width, more than 256 nodes touched by port-bearing
+        pods, or a list with holes of which more changed than stayed —
+        returns False and the caller takes the full encode (which
         rebuilds the stable cache from scratch, so partial st mutations
         on a failed fold are discarded wholesale along with the stale
         _stable_key).
@@ -2032,8 +2037,8 @@ class SnapshotEncoder:
         nodeAffinity / exotic operators) does NOT fail the fold: its row
         comes from the Python row builder and is written through
         apply_rows, per pod, under the same guards as the native rows
-        (`fold_fallback_pods` counts them). One such pod among the newly
-        bound used to cost a full encode of the whole resident set.
+        (`fold_fallback_pods` counts them). `fold_removed_pods` counts
+        the rows that left.
 
         Exactness contract: after a successful fold, every st array is
         byte-identical to what a from-scratch assembly over the new
@@ -2062,68 +2067,80 @@ class SnapshotEncoder:
             ds["exist_ids"] = (id(existing), len(existing))
             return True
         n_old, n_new = len(old), len(new)
-        if n_new > n_old and new[:n_old] == old:
-            pass  # pure append
-        elif n_new < n_old and old[:n_new] == new:
-            pass  # pure tail removal
-        else:
-            return False
-        L = min(n_old, n_new)
         exist_req = st["exist_req"]
         E = exist_req.shape[0]
         if n_new > E:
             return False  # E pad exhausted: full path grows the regime
+
+        # ---- diff once: `keep` = the old slots that survive, ascending
+        # (the longest prefix of `new` that is a subsequence of `old`);
+        # what follows it in `new` is the appended tail. The old list's
+        # pods are still pinned by st["__refs"][1], so an equal id() is
+        # the same pod ----
+        first = n_kept = min(n_old, n_new)
+        if new[:first] == old[:first]:
+            gone = np.arange(n_kept, n_old)  # no hole: nothing moves
+        else:
+            walk = []
+            j, want = 0, new[0]
+            for i, e in enumerate(old):
+                if e == want:
+                    walk.append(i)
+                    j += 1
+                    want = new[j] if j < n_new else None
+            n_kept = j
+            if n_old + n_new - 2 * n_kept > n_kept:
+                return False  # more changed than stayed: full is cheaper
+            keep = np.asarray(walk, np.int64)
+            left = np.ones(n_old, bool)
+            left[keep] = False
+            gone = np.flatnonzero(left)
+            # the first hole: every kept row behind it moves up
+            first = min(int(gone[0]), n_kept)
+
         dims = ds["dims"]
         exist_node = st["exist_node"]
         exist_ports = st["exist_ports"]
         exist_group = st["exist_group"]
         ca = st["exist_creation_abs"]
-        affected_nodes: set[int] = set()
+        nr = st["node_requested"]
+        # nodes whose victim-table row is stale: those that lose or gain
+        # a pod and, node_pods holding SLOT numbers, every node with a
+        # pod at or after the first hole
+        stale = np.zeros(nr.shape[0], bool)
         port_nodes: set[int] = set()
         n_fallback = 0  # appended rows built in Python (counted on commit)
 
-        if n_new < n_old:  # ---- tail removal ----
-            sl = np.arange(L, n_old)
-            en = exist_node[sl]
-            m = en >= 0
-            g = exist_group[sl]
+        if gone.size:  # ---- removal: compact over the holes ----
+            en = exist_node[gone]
+            lost = en[en >= 0]
+            g = exist_group[gone]
             np.subtract.at(st["group_existing_count"], g[g >= 0], 1)
-            affected_nodes.update(int(x) for x in en[m])
             port_nodes.update(
-                int(n) for n, p0 in zip(en, exist_ports[sl, 0])
-                if n >= 0 and p0 >= 0
+                int(n) for n in en[(exist_ports[gone, 0] >= 0) & (en >= 0)]
             )
-            # restore full-path pad values so the arena stays
-            # byte-identical to a fresh assembly
-            exist_req[sl] = 0.0
-            st["el_keys"][sl] = -1
-            st["el_vals"][sl] = -1
-            exist_ports[sl] = -1
-            st["exist_anti"][sl] = -1
-            st["exist_pref"][sl] = -1
-            st["exist_pref_w"][sl] = 0.0
-            st["exist_prio"][sl] = 0
-            st["exist_pdb"][sl] = -1
-            st["exist_start"][sl] = 0.0
-            exist_node[sl] = -1
-            exist_group[sl] = -1
-            ca[sl] = 0.0
-            st["exist_valid"][sl] = False
+            for name, pad, _arena in _EXIST_SLOT_ARRAYS:
+                a = st[name]
+                if first < n_kept:
+                    a[first:n_kept] = a[keep[first:]]
+                # restore full-path pad values so the arena stays
+                # byte-identical to a fresh assembly
+                a[n_kept:n_old] = pad
             # node_requested: f32 subtract is NOT the exact inverse of
             # the full path's slot-ascending add accumulation — recompute
-            # the affected nodes' sums from their remaining member rows
-            # in the same ascending-slot order, so the result stays
-            # bitwise equal to a from-scratch assembly
-            if affected_nodes:
-                nr = st["node_requested"]
-                an0 = np.fromiter(affected_nodes, np.int64)
-                nr[an0] = 0.0
-                en_rem = exist_node[:n_new]
-                sel0 = np.isin(en_rem, an0)
-                mem = np.flatnonzero(sel0)  # ascending slots
+            # the sums of the nodes that lost a pod from their remaining
+            # member rows in the same ascending-slot order, so the result
+            # stays bitwise equal to a from-scratch assembly
+            if lost.size:
+                stale[lost] = True
+                nr[stale] = 0.0
+                en_k = exist_node[:n_kept]
+                mem = np.flatnonzero(stale[en_k] & (en_k >= 0))
                 if mem.size:
-                    np.add.at(nr, en_rem[mem], exist_req[mem])
-        else:  # ---- pure append ----
+                    np.add.at(nr, en_k[mem], exist_req[mem])
+
+        L = n_kept
+        if n_new > L:  # ---- append behind the kept rows ----
             slots = np.arange(L, n_new, dtype=np.int64)
             app = existing[L:]
             specs = ds.get("exist_specs")
@@ -2192,13 +2209,13 @@ class SnapshotEncoder:
             exist_node[slots] = en_new
             st["exist_valid"][slots] = True
             m = en_new >= 0
-            np.add.at(st["node_requested"], en_new[m], exist_req[slots][m])
+            # on top of the kept rows' sums: the ascending-slot order
+            # of a from-scratch assembly
+            np.add.at(nr, en_new[m], exist_req[slots][m])
             g = exist_group[slots]
             np.add.at(st["group_existing_count"], g[g >= 0], 1)
-            affected_nodes.update(int(x) for x in en_new[m])
             port_nodes.update(
-                int(n) for n, s in zip(en_new, slots)
-                if n >= 0 and exist_ports[s, 0] >= 0
+                int(n) for n in en_new[(exist_ports[slots, 0] >= 0) & m]
             )
             pdbs = st["__refs"][5]
             if pdbs:
@@ -2220,78 +2237,56 @@ class SnapshotEncoder:
             if len(port_nodes) > 256:
                 return False  # pathological: cheaper as a full encode
             nup = st["node_used_ports"]
-            MUP = nup.shape[1]
-            en_all = exist_node[:n_new]
+            used = _used_ports_by_node(
+                exist_node, exist_ports, n_new,
+                only=np.fromiter(port_nodes, np.int64),
+            )
             for n in port_nodes:
-                members = np.flatnonzero(en_all == n)
-                ports_concat = [
-                    int(x) for s in members for x in exist_ports[s]
-                    if x >= 0
-                ]
-                if len(ports_concat) > MUP:
+                u = used.get(n, ())
+                if len(u) > nup.shape[1]:
                     return False
                 nup[n] = -1
-                if ports_concat:
-                    nup[n, : len(ports_concat)] = ports_concat
+                nup[n, : len(u)] = u
 
-        # ---- victim table rows of affected nodes (same lexsort key as
-        # the full path, restricted to those nodes) ----
-        if affected_nodes:
+        # ---- victim table rows of the stale nodes (the full path's own
+        # sort, restricted to those nodes' rows) ----
+        en_all = exist_node[:n_new]
+        moved = en_all[first:]
+        stale[moved[moved >= 0]] = True
+        if stale.any():
             npods = st["node_pods"]
-            MPN = npods.shape[1]
-            an = np.fromiter(affected_nodes, np.int64)
-            en_all = exist_node[:n_new]
-            sel = np.isin(en_all, an)
-            e_ids = np.flatnonzero(sel)
-            npods[an] = -1
+            npods[stale] = -1
+            e_ids = np.flatnonzero(stale[en_all] & (en_all >= 0))
             if e_ids.size:
-                order_v = np.lexsort(
-                    (-e_ids, st["exist_prio"][e_ids], en_all[e_ids])
-                )
-                se = e_ids[order_v].astype(np.int32)
-                sn = en_all[se]
-                starts = np.r_[True, sn[1:] != sn[:-1]]
-                group_start = np.maximum.accumulate(
-                    np.where(starts, np.arange(sn.size), 0)
-                )
-                col = np.arange(sn.size) - group_start
-                if int(col.max()) >= MPN:
+                sn, col, se = _victim_table(en_all, st["exist_prio"], e_ids)
+                if int(col.max()) >= npods.shape[1]:
                     return False  # a node outgrew the victim-table width
                 npods[sn, col] = se
 
         # ---- start times: re-base exactly when the oldest pod changed
-        # (full assembly computes base = min over the live set) ----
+        # (full assembly computes base = min over the live set): an
+        # older pod arrived, or the oldest one left ----
         newbase = float(ca[:n_new].min()) if n_new else 0.0
         if newbase != st["start_base"]:
             st["exist_start"][:n_new] = (
                 ca[:n_new] - newbase
             ).astype(np.float32)
             st["start_base"] = newbase
-        elif n_new > n_old:
-            sl2 = np.arange(L, n_new)
-            st["exist_start"][sl2] = (ca[sl2] - newbase).astype(np.float32)
+        elif n_new > L:
+            st["exist_start"][L:n_new] = (
+                ca[L:n_new] - newbase
+            ).astype(np.float32)
         st["e_real"] = n_new
 
-        # ---- mirror into the packed arena ----
+        # ---- mirror into the packed arena: the per-slot rows from the
+        # first hole on, the aggregates whole ----
         A = self._arena
-        lo, hi = L, max(n_old, n_new)
-        rng = slice(lo, hi)
-        for arena_name, st_name in (
-            ("exist_requested", "exist_req"),
-            ("exist_label_keys", "el_keys"),
-            ("exist_label_vals", "el_vals"),
-            ("exist_ports", "exist_ports"),
-            ("exist_anti_terms", "exist_anti"),
-            ("exist_pref_aff", "exist_pref"),
-            ("exist_pref_aff_w", "exist_pref_w"),
-            ("exist_node", "exist_node"),
-            ("exist_priority", "exist_prio"),
-            ("exist_pdb", "exist_pdb"),
-            ("exist_valid", "exist_valid"),
-        ):
-            A[arena_name][rng] = st[st_name][rng]
+        rng = slice(first, max(n_old, n_new))
+        for st_name, _pad, arena_name in _EXIST_SLOT_ARRAYS:
+            if arena_name is not None:
+                A[arena_name][rng] = st[st_name][rng]
         A["exist_start"][:] = st["exist_start"]
-        A["node_requested"][:] = st["node_requested"]
+        A["node_requested"][:] = nr
         A["node_pods"][:] = st["node_pods"]
         A["node_used_ports"][:] = st["node_used_ports"]
         A["group_existing_count"][:] = st["group_existing_count"]
@@ -2314,6 +2309,7 @@ class SnapshotEncoder:
             ds["fold_port_dirty"] = True
         self.fold_hits += 1
         self.fold_fallback_pods += n_fallback
+        self.fold_removed_pods += int(gone.size)
         return True
 
     def _encode_delta(self, ds, pending, pod_groups, mutated_ids):
@@ -2609,6 +2605,65 @@ class SnapshotEncoder:
             self._arena_w, self._arena_b, self._arena_spec,
             self._arena_snap, None,
         )
+
+
+# The per-slot arrays of the stable side's existing-pod block: st name,
+# the pad value a from-scratch assembly leaves in an unused slot, and
+# the arena field that mirrors the array row for row (None: not packed;
+# exist_start is mirrored whole, a re-base rewrites every row). The
+# existing-set fold compacts and re-pads exactly these.
+_EXIST_SLOT_ARRAYS = (
+    ("exist_req", 0.0, "exist_requested"),
+    ("el_keys", -1, "exist_label_keys"),
+    ("el_vals", -1, "exist_label_vals"),
+    ("exist_ports", -1, "exist_ports"),
+    ("exist_anti", -1, "exist_anti_terms"),
+    ("exist_pref", -1, "exist_pref_aff"),
+    ("exist_pref_w", 0.0, "exist_pref_aff_w"),
+    ("exist_prio", 0, "exist_priority"),
+    ("exist_pdb", -1, "exist_pdb"),
+    ("exist_start", 0.0, None),
+    ("exist_node", -1, "exist_node"),
+    ("exist_group", -1, None),
+    ("exist_creation_abs", 0.0, None),
+    ("exist_valid", False, "exist_valid"),
+)
+
+
+def _victim_table(en, prio, e_ids):
+    """node_pods' cells for the existing slots `e_ids` (ascending, all
+    placed): per node the slots in ascending priority, ties the higher
+    slot first. Returns (node, column, slot) of every cell. Shared by
+    the full stable assembly (every placed slot) and the existing-set
+    fold (the slots of the nodes whose row went stale), so the two
+    cannot drift."""
+    order_v = np.lexsort((-e_ids, prio[e_ids], en[e_ids]))
+    se = e_ids[order_v].astype(np.int32)
+    sn = en[se]
+    starts = np.r_[True, sn[1:] != sn[:-1]]
+    group_start = np.maximum.accumulate(
+        np.where(starts, np.arange(sn.size), 0)
+    )
+    col = np.arange(sn.size) - group_start
+    return sn, col, se
+
+
+def _used_ports_by_node(exist_node, exist_ports, e_real, only=None):
+    """The host ports in use per node, {node: [encoded port, ...]}:
+    member slots ascending, ports in row order (a sparse residue loop:
+    few pods hold a host port). `only` restricts it to those nodes.
+    Shared by the full stable assembly and the existing-set fold."""
+    rows = np.flatnonzero(
+        (exist_ports[:e_real, 0] >= 0) & (exist_node[:e_real] >= 0)
+    )
+    if only is not None:
+        rows = rows[np.isin(exist_node[rows], only)]
+    used: dict[int, list[int]] = {}
+    for s in rows:
+        used.setdefault(int(exist_node[s]), []).extend(
+            int(x) for x in exist_ports[s] if x >= 0
+        )
+    return used
 
 
 def _pdb_matches(pdb: api.PodDisruptionBudget, p: Pod) -> bool:

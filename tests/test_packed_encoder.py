@@ -15,7 +15,11 @@ import numpy as np
 import pytest
 
 from k8s_scheduler_tpu.models import MakeNode, MakePod, SnapshotEncoder, packing
-from k8s_scheduler_tpu.models.api import PodGroup
+from k8s_scheduler_tpu.models.api import (
+    LabelSelector,
+    PodDisruptionBudget,
+    PodGroup,
+)
 from k8s_scheduler_tpu.models.encoding import ClusterSnapshot
 from k8s_scheduler_tpu.utils.synth import make_cluster, make_pods
 
@@ -316,10 +320,14 @@ def test_fold_existing_append_tail_remove_and_rebase():
     assert d.a.fold_hits == folds0 + 3
     assert d.a.full_encodes == fulls0
 
-    # ---- middle-of-list removal: NOT foldable, full path, still exact
+    # ---- middle-of-list removal: the rows behind the hole move up
+    removed0 = d.a.fold_removed_pods
     existing5 = existing4[1:]
     d.step(nodes, pending2, existing5)
-    assert d.a.full_encodes == fulls0 + 1
+    assert d.a.fold_hits == folds0 + 4
+    assert d.a.full_encodes == fulls0
+    assert d.a.fold_removed_pods == removed0 + 1
+    assert_fold_exact(d)
 
 
 def test_fold_unfold_float_exactness_under_inexact_requests():
@@ -509,6 +517,177 @@ def test_fold_fallback_rows_keep_the_folds_guards(guard):
     )
     assert d.a.fold_hits == folds0 + 1
     assert_fold_exact(d)
+
+
+def _scattered(existing, **_):
+    return [e for i, e in enumerate(existing) if i % 3 != 1]
+
+
+def _scattered_plus_appends(existing, pods, **_):
+    return _scattered(existing) + [
+        (pods[i], f"node-{i % 8}") for i in (0, 10, 1, 11, 2)
+    ]
+
+
+def _oldest_leaves(existing, **_):
+    # the fixture's resident pods are created at 0.0, 1.0, ...: the
+    # base of exist_start moves when slot 0 goes
+    assert existing[0][0].metadata.creation_timestamp < min(
+        p.metadata.creation_timestamp for p, _ in existing[1:]
+    )
+    return existing[1:]
+
+
+def _port_pod_leaves(existing, **_):
+    assert existing[5][0].host_ports() and existing[13][0].host_ports()
+    return existing[:5] + existing[6:]  # node-5 keeps the other's port
+
+
+def _fallback_row_leaves(existing, odd, **_):
+    assert existing[-2][0] is odd[0]
+    return existing[:-2] + existing[-1:]
+
+
+def _a_node_empties(existing, **_):
+    return [e for e in existing if e[1] != "node-3"]
+
+
+def _reordered(existing, **_):
+    return existing[:4] + existing[9:12] + existing[4:9] + existing[12:]
+
+
+def _append_only(existing, pods, **_):
+    return existing + [(pods[i], f"node-{i % 8}") for i in (0, 10, 1)]
+
+
+def _tail_only(existing, **_):
+    return existing[:-3]
+
+
+def _mostly_new(existing, pods, **_):
+    # a hole, and more rows changed (6 left, 12 arrived) than stayed
+    return existing[::2][:6] + [
+        (p, f"node-{i % 8}") for i, p in enumerate(pods[:12])
+    ]
+
+
+_FOLD_SHAPES = {
+    "scattered_removals": (_scattered, True),
+    "scattered_removals_plus_appends": (_scattered_plus_appends, True),
+    "oldest_pod_leaves_rebase": (_oldest_leaves, True),
+    "port_bearing_pod_leaves": (_port_pod_leaves, True),
+    "python_fallback_row_leaves": (_fallback_row_leaves, True),
+    "every_pod_of_a_node_leaves": (_a_node_empties, True),
+    # the two ends of the same code: no hole, no tail
+    "no_hole_pure_append": (_append_only, True),
+    "no_tail_pure_tail_removal": (_tail_only, True),
+    # read as removals plus appends of the same pods: exact either way
+    "reordered": (_reordered, None),
+    # the full path is cheaper, and is still there
+    "more_changed_than_stayed": (_mostly_new, False),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(_FOLD_SHAPES))
+def test_fold_takes_removals_anywhere_in_the_list(shape):
+    """The new list is the old one less pods from anywhere in it, plus
+    an appended tail: the fold compacts the surviving rows over the
+    holes in place. Byte-identical to a from-scratch assembly, with no
+    full encode (a reorder may take either path, and is as exact)."""
+    d, nodes, pods, odd, existing, kw = _fold_fixture(
+        "preferred_node_affinity"
+    )
+    # two port-bearing resident pods on one node, and two rows from the
+    # Python row builder at the tail (bound the cycle before)
+    for i, port in ((5, 8080), (13, 9090)):
+        existing[i] = (
+            MakePod(f"port-{i}").req({"cpu": "100m"}).host_port(port)
+            .created(float(i)).obj(),
+            "node-5",
+        )
+    existing = existing + [(odd[0], "node-2"), (pods[3], "node-3")]
+    pods = [p for p in pods if p is not odd[0] and p is not pods[3]]
+    d.step(nodes, pods, existing, **kw)
+    d.step(nodes, pods, existing, **kw)
+    make_list, folds = _FOLD_SHAPES[shape]
+    folds0, fulls0 = d.a.fold_hits, d.a.full_encodes
+    removed0 = d.a.fold_removed_pods
+    existing2 = make_list(existing=existing, pods=pods, odd=odd)
+    bound = {id(p) for p, _ in existing2}
+    pending2 = [p for p in pods if id(p) not in bound]
+    d.step(nodes, pending2, existing2, **kw)
+    assert_fold_exact(d)
+    if folds:
+        assert d.a.full_encodes == fulls0
+        assert d.a.fold_hits == folds0 + 1
+        kept = {id(p) for p, _ in existing} & bound
+        assert d.a.fold_removed_pods == removed0 + len(existing) - len(kept)
+    elif folds is False:
+        assert d.a.full_encodes == fulls0 + 1
+        assert d.a.fold_removed_pods == removed0
+    if shape == "every_pod_of_a_node_leaves":
+        assert (d.a._stable["node_pods"][3] == -1).all()
+    # and the folded state folds again: what left comes back at the tail
+    back = [e for e in existing if id(e[0]) not in bound]
+    d.step(nodes, pending2, existing2 + back, **kw)
+    assert_fold_exact(d)
+    if folds:
+        assert d.a.full_encodes == fulls0
+
+
+def test_fold_churn_with_inexact_requests_stays_exact():
+    """Twelve seeded cycles in which a tenth of the resident set leaves
+    from anywhere in the list while as many pods bind at its tail, with
+    requests that are inexact in float32 (100m): node_requested is
+    re-summed in slot order, never subtracted, so every cycle stays
+    byte-identical to a from-scratch assembly and none encodes in full."""
+    from k8s_scheduler_tpu import native
+
+    if native.pod_rows_into is None:
+        pytest.skip("native extension not built")
+    rng = np.random.default_rng(37)
+    nodes = make_cluster(8)
+    d = Driver(pad_pods=64)
+    serial = iter(range(10**6))
+
+    def fresh(n):
+        return [
+            MakePod(f"c-{next(serial)}")
+            .req({"cpu": "100m", "memory": "100Mi"})
+            .labels({"app": "ab"[int(rng.integers(0, 2))]})
+            .priority(int(rng.integers(0, 3)))
+            .created(float(rng.integers(0, 500))).obj()
+            for _ in range(n)
+        ]
+
+    existing = [
+        (p, f"node-{int(rng.integers(0, 8))}") for p in fresh(120)
+    ]
+    pending = fresh(24)
+    # a budget over half the pods: exist_pdb's rows move with the rest
+    kw = dict(pdbs=[PodDisruptionBudget(
+        "a-pdb", selector=LabelSelector(match_labels={"app": "a"}),
+        disruptions_allowed=1,
+    )])
+    d.step(nodes, pending, existing, **kw)
+    d.step(nodes, pending, existing, **kw)
+    folds0, fulls0 = d.a.fold_hits, d.a.full_encodes
+    removed0 = d.a.fold_removed_pods
+    for _cycle in range(12):
+        leave = rng.choice(
+            len(existing), len(existing) // 10, replace=False
+        ).tolist()
+        # a bound pod takes the node of one that left: no node outgrows
+        # the victim table's width, which would be the full path's case
+        existing = [
+            e for i, e in enumerate(existing) if i not in set(leave)
+        ] + [(p, existing[i][1]) for p, i in zip(pending[:12], leave)]
+        pending = pending[12:] + fresh(12)
+        d.step(nodes, pending, existing, **kw)
+        assert_fold_exact(d)
+    assert d.a.full_encodes == fulls0
+    assert d.a.fold_hits == folds0 + 12
+    assert d.a.fold_removed_pods == removed0 + 12 * 12
 
 
 def test_pad_ma_mc_presize_keeps_regime_stable():

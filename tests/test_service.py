@@ -654,3 +654,80 @@ def test_a_bound_pod_with_node_affinity_costs_no_full_encode():
     finally:
         client.close()
         server.stop(grace=None)
+
+
+def test_bound_pods_deleted_from_the_middle_cost_no_full_encode():
+    """The agent path with the rehearsal's pads: after the warm-up, every
+    `Update` deletes bound pods from the middle of the resident set
+    (pods finish in no particular order) while the pods of the cycle
+    before bind behind them. The fold compacts the rows over the holes:
+    `full_encodes` stays flat in the flight records,
+    `fold_removed_pods` rises by the pods deleted, and the observer
+    raises no `fold_miss`."""
+    from k8s_scheduler_tpu import native
+
+    if native.pod_rows_into is None:
+        pytest.skip("native extension not built")
+    server, service, port = serve(
+        "127.0.0.1:0",
+        config=SchedulerConfiguration(
+            pad_existing=256, pad_pods_per_node=32, pad_hysteresis_pct=100
+        ),
+    )
+    client = SchedulerClient(f"127.0.0.1:{port}")
+    try:
+        applier = Applier()
+        agent = SchedulerAgent(client, applier.bind, applier.evict)
+        for i in range(8):
+            agent.upsert_node(MakeNode(f"n{i}").capacity({"cpu": "16"}).obj())
+        resident: list[str] = []  # uids, oldest first
+
+        def wave(c, finish):
+            pods = [
+                MakePod(f"w-{c}-{j}").req({"cpu": "100m"})
+                .labels({"app": "a"}).obj()
+                for j in range(6)
+            ]
+            with agent.batched():
+                for uid in finish:
+                    agent.delete_pod(uid)
+                    resident.remove(uid)
+                for p in pods:
+                    agent.upsert_pod(p)
+            resp = agent.run_cycle()
+            resident.extend(p.uid for p in pods)
+            return resp
+
+        warm, cycles = 3, 5
+        for c in range(warm):
+            assert wave(c, []).stats.scheduled == 6
+        deleted = []
+        for c in range(warm, warm + cycles):
+            # two of the oldest third, one of the middle: never the tail
+            finish = [resident[1], resident[3], resident[len(resident) // 2]]
+            deleted.append(len(finish))
+            assert wave(c, finish).stats.scheduled == 6
+
+        sched = service.scheduler
+        assert sched.cache.counts()["bound"] == len(resident)
+        recs = [r for r in sched.flight.snapshot() if r.counts.get("pods")]
+        assert len(recs) == warm + cycles
+        before, after = recs[warm - 1], recs[warm:]
+        assert {r.counts["full_encodes"] for r in after} == {
+            before.counts["full_encodes"]}
+        assert [
+            b.counts["fold_removed_pods"] - a.counts["fold_removed_pods"]
+            for a, b in zip([before] + after, after)
+        ] == deleted
+        assert (after[-1].counts["fold_hits"]
+                - before.counts["fold_hits"]) == cycles
+        assert not [
+            a for a in sched.observer.anomalies()
+            if a["class"] == "fold_miss" and a["seq"] >= after[0].seq
+        ]
+        assert (b"scheduler_encode_fold_removed_pods_total %.1f"
+                % after[-1].counts["fold_removed_pods"]
+                ) in client.metrics_text()
+    finally:
+        client.close()
+        server.stop(grace=None)
