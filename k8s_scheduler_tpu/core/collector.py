@@ -36,7 +36,9 @@ for every generation-2 pass the interpreter starts itself (`auto_full`),
 with `generation`, `collected` and `frozen`. Automatic young passes are
 too many for the ring: they are counted, and `cycle_done` carries the
 counts to `scheduler_gc_young_passes_total` and
-`scheduler_gc_young_pass_seconds_total`.
+`scheduler_gc_young_pass_seconds_total`. Placed sweeps are counted too
+(`sweeps`, `scheduler_gc_sweeps_total`, and `gc_sweeps` in every flight
+record): a span's `kind` is read by no metric, a count is.
 """
 
 from __future__ import annotations
@@ -77,7 +79,9 @@ SWEEP_MIN_DEPARTURES = 1_000
 class CollectorPolicy:
     """`census()` gives (pods and nodes resident, pods and nodes that
     have left since the process began): `Scheduler.census`. `metrics`
-    (a `SchedulerMetrics`) receives the two young-pass counters."""
+    (a `SchedulerMetrics`) receives the two young-pass counters and the
+    count of sweeps; `sweeps` is the same running total, which the
+    scheduler's flight records carry as `gc_sweeps`."""
 
     def __init__(
         self, census: Callable[[], "tuple[int, int]"], metrics
@@ -148,6 +152,7 @@ class CollectorPolicy:
                 self._place("sweep", resident, count=True)
                 self._swept_at = departed
                 self.sweeps += 1
+                self._metrics.gc_sweeps.inc()
             elif any(gc.get_count()[1:]):
                 self._place(
                     "freeze", resident,

@@ -661,6 +661,13 @@ class CycleObserver:
                     if prev_v is not None else 0
                 )
                 pc["full_encodes"] = counts["full_encodes"]
+                # the full encodes the fold asked for itself: more of
+                # the existing set changed than stayed, so it stood
+                # aside (models/encoding.py, `fold_declined`) —
+                # explained, not a miss
+                declined = counts.get("fold_declined", 0)
+                delta -= declined - pc.get("fold_declined", declined)
+                pc["fold_declined"] = declined
                 if (
                     delta > 0 and not first and not flipped
                     and not counts.get("regime_flip")

@@ -195,6 +195,11 @@ class Scheduler:
         # land in global_metrics(); the CLI passes metrics=global_metrics()
         # explicitly so the SERVED /metrics registry includes them.
         self.metrics = metrics or SchedulerMetrics()
+        # core/collector.CollectorPolicy, set by cmd/main.main() (through
+        # the servicer, which places its operations) and by nothing
+        # else: None leaves the interpreter's collector alone. Held here
+        # because the flight records carry its count of sweeps
+        self.collector = None
         self.queue = SchedulingQueue(
             initial_backoff_seconds=self.config.pod_initial_backoff_seconds,
             max_backoff_seconds=self.config.pod_max_backoff_seconds,
@@ -2785,6 +2790,10 @@ class Scheduler:
             fold_hits=int(encoder.fold_hits),
             fold_fallback_pods=fold_fallback,
             fold_removed_pods=removed_total,
+            # cycles in which the fold stood aside because more of the
+            # existing set changed than stayed: the observer reads a
+            # full encode beside a rise of this one as explained
+            fold_declined=int(encoder.fold_declined),
             **tot,
             # admission-time incremental encode: dirty slots whose
             # flush-time parse was skipped (a staged ingest row was
@@ -2805,6 +2814,11 @@ class Scheduler:
             ),
             **(extra_counts or {}),
         )
+        if self.collector is not None:
+            # the collector policy's placed sweeps so far (each falls
+            # after a cycle's response has left, so a record carries
+            # those up to the cycle before); no policy, no count
+            rec.counts["gc_sweeps"] = self.collector.sweeps
         if self._pod_spans:
             self._emit_cycle_spans(rec, pending, speculation, row_window)
         self._commit_traced(rec)

@@ -224,6 +224,10 @@ cmd/main.py startup stamp):
   minute, so counted here and not stamped as spans; placed operations
   and full passes are `gc.pass` spans), carried over at each cycle's end
 - scheduler_gc_young_pass_seconds_total — seconds spent in them
+- scheduler_gc_sweeps_total — sweeps the policy placed (unfreeze, full
+  pass, freeze: once the pods and nodes that left since the last one
+  are at least 1,000 and over a quarter of those resident); the flight
+  records carry the same running total as `gc_sweeps`
 - scheduler_alerts_total{rule,severity} — declarative alert-rule
   firings from the in-process watchtower (metrics/rules.py; one
   increment per ok->firing transition, never per evaluation); the
@@ -706,6 +710,13 @@ class SchedulerMetrics:
             "scheduler_gc_young_pass_seconds_total",
             "Seconds spent in automatic generation-0 and generation-1 "
             "passes of the cyclic collector.",
+            registry=r,
+        )
+        self.gc_sweeps = Counter(
+            "scheduler_gc_sweeps_total",
+            "Sweeps the collector's policy placed after a cycle's end "
+            "(unfreeze, full pass, freeze), by departures since the "
+            "last one.",
             registry=r,
         )
         self.alerts = Counter(

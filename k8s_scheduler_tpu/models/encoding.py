@@ -493,6 +493,10 @@ class SnapshotEncoder:
         self.fold_hits = 0
         self.fold_fallback_pods = 0
         self.fold_removed_pods = 0
+        # ... and the cycles in which the fold stood aside by its own
+        # rule (more of the list changed than stayed): a full encode
+        # taken on purpose, which core/observe does not read as a miss
+        self.fold_declined = 0
         # per-segment ms of the LAST delta encode (see _encode_delta)
         self.delta_profile: dict[str, float] = {}
         # admission-time incremental encode (ingest/finalize split, PR 16):
@@ -1738,7 +1742,14 @@ class SnapshotEncoder:
             # stable-side argument identity: the fast path first compares
             # LIST identity (0-cost; the contract is that callers keep one
             # list per stable side and replace it on change), and falls
-            # back to element-identity tuples when the list was rebuilt
+            # back to element-identity tuples when the list was rebuilt.
+            # The lists themselves are pinned (`id_lists`, `exist_list`):
+            # a caller that builds a fresh list every cycle and drops it
+            # (cache.existing_pods()) may get the freed one's address
+            # back, and with an equal length (a resident set held at a
+            # target) a recycled id() would read as "unchanged"
+            "id_lists": (nodes, pvcs, pvs, storage_classes, pdbs),
+            "exist_list": existing,
             "nodes_ids": (id(nodes), len(nodes)),
             "nodes_elems": tuple(id(nd) for nd in nodes),
             "exist_ids": (id(existing), len(existing)),
@@ -2038,7 +2049,8 @@ class SnapshotEncoder:
         comes from the Python row builder and is written through
         apply_rows, per pod, under the same guards as the native rows
         (`fold_fallback_pods` counts them). `fold_removed_pods` counts
-        the rows that left.
+        the rows that left, `fold_declined` the calls that answered
+        False because more changed than stayed (and for no other reason).
 
         Exactness contract: after a successful fold, every st array is
         byte-identical to what a from-scratch assembly over the new
@@ -2065,6 +2077,7 @@ class SnapshotEncoder:
             new = tuple((id(p), nm) for p, nm in existing)
         if new == old:  # same elements, rebuilt list object
             ds["exist_ids"] = (id(existing), len(existing))
+            ds["exist_list"] = existing
             return True
         n_old, n_new = len(old), len(new)
         exist_req = st["exist_req"]
@@ -2090,6 +2103,7 @@ class SnapshotEncoder:
                     want = new[j] if j < n_new else None
             n_kept = j
             if n_old + n_new - 2 * n_kept > n_kept:
+                self.fold_declined += 1
                 return False  # more changed than stayed: full is cheaper
             keep = np.asarray(walk, np.int64)
             left = np.ones(n_old, bool)
@@ -2301,6 +2315,7 @@ class SnapshotEncoder:
         k = self._stable_key
         self._stable_key = (k[0], new) + k[2:]
         ds["exist_ids"] = (id(existing), len(existing))
+        ds["exist_list"] = existing
         ds["exist_elems"] = new
         # NodePorts static rows read node_used_ports: when the fold
         # actually touched a used-port list, recompute the carry rows of

@@ -85,9 +85,17 @@ class SchedulerService:
         # enable_front_door() — the Submit/NodeChurn RPCs answer
         # FAILED_PRECONDITION while disabled
         self.admission = None
-        # core/collector.CollectorPolicy, set by cmd/main.main() and by
-        # nothing else: None leaves the interpreter's collector alone
-        self.collector = None
+
+    @property
+    def collector(self):
+        """The scheduler's core/collector.CollectorPolicy, set by
+        cmd/main.main() and by nothing else: None leaves the
+        interpreter's collector alone."""
+        return self.scheduler.collector
+
+    @collector.setter
+    def collector(self, policy) -> None:
+        self.scheduler.collector = policy
 
     def enable_front_door(self, **kwargs):
         """Attach an AdmissionController (idempotent) so the Submit /

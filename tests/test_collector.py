@@ -9,6 +9,7 @@ so no other test inherits the policy.
 
 from __future__ import annotations
 
+import contextlib
 import gc
 import glob
 import itertools
@@ -65,26 +66,34 @@ def svc(shared):
     assert shared.scheduler.census()[0] == NODES
 
 
-@pytest.fixture()
-def policy(svc, monkeypatch):
-    """An installed CollectorPolicy on the service, with thresholds and
-    a floor that fit a test's few dozen pods; undone whatever the test
-    did."""
+@contextlib.contextmanager
+def installed(svc, census):
+    """A CollectorPolicy over `census`, installed on the service; undone
+    whatever the body did."""
     found = collector_state()
-    monkeypatch.setattr(collector, "THRESHOLDS", (50, 10, 10))
-    monkeypatch.setattr(collector, "SWEEP_MIN_DEPARTURES", 10)
-    pol = collector.CollectorPolicy(
-        svc.scheduler.census, metrics=svc.scheduler.metrics)
+    pol = collector.CollectorPolicy(census, metrics=svc.scheduler.metrics)
     svc.collector = pol
     pol.install()
-    yield pol
-    svc.collector = None
-    pol.uninstall()
+    try:
+        yield pol
+    finally:
+        svc.collector = None
+        pol.uninstall()
     # thresholds and callbacks as found and nothing left frozen (the
     # interpreter starts with a few hundred objects frozen of its own;
     # `gc.unfreeze()` knows no part)
     assert (gc.get_threshold(), list(gc.callbacks)) == (found[0], found[2])
     assert gc.get_freeze_count() == 0
+
+
+@pytest.fixture()
+def policy(svc, monkeypatch):
+    """An installed CollectorPolicy on the service, with thresholds and
+    a floor that fit a test's few dozen pods."""
+    monkeypatch.setattr(collector, "THRESHOLDS", (50, 10, 10))
+    monkeypatch.setattr(collector, "SWEEP_MIN_DEPARTURES", 10)
+    with installed(svc, svc.scheduler.census) as pol:
+        yield pol
 
 
 @pytest.fixture()
@@ -389,6 +398,47 @@ def test_young_passes_reach_the_two_counters(svc, policy):
     assert "scheduler_gc_young_passes_total" in text
     assert "scheduler_gc_young_pass_seconds_total" in text
     del keep
+
+
+# ---- a small cluster: a sweep after most cycles ---------------------------
+
+@pytest.mark.parametrize("per_cycle, swept_after", [
+    (1000, [1, 2, 3, 4, 5, 6]),  # the floor is met by every cycle
+    (996, [0, 1, 1, 2, 2, 3]),   # four short of it: every other cycle
+])
+def test_a_small_cluster_is_swept_after_most_cycles(
+        svc, per_cycle, swept_after):
+    """scheduler_perf's SchedulingBasic 500Nodes under the policy as
+    shipped (a floor of 1,000 departures, a quarter of what is
+    resident): 500 nodes and 2,500 pods stand at a cycle's end and
+    `per_cycle` pods have finished since the one before. 40% of the set
+    is over the share after every cycle, so the floor alone decides. The
+    flight records carry the policy's running total as `gc_sweeps` (a
+    cycle's record is committed before the sweep that follows it), and
+    `scheduler_gc_sweeps_total` keeps step."""
+    assert (collector.SWEEP_MIN_DEPARTURES, collector.SWEEP_SHARE) == (
+        1_000, 0.25)
+    departed = [0]
+    metrics = svc.scheduler.metrics
+    at = metrics.gc_sweeps._value.get()
+    with installed(svc, lambda: (500 + 2_500, departed[0])) as pol:
+        in_record, after = [], []
+        for _ in swept_after:
+            departed[0] += per_cycle
+            add_pods(svc, 2)
+            svc.Cycle(pb.CycleRequest(), None)  # no context: swept at once
+            in_record.append(
+                svc.scheduler.flight.last_record().counts["gc_sweeps"])
+            after.append(pol.sweeps)
+        assert after == swept_after
+        assert in_record == [0] + swept_after[:-1]
+        assert metrics.gc_sweeps._value.get() == at + swept_after[-1]
+        assert (b"scheduler_gc_sweeps_total %.1f" % (at + swept_after[-1])
+                ) in metrics.expose()
+    # and with no policy the next record keeps no such count
+    add_pods(svc, 1)
+    svc.Cycle(pb.CycleRequest(), None)
+    assert "gc_sweeps" not in svc.scheduler.flight.last_record().counts
 
 
 # ---- placement: after the response, not before ----------------------------

@@ -12,6 +12,8 @@ import json
 import urllib.error
 import urllib.request
 
+import pytest
+
 from k8s_scheduler_tpu.cmd.httpserver import (
     staleness_healthz,
     start_http_server,
@@ -313,6 +315,27 @@ def test_fold_miss_only_without_regime_flip():
     assert ev["class"] == "recompile"
     assert ev["detail"]["dims"] == []
     assert obs.anomaly_counts["fold_miss"] == 1  # unchanged
+
+
+@pytest.mark.parametrize("declined, misses", [
+    ((0, 0, 1, 2), 0),  # every full encode is one the fold asked for
+    ((0, 0, 0, 1), 1),  # one of the two was not
+    ((0, 0, 0, 0), 2),
+    (None, 2),          # a record that keeps no such count (older program)
+], ids=["all_declined", "one_unexplained", "none_declined", "no_count"])
+def test_a_full_encode_the_fold_declined_into_is_no_fold_miss(
+        declined, misses):
+    """`fold_declined` rising beside `full_encodes` explains the full
+    encode (more of the existing set changed than stayed: the encoder's
+    own rule); what rises beyond it is still a miss."""
+    fr, obs = _observed()
+    sig = (("E", 256),)
+    for i, full in enumerate((1, 1, 2, 3)):
+        extra = {} if declined is None else {"fold_declined": declined[i]}
+        _commit_cycle(fr, float(i), sig=sig, full_encodes=full, **extra)
+    evs = obs.anomalies()
+    assert [e["class"] for e in evs] == ["fold_miss"] * misses
+    assert all(e["detail"]["full_encodes"] == 1 for e in evs)
 
 
 def test_wedge_precursor_from_strike_deltas():

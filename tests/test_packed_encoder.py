@@ -611,7 +611,7 @@ def test_fold_takes_removals_anywhere_in_the_list(shape):
     d.step(nodes, pods, existing, **kw)
     make_list, folds = _FOLD_SHAPES[shape]
     folds0, fulls0 = d.a.fold_hits, d.a.full_encodes
-    removed0 = d.a.fold_removed_pods
+    removed0, declined0 = d.a.fold_removed_pods, d.a.fold_declined
     existing2 = make_list(existing=existing, pods=pods, odd=odd)
     bound = {id(p) for p, _ in existing2}
     pending2 = [p for p in pods if id(p) not in bound]
@@ -625,6 +625,10 @@ def test_fold_takes_removals_anywhere_in_the_list(shape):
     elif folds is False:
         assert d.a.full_encodes == fulls0 + 1
         assert d.a.fold_removed_pods == removed0
+    # the rule that more changed than stayed, and nothing else, counts
+    # as the fold declining (a reorder may read as either)
+    if folds is not None:
+        assert d.a.fold_declined == declined0 + (not folds)
     if shape == "every_pod_of_a_node_leaves":
         assert (d.a._stable["node_pods"][3] == -1).all()
     # and the folded state folds again: what left comes back at the tail
@@ -688,6 +692,94 @@ def test_fold_churn_with_inexact_requests_stays_exact():
     assert d.a.full_encodes == fulls0
     assert d.a.fold_hits == folds0 + 12
     assert d.a.fold_removed_pods == removed0 + 12 * 12
+
+
+@pytest.mark.parametrize("leave, declines", [
+    (6, True),   # 6 of the 15 the encoder saw leave, 6 arrive: 12 > 9
+    (5, False),  # 10 changed, 10 stayed: not MORE changed than stayed
+    (2, False),
+], ids=["over_half", "exactly_half", "under_half"])
+def test_a_resident_set_that_turns_over_every_cycle(leave, declines):
+    """scheduler_perf's SchedulingBasic 500Nodes in small: of the pods
+    the encoder saw, `leave` finish from anywhere in the list before
+    every cycle while as many bind at its tail. Past half, the fold
+    stands aside by its own rule: `fold_declined` rises by one a cycle
+    and `full_encodes` with it; under it the fold compacts and appends
+    and both stay flat. Either way the arena is a from-scratch encode
+    byte for byte."""
+    from k8s_scheduler_tpu import native
+
+    if native.pod_rows_into is None:
+        pytest.skip("native extension not built")
+    nodes = make_cluster(8)
+    d = Driver(pad_pods=64)
+    serial = iter(range(10**6))
+
+    def fresh(n):
+        return [
+            MakePod(f"t-{next(serial)}")
+            .req({"cpu": "100m", "memory": "500Mi"})
+            .labels({"app": "a"}).created(float(next(serial))).obj()
+            for _ in range(n)
+        ]
+
+    existing = [(p, f"node-{i % 8}") for i, p in enumerate(fresh(15))]
+    pending = fresh(8)
+    d.step(nodes, pending, existing)
+    d.step(nodes, pending, existing)
+    fulls0, folds0 = d.a.full_encodes, d.a.fold_hits
+    declined0 = d.a.fold_declined
+    cycles = 5
+    for c in range(1, cycles + 1):
+        gone = set(range(1, 2 * leave, 2))  # scattered: never a pure tail
+        existing = [e for i, e in enumerate(existing) if i not in gone] + [
+            (p, f"node-{(c + j) % 8}") for j, p in enumerate(fresh(leave))
+        ]
+        d.step(nodes, pending, existing)
+        assert_fold_exact(d)
+        assert d.a.fold_declined - declined0 == (c if declines else 0)
+        assert d.a.full_encodes - fulls0 == (c if declines else 0)
+        assert d.a.fold_hits - folds0 == (0 if declines else c)
+
+
+def test_the_lists_whose_identity_is_remembered_are_kept_alive():
+    """The delta path's precheck first compares `id()` and length of each
+    stable-side list with the last encode's. A caller that builds a
+    fresh list every cycle and drops it (`cache.existing_pods()`) can be
+    handed the freed list's address again, and where the resident set is
+    held at a target the length is equal too: the encoder therefore
+    keeps the lists it remembers alive, through the full path and
+    through a fold."""
+    import weakref
+
+    class Kept(list):  # a plain list takes no weak reference
+        pass
+
+    nodes = make_cluster(4)
+    enc = SnapshotEncoder(pad_pods=32, pad_nodes=16)
+    pods = [
+        MakePod(f"k-{i}").req({"cpu": "100m"}).created(float(i)).obj()
+        for i in range(9)
+    ]
+    pending = pods[6:]
+    first = Kept((p, "node-0") for p in pods[:4])
+    node_list = Kept(nodes)
+    alive = [weakref.ref(first), weakref.ref(node_list)]
+    enc.encode_packed(node_list, pending, first)
+    del first, node_list
+    assert all(r() is not None for r in alive)
+    # ... and after a fold the list it folded in, not the one before
+    second = Kept((p, "node-0") for p in pods[:5])
+    alive.append(weakref.ref(second))
+    folds0 = enc.fold_hits
+    enc.encode_packed(alive[1](), pending, second)
+    del second
+    from k8s_scheduler_tpu import native
+
+    if native.pod_rows_into is not None:
+        assert enc.fold_hits == folds0 + 1
+        assert alive[0]() is None
+    assert alive[2]() is not None and alive[1]() is not None
 
 
 def test_pad_ma_mc_presize_keeps_regime_stable():

@@ -18,6 +18,8 @@ from k8s_scheduler_tpu.models import SnapshotEncoder
 from k8s_scheduler_tpu.ops import sampling
 from k8s_scheduler_tpu.utils.synth import ZONES, make_cluster, make_pods
 
+from k8s_scheduler_tpu.models.builders import MakePod
+
 from test_sampling import PROGRAMS, _pod, run
 
 
@@ -85,6 +87,64 @@ def test_candidate_set_equals_the_sequential_walk(seed, pct):
         assert sorted(want) == np.nonzero(got[i])[0].tolist(), i
         assert bool(narrowed[i]) == (sum(row) > k)
     assert narrowed.any() and not narrowed.all()
+
+
+@pytest.mark.parametrize("n, k", [
+    (99, 99),      # under minFeasibleNodesToFind: every node
+    (100, 100),    # 50% of 100, floored at 100
+    (500, 230),    # 50 - 500/125 = 46%: SchedulingBasic 500Nodes
+    (5000, 500),   # 10%: the 5,000-node cells
+    (6000, 300),   # 50 - 48 = 2, floored at 5%
+])
+def test_the_adaptive_k_on_both_branches(n, k):
+    """`percentageOfNodesToScore` unset: the traced arithmetic gives the
+    reference's k where the percentage follows the node count (500
+    nodes) and where it sits on its floor (5,000 and beyond)."""
+    assert oracle.num_feasible_nodes_to_find(n, 0) == k
+    assert int(sampling.num_feasible_nodes_to_find(np.int32(n), 0)) == k
+
+
+@functools.cache
+def _plain(seed):
+    """SchedulingBasic 500Nodes' shapes: 500 nodes of 4 CPU, 32Gi, 110
+    pods (`node-default`), pods of 100m, 500Mi with no constraint
+    (`pod-default`), seeded priorities and ages so that the queue order
+    is not the list's."""
+    rng = np.random.default_rng(seed)
+    nodes = make_cluster(500, seed=seed, cpu_choices=(4,),
+                         memory_choices=(32,))
+    pods = [
+        MakePod(f"basic-{i}").req({"cpu": "100m", "memory": "500Mi"})
+        .labels({"app": f"app-{i % 50}"})
+        .priority(int(rng.integers(0, 3)))
+        .created(float(rng.integers(0, 1000))).obj()
+        for i in range(120)
+    ]
+    return nodes, pods
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+@pytest.mark.parametrize("seed", [0, 1])
+def test_500_plain_nodes_under_the_default_sample(seed, program):
+    """k = 230 of 500, every pod narrowed (all 500 nodes admit it). The
+    scan binds what the reference's sequential assignment binds, pod for
+    pod; the rounds programs bind every pod validly, each on a node of
+    its walk's sample (no node fills up: 120 pods of 100m)."""
+    nodes, pods = _plain(seed)
+    snap, out = run(program, nodes, pods, 0)
+    a = np.asarray(out.assignment)[: len(pods)].tolist()
+    assert (int(out.sample_k), int(out.sample_narrowed_pods)) == (230, 120)
+    if program == "scan":
+        assert a == [d.node_index for d in oracle.schedule(
+            nodes, pods, percentage_of_nodes_to_score=0,
+            cycle_index=int(snap.cycle_index))]
+        return
+    assert min(a) >= 0
+    assert oracle.validate_rounds_assignment(nodes, pods, a) == []
+    rank = {pi: r for r, pi in enumerate(oracle.queue_order(pods))}
+    for j, i in enumerate(a):
+        start = oracle.sample_start(rank[j], int(snap.cycle_index), 500)
+        assert i in oracle.sampled_candidates([True] * 500, start, 230), j
 
 
 @pytest.mark.parametrize("pct", [0, 10, 50])

@@ -656,6 +656,105 @@ def test_a_bound_pod_with_node_affinity_costs_no_full_encode():
         server.stop(grace=None)
 
 
+@pytest.mark.parametrize("depth, old_out, new_out, node_event", [
+    (10, 6, 4, False),
+    (3, 2, 1, False),
+    (3, 2, 1, True),
+], ids=["over_half", "under_half", "under_half_and_a_node_changes"])
+def test_turnover_of_the_resident_set_is_told_from_a_fold_miss(
+        depth, old_out, new_out, node_event):
+    """scheduler_perf's SchedulingBasic 500Nodes in small, on the agent
+    path: 15 pods resident at a cycle's start, `depth` binding in it,
+    and before the next one `old_out` of the pods the encoder has seen
+    finish (from anywhere in the list) with `new_out` of those just
+    bound. With 10 of 25 turning over, more of the encoder's list
+    changes than stays: the fold stands aside by its own rule, the
+    records' `fold_declined` rises by one a cycle with `full_encodes`,
+    and the observer raises NO `fold_miss`. With 3 of 18 the fold runs
+    and both stay flat; and a full encode forced another way (a node
+    replaced in every cycle) is a `fold_miss` as before."""
+    from k8s_scheduler_tpu import native
+
+    if native.pod_rows_into is None:
+        pytest.skip("native extension not built")
+    server, service, port = serve(
+        "127.0.0.1:0",
+        config=SchedulerConfiguration(
+            pad_existing=256, pad_pods_per_node=32, pad_hysteresis_pct=100
+        ),
+    )
+    client = SchedulerClient(f"127.0.0.1:{port}")
+    try:
+        applier = Applier()
+        agent = SchedulerAgent(client, applier.bind, applier.evict)
+        for i in range(8):
+            agent.upsert_node(MakeNode(f"n{i}").capacity({"cpu": "16"}).obj())
+        seen: list[str] = []  # uids the encoder has had in its list
+        fresh: list[str] = []  # bound by the last cycle
+
+        def wave(c, n, finish=(), touch_node=False):
+            nonlocal seen, fresh
+            pods = [
+                MakePod(f"t-{c}-{j}").req({"cpu": "100m"})
+                .labels({"app": "a"}).obj()
+                for j in range(n)
+            ]
+            with agent.batched():
+                for uid in finish:
+                    agent.delete_pod(uid)
+                if touch_node:
+                    agent.upsert_node(
+                        MakeNode(f"n{c % 8}").capacity({"cpu": "16"})
+                        .labels({"touched": "yes"}).obj())
+                for p in pods:
+                    agent.upsert_pod(p)
+            assert agent.run_cycle().stats.scheduled == n
+            seen = [u for u in seen + fresh if u not in finish]
+            fresh = [p.uid for p in pods]
+
+        wave(0, 15)
+        wave(1, depth)
+        warm, cycles = 2, 5
+        for c in range(warm, warm + cycles):
+            wave(c, depth, seen[1:2 * old_out:2] + fresh[:new_out],
+                 touch_node=node_event)
+
+        sched = service.scheduler
+        assert sched.cache.counts()["bound"] == 15 + depth
+        recs = [r for r in sched.flight.snapshot() if r.counts.get("pods")]
+        assert len(recs) == warm + cycles
+        before, after = recs[warm - 1], recs[warm:]
+
+        def rise(name):
+            return [b.counts[name] - a.counts[name]
+                    for a, b in zip([before] + after, after)]
+
+        misses = [
+            a["seq"] for a in sched.observer.anomalies()
+            if a["class"] == "fold_miss"
+        ]
+        if node_event:
+            assert rise("fold_declined") == [0] * cycles
+            assert rise("full_encodes") == [1] * cycles
+            assert misses == [r.seq for r in after]
+        elif depth == 10:
+            assert rise("fold_declined") == [1] * cycles
+            assert rise("full_encodes") == [1] * cycles
+            assert rise("fold_hits") == [0] * cycles
+            assert misses == []
+        else:
+            assert rise("fold_declined") == [0] * cycles
+            assert rise("full_encodes") == [0] * cycles
+            assert rise("fold_removed_pods") == [old_out] * cycles
+            assert misses == []
+        # no collector policy in this process: the records keep no count
+        # of its sweeps (None, never 0)
+        assert "gc_sweeps" not in after[-1].counts
+    finally:
+        client.close()
+        server.stop(grace=None)
+
+
 def test_bound_pods_deleted_from_the_middle_cost_no_full_encode():
     """The agent path with the rehearsal's pads: after the warm-up, every
     `Update` deletes bound pods from the middle of the resident set
