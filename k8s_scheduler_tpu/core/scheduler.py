@@ -200,6 +200,10 @@ class Scheduler:
         # else: None leaves the interpreter's collector alone. Held here
         # because the flight records carry its count of sweeps
         self.collector = None
+        # Update RPCs the servicer has handled (service/server.py counts
+        # them here because the flight records carry the total); None
+        # where no servicer feeds this scheduler
+        self.update_rpcs: int | None = None
         self.queue = SchedulingQueue(
             initial_backoff_seconds=self.config.pod_initial_backoff_seconds,
             max_backoff_seconds=self.config.pod_max_backoff_seconds,
@@ -2819,6 +2823,11 @@ class Scheduler:
             # after a cycle's response has left, so a record carries
             # those up to the cycle before); no policy, no count
             rec.counts["gc_sweeps"] = self.collector.sweeps
+        if self.update_rpcs is not None:
+            # the Update RPCs the servicer handled before this cycle:
+            # two a cycle where the agent sends each batch whole, more
+            # where batched() flushed it in chunks
+            rec.counts["update_rpcs"] = self.update_rpcs
         if self._pod_spans:
             self._emit_cycle_spans(rec, pending, speculation, row_window)
         self._commit_traced(rec)

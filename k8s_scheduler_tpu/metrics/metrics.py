@@ -228,6 +228,10 @@ cmd/main.py startup stamp):
   pass, freeze: once the pods and nodes that left since the last one
   are at least 1,000 and over a quarter of those resident); the flight
   records carry the same running total as `gc_sweeps`
+- scheduler_update_rpcs_total — Update RPCs the servicer has handled
+  (an agent's batched() block is several: it flushes the open batch
+  in chunks while it builds it); the flight records carry the same
+  running total as `update_rpcs`
 - scheduler_alerts_total{rule,severity} — declarative alert-rule
   firings from the in-process watchtower (metrics/rules.py; one
   increment per ok->firing transition, never per evaluation); the
@@ -717,6 +721,12 @@ class SchedulerMetrics:
             "Sweeps the collector's policy placed after a cycle's end "
             "(unfreeze, full pass, freeze), by departures since the "
             "last one.",
+            registry=r,
+        )
+        self.update_rpcs = Counter(
+            "scheduler_update_rpcs_total",
+            "Update RPCs the servicer has handled (an agent's batched() "
+            "block is several chunks).",
             registry=r,
         )
         self.alerts = Counter(

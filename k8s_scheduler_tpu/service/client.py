@@ -70,6 +70,14 @@ class SchedulerClient:
     def update(self, request: pb.UpdateRequest, timeout: float = 10.0):
         return self._update(request, timeout=timeout)
 
+    def update_future(
+        self, request: pb.UpdateRequest, timeout: float = 10.0
+    ) -> grpc.Future:
+        """`update` without the wait: the request is serialised and
+        handed to gRPC before this returns, and `.result()` gives the
+        response or raises what `update` would have raised."""
+        return self._update.future(request, timeout=timeout)
+
     def cycle(self, timeout: float = 120.0) -> pb.CycleResponse:
         return self._cycle(pb.CycleRequest(), timeout=timeout)
 
@@ -150,10 +158,21 @@ class SchedulerClient:
 # bind_applier(pod_uid, pod_name, namespace, node_name) -> None; raise = failed
 BindApplier = Callable[[str, str, str, str], None]
 
-# an open batched() request is sent on once it holds this much: the
+# an open batched() request is sent on once it holds this much,
+# whatever is in flight (the one in flight is waited for first): the
 # server keeps gRPC's default 4 MiB receive limit (service/server.py
 # sets none), and a 10k-pod re-list or bind confirmation is larger
 MAX_UPDATE_BYTES = 3 * 1024 * 1024
+# ... and, while no Update of this agent is in flight, already once it
+# holds this much: the server converts and applies one chunk while the
+# agent converts the next. A request's fixed cost is about a millisecond
+# on each side of the wire (and three spans when the ring is armed); a
+# whole pod is ~240 bytes and ~30 us to convert on each side, so 128 KiB
+# is ~500 pods and ~15 ms of work on each side against that
+# millisecond. What is in flight is ONE chunk, so a server slower than
+# the agent gets few large chunks and a faster one many of this size; a
+# batch that never reaches it is one request
+FLUSH_FLOOR_BYTES = 128 * 1024
 
 
 class SchedulerAgent:
@@ -189,6 +208,9 @@ class SchedulerAgent:
         self._boot_id: str | None = None  # shim incarnation last fed state
         self._batch: pb.UpdateRequest | None = None  # open batched() request
         self._batch_bytes = 0
+        # the chunk of the open batch the server has not acknowledged
+        # yet, and its call: never more than this one (batched())
+        self._unacked: tuple[pb.UpdateRequest, grpc.Future] | None = None
 
     # ---- informer-side entry points -------------------------------------
 
@@ -332,21 +354,38 @@ class SchedulerAgent:
 
     @contextlib.contextmanager
     def batched(self) -> Iterator[None]:
-        """Coalesce the upserts/deletes inside the block into as few
-        Update RPCs as fit the server's message limit (one, up to
-        MAX_UPDATE_BYTES) — the informer re-list path would otherwise pay
-        one round-trip per object (10k pods = 10k RPCs). Nesting reuses
-        the open batch."""
+        """Coalesce the upserts/deletes inside the block into few Update
+        RPCs, flushed while the block still builds them: the open batch
+        is sent on as a chunk whenever it holds FLUSH_FLOOR_BYTES and
+        the previous chunk has been acknowledged, and at
+        MAX_UPDATE_BYTES (the server's message limit) whatever is in
+        flight, after a wait for it. So the server converts and applies
+        one chunk while the agent converts the next, the chunk size
+        follows how fast the server acknowledges, and a batch under the
+        floor is one request. Never two Updates of this agent in flight
+        (the server would keep no order between them): chunks arrive in
+        the order of the calls. The block's exit sends what is left and
+        returns once the last chunk is acknowledged, so a Cycle asked
+        after it sees every object. Every chunk's response is handled as
+        an Update's outside a block is (`_acknowledged`), no later than
+        the exit. Nesting reuses the open batch."""
         if self._batch is not None:
             yield
             return
         self._batch, self._batch_bytes = pb.UpdateRequest(), 0
         try:
             yield
-            if self._batch.ByteSize():
+            self._collect()
+            if self._batch_bytes:
                 self._send_now(self._batch)
         finally:
             self._batch = None
+            if self._unacked is not None:
+                # the block raised with a chunk on its way: wait for it,
+                # or the next Update would be a second one in flight
+                _, call = self._unacked
+                self._unacked = None
+                call.exception()
 
     def _send(self, request: pb.UpdateRequest) -> None:
         if self._batch is None:
@@ -354,13 +393,40 @@ class SchedulerAgent:
             return
         self._batch.MergeFrom(request)
         self._batch_bytes += request.ByteSize()
-        if self._batch_bytes >= MAX_UPDATE_BYTES:
-            full = self._batch
-            self._batch, self._batch_bytes = pb.UpdateRequest(), 0
-            self._send_now(full)
+        if self._batch_bytes >= MAX_UPDATE_BYTES or (
+            self._batch_bytes >= FLUSH_FLOOR_BYTES
+            and (self._unacked is None or self._unacked[1].done())
+        ):
+            self._flush()
+
+    def _flush(self) -> None:
+        """Send the open batch on as a chunk, once the chunk before it
+        has been acknowledged and its response handled."""
+        self._collect()
+        chunk = self._batch
+        self._batch, self._batch_bytes = pb.UpdateRequest(), 0
+        self._unacked = (chunk, self.client.update_future(chunk))
+
+    def _collect(self) -> None:
+        """Wait for the chunk in flight, if any, and handle its response."""
+        if self._unacked is None:
+            return
+        request, call = self._unacked
+        self._unacked = None
+        resp = self._with_recovery(
+            call.result, retry=lambda: self.client.update(request)
+        )
+        self._acknowledged(request, resp)
 
     def _send_now(self, request: pb.UpdateRequest) -> None:
         resp = self._with_recovery(lambda: self.client.update(request))
+        self._acknowledged(request, resp)
+
+    def _acknowledged(
+        self, request: pb.UpdateRequest, resp: pb.UpdateResponse
+    ) -> None:
+        """What an Update's response asks of the agent; nothing of this
+        agent is in flight when it runs."""
         if self._boot_changed(resp.boot_id):
             # state before this delta is gone: replay everything (the delta
             # itself was applied to the fresh shim, and relist re-sends the
@@ -390,7 +456,10 @@ class SchedulerAgent:
         if size:
             self._send_now(full)
 
-    def _with_recovery(self, call):
+    def _with_recovery(self, call, retry=None):
+        """`call()`, and once more (`retry()`, where another call has to
+        be made for it: a future gives its first answer again) after a
+        relist if the shim was unavailable."""
         try:
             return call()
         except grpc.RpcError as e:
@@ -401,7 +470,7 @@ class SchedulerAgent:
                 raise
             # shim restarted (or hiccuped): replay the full state, retry once
             self.relist()
-            return call()
+            return (retry or call)()
 
     def relist(self) -> None:
         """Replay everything we know into a (possibly fresh) shim."""

@@ -81,6 +81,9 @@ class SchedulerService:
         # plugin-latency histograms stay populated in steady serving
         self.profile_every = int(profile_every)
         self._cycle_count = 0
+        # Update RPCs handled: the scheduler holds the running total,
+        # because its flight records carry it (None with no servicer)
+        self.scheduler.update_rpcs = 0
         # submission front door (service/admission.py): None until
         # enable_front_door() — the Submit/NodeChurn RPCs answer
         # FAILED_PRECONDITION while disabled
@@ -156,7 +159,10 @@ class SchedulerService:
         interleaved pass gave; a request with an unparseable object now
         fails before any of it is applied. Armed, the RPC is one trace
         (core/spans): `rpc.update` with `update.convert` and
-        `update.apply` as its children, four clock reads in all."""
+        `update.apply` as its children, four clock reads in all. Each
+        request handled is counted (`update_rpcs` in the flight records,
+        `scheduler_update_rpcs_total`): an agent's batched() block is
+        several."""
         armed = _spans.ARMED
         if armed:
             trace, caller = _spans.rpc_context(_traceparent(context))
@@ -275,6 +281,8 @@ class SchedulerService:
                 node_events=node_events, bind_confirms=confirmed,
                 confirm_fallbacks=len(unconfirmed),
             )
+        s.update_rpcs += 1
+        s.metrics.update_rpcs.inc()
         return pb.UpdateResponse(
             boot_id=self.boot_id, bind_confirms_applied=confirmed,
             unconfirmed=unconfirmed,

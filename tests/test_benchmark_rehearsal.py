@@ -48,6 +48,10 @@ ALWAYS_READ = (
 # `fold_declined_per_cycle`) need two records in the window to rise
 # through, like `full_encodes_per_cycle`: printed, not owed
 WHERE_PODS_FIT_NOWHERE = ("loser_loop_ms", "postfilter_ms")
+# ... but for the servicer's count of `Update` RPCs (PR 39), which every
+# record of a served scheduler carries: every cell's rehearsal runs
+# several cycles, and each follows at least its two `Update`s
+RPCS_PER_CYCLE = "update_rpcs_per_cycle"
 
 
 def owed_by(cell: str) -> tuple:
@@ -101,3 +105,4 @@ def test_rehearsal(cell, trace):
     owed = {n for n in of_cell if n.split(".")[0] in bases}
     assert len(owed) == len(bases), owed
     assert owed <= printed, owed - printed
+    assert line["metrics"][RPCS_PER_CYCLE]["value"] >= 2.0
