@@ -39,6 +39,17 @@ module makes that whole life one trace:
   are its children. Stamps are per RPC and per phase, never per pod,
   and no context is registered; `any_context()` lets the scheduler
   skip its per-pod stamp sites in every cycle where none is.
+- The agent's side — `service/client.py` stamps six `client.*` spans
+  around its `Update`s and `Cycle`s, in ITS process, and ships the
+  completed ones as one binary metadata entry of its next RPC
+  (`Outbox`, at most `SHIP_MAX_BYTES` a call). The armed servicer hands
+  the entry to `ingest`, which bounds the client's clock against the
+  recorder's from both sides (`_Peer`) and stores the spans in the ring
+  under the ids the client minted, on the recorder's clock: `rpc.*` is
+  the child of `client.update` / `client.cycle` by the `traceparent`
+  the same call carried. A call whose spans cannot be placed (the
+  bounds wider apart than `PLACE_WINDOW_S`) stores none of them and
+  counts them.
 - Export — `spans_to_chrome_events` renders per-trace tracks that
   `to_chrome_trace` merges into the cycle lanes (one Perfetto view
   shows a pod's spans overlapping the batch that served it; the
@@ -61,9 +72,11 @@ import itertools
 import json
 import os
 import re
+import struct
 import threading
 import time as _time
 import uuid
+from collections import deque
 from typing import Any, Callable, Iterable
 
 # The pinned span-name inventory — every stamp site emits one of
@@ -111,6 +124,23 @@ from typing import Any, Callable, Iterable
 #                   or the front door's loop; a generation-2 pass the
 #                   interpreter started itself (`auto_full`) on the
 #                   thread whose allocation set it off
+#   the agent's process (service/client.py; stamped there per block and
+#   per request, never per pod, shipped with the agent's next RPC and
+#   stored here by `ingest` on the recorder's clock — see
+#   CLIENT_SPAN_NAMES): client.batch (the outermost
+#                   `SchedulerAgent.batched()` block, entry -> its last
+#                   chunk acknowledged; a trace of its own),
+#                   client.build (a chunk opened -> its send begins:
+#                   the agent converting), client.send (an `Update`
+#                   serialised and handed to gRPC), client.ack_wait
+#                   (the agent blocked on the `Update` in flight; only
+#                   where it blocked), all three children of
+#                   client.batch, client.update (one `Update` request,
+#                   send begins -> response in the agent's hands;
+#                   `rpc.update` is its child), client.cycle
+#                   (`SchedulerClient.cycle()` called -> the decoded
+#                   response returned; a trace of its own, `rpc.cycle`
+#                   its child)
 SPAN_NAMES = (
     "submit.validate",
     "submit.journal",
@@ -135,6 +165,12 @@ SPAN_NAMES = (
     "cycle.losers",
     "cycle.respond",
     "gc.pass",
+    "client.batch",
+    "client.build",
+    "client.send",
+    "client.ack_wait",
+    "client.update",
+    "client.cycle",
 )
 
 # the agent path's spans (Update / Cycle): per RPC and per phase, never
@@ -146,6 +182,21 @@ AGENT_SPAN_NAMES = frozenset(
     n for n in SPAN_NAMES
     if n.startswith(("rpc.", "update.", "cycle.", "gc."))
 )
+
+# the agent's own spans, in the order of their index on the wire, each
+# with the names of the integers it ships (`Outbox.add`'s `a`, `b`).
+# They overlap the RPC lane's spans without nesting in them (a
+# `client.build` runs under the previous chunk's `rpc.update`), so
+# they render on a lane of their own
+CLIENT_SPAN_ATTRS = {
+    "client.batch": ("requests", "bytes"),
+    "client.build": ("objects",),
+    "client.send": ("bytes",),
+    "client.ack_wait": (),
+    "client.update": ("bytes", "objects"),
+    "client.cycle": ("bindings", "events"),
+}
+CLIENT_SPAN_NAMES = tuple(n for n in SPAN_NAMES if n in CLIENT_SPAN_ATTRS)
 
 # default head-sampling rate (absent an explicit traceparent): 1/64
 DEFAULT_SAMPLE_RATE = 1.0 / 64.0
@@ -306,9 +357,15 @@ class SpanRecorder:
             name=name,
             t0=t0,
             t1=t1,
-            seq=next(self._seq),
             attrs=attrs,
         )
+        return self.ingest(span)
+
+    def ingest(self, span: Span) -> Span:
+        """Store a span under the ids it came with: `record`'s tail,
+        and the way in for a span another process minted (the agent's
+        `client.*`, already on this recorder's clock)."""
+        span.seq = next(self._seq)
         self._ring[span.seq % self.capacity] = span
         # publish AFTER the slot store (GIL-ordered); see class doc
         # for why the racy increment is safe here
@@ -402,6 +459,8 @@ def disarm() -> None:
     _COUNTER = None
     with _ctx_lock:
         _contexts.clear()
+    with _peer_lock:
+        _peers.clear()
 
 
 def now() -> float:
@@ -493,12 +552,171 @@ def record_span(
     if rec is None:
         return
     rec.record(name, ctx, t0, t1, root_of=root_of, **attrs)
+    _count(name)
+
+
+def _count(name: str) -> None:
     cb = _COUNTER
     if cb is not None:
         try:
             cb(name)
         except Exception:  # schedlint: disable=RB001 -- observability counter failure must never reach a stamp site on the serve/submit path
             pass
+
+
+# ---- the agent's spans: shipped by the client, ingested here -------------
+
+# the invocation-metadata key of the one binary entry a call carries
+CLIENT_SPANS_KEY = "client-spans-bin"
+# ... and its size limit: what does not fit is dropped, oldest first
+SHIP_MAX_BYTES = 4096
+# the entry: a head (who ships: 8 random bytes a client; the client's
+# clock when it handed the call to gRPC; spans it dropped since its last
+# call) and fixed-width spans (index into CLIENT_SPAN_NAMES, trace id,
+# span id, parent, t0 and t1 on the client's clock, two integers named
+# by CLIENT_SPAN_ATTRS)
+_SHIP_HEAD = struct.Struct("<8sdI")
+_SHIP_SPAN = struct.Struct("<B16s8s8sddII")
+SHIP_MAX_SPANS = (SHIP_MAX_BYTES - _SHIP_HEAD.size) // _SHIP_SPAN.size
+# a client's spans are stored only while the two bounds on its clock
+# stand no wider apart than this: a stored span may sit that far from
+# its true place on the recorder's clock, and usually sits within a
+# fraction of it (the two ways of the wire cost about the same). The
+# bounds close to the connection's best round trip AS PYTHON SEES IT:
+# 1.3-3.6 ms on one host (gRPC's call set-up, a thread hand-off on each
+# side; PERF.md section 6), where the clocks are one and the offset
+# came out as 0.1-0.6 ms. The nesting of `rpc.*` in its `client.*`
+# parent does not hang on the width: see `ingest`
+PLACE_WINDOW_S = 10e-3
+_MAX_PEERS = 64
+_MAX_EXITS = 16
+
+
+class Outbox:
+    """The completed `client.*` spans of one client, waiting for its
+    next RPC. Bounded to what one call may carry: a span added to a full
+    outbox pushes the oldest out, and `shipment` tells the server how many
+    went that way. A span completed after the process's last RPC is
+    never shipped."""
+
+    def __init__(self) -> None:
+        self.client_id = os.urandom(8)
+        self._spans: deque = deque(maxlen=SHIP_MAX_SPANS)
+        self.dropped = 0
+
+    def add(self, name: str, trace_id: str, span_id: str, parent: str,
+            t0: float, t1: float, a: int = 0, b: int = 0) -> None:
+        if len(self._spans) == SHIP_MAX_SPANS:
+            self.dropped += 1
+        self._spans.append(_SHIP_SPAN.pack(
+            CLIENT_SPAN_NAMES.index(name), bytes.fromhex(trace_id),
+            bytes.fromhex(span_id), bytes.fromhex(parent), t0, t1,
+            min(a, 0xFFFFFFFF), min(b, 0xFFFFFFFF)))
+
+    def shipment(self, sent: float) -> bytes:
+        """The metadata entry of a call handed to gRPC at `sent` on the
+        client's clock; empties the outbox."""
+        entry = _SHIP_HEAD.pack(
+            self.client_id, sent, self.dropped) + b"".join(self._spans)
+        self._spans.clear()
+        self.dropped = 0
+        return entry
+
+
+class _Peer:
+    """What the recorder knows of one shipping client's clock: the
+    offset (recorder's less client's) bounded from both sides. `up` is
+    the least (handler entry - the client's clock at send) and `lo` the
+    greatest (handler exit - the client's clock at the response) over
+    its calls: each sample is off by one way of the wire, never by less
+    than nothing. `exits` holds the handler exits by the `client.*` span
+    that called, until that span arrives."""
+
+    __slots__ = ("up", "lo", "offset", "exits", "unplaced")
+
+    def __init__(self) -> None:
+        self.up, self.lo = float("inf"), float("-inf")
+        self.offset: "float | None" = None
+        self.exits: "dict[str, float]" = {}
+        self.unplaced = 0
+
+
+_peer_lock = threading.Lock()
+_peers: "dict[bytes, _Peer]" = {}
+
+
+def ingest(blob: bytes, caller: str, t_in: float, t_out: float) -> int:
+    """Take in the `client.*` spans one `Update` or `Cycle` carried
+    (`CLIENT_SPANS_KEY`), the armed servicer's call once its own `rpc.*`
+    span is recorded; `caller` is the `client.*` span the call's
+    `traceparent` named, `t_in` and `t_out` the handler's entry and exit
+    on the recorder's clock. Returns the spans stored.
+
+    One clock: the spans come on the client's `perf_counter` and are
+    stored on the recorder's, by an offset between the two bounds
+    `_Peer` keeps: their midpoint, taken once and again when the bounds
+    have closed away from it, so that a client's spans keep their order
+    whatever call carried them. Both bounds hold for every call they
+    were taken from, so with any offset between them a `client.update`
+    or `client.cycle` begins before its `rpc.*` child and ends after
+    it: exactly, however wide they stand. How far the pair may sit from
+    its true place is that width: on one host both clocks are
+    CLOCK_MONOTONIC and the bounds close around 0 to the loopback's
+    round trip, across hosts to the connection's best. While a client
+    is bounded from one side only (its first calls) or the bounds stand
+    wider apart than PLACE_WINDOW_S, the call's spans are dropped and
+    counted, never guessed: the count rides as `unplaced` on the next
+    span stored for that client, as the client's own `dropped` does.
+    Bounds that cross (a clock was stepped, or drifted over a long
+    connection) start again from this call. A malformed entry stores
+    nothing and raises nothing."""
+    rec = RECORDER
+    n, rest = divmod(len(blob) - _SHIP_HEAD.size, _SHIP_SPAN.size)
+    if rec is None or n < 0 or rest:
+        return 0
+    client_id, sent, dropped = _SHIP_HEAD.unpack_from(blob)
+    shipped = [
+        s for s in _SHIP_SPAN.iter_unpack(blob[_SHIP_HEAD.size:])
+        if s[0] < len(CLIENT_SPAN_NAMES)
+    ]
+    with _peer_lock:
+        peer = _peers.pop(client_id, None) or _Peer()
+        _peers[client_id] = peer  # newest last
+        if len(_peers) > _MAX_PEERS:
+            _peers.pop(next(iter(_peers)))
+        peer.up = min(peer.up, t_in - sent)
+        for s in shipped:
+            left = peer.exits.pop(s[2].hex(), None)
+            if left is not None:
+                peer.lo = max(peer.lo, left - s[5])
+        if caller:
+            peer.exits[caller] = t_out
+            if len(peer.exits) > _MAX_EXITS:
+                peer.exits.pop(next(iter(peer.exits)))
+        if peer.lo > peer.up:
+            peer.up, peer.lo, peer.offset = t_in - sent, float("-inf"), None
+        width = peer.up - peer.lo
+        if width > PLACE_WINDOW_S:  # also while `lo` is unbounded
+            peer.unplaced += len(shipped)
+            return 0
+        middle, offset = (peer.up + peer.lo) / 2, peer.offset
+        if offset is None or abs(offset - middle) > width / 4:
+            offset = peer.offset = middle
+        extra = {}
+        if dropped:
+            extra["dropped"] = dropped
+        if peer.unplaced:
+            extra["unplaced"], peer.unplaced = peer.unplaced, 0
+    for index, trace_id, span_id, parent, t0, t1, a, b in shipped:
+        name = CLIENT_SPAN_NAMES[index]
+        attrs = dict(zip(CLIENT_SPAN_ATTRS[name], (a, b)), **extra)
+        extra = {}
+        rec.ingest(Span(
+            trace_id.hex(), span_id.hex(),
+            parent.hex() if any(parent) else "", name,
+            t0 + offset, t1 + offset, attrs=attrs))
+        _count(name)
+    return len(shipped)
 
 
 # ---- export --------------------------------------------------------------
@@ -511,6 +729,16 @@ TRACE_TRACK_PID = 2
 # per RPC would be thousands of one-slice tracks
 AGENT_LANE_PID = 1
 AGENT_LANE_TID = 4
+# ... and the agent's own spans one lane above it: the caller, the
+# servicer, the host, the device, top down
+CLIENT_LANE_TID = 5
+# (span names, tid, sort index, lane name)
+_LANES = (
+    (AGENT_SPAN_NAMES, AGENT_LANE_TID, 0, "agent RPCs (Update/Cycle)"),
+    (frozenset(CLIENT_SPAN_NAMES), CLIENT_LANE_TID, -1,
+     "agent process (client.*)"),
+)
+_LANE_OF = {n: tid for names, tid, _, _ in _LANES for n in names}
 
 
 def spans_to_chrome_events(
@@ -521,16 +749,17 @@ def spans_to_chrome_events(
     carry the span/parent ids and attrs — the flight-record `seq` attr
     included, which is the exemplar join back to the cycle lanes. The
     agent RPC spans (AGENT_SPAN_NAMES) share one lane beside the host
-    lane; children nest inside their RPC by time."""
+    lane; children nest inside their RPC by time. The agent's own
+    (CLIENT_SPAN_NAMES) take the lane above it."""
     events: "list[dict]" = []
     tids: "dict[str, int]" = {}
     uids: "dict[str, set]" = {}
     tenants: "dict[str, set]" = {}
     spans = list(spans)
-    agent_lane = False
+    lanes = set()
     for s in spans:
-        if s.name in AGENT_SPAN_NAMES:
-            agent_lane = True
+        if s.name in _LANE_OF:
+            lanes.add(_LANE_OF[s.name])
             continue
         tid = tids.setdefault(s.trace_id, len(tids) + 1)
         uid = s.attrs.get("uid")
@@ -539,14 +768,16 @@ def spans_to_chrome_events(
         tn = s.attrs.get("tenant")
         if tn:
             tenants.setdefault(s.trace_id, set()).add(tn)
-    if agent_lane:
+    for _, lane, at, title in _LANES:
+        if lane not in lanes:
+            continue
         events.append(
             {
                 "name": "thread_name",
                 "ph": "M",
                 "pid": AGENT_LANE_PID,
-                "tid": AGENT_LANE_TID,
-                "args": {"name": "agent RPCs (Update/Cycle)"},
+                "tid": lane,
+                "args": {"name": title},
             }
         )
         events.append(
@@ -554,8 +785,8 @@ def spans_to_chrome_events(
                 "name": "thread_sort_index",
                 "ph": "M",
                 "pid": AGENT_LANE_PID,
-                "tid": AGENT_LANE_TID,
-                "args": {"sort_index": 0},
+                "tid": lane,
+                "args": {"sort_index": at},
             }
         )
     if tids:
@@ -594,13 +825,13 @@ def spans_to_chrome_events(
             }
         )
     for s in spans:
-        agent = s.name in AGENT_SPAN_NAMES
+        lane = _LANE_OF.get(s.name)
         events.append(
             {
                 "name": s.name,
                 "ph": "X",
-                "pid": AGENT_LANE_PID if agent else TRACE_TRACK_PID,
-                "tid": AGENT_LANE_TID if agent else tids[s.trace_id],
+                "pid": AGENT_LANE_PID if lane else TRACE_TRACK_PID,
+                "tid": lane or tids[s.trace_id],
                 "ts": round((s.t0 - epoch) * 1e6, 3),
                 "dur": round(max(s.t1 - s.t0, 0.0) * 1e6, 3),
                 "cat": "pod-trace",

@@ -204,7 +204,10 @@ cmd/main.py startup stamp):
   update.apply | rpc.cycle | cycle.lock_wait | cycle.pop |
   cycle.snapshot | cycle.postfilter | cycle.losers | cycle.respond)
   and the collector's passes
-  (gc.pass); the inventory is
+  (gc.pass), and the agent's own side of those RPCs, stamped in its
+  process and shipped into this ring (client.batch | client.build |
+  client.send | client.ack_wait | client.update | client.cycle:
+  service/client.py, core/spans.ingest); the inventory is
   core/spans.SPAN_NAMES, machine-checked by schedlint ID010 against
   this docstring and the README span table; spans serve at
   /debug/traces and join /debug/explain verdicts
@@ -670,8 +673,8 @@ class SchedulerMetrics:
         # ---- pod-lifecycle tracing / build identity (core/spans.py) ----
         self.trace_spans = Counter(
             "scheduler_trace_spans_total",
-            "Trace spans recorded, by span name: pod-lifecycle spans "
-            "and the agent path's per-RPC spans (the "
+            "Trace spans recorded, by span name: pod-lifecycle spans, "
+            "the agent path's per-RPC spans and the agent's own (the "
             "core/spans.SPAN_NAMES inventory; serves /debug/traces).",
             ["name"],
             registry=r,

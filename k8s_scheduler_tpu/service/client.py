@@ -7,6 +7,46 @@ back, and — because the shim is stateless like upstream's scheduler
 (SURVEY.md §5.3) — recovers from a shim restart by re-listing everything it
 knows. A binding the agent fails to apply is reported as a bind_failure so
 the shim forgets the assumption and backs the pod off.
+
+The agent's side of `Update` and `Cycle` is traced (core/spans), while
+the server it talks to has its span ring armed and only then: every
+armed `Update` and `Cycle` response carries the server's `traceparent`
+in its trailing metadata, and `SchedulerClient.tracing` follows the
+last one heard. Against an unarmed server every stamp site below is one
+attribute load and a falsy branch, no clock is read and no metadata is
+sent. Armed, six spans are stamped on this process's `perf_counter`, per
+block and per request, never per pod:
+
+- `client.batch`: the outermost `batched()` block, entry to exit
+  (`requests`, `bytes`); a trace of its own, and the parent of
+- `client.build`: a chunk opened (block entry, or the flush before it
+  returned) to its send beginning: the agent converting and its
+  caller's loop (`objects`);
+- `client.send`: `update_future()` called to returned: the request
+  serialised and handed to gRPC (`bytes`);
+- `client.ack_wait`: the wait for the `Update` in flight, where it
+  blocked: the agent standing still because the server is the slower
+  side;
+- `client.update`: one `Update` request, send begun to the response
+  landed (a done-callback takes the time, so it does not stretch while
+  the agent builds the next chunk; `bytes`, `objects`); child of the
+  block, or the root of a trace of its own (its `client.send` and
+  `client.ack_wait` then its children); the server's `rpc.update` is
+  its child, by the `traceparent` the request carries;
+- `client.cycle`: `SchedulerClient.cycle()` called to the decoded
+  response returned (`bindings`, `events`); a trace of its own, the
+  server's `rpc.cycle` its child.
+
+A completed span waits in the client's `Outbox` and goes with the next
+`Update` or `Cycle` as one binary metadata entry
+(`core/spans.CLIENT_SPANS_KEY`, at most `SHIP_MAX_BYTES`: what does not
+fit is dropped oldest first, and the count goes along), with this
+process's clock at the hand-off; the server stores them in its ring on
+its own clock (`core/spans.ingest`), so `/debug/traces` holds both
+sides of every RPC. What is lost: the spans of a client's first call
+(it has not heard yet that the ring is armed), those the server could
+not place (counted there), and whatever completes after the process's
+last RPC, which is never shipped.
 """
 
 from __future__ import annotations
@@ -16,10 +56,74 @@ from typing import Callable, Iterator
 
 import grpc
 
+from ..core import spans as _spans
 from ..models.api import Node, Pod, PodGroup
 from . import convert
 from . import scheduler_pb2 as pb
 from .server import SERVICE_NAME
+
+
+class _Block:
+    """An open `client.batch`: the ids its children name, when it and
+    its open chunk began, and what its requests add up to."""
+
+    __slots__ = ("trace_id", "span_id", "t0", "opened", "requests", "bytes")
+
+    def __init__(self, t0: float) -> None:
+        self.trace_id, self.span_id = _spans.new_trace_id(), _spans.new_span_id()
+        self.t0 = self.opened = t0
+        self.requests = self.bytes = 0
+
+
+class UpdateCall:
+    """An `Update` in flight: gRPC's future, and what the client does
+    at the call's end, once, whichever of `result()` and `exception()`
+    is asked first: it hears from the response whether the server's ring
+    is armed and, where the call was traced, stamps the part of the wait
+    that blocked (`client.ack_wait`) and the request (`client.update`)."""
+
+    __slots__ = ("_client", "_future", "_span")
+
+    def __init__(self, client: "SchedulerClient", future: grpc.Future,
+                 span: tuple | None) -> None:
+        self._client, self._future, self._span = client, future, span
+
+    def done(self) -> bool:
+        return self._future.done()
+
+    def result(self) -> pb.UpdateResponse:
+        self._settle()
+        return self._future.result()
+
+    def exception(self):
+        self._settle()
+        return self._future.exception()
+
+    def _settle(self) -> None:
+        client, future, span = self._client, self._future, self._span
+        if client is None:
+            return
+        self._client = None  # schedlint: disable=TR001 -- a call is waited for by the one thread that made it (the agent's); the write only marks it settled
+        if span is not None:
+            trace_id, span_id, block_id, t0, size, objects, landed = span
+            if not future.done():
+                t_wait = _spans.now()
+                future.exception()
+                client.outbox.add(
+                    "client.ack_wait", trace_id, _spans.new_span_id(),
+                    block_id or span_id, t_wait, _spans.now())
+        if future.exception() is None:
+            client._heard(future.trailing_metadata())
+        if span is not None:
+            # the callback runs on gRPC's thread and may trail the wait
+            client.outbox.add(
+                "client.update", trace_id, span_id, block_id, t0,
+                landed[0] if landed else _spans.now(), size, objects)
+
+
+def _objects(request: pb.UpdateRequest) -> int:
+    """The objects a request carries: every field of it is a list."""
+    return sum(len(value) for _, value in request.ListFields())
 
 
 class SchedulerClient:
@@ -30,10 +134,18 @@ class SchedulerClient:
         # effective W3C traceparent from the last submit's trailing
         # metadata ("" until a traced submit acks)
         self.last_traceparent = ""
+        # whether the server's span ring is armed, by its last Update or
+        # Cycle response: the client.* spans are stamped only while it is
+        self.tracing = False
+        # completed client.* spans, until the next Update or Cycle
+        self.outbox = _spans.Outbox()
+        # the open batched() block of the agent on this client, traced
+        self.block: _Block | None = None
         mk = self.channel.unary_unary
         self._update = mk(
             f"/{SERVICE_NAME}/Update",
-            request_serializer=pb.UpdateRequest.SerializeToString,
+            # update_future() serialises: the size is a span's attr
+            request_serializer=None,
             response_deserializer=pb.UpdateResponse.FromString,
         )
         self._cycle = mk(
@@ -68,18 +180,103 @@ class SchedulerClient:
         )
 
     def update(self, request: pb.UpdateRequest, timeout: float = 10.0):
-        return self._update(request, timeout=timeout)
+        """Traced, a future and a wait for it, so that one rule stamps
+        every `Update`: the hand-off is `client.send`, the wait
+        `client.ack_wait`. Untraced, the blocking call it always was (a
+        future costs gRPC a thread, ~0.4 ms)."""
+        if self.tracing:
+            return self.update_future(request, timeout=timeout).result()
+        resp, call = self._update.with_call(
+            request.SerializeToString(), timeout=timeout)
+        self._heard(call.trailing_metadata())
+        return resp
 
     def update_future(
         self, request: pb.UpdateRequest, timeout: float = 10.0
-    ) -> grpc.Future:
+    ) -> UpdateCall:
         """`update` without the wait: the request is serialised and
         handed to gRPC before this returns, and `.result()` gives the
         response or raises what `update` would have raised."""
-        return self._update.future(request, timeout=timeout)
+        if not self.tracing:
+            return UpdateCall(self, self._update.future(
+                request.SerializeToString(), timeout=timeout), None)
+        t0 = _spans.now()
+        block = self.block
+        trace_id = block.trace_id if block else _spans.new_trace_id()
+        span_id = _spans.new_span_id()
+        data = request.SerializeToString()
+        future = self._update.future(
+            data, timeout=timeout, metadata=self._calling(trace_id, span_id))
+        landed: list[float] = []
+        future.add_done_callback(lambda _: landed.append(_spans.now()))
+        block_id = ""
+        if block:
+            block_id = block.span_id
+            block.requests += 1
+            block.bytes += len(data)
+        self.outbox.add(
+            "client.send", trace_id, _spans.new_span_id(),
+            block_id or span_id, t0, _spans.now(), len(data))
+        return UpdateCall(self, future, (
+            trace_id, span_id, block_id, t0, len(data), _objects(request),
+            landed))
 
     def cycle(self, timeout: float = 120.0) -> pb.CycleResponse:
-        return self._cycle(pb.CycleRequest(), timeout=timeout)
+        tracing, metadata = self.tracing, None
+        if tracing:
+            t0 = _spans.now()
+            trace_id, span_id = _spans.new_trace_id(), _spans.new_span_id()
+            metadata = self._calling(trace_id, span_id)
+        resp, call = self._cycle.with_call(
+            pb.CycleRequest(), timeout=timeout, metadata=metadata)
+        self._heard(call.trailing_metadata())
+        if tracing:
+            self.outbox.add(
+                "client.cycle", trace_id, span_id, "", t0, _spans.now(),
+                len(resp.bindings), len(resp.events))
+        return resp
+
+    # ---- the agent's side of the trace (module docstring) ----------------
+
+    def _calling(self, trace_id: str, span_id: str) -> tuple:
+        """A traced call's metadata: the `client.*` span the server's
+        `rpc.*` is the child of, and the spans completed since the last
+        call with this clock's reading at the hand-off."""
+        return (
+            ("traceparent", _spans.format_traceparent(trace_id, span_id)),
+            (_spans.CLIENT_SPANS_KEY, self.outbox.shipment(_spans.now())),
+        )
+
+    def _heard(self, trailing) -> None:
+        """A response's trailing metadata says whether the ring is
+        armed (service/server._agents_side)."""
+        self.tracing = any(  # schedlint: disable=TR001 -- a flag, not state: a client's calls come from its agent's one thread, and a write that raced another would cost one call's spans
+            key == "traceparent" for key, _ in trailing or ())
+
+    def block_begins(self) -> None:
+        if self.tracing:
+            self.block = _Block(_spans.now())
+
+    def chunk_built(self, chunk: pb.UpdateRequest) -> None:
+        """The open chunk is about to be sent (or the block ends)."""
+        block = self.block
+        if block is not None:
+            self.outbox.add(
+                "client.build", block.trace_id, _spans.new_span_id(),
+                block.span_id, block.opened, _spans.now(), _objects(chunk))
+
+    def chunk_opened(self) -> None:
+        block = self.block
+        if block is not None:
+            block.opened = _spans.now()
+
+    def block_ends(self) -> None:
+        """Its last chunk is acknowledged and the response handled."""
+        block, self.block = self.block, None
+        if block is not None:
+            self.outbox.add(
+                "client.batch", block.trace_id, block.span_id, "", block.t0,
+                _spans.now(), block.requests, block.bytes)
 
     def health(self, timeout: float = 5.0) -> pb.HealthResponse:
         return self._health(pb.HealthRequest(), timeout=timeout)
@@ -210,7 +407,7 @@ class SchedulerAgent:
         self._batch_bytes = 0
         # the chunk of the open batch the server has not acknowledged
         # yet, and its call: never more than this one (batched())
-        self._unacked: tuple[pb.UpdateRequest, grpc.Future] | None = None
+        self._unacked: tuple[pb.UpdateRequest, UpdateCall] | None = None
 
     # ---- informer-side entry points -------------------------------------
 
@@ -373,13 +570,17 @@ class SchedulerAgent:
             yield
             return
         self._batch, self._batch_bytes = pb.UpdateRequest(), 0
+        self.client.block_begins()
         try:
             yield
+            self.client.chunk_built(self._batch)
             self._collect()
             if self._batch_bytes:
                 self._send_now(self._batch)
+            self.client.block_ends()
         finally:
             self._batch = None
+            self.client.block = None  # where the block raised: no span
             if self._unacked is not None:
                 # the block raised with a chunk on its way: wait for it,
                 # or the next Update would be a second one in flight
@@ -402,10 +603,12 @@ class SchedulerAgent:
     def _flush(self) -> None:
         """Send the open batch on as a chunk, once the chunk before it
         has been acknowledged and its response handled."""
+        self.client.chunk_built(self._batch)
         self._collect()
         chunk = self._batch
         self._batch, self._batch_bytes = pb.UpdateRequest(), 0
         self._unacked = (chunk, self.client.update_future(chunk))
+        self.client.chunk_opened()
 
     def _collect(self) -> None:
         """Wait for the chunk in flight, if any, and handle its response."""

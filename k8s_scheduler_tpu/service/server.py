@@ -159,7 +159,9 @@ class SchedulerService:
         interleaved pass gave; a request with an unparseable object now
         fails before any of it is applied. Armed, the RPC is one trace
         (core/spans): `rpc.update` with `update.convert` and
-        `update.apply` as its children, four clock reads in all. Each
+        `update.apply` as its children, four clock reads in all, and the
+        child of the caller's `client.update` where the call names one
+        (`_agents_side`). Each
         request handled is counted (`update_rpcs` in the flight records,
         `scheduler_update_rpcs_total`): an agent's batched() block is
         several."""
@@ -281,6 +283,7 @@ class SchedulerService:
                 node_events=node_events, bind_confirms=confirmed,
                 confirm_fallbacks=len(unconfirmed),
             )
+            _agents_side(context, trace, caller, t_in, t_out)
         s.update_rpcs += 1
         s.metrics.update_rpcs.inc()
         return pb.UpdateResponse(
@@ -366,6 +369,8 @@ class SchedulerService:
                     events=n_ev, evictions=len(resp.evictions),
                     **s.last_cycle_sample,
                 )
+        if armed:
+            _agents_side(context, trace, caller, t_in, t_out)
         self._cycle_left_standing(context)
         return resp
 
@@ -528,6 +533,23 @@ def _traceparent(context) -> str:
         if key == "traceparent":
             return value
     return ""
+
+
+def _agents_side(context, trace, caller: str, t_in: float,
+                 t_out: float) -> None:
+    """The end of an armed `Update` or `Cycle`, its `rpc.*` span
+    recorded: tell the caller that the ring is armed, as `Submit` does,
+    by the effective `traceparent` in the trailing metadata
+    (service/client.py stamps its `client.*` spans only while it sees
+    one), and take in the spans the call carried
+    (`core/spans.ingest`)."""
+    if context is None:
+        return
+    context.set_trailing_metadata((("traceparent", trace.traceparent()),))
+    for key, value in context.invocation_metadata() or ():
+        if key == _spans.CLIENT_SPANS_KEY:
+            _spans.ingest(value, caller, t_in, t_out)
+            return
 
 
 _RPCS = {

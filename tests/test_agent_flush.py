@@ -425,21 +425,34 @@ def test_records_carry_update_rpcs_and_it_rises_by_the_requests_sent(
             ) in metrics.expose()
 
 
-def test_a_chunk_costs_three_spans(served, small_floor):
+def test_a_chunk_costs_three_spans_and_the_agent_s_four(served, small_floor):
     """Armed, every Update RPC is `rpc.update` with `update.convert`
-    and `update.apply` and nothing else, so a block that goes in n
-    chunks stamps 3 n spans: what PERF.md reckons the ring by."""
+    and `update.apply` and nothing else on the server's side, so a
+    block that goes in n chunks stamps 3 n spans there. The agent's own
+    (service/client.py) come on top once it has heard that the ring is
+    armed: per chunk a `client.build`, a `client.send` and a
+    `client.update`, a `client.ack_wait` where it waited, and one
+    `client.batch` a block; those the server could place are in the
+    ring too: what PERF.md reckons the ring by."""
     rec = _spans.arm(rate=1.0)
     try:
         s = served()
-        load_nodes(s)
+        load_nodes(s)  # the first call: the agent hears of the ring
+        assert s.client.tracing
         offer(s, pods(150))
         n = len(s.requests)
         assert n > 4
         spans = rec.snapshot()
     finally:
         _spans.disarm()
-    names = sorted(sp.name for sp in spans)
+    served_side = [sp for sp in spans if sp.name not in
+                   _spans.CLIENT_SPAN_NAMES]
+    names = sorted(sp.name for sp in served_side)
     assert names == sorted(
         ["rpc.update", "update.convert", "update.apply"] * n)
-    assert len({sp.trace_id for sp in spans}) == n
+    # the nodes' request is a trace of its own; the block's chunks
+    # join the block's
+    assert len({sp.trace_id for sp in served_side}) == 2
+    agents = [sp.name for sp in spans if sp.name in _spans.CLIENT_SPAN_NAMES]
+    assert len(agents) <= 4 * (n - 1)  # the last chunk's are not shipped
+    assert "client.batch" not in agents and "client.cycle" not in agents

@@ -49,6 +49,9 @@ class Metadata:
     def invocation_metadata(self):
         return (("user-agent", "test"), ("traceparent", self.traceparent))
 
+    def set_trailing_metadata(self, metadata) -> None:
+        self.trailing = dict(metadata)
+
 
 def service(state=None) -> SchedulerService:
     return SchedulerService(
@@ -406,8 +409,12 @@ def test_agent_rpcs_render_on_one_lane(armed):
     assert pod_ev["pid"] == _spans.TRACE_TRACK_PID
 
 
-def test_span_inventory_has_twenty_three_names():
-    assert len(SPAN_NAMES) == len(set(SPAN_NAMES)) == 23
+def test_span_inventory_has_twenty_nine_names():
+    assert len(SPAN_NAMES) == len(set(SPAN_NAMES)) == 29
+    # the agent's own six (service/client.py) have a lane to themselves
+    assert set(_spans.CLIENT_SPAN_NAMES) == {
+        n for n in SPAN_NAMES if n.startswith("client.")}
+    assert len(_spans.CLIENT_SPAN_NAMES) == 6
     # the collector's passes (core/collector.py) share the agent's lane
     assert AGENT_SPAN_NAMES == UPDATE_SPANS | CYCLE_SPANS | LOSER_SPANS | {
         "cycle.snapshot", "gc.pass"}

@@ -36,6 +36,10 @@ ALWAYS_READ = (
     "update_servicer_ms", "update_convert_ms", "update_apply_ms",
     "cycle_servicer_ms", "cycle_respond_ms", "encode_ms", "apply_ms",
     "device_wait_ms", "gc_pass_ms",
+    # the agent's own spans (PR 40), shipped into the server's ring:
+    # one file and entry each, read in every cell
+    "client_batch_ms", "client_build_ms", "client_send_ms",
+    "client_ack_wait_ms", "client_update_ms", "client_cycle_ms",
 )
 # ... and from the loser loop's two spans (PR 36), in a cell whose
 # configuration states `unschedulable.count` > 0: its queue holds pods

@@ -452,6 +452,9 @@ class Context:
     def invocation_metadata(self):
         return ()
 
+    def set_trailing_metadata(self, metadata) -> None:
+        self.trailing = metadata
+
     def add_callback(self, fn) -> bool:
         if self.open:
             self.callbacks.append(fn)
