@@ -20,9 +20,28 @@ the policy is:
   what freezing can leak. Frozen cyclic garbage can only come from
   objects that left the resident set, so the sweep follows departures
   and not the clock: CPython's own 25% rule, over the pods and nodes
-  removed since the last sweep instead of over allocations;
-- all of it placed by the caller where nobody waits: the servicer runs
-  `cycle_done` after `Cycle`'s response has left (service/server.py).
+  removed since the last sweep instead of over allocations. How much
+  of a departure leaks is measured, not presumed: the objects a sweep
+  found (`collected`) over the departures it covered, at most 1, is
+  the rate `q` the next one is placed by. An object found counts as a
+  whole departure: a pod holds ~26, so the rate overstates a leak many
+  times over and never understates one, and any pass that finds as
+  many objects as pods have left puts the schedule back where the
+  presumption had it. `q` starts at 1 (no evidence yet: every
+  departure leaks), a sweep that finds a leak puts it at the rate
+  found at once, and one that finds nothing lets it fall to a quarter,
+  so the next waits four times as long and stale evidence is tested
+  again on a geometric schedule. On the served path sweeps find
+  nothing (0 objects in every one of 66 sweeps a window at 500 nodes
+  and of 2 at 5,000, PERF.md section 6, PR 41): pods leave by
+  reference count;
+- all of it placed by the caller outside `Cycle`: the servicer runs
+  `cycle_done` after the response has left (service/server.py).
+  Somebody waits all the same: a pass holds the interpreter lock, and
+  the agent's next `Update` (the confirmations) arrives a few
+  milliseconds in and stands behind it (94-99% of every sweep, PERF.md
+  section 6, PR 40). A freeze is a few milliseconds; a sweep walks the
+  whole resident set, which is why it has to be rare.
 
 Installed by `cmd/main.main()` and by nothing else: importing this
 module or constructing a `Scheduler` or a `SchedulerService` leaves the
@@ -38,7 +57,11 @@ too many for the ring: they are counted, and `cycle_done` carries the
 counts to `scheduler_gc_young_passes_total` and
 `scheduler_gc_young_pass_seconds_total`. Placed sweeps are counted too
 (`sweeps`, `scheduler_gc_sweeps_total`, and `gc_sweeps` in every flight
-record): a span's `kind` is read by no metric, a count is.
+record): a span's `kind` is read by no metric, a count is. So are the
+cycles after which the departures alone asked for a sweep and the
+measured rate did not (`deferred`, `scheduler_gc_sweeps_deferred_total`,
+`gc_sweeps_deferred`); a sweep's span also carries `left`, the
+departures it covered, and `q`, the rate it was placed by.
 """
 
 from __future__ import annotations
@@ -65,10 +88,13 @@ from . import spans as _spans
 # frozen.
 THRESHOLDS = (700, 10, 1_000)
 
-# Sweep once the pods and nodes that left since the last sweep pass this
-# share of those resident: the share of its old generation by which
-# CPython lets the heap grow before a full pass
-# (`long_lived_pending < long_lived_total / 4`).
+# Sweep once what the pods and nodes that left since the last sweep have
+# leaked (their number times the measured rate `q`) passes this share of
+# those resident: the share of its old generation by which CPython lets
+# the heap grow before a full pass
+# (`long_lived_pending < long_lived_total / 4`). It is also the most `q`
+# falls by in one sweep: a fruitless sweep makes the next wait four
+# times the departures, no more.
 SWEEP_SHARE = 0.25
 # ... and at least this many: a sweep walks every module the process has
 # imported as well, and under a small cluster the rule above would ask
@@ -80,8 +106,9 @@ class CollectorPolicy:
     """`census()` gives (pods and nodes resident, pods and nodes that
     have left since the process began): `Scheduler.census`. `metrics`
     (a `SchedulerMetrics`) receives the two young-pass counters and the
-    count of sweeps; `sweeps` is the same running total, which the
-    scheduler's flight records carry as `gc_sweeps`."""
+    counts of sweeps placed and deferred; `sweeps` and `deferred` are
+    the same running totals, which the scheduler's flight records carry
+    as `gc_sweeps` and `gc_sweeps_deferred`."""
 
     def __init__(
         self, census: Callable[[], "tuple[int, int]"], metrics
@@ -96,6 +123,10 @@ class CollectorPolicy:
         self._swept_at = 0  # departures at the last sweep
         self._placing = 0  # ident of the thread inside a placed operation
         self._t_start = 0.0  # the hook's: when the pass under way began
+        # the share of a departure that leaks, as the last sweep
+        # measured it (objects found over departures covered, at most
+        # 1); 1 until a sweep has run
+        self.q = 1.0
         # `gc.get_freeze_count()` walks every frozen object (~60 ns each
         # on the chip's host: 105-175 ms at 1.1-2.9 million, several times
         # the freeze it would describe). So it is taken after a sweep and
@@ -107,6 +138,9 @@ class CollectorPolicy:
         self.young_seconds = 0.0
         self._young_flushed = (0, 0.0)
         self.sweeps = 0
+        # cycles after which the departures alone asked for a sweep and
+        # `q` did not
+        self.deferred = 0
 
     # ---- install / uninstall --------------------------------------------
 
@@ -137,29 +171,41 @@ class CollectorPolicy:
     # ---- the placed operations ------------------------------------------
 
     def cycle_done(self) -> None:
-        """A scheduling cycle has ended and nobody waits for it: sweep
-        if the departures ask for one, else freeze what the cycle left
-        standing. A cycle that left less than one young pass's worth
-        (no automatic pass has run since the last freeze) is left to
-        the next."""
+        """A scheduling cycle has ended and its response has left:
+        sweep if what the departures have leaked, at the measured rate,
+        asks for one, else freeze what the cycle left standing. A cycle
+        that left less than one young pass's worth (no automatic pass
+        has run since the last freeze) is left to the next."""
         with self._lock:
             if self._restore is None:
                 return
             self._flush_young()
             resident, departed = self._census()
             left = departed - self._swept_at
-            if left >= SWEEP_MIN_DEPARTURES and left > SWEEP_SHARE * resident:
-                self._place("sweep", resident, count=True)
+            due = SWEEP_SHARE * resident
+            # what every departure presumed leaked whole would ask
+            asked = left >= SWEEP_MIN_DEPARTURES and left > due
+            if asked and self.q * left > due:
+                collected = self._place(
+                    "sweep", resident, count=True, left=left, q=self.q)
                 self._swept_at = departed
                 self.sweeps += 1
                 self._metrics.gc_sweeps.inc()
-            elif any(gc.get_count()[1:]):
+                # the rate found, and never under a quarter of the rate
+                # before: one fruitless sweep is no proof
+                self.q = min(
+                    1.0, max(collected / left, self.q * SWEEP_SHARE))
+                return
+            if asked:
+                self.deferred += 1
+                self._metrics.gc_sweeps_deferred.inc()
+            if any(gc.get_count()[1:]):
                 self._place(
                     "freeze", resident,
                     count=resident > 2 * self._counted_at,
                 )
 
-    def _place(self, kind: str, resident: int, count: bool) -> None:
+    def _place(self, kind: str, resident: int, count: bool, **attrs) -> int:
         sweep = kind == "sweep"
         t0 = _spans.now()
         self._placing = threading.get_ident()
@@ -174,7 +220,8 @@ class CollectorPolicy:
             if count:
                 self._frozen = gc.get_freeze_count()
                 self._counted_at = resident
-            self._stamp(kind, t0, 2 if sweep else 1, collected)
+            self._stamp(kind, t0, 2 if sweep else 1, collected, **attrs)
+        return collected
 
     # ---- what the interpreter starts itself -----------------------------
 
@@ -197,7 +244,7 @@ class CollectorPolicy:
     # ---- stamping --------------------------------------------------------
 
     def _stamp(self, kind: str, t0: float, generation: int,
-               collected: int) -> None:
+               collected: int, **attrs) -> None:
         # a trace of its own, with no parent: a pass belongs to no RPC,
         # it delays whichever one is open
         trace = _spans.TraceContext(
@@ -206,7 +253,7 @@ class CollectorPolicy:
         _spans.record_span(
             "gc.pass", trace, t0, _spans.now(), root_of="", kind=kind,
             generation=generation, collected=collected,
-            frozen=self._frozen,
+            frozen=self._frozen, **attrs,
         )
 
     def _flush_young(self) -> None:

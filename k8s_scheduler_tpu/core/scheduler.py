@@ -2821,8 +2821,11 @@ class Scheduler:
         if self.collector is not None:
             # the collector policy's placed sweeps so far (each falls
             # after a cycle's response has left, so a record carries
-            # those up to the cycle before); no policy, no count
+            # those up to the cycle before), and the cycles after which
+            # the departures asked for one and the measured leak did
+            # not; no policy, no counts
             rec.counts["gc_sweeps"] = self.collector.sweeps
+            rec.counts["gc_sweeps_deferred"] = self.collector.deferred
         if self.update_rpcs is not None:
             # the Update RPCs the servicer handled before this cycle:
             # two a cycle where the agent sends each batch whole, more

@@ -229,8 +229,13 @@ cmd/main.py startup stamp):
 - scheduler_gc_young_pass_seconds_total — seconds spent in them
 - scheduler_gc_sweeps_total — sweeps the policy placed (unfreeze, full
   pass, freeze: once the pods and nodes that left since the last one
-  are at least 1,000 and over a quarter of those resident); the flight
+  are at least 1,000 and, times the share of a departure the last
+  sweep found leaked, over a quarter of those resident); the flight
   records carry the same running total as `gc_sweeps`
+- scheduler_gc_sweeps_deferred_total — cycles after which the
+  departures alone (every one presumed leaked whole) asked for a sweep
+  and the measured leak did not; the flight records carry the same
+  running total as `gc_sweeps_deferred`
 - scheduler_update_rpcs_total — Update RPCs the servicer has handled
   (an agent's batched() block is several: it flushes the open batch
   in chunks while it builds it); the flight records carry the same
@@ -723,7 +728,13 @@ class SchedulerMetrics:
             "scheduler_gc_sweeps_total",
             "Sweeps the collector's policy placed after a cycle's end "
             "(unfreeze, full pass, freeze), by departures since the "
-            "last one.",
+            "last one and the leak it measured.",
+            registry=r,
+        )
+        self.gc_sweeps_deferred = Counter(
+            "scheduler_gc_sweeps_deferred_total",
+            "Cycles after which the departures alone asked for a sweep "
+            "and the leak the last sweep measured did not.",
             registry=r,
         )
         self.update_rpcs = Counter(

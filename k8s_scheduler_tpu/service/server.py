@@ -126,14 +126,17 @@ class SchedulerService:
         return stats
 
     def _cycle_left_standing(self, context) -> None:
-        """The collector's turn (core/collector), where no caller waits
-        for it: once gRPC has sent `Cycle`'s response and closed the
-        call, which is when it runs a context's callbacks. A pass holds
-        the interpreter lock, so one placed before the return would be
-        paid by the agent inside `Cycle`; after it, it overlaps with the
-        agent building its next `Update` in another process. The front
-        door's loop has no caller, and an in-process one (no context) no
-        such moment: there it runs at once."""
+        """The collector's turn (core/collector), outside the RPC: once
+        gRPC has sent `Cycle`'s response and closed the call, which is
+        when it runs a context's callbacks. A pass holds the interpreter
+        lock, so one placed before the return would be paid by the agent
+        inside `Cycle`; after it, it overlaps with the agent handling
+        the response and building its next `Update` in another process,
+        for as long as that takes: a few milliseconds since the
+        confirmations go by reference, so a freeze hides there and a
+        sweep does not (which is why the policy keeps sweeps rare). The
+        front door's loop has no caller, and an in-process one (no
+        context) no such moment: there it runs at once."""
         collector = self.collector
         if collector is None:
             return

@@ -56,6 +56,9 @@ WHERE_PODS_FIT_NOWHERE = ("loser_loop_ms", "postfilter_ms")
 # record of a served scheduler carries: every cell's rehearsal runs
 # several cycles, and each follows at least its two `Update`s
 RPCS_PER_CYCLE = "update_rpcs_per_cycle"
+# ... and for the collector policy's two counts (PRs 38 and 41), which
+# every record of a server that `main()` started carries: 0.0 or more
+COLLECTOR_COUNTS = ("gc_sweeps_per_cycle", "gc_sweeps_deferred_per_cycle")
 
 
 def owed_by(cell: str) -> tuple:
@@ -110,3 +113,5 @@ def test_rehearsal(cell, trace):
     assert len(owed) == len(bases), owed
     assert owed <= printed, owed - printed
     assert line["metrics"][RPCS_PER_CYCLE]["value"] >= 2.0
+    for name in COLLECTOR_COUNTS:
+        assert line["metrics"][name]["value"] >= 0.0
