@@ -100,7 +100,9 @@ class CycleRecord:
     `marks` hold ABSOLUTE recorder-clock times (perf_counter seconds)
     for phase boundaries; `phases` hold derived millisecond durations
     (the ServingPipeline stage report plus scheduler-side phases);
-    `counts` hold integers (pods, binds, queue depths, fetch bytes...).
+    `counts` hold integers (pods, binds, queue depths, fetch bytes, and
+    of the pods an earlier cycle nominated those this cycle dispatched
+    and those it bound: `nominated_dispatched`, `nominated_bound`).
     Records are immutable once committed — the ring replaces slots, it
     never mutates them."""
 

@@ -3113,10 +3113,16 @@ class Scheduler:
         )
         with batch_cm:
             n_bound = 0
+            # of this batch, the pods an earlier cycle nominated, and
+            # those of them this cycle binds (on whatever node): counted
+            # where the two loops walk them
+            nom_dispatched = nom_bound = 0
             for i in win_idx:
                 i = int(i)
                 pod = pending[i]
                 node_name = nodes[int(a[i])].name
+                came_nominated = bool(pod.nominated_node_name)
+                nom_dispatched += came_nominated
                 try:
                     # a per-pod scheduling error (e.g. the uid raced to
                     # bound via an informer echo mid-cycle) must not
@@ -3195,6 +3201,7 @@ class Scheduler:
                     self.queue.attempts_of(pod.uid)
                 )
                 n_bound += 1
+                nom_bound += came_nominated
             if n_bound:
                 # the happy-path outcome batches: one counter inc + one
                 # shared latency sample for the cycle's binds (error
@@ -3240,6 +3247,7 @@ class Scheduler:
             for i in lose_idx:
                 i = int(i)
                 pod = pending[i]
+                nom_dispatched += bool(pod.nominated_node_name)
                 if i in extender_errors:
                     # non-ignorable extender failure: retry with backoff
                     # (transient webhook errors must not park the pod)
@@ -3311,6 +3319,12 @@ class Scheduler:
                         "cycle.losers", trace, t_sp_post, _spans.now(),
                         losers=len(lose_idx), diagnosed=n_diagnosed,
                     )
+            if rec is not None:
+                # this cycle's own counts, as `preemptors` and `victims`
+                rec.counts.update(
+                    nominated_dispatched=nom_dispatched,
+                    nominated_bound=nom_bound,
+                )
 
             if victims is not None and victims.any():
                 # victims belong to the preemptor nominated onto their
@@ -3398,7 +3412,14 @@ class Scheduler:
                     uid=pod.uid, node=node_name,
                 )
 
-    def _update_gauges(self) -> None:
+    def stamp_store_gauges(self) -> None:
+        """scheduler_pending_pods{queue} and scheduler_cache_size{type}
+        as the queue and the cache stand now: stamped at a cycle's end
+        (`_update_gauges`) and after every applied `Update`
+        (service/server.py), so between two cycles /metrics follows
+        the pods an agent added, confirmed or deleted (preemption's
+        victims leave the cache when their delete arrives, not in the
+        cycle that evicted them). Three `len()`s under a lock each."""
         self.metrics.set_pending(self.queue.pending_counts())
         c = self.cache.counts()
         # upstream cache_size{type="pods"} counts every tracked pod state;
@@ -3408,6 +3429,9 @@ class Scheduler:
             c.get("bound", 0) + c.get("assumed", 0),
             c.get("assumed", 0),
         )
+
+    def _update_gauges(self) -> None:
+        self.stamp_store_gauges()
         # flight-recorder derived gauges: the continuous overlap story
         # (scheduler_pipeline_overlap_ratio) computed from the recent
         # cycle window instead of separated probe runs

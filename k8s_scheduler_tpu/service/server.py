@@ -253,6 +253,9 @@ class SchedulerService:
             s.on_pdb_upsert(pdb)
         for key in request.pdb_deletes:
             s.on_pdb_delete(key)
+        # /metrics follows what this request added, confirmed and
+        # deleted now, not at the next cycle's end
+        s.stamp_store_gauges()
         if armed:
             t_out = _spans.now()
             converted = (

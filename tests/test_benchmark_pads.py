@@ -5,7 +5,9 @@ process started.
   `padExisting` is the power of two above the resident set the JSON
   states: `resident_target` + `depth` + the probe pools' load pods (the
   arithmetic of `benchmark/tests/test_cells.py`, which is run by hand;
-  the pad follows the resident set, not the rate, since PR 35). The
+  the pad follows the resident set, not the rate, since PR 35), or,
+  where a configuration states no target because nothing completes,
+  `init_pods` + `depth` + the load pods, at size and in the cut. The
   three rate inputs of the rule before that are read only where a
   configuration still carries them, and must then stand at 0. Past the
   pad the encoder leaves the delta path and programs compile inside the
@@ -43,22 +45,36 @@ BENCHMARK = load("BENCHMARK.json")
     "entry", BENCHMARK["configs"], ids=lambda e: e["name"])
 def test_the_existing_pad_is_what_the_configurations_rule_gives(entry):
     cfg = load(entry["file"])
-    holds = (
-        cfg["resident_target"] + cfg["depth"]
-        + cfg["probe"]["pools"] * cfg["probe"]["nodes_per_pool"])
     served = load("benchmark", "configs", cfg["server_config"])
+    cut = cfg["rehearse"]
+    rule = cfg["pad_rule"]
+
+    def probe_loads(c):
+        return c["probe"]["pools"] * c["probe"]["nodes_per_pool"]
+
+    if "resident_target" not in cfg:
+        # nothing completes (`sp5000-preempt`, PR 44: the resident set
+        # only falls), so the pad holds all that can ever be resident:
+        # `init_pods + depth +` the probe loads, at size and in the cut
+        assert "init_pods + depth" in rule["holds"]
+        for c, pad in ((cfg, served["padExisting"]),
+                       (cut, cut["server"]["padExisting"])):
+            assert "resident_target" not in c
+            holds = c["init_pods"] + c["depth"] + probe_loads(c)
+            assert pad == 1 << holds.bit_length(), holds
+        assert cut["server"]["padExisting"] < served["padExisting"]
+        return
+    holds = cfg["resident_target"] + cfg["depth"] + probe_loads(cfg)
     assert served["padExisting"] == 1 << holds.bit_length(), holds
     # the window opens under the target and the warm-up batch fills it
     assert cfg["init_pods"] + cfg["depth"] <= cfg["resident_target"] + 64
     # no rate is an input: a file that still names the old rule's three
     # (until a benchmark PR deletes the keys) holds them at 0
-    rule = cfg["pad_rule"]
     assert "resident_target + depth" in rule["holds"]
     assert all(rule[k] == 0 for k in (
         "rate_ref_pods_per_s", "factor", "iteration_s") if k in rule)
     # the rehearsal's cut server is the same program under a smaller
     # pad, which holds the cut resident set the same way
-    cut = cfg["rehearse"]
     assert cut["server"]["padExisting"] < served["padExisting"]
     assert cut["resident_target"] + cut["depth"] < (
         cut["server"]["padExisting"])

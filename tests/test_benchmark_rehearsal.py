@@ -11,20 +11,34 @@ phase renamed, a `/debug` field, a YAML key, a log line) fails here and
 not in the driver's check a PR later. No number of a rehearsal is a
 measurement, and none is asserted.
 
-The cells come from `BENCHMARK.json`, so a cell a later PR adds is
-covered without an edit. This file is one xdist worker's (`--dist
-loadfile`): the cases share `.bench/` (git-ignored: `cache-cpu` and the
-server logs, ~10 MB) and run one after another.
+The cells come from `BENCHMARK.json`, and what a cell owes comes from
+its own files (PR 44), so a cell a later PR adds is covered without an
+edit: completions are owed where its traffic file has a `completions`
+block, the loser loop's spans where its configuration states stuck
+pods, preemption's facts and metrics where its configuration has
+`templates` of more than one priority, and of the stems below those
+that its own `per_layer` entries name. The five cells accepted before
+PR 44 are named, and owe every stem: none of theirs can be dropped
+unseen. This file is one xdist worker's (`--dist loadfile`): the cases
+share `.bench/` (git-ignored: `cache-cpu` and the server logs, ~10 MB)
+and run one after another.
 """
 
 import json
 import os
+import shutil
 import subprocess
 import sys
 
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from benchmark import run as bench_run  # noqa: E402
+from benchmark.lib import agent, generate, reference  # noqa: E402
+from benchmark.lib.child import Server  # noqa: E402
+
 with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
     BENCHMARK = json.load(_f)
 
@@ -53,20 +67,65 @@ ALWAYS_READ = (
 # through, like `full_encodes_per_cycle`: printed, not owed
 WHERE_PODS_FIT_NOWHERE = ("loser_loop_ms", "postfilter_ms")
 # ... but for the servicer's count of `Update` RPCs (PR 39), which every
-# record of a served scheduler carries: every cell's rehearsal runs
-# several cycles, and each follows at least its two `Update`s
+# record of a served scheduler carries: a cell whose iterations all
+# offer and confirm follows each cycle with at least its two `Update`s
+# (one whose iterations mostly offer and confirm nothing,
+# `sp5000-preempt.sat`, reads 1.75 and has no such entry)
 RPCS_PER_CYCLE = "update_rpcs_per_cycle"
 # ... and for the collector policy's two counts (PRs 38 and 41), which
 # every record of a server that `main()` started carries: 0.0 or more
 COLLECTOR_COUNTS = ("gc_sweeps_per_cycle", "gc_sweeps_deferred_per_cycle")
+# ... and, in a cell whose configuration has `templates` of more than
+# one priority (PR 44: pending pods that fit only once lower ones are
+# evicted), from the loser loop's two spans and from the counts every
+# record keeps of ITS cycle (`mean_per_cycle` reads one record)
+WHERE_PODS_PREEMPT = (
+    "postfilter_ms", "loser_loop_ms", "nominations_per_cycle",
+    "victims_per_cycle", "bound_per_cycle", "backoff_held_per_cycle",
+    "nominated_dispatched_per_cycle", "nominated_bound_per_cycle",
+)
+CHECK_F = ("bad_nominations", "bad_evictions", "victims_not_lower",
+           "nominations_without_room", "needless_victims")
+# the cells the benchmark had before PR 44 owe every stem of
+# `ALWAYS_READ`; a later cell owes those its own entries name
+FULL_DUTY = (
+    "sp5000-mixed.sat", "sp5000-mixed.steady", "sp5000-default.sat",
+    "sp5000-unschedulable.sat", "sp500-basic.sat",
+)
 
 
-def owed_by(cell: str) -> tuple:
+def load(*parts) -> dict:
+    with open(os.path.join(ROOT, "benchmark", *parts)) as f:
+        return json.load(f)
+
+
+def files_of(cell: str) -> tuple:
+    """The cell's configuration and traffic file."""
     (entry,) = [w for w in BENCHMARK["workloads"] if w["name"] == cell]
-    with open(os.path.join(
-            ROOT, "benchmark", "configs", entry["config"] + ".json")) as f:
-        stuck = json.load(f).get("unschedulable", {}).get("count", 0)
-    return ALWAYS_READ + (WHERE_PODS_FIT_NOWHERE if stuck else ())
+    return (load("configs", entry["config"] + ".json"),
+            load("workloads", cell + ".json"))
+
+
+def preempts(cfg: dict) -> bool:
+    return len({p for t in cfg.get("templates", {}).values()
+                for p in t["priorities"]}) > 1
+
+
+def owed_by(cell: str, cfg: dict, of_cell: set) -> set:
+    """The entries of the cell that a traced rehearsal must print: of
+    `ALWAYS_READ` those the cell names, and one each of what its files
+    call for."""
+    must = set(ALWAYS_READ if cell in FULL_DUTY else ())
+    if cfg.get("unschedulable", {}).get("count", 0):
+        must.update(WHERE_PODS_FIT_NOWHERE)
+    if preempts(cfg):
+        must.update(WHERE_PODS_PREEMPT)
+    stems = {n: n.split(".")[0] for n in of_cell}
+    owed = {n for n, stem in stems.items()
+            if stem in must or stem in ALWAYS_READ}
+    assert sorted(stems[n] for n in owed if stems[n] in must) == sorted(
+        must), owed
+    return owed
 
 
 @pytest.mark.parametrize("trace", (0, 1), ids=("untraced", "traced"))
@@ -90,17 +149,26 @@ def test_rehearsal(cell, trace):
     assert line["failed"] == 0
     for name, (value, limit) in line["compared"].items():
         assert value <= limit, (name, value, limit)
-    # pods finish: the delete path ran, the resident set is held at the
-    # cut target (a cycle of the open loop that follows one which bound
-    # little may start a pod under it; none starts over it:
-    # `resident_over_target`), and the replay and the server agree on
-    # what is resident
     (facts,) = [r["facts"] for r in rows if "facts" in r]
-    assert facts["completed"] > 0
-    assert facts["resident_at_start"][1] == facts["resident_target"]
-    for name in ("bad_completions", "resident_over_target",
-                 "server_resident_drift"):
-        assert line["compared"][name] == [0, 0], name
+    cfg, traffic = files_of(cell)
+    if "completions" in traffic:
+        # pods finish: the delete path ran and the resident set is held
+        # at the cut target (a cycle of the open loop that follows one
+        # which bound little may start a pod under it; none starts over
+        # it: `resident_over_target`)
+        assert facts["completed"] > 0
+        assert facts["resident_at_start"][1] == facts["resident_target"]
+        for name in ("bad_completions", "resident_over_target"):
+            assert line["compared"][name] == [0, 0], name
+    else:
+        assert facts["completed"] == 0
+    # the replay and the server agree on what is resident
+    assert line["compared"]["server_resident_drift"] == [0, 0]
+    if preempts(cfg):
+        # preemption is the work, and check (f) judged it
+        assert facts["nominations"] > 0 and facts["victims"] > 0
+        for name in CHECK_F:
+            assert line["compared"][name] == [0, 0], name
     printed = set(line["metrics"])
     if not trace:
         assert {"pods_bound_per_s", "setup_s"} <= printed
@@ -108,10 +176,66 @@ def test_rehearsal(cell, trace):
     of_cell = {m["name"] for m in BENCHMARK["per_layer"]
                if cell in m["workloads"]}
     assert printed <= of_cell, printed - of_cell
-    bases = owed_by(cell)
-    owed = {n for n in of_cell if n.split(".")[0] in bases}
-    assert len(owed) == len(bases), owed
+    owed = owed_by(cell, cfg, of_cell)
     assert owed <= printed, owed - printed
-    assert line["metrics"][RPCS_PER_CYCLE]["value"] >= 2.0
-    for name in COLLECTOR_COUNTS:
+    if cell in FULL_DUTY:
+        assert {RPCS_PER_CYCLE, *COLLECTOR_COUNTS} <= of_cell
+    if RPCS_PER_CYCLE in of_cell:
+        assert line["metrics"][RPCS_PER_CYCLE]["value"] >= 2.0
+    for name in of_cell.intersection(COLLECTOR_COUNTS):
         assert line["metrics"][name]["value"] >= 0.0
+
+
+def test_the_servers_count_follows_the_deletes_of_a_cycles_victims(
+        monkeypatch):
+    """`server_resident_drift` holds the server's own
+    `scheduler_cache_size{type="pods"}` to the replay's count after the
+    LAST cycle. Preemption's victims leave the replay in the cycle that
+    evicted them and the server's cache when the agent's next `Update`
+    deletes them, so a run that ends on an evicting cycle reads 0 only
+    if the gauge is stamped when that `Update` has been applied
+    (PR 44; before it, at a cycle's end alone: such a run read that
+    cycle's victims, `correct: false` with nothing else amiss). Driven
+    as `run.py` drives `sp5000-preempt.sat`'s rehearsal and stopped at
+    the first cycle whose response carries evictions: where a 1 s
+    window happens to end is not relied on."""
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    monkeypatch.delenv("XLA_FLAGS", raising=False)  # one CPU device
+    cfg, traffic = files_of("sp5000-preempt.sat")
+    assert preempts(cfg) and "completions" not in traffic
+    cut = {k: v for k, v in cfg["rehearse"].items() if k != "server"}
+    seed = 3000000019
+    dep = generate.deployment(cfg, seed, cut)
+    workdir = os.path.join(bench_run.SCRATCH, f"victims-{seed}")
+    cache = os.path.join(bench_run.SCRATCH, "cache-cpu")
+    shutil.rmtree(workdir, ignore_errors=True)
+    os.makedirs(workdir)
+    os.makedirs(cache, exist_ok=True)
+    server = Server(
+        ROOT, workdir, bench_run.server_yaml(cfg, True, workdir),
+        aot_dir=os.path.join(cache, "aot"),
+        jax_cache_dir=os.path.join(cache, "jax"), traced=False)
+    drv = agent.Driver(server.grpc_port, dep, None, seed)
+    try:
+        server.started(require_tpu=False, chips=1)
+        drv.load()
+        drv.step(dep.pending(dep.cfg["depth"], "warm"))
+        for _ in range(50):
+            if drv.cycles[-1].evictions:
+                break
+            drv.step([])
+        victims = len(drv.cycles[-1].evictions)
+        assert victims and len(drv.cycles[-1].nominations) * 3 == victims
+        held = server.metrics()['scheduler_cache_size{type="pods"}']
+        verdict = reference.check_run(
+            dep.nodes, dep.init, drv.pods, drv.cycles, dep.pools,
+            drv.probe_rounds, drv.resident_target)
+        server.stop()
+    finally:
+        drv.close()
+        server.kill()
+        shutil.rmtree(workdir, ignore_errors=True)
+    assert verdict.ok, verdict.problems
+    # the replay took this cycle's victims out, and so has the server
+    assert verdict.resident_after[-1] == drv.resident
+    assert int(held) == verdict.resident_after[-1], victims

@@ -261,7 +261,15 @@ def test_ack_wait_is_stamped_only_where_the_wait_blocked(served, armed):
     (wait,) = got["client.ack_wait"]
     slow, fast = got["client.update"]
     assert wait.parent == slow.span_id and slow.parent == ""
-    assert 0.04 < wait.t1 - wait.t0 <= slow.t1 - slow.t0
+    # the wait began inside its request, and blocked for the server's
+    # 50 ms: both starts are the agent's thread's. The two ENDS are two
+    # threads' (the request's is stamped by gRPC's done-callback, the
+    # wait's by the agent's thread once it wakes with the response), so
+    # either may trail the other by a thread switch (2.5 ms seen in a
+    # loaded tier-1 run: a wait of 56.6 ms in a request of 54.1): they
+    # are held together, not in order
+    assert slow.t0 <= wait.t0 and 0.04 < wait.t1 - wait.t0
+    assert abs(wait.t1 - slow.t1) < 0.1, (wait.t1, slow.t1)
     # outside a block the request is the root and its send hangs on it
     assert [sp.parent for sp in got["client.send"]] == [
         slow.span_id, fast.span_id]
@@ -435,7 +443,12 @@ def test_a_client_layer_file_reads_its_span_or_nothing(name):
     (entry,) = [m for m in bench["per_layer"] if m["name"] == name]
     with open(os.path.join(REPO, "benchmark", "layers", name + ".json")) as f:
         spec = json.load(f)
-    cells = [w["name"] for w in bench["workloads"]]
+    # the five cells the benchmark had when the entry was accepted, in
+    # order, and any cell a later PR appended: a cell whose own entries
+    # leave the stem out (`sp5000-preempt.sat`) is not in the list
+    every = [w["name"] for w in bench["workloads"]]
+    cells = entry["workloads"]
+    assert cells[:5] == every[:5] and set(cells) <= set(every)
     assert entry == {
         "name": name, "unit": "ms", "better": "lower",
         "source": "program_span", "layer": "client and servicer (service/)",

@@ -463,21 +463,29 @@ def test_gc_pass_ms_names_one_accepted_cell_and_a_stamped_span(cell, suffix):
 
 def test_gc_sweeps_deferred_per_cycle_names_every_cell_and_a_kept_count():
     """The metric over the policy's second count is data too: one layer
-    file and one `per_layer` entry for all five cells, beside
+    file and one `per_layer` entry for the five cells that have
+    completions, beside
     `gc_sweeps_per_cycle`'s and of its shape, over a count the flight
     records keep."""
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
     name = "gc_sweeps_deferred_per_cycle"
     (entry,) = [m for m in bench["per_layer"] if m["name"] == name]
-    assert entry is bench["per_layer"][-1]  # appended, nothing moved
     with open(os.path.join(REPO, "benchmark", "layers", name + ".json")) as f:
         spec = json.load(f)
     with open(os.path.join(
             REPO, "benchmark", "layers", "gc_sweeps_per_cycle.json")) as f:
         placed = json.load(f)
-    cells = [w["name"] for w in bench["workloads"]]
-    assert len(cells) == 5 and sorted(entry["workloads"]) == sorted(cells)
+    # the cells in which pods finish: the policy counts departures, and
+    # a cell that completes nothing (its traffic file has no
+    # `completions` block) has no sweep to put off
+    cells = []
+    for w in bench["workloads"]:
+        with open(os.path.join(REPO, "benchmark", "workloads",
+                               w["name"] + ".json")) as f:
+            if "completions" in json.load(f):
+                cells.append(w["name"])
+    assert sorted(entry["workloads"]) == sorted(cells)
     assert entry["workloads"] == spec["workloads"] == placed["workloads"]
     assert spec["select"] == ["gc_sweeps_deferred"]
     own = ("name", "select", "what")  # all else is its neighbour's
