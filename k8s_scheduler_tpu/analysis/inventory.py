@@ -96,6 +96,7 @@ REQUIRED_FAMILIES = {
     "scheduler_journal_segments",
     "scheduler_snapshot_writes_total",
     "scheduler_snapshot_duration_seconds",
+    "scheduler_snapshot_rows_total",
     "scheduler_snapshot_last_bytes",
     "scheduler_snapshot_last_restore_records",
     "scheduler_snapshot_last_restore_seconds",

@@ -1225,8 +1225,11 @@ class Scheduler:
             return
         t_snap = _spans.now() if trace is not None else 0.0
         if self.state.maybe_snapshot() and trace is not None:
+            last = self.state.last_snapshot
             _spans.record_span(
-                "cycle.snapshot", trace, t_snap, _spans.now()
+                "cycle.snapshot", trace, t_snap, _spans.now(),
+                rows=last["rows"], rows_encoded=last["rows_encoded"],
+                bytes=last["bytes"],
             )
 
     def _schedule_profile(
@@ -2826,6 +2829,11 @@ class Scheduler:
             # not; no policy, no counts
             rec.counts["gc_sweeps"] = self.collector.sweeps
             rec.counts["gc_sweeps_deferred"] = self.collector.deferred
+        if self.state is not None:
+            # pod rows the journal compactions have serialised so far
+            # (a compaction follows its cycle's record, so a record
+            # carries those up to the cycle before); no state, no count
+            rec.counts["snapshot_rows_encoded"] = self.state.rows_encoded
         if self.update_rpcs is not None:
             # the Update RPCs the servicer handled before this cycle:
             # two a cycle where the agent sends each batch whole, more

@@ -39,17 +39,23 @@ from ..models.api import Node, Pod
 # codec bindings for journal emission, bound on first use so schedulers
 # without durability never import state/ — and journaling mutators skip
 # per-call import machinery inside the cache lock
-_pod_to_state = _node_to_state = None
+_pod_to_state = _node_to_state = _json_bytes = None
 
 
 def _codec():
-    global _pod_to_state, _node_to_state
+    global _pod_to_state, _node_to_state, _json_bytes
     if _pod_to_state is None:
-        from ..state.codec import node_to_state, pod_to_state
+        from ..state.codec import json_bytes, node_to_state, pod_to_state
 
         _pod_to_state = pod_to_state
         _node_to_state = node_to_state
-    return _pod_to_state, _node_to_state
+        _json_bytes = json_bytes
+    return _pod_to_state, _node_to_state, _json_bytes
+
+
+def _row_open(pod_json: bytes, node_json: bytes) -> bytes:
+    """How a pod's row of a snapshot opens, bound or assumed."""
+    return b'{"pod":%b,"node":%b' % (pod_json, node_json)
 
 
 @dataclasses.dataclass
@@ -74,15 +80,39 @@ class SchedulerCache:
         self._nodes: dict[str, Node] = {}
         self._bound: dict[str, tuple[Pod, str]] = {}  # uid -> (pod, node)
         self._assumed: dict[str, _AssumedPod] = {}
+        # uid -> snapshot fragment of a bound or assumed pod: the
+        # opening of its row in a snapshot, `{"pod":<pod>,"node":<name>`,
+        # serialised once, where the pod was journaled, from the state
+        # dict that record carries (`_pod_state`). A bound row is that
+        # and `}`; an assumed one closes with `finished` and `deadline`,
+        # which move without a pod record; `confirm` moves the pod and
+        # not its node, so the fragment stays as it is. Kept beside
+        # `_bound` / `_assumed`, not inside: what `existing_pods()`
+        # hands the encoder does not change. Goes when the row goes;
+        # empty with no journal attached, with one that never compacts
+        # (`set_journal`) and after `load_state`
+        self._frags: dict[str, bytes] = {}
+        # the node rows as the last compaction serialised them, until a
+        # node event
+        self._nodes_json: bytes | None = None
+        self._frag_at_entry = True  # set_journal
         # pods and nodes dropped since the process began: what
         # core/collector sweeps by (not state: never journaled)
         self.departed = 0
 
     def set_journal(
-        self, journal: Callable[[str, float, dict], None] | None
+        self,
+        journal: Callable[[str, float, dict], None] | None,
+        compacts: bool = True,
     ) -> None:
+        """`compacts`: whether the journal's owner takes periodic
+        snapshots. Where it never does (`snapshotInterval: 0`, journal
+        only) no fragment is made where a pod is journaled: the one
+        snapshot such a process writes, the seal at exit, serialises
+        each row from its pod, as it does a restored row."""
         with self._lock:
             self._journal = journal
+            self._frag_at_entry = compacts
 
     def _emit(self, op: str, data: dict) -> None:
         if self._journal is not None:
@@ -94,22 +124,48 @@ class SchedulerCache:
                 op, self._now(), {"node": _codec()[1](node)}
             )
 
+    def _pod_state(self, pod: Pod, node_name: str) -> dict | None:
+        """The state dict for the record a mutator is about to journal;
+        the row's fragment is made from it here, so it is the pod as
+        last journaled and never a second `pod_to_state`. A pod that
+        enters the cache stays for ~15 cycles and as many compactions as
+        fall in them, so it is serialised where it enters (the queue's
+        entries, most of which no compaction ever meets, wait for one:
+        `_QueuedPod.frag`). With no journal attached there is no record,
+        no dict and no fragment, with one that never compacts no
+        fragment (and none stays from before)."""
+        if self._journal is None:
+            state = None
+        else:
+            to_state, _, json_bytes = _codec()
+            state = to_state(pod)
+        if state is not None and self._frag_at_entry:
+            self._frags[pod.uid] = _row_open(
+                json_bytes(state), json_bytes(node_name)
+            )
+        else:
+            self._frags.pop(pod.uid, None)
+        return state
+
     # ---- node events -----------------------------------------------------
 
     def add_node(self, node: Node) -> None:
         with self._lock:
             self._nodes[node.name] = node
+            self._nodes_json = None
             self._emit_node("c.add_node", node)
 
     def update_node(self, node: Node) -> None:
         with self._lock:
             self._nodes[node.name] = node
+            self._nodes_json = None
             self._emit_node("c.update_node", node)
 
     def remove_node(self, node_name: str) -> None:
         with self._lock:
             if self._nodes.pop(node_name, None) is not None:
                 self.departed += 1
+                self._nodes_json = None
                 self._emit("c.remove_node", {"name": node_name})
 
     # ---- pod events (bound pods observed via informer) -------------------
@@ -119,11 +175,9 @@ class SchedulerCache:
         with self._lock:
             self._assumed.pop(pod.uid, None)
             self._bound[pod.uid] = (pod, node_name)
-            if self._journal is not None:
-                self._emit(
-                    "c.add_pod",
-                    {"pod": _codec()[0](pod), "node": node_name},
-                )
+            state = self._pod_state(pod, node_name)
+            if state is not None:
+                self._emit("c.add_pod", {"pod": state, "node": node_name})
 
     def remove_pod(self, pod_uid: str) -> None:
         with self._lock:
@@ -131,6 +185,7 @@ class SchedulerCache:
             a = self._assumed.pop(pod_uid, None)
             if b is not None or a is not None:
                 self.departed += 1
+                self._frags.pop(pod_uid, None)
                 self._emit("c.remove_pod", {"uid": pod_uid})
 
     # ---- assume lifecycle ------------------------------------------------
@@ -142,11 +197,9 @@ class SchedulerCache:
                 # replayed (replay would refuse it again and abort)
                 raise ValueError(f"pod {pod.name} already bound")
             self._assumed[pod.uid] = _AssumedPod(pod, node_name)
-            if self._journal is not None:
-                self._emit(
-                    "c.assume",
-                    {"pod": _codec()[0](pod), "node": node_name},
-                )
+            state = self._pod_state(pod, node_name)
+            if state is not None:
+                self._emit("c.assume", {"pod": state, "node": node_name})
 
     def finish_binding(self, pod_uid: str) -> None:
         with self._lock:
@@ -173,6 +226,7 @@ class SchedulerCache:
             if a is None or node_name not in (None, a.node_name):
                 return None
             del self._assumed[pod_uid]
+            # the same pod on the same node: its fragment stays
             self._bound[pod_uid] = (a.pod, a.node_name)
             self._emit("c.confirm", {"uid": pod_uid})
             return a.pod
@@ -181,6 +235,7 @@ class SchedulerCache:
         with self._lock:
             if self._assumed.pop(pod_uid, None) is not None:
                 self.departed += 1
+                self._frags.pop(pod_uid, None)
                 self._emit("c.forget", {"uid": pod_uid})
 
     def is_assumed(self, pod_uid: str) -> bool:
@@ -206,6 +261,7 @@ class SchedulerCache:
             out = []
             for u in gone:
                 a = self._assumed.pop(u)
+                self._frags.pop(u, None)
                 out.append((a.pod, a.node_name))
             self.departed += len(out)
             if out and self._journal is not None:
@@ -242,6 +298,50 @@ class SchedulerCache:
                 ],
             }
 
+    def dump_state_json(self) -> tuple[bytes, int, int]:
+        """`dump_state()` as the compact JSON a snapshot body holds,
+        byte for byte what `json.dumps` makes of it, with the pod rows
+        it holds and how many of them were serialised here: those with
+        no fragment (restored by `load_state`, or placed while no
+        journal was attached), once. Every other bound row is joined as
+        it stands, an assumed one closed with its `finished` and
+        `deadline`; the node rows are serialised after a node event
+        only."""
+        from ..state.codec import json_bytes, node_to_state, pod_fragment
+
+        with self._lock:
+            frags = self._frags
+            if self._nodes_json is None:
+                self._nodes_json = json_bytes(
+                    [node_to_state(n) for n in self._nodes.values()]
+                )
+            encoded = 0
+            rows = list(map(frags.get, self._bound))
+            if None in rows:
+                for i, (uid, (pod, node)) in enumerate(self._bound.items()):
+                    if rows[i] is None:
+                        rows[i] = frags[uid] = _row_open(
+                            pod_fragment(None, pod), json_bytes(node)
+                        )
+                        encoded += 1
+            bound = b"},".join(rows) + b"}" if rows else b""
+            assumed = []
+            for uid, a in self._assumed.items():
+                frag = frags.get(uid)
+                if frag is None:
+                    frag = frags[uid] = _row_open(
+                        pod_fragment(None, a.pod), json_bytes(a.node_name)
+                    )
+                    encoded += 1
+                assumed.append(frag + b"," + json_bytes({
+                    "finished": a.binding_finished,
+                    "deadline": a.deadline,
+                })[1:])
+            body = b'{"nodes":%b,"bound":[%b],"assumed":[%b]}' % (
+                self._nodes_json, bound, b",".join(assumed)
+            )
+            return body, len(rows) + len(assumed), encoded
+
     def load_state(self, state: dict) -> None:
         from ..state.codec import node_from_state, pod_from_state
 
@@ -249,6 +349,8 @@ class SchedulerCache:
             self._nodes.clear()
             self._bound.clear()
             self._assumed.clear()
+            self._frags.clear()
+            self._nodes_json = None
             for d in state.get("nodes", ()):
                 n = node_from_state(d)
                 self._nodes[n.name] = n

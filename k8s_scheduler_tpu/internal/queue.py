@@ -103,6 +103,12 @@ class _QueuedPod:
     # union of the failed plugins' EventsToRegister hints).
     unschedulable_reasons: tuple[str, ...] = ()
     enqueued_at: float = 0.0
+    # snapshot fragment: the state dict of `pod` as the entry's last
+    # journal record carried it, until a compaction has serialised it,
+    # and those bytes from then on (state/codec.pod_fragment). A field
+    # of the entry, so it moves and goes with it; None with no journal
+    # attached and after `load_state`
+    frag: dict | bytes | None = None
 
 
 class SchedulingQueue:
@@ -152,28 +158,42 @@ class SchedulingQueue:
         """New pod (informer Add): straight to active."""
         with self._lock:
             now = self._now()
-            self._add_locked(pod, now, EVENT_POD_ADD)
-            if self._journal is not None:
-                self._emit("q.add", now, {"pod": _codec_pod()(pod)})
+            state = self._pod_state(pod)
+            self._add_locked(pod, now, EVENT_POD_ADD, state)
+            if state is not None:
+                self._emit("q.add", now, {"pod": state})
 
-    def _add_locked(self, pod: Pod, now: float, event: str) -> None:
+    def _pod_state(self, pod: Pod) -> dict | None:
+        """The state dict for the record a mutator is about to journal,
+        which the entry then keeps as its fragment: the fragment is the
+        pod as last journaled for that entry, never a second
+        `pod_to_state`. None with no journal attached."""
+        if self._journal is None:
+            return None
+        return _codec_pod()(pod)
+
+    def _add_locked(
+        self, pod: Pod, now: float, event: str, state: dict | None
+    ) -> None:
         uid = pod.uid
         self._backoff.pop(uid, None)
         self._unschedulable.pop(uid, None)
-        self._active[uid] = _QueuedPod(pod, enqueued_at=now)
+        self._active[uid] = _QueuedPod(pod, enqueued_at=now, frag=state)
         self._on_enqueue("active", event)
 
     def update(self, pod: Pod) -> None:
         """Spec/labels changed: an update can unstick its own pod."""
         with self._lock:
             now = self._now()
-            if self._journal is not None:
-                self._emit("q.update", now, {"pod": _codec_pod()(pod)})
+            state = self._pod_state(pod)
+            if state is not None:
+                self._emit("q.update", now, {"pod": state})
             uid = pod.uid
             for tier in (self._active, self._backoff, self._unschedulable):
                 if uid in tier:
                     entry = tier[uid]
                     entry.pod = pod
+                    entry.frag = state
                     if tier is self._unschedulable:
                         # the update may cure the failure, but the pod's
                         # backoff window still applies (upstream checks
@@ -190,9 +210,11 @@ class SchedulingQueue:
             if uid in self._in_flight:
                 # being scheduled right now: refresh the in-flight object so
                 # a requeue carries the new spec, but do NOT double-enqueue
-                self._in_flight[uid].pod = pod
+                entry = self._in_flight[uid]
+                entry.pod = pod
+                entry.frag = state
                 return
-            self._add_locked(pod, now, EVENT_POD_ADD)
+            self._add_locked(pod, now, EVENT_POD_ADD, state)
 
     def delete(self, pod_uid: str) -> None:
         with self._lock:
@@ -288,11 +310,11 @@ class SchedulingQueue:
             # journal BEFORE the deleted-in-flight check: the discard
             # branch mutates state too (clears the tombstone + in-flight
             # entry), and replay must take the same branch it took live
-            if self._journal is not None:
+            state = self._pod_state(pod)
+            if state is not None:
                 self._emit(
                     "q.unsched", now,
-                    {"pod": _codec_pod()(pod),
-                     "reasons": list(reasons)},
+                    {"pod": state, "reasons": list(reasons)},
                 )
             if uid in self._deleted_in_flight:
                 self._deleted_in_flight.discard(uid)
@@ -302,6 +324,7 @@ class SchedulingQueue:
             self._backoff.pop(uid, None)
             entry = self._in_flight.pop(uid, None) or _QueuedPod(pod)
             entry.pod = pod
+            entry.frag = state
             entry.unschedulable_reasons = tuple(reasons)
             entry.enqueued_at = now
             entry.backoff_expiry = now + self._backoff_for(entry.attempts)
@@ -315,10 +338,10 @@ class SchedulingQueue:
             uid = pod.uid
             # journal before the deleted-in-flight check (see
             # requeue_unschedulable: the discard branch mutates state)
-            if self._journal is not None:
+            state = self._pod_state(pod)
+            if state is not None:
                 self._emit(
-                    "q.backoff", now,
-                    {"pod": _codec_pod()(pod), "event": event},
+                    "q.backoff", now, {"pod": state, "event": event}
                 )
             if uid in self._deleted_in_flight:
                 self._deleted_in_flight.discard(uid)
@@ -328,6 +351,7 @@ class SchedulingQueue:
             self._unschedulable.pop(uid, None)
             entry = self._in_flight.pop(uid, None) or _QueuedPod(pod)
             entry.pod = pod
+            entry.frag = state
             entry.backoff_expiry = now + self._backoff_for(entry.attempts)
             self._backoff[uid] = entry
             self._on_enqueue("backoff", event)
@@ -456,6 +480,44 @@ class SchedulingQueue:
                 "in_flight": [entry(e) for e in self._in_flight.values()],
                 "deleted_in_flight": sorted(self._deleted_in_flight),
             }
+
+    def dump_state_json(self) -> tuple[bytes, int, int]:
+        """`dump_state()` as the compact JSON a snapshot body holds,
+        byte for byte what `json.dumps` makes of it, with the rows it
+        holds and how many of them were serialised here: an entry met
+        before is its kept fragment with its scalar fields (which
+        change without a pod record) spliced after it."""
+        from ..state.codec import json_bytes, pod_fragment
+
+        with self._lock:
+            encoded = 0
+            tiers = []
+            for tier in (
+                self._active, self._backoff,
+                self._unschedulable, self._in_flight,
+            ):
+                rows = []
+                for e in tier.values():
+                    frag = e.frag
+                    if type(frag) is not bytes:
+                        frag = e.frag = pod_fragment(frag, e.pod)
+                        encoded += 1
+                    rows.append(b'{"pod":%b,%b' % (frag, json_bytes({
+                        "attempts": e.attempts,
+                        "backoff_expiry": e.backoff_expiry,
+                        "reasons": list(e.unschedulable_reasons),
+                        "enqueued_at": e.enqueued_at,
+                    })[1:]))
+                tiers.append(b",".join(rows))
+            body = (
+                b'{"active":[%b],"backoff":[%b],"unschedulable":[%b],'
+                b'"in_flight":[%b],"deleted_in_flight":%b}'
+            ) % (*tiers, json_bytes(sorted(self._deleted_in_flight)))
+            rows = (
+                len(self._active) + len(self._backoff)
+                + len(self._unschedulable) + len(self._in_flight)
+            )
+            return body, rows, encoded
 
     def load_state(self, state: dict) -> None:
         """Inverse of dump_state: replace this queue's contents. Expiry

@@ -259,7 +259,14 @@ restore) and leader election:
   (the journal lag; grows if the disk can't keep up)
 - scheduler_journal_segments — journal segment files on disk
 - scheduler_snapshot_writes_total — snapshot compactions written
-- scheduler_snapshot_duration_seconds — dump+write+prune latency
+- scheduler_snapshot_duration_seconds — a compaction's latency: the
+  splice (only rows it has not met are serialised), write + fsync, the
+  journal writer's barrier, prune
+- scheduler_snapshot_rows_total{source} — pod rows compactions wrote:
+  kept (the bytes an earlier compaction made of the row, spliced in) |
+  encoded (serialised inside the compaction: what entered since the
+  last one); the flight records carry the encoded total as
+  `snapshot_rows_encoded`
 - scheduler_snapshot_last_bytes — size of the newest snapshot
 - scheduler_snapshot_last_restore_records — journal records replayed by
   the most recent restore (0 after a clean-shutdown takeover)
@@ -787,8 +794,17 @@ class SchedulerMetrics:
         )
         self.snapshot_duration = Histogram(
             "scheduler_snapshot_duration_seconds",
-            "Snapshot dump+write+prune latency.",
+            "Snapshot compaction latency: splice, write + fsync, "
+            "journal barrier, prune.",
             buckets=_DURATION_BUCKETS,
+            registry=r,
+        )
+        self.snapshot_rows = Counter(
+            "scheduler_snapshot_rows_total",
+            "Pod rows written by snapshot compactions, by whether the "
+            "row's kept fragment was spliced in or the row was "
+            "serialised inside the compaction.",
+            ["source"],
             registry=r,
         )
         self.snapshot_bytes = Gauge(

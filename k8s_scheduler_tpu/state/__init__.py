@@ -11,12 +11,22 @@ state layer that closes that gap:
   logical queue/cache mutations, drained by a writer thread with group
   fsync (appends never touch the bind path's latency budget);
 - `codec.py` — fast hand-rolled Pod/Node <-> plain-dict converters
-  (the journal/snapshot wire format) plus the canonical state digest;
+  (the journal/snapshot wire format), the serialiser of a row's
+  snapshot fragment, plus the canonical state digest;
 - `snapshot.py` — atomic whole-state snapshots that compact the
   journal (write-temp + fsync + rename);
 - `manager.py` — `DurableState`: wires emitters into a live
   queue/cache pair, restores snapshot+tail on attach, snapshots on an
   interval, and seals the journal on clean shutdown.
+
+A snapshot file is the whole state every time, but a compaction
+serialises no pod twice: the cache and the queue keep each resident
+row's fragment, the compact JSON bytes of the pod as the row's last
+journal record carried it (the cache makes them where the pod is
+journaled; a queue entry keeps the record's state dict and the first
+compaction that meets it turns that into bytes), and
+`DurableState.snapshot()` splices kept fragments, so its cost follows
+what entered the queue since the last one and not what is resident.
 
 Replay is exact: each journal record carries the emitting clock value
 and restore re-executes the logical operation under a replay clock, so

@@ -390,6 +390,35 @@ def node_from_state(d: dict) -> Node:
 
 
 # ---------------------------------------------------------------------------
+# fragments
+# ---------------------------------------------------------------------------
+
+_compact = json.JSONEncoder(separators=(",", ":")).encode
+
+
+def json_bytes(obj) -> bytes:
+    """The compact JSON of a snapshot's body: what `write_snapshot`'s
+    `json.dumps(payload, separators=(",", ":"))` makes of `obj`."""
+    return _compact(obj).encode()
+
+
+def pod_fragment(frag: dict | None, pod: Pod) -> bytes:
+    """Serialise a pod for a resident row's snapshot fragment, inside a
+    compaction: the compact JSON bytes of `pod_to_state(pod)` as the
+    row's last journal record carried it.
+
+    A queue entry keeps the state dict its last record carried; the
+    first compaction that meets the entry calls this and keeps the bytes
+    in the dict's place (bytes are not tracked by the collector, the
+    dict's dozen containers are), so a row is serialised once however
+    many compactions it lives through. (The cache serialises where the
+    pod is journaled and never holds the dict.) A row with nothing kept
+    (restored by `load_state`, or placed while no journal was attached)
+    is serialised from the pod itself."""
+    return json_bytes(pod_to_state(pod) if frag is None else frag)
+
+
+# ---------------------------------------------------------------------------
 # digest
 # ---------------------------------------------------------------------------
 

@@ -19,6 +19,19 @@ derived state (backoff expiries = t + backoff(attempts), TTL deadlines
 = t + ttl, attempt counts from pop replay) is reproduced bit-identically
 — the differential tests assert digest equality over randomized traces.
 
+Compaction cost: `snapshot()` does not re-dump the state. Both classes
+hand over their `dump_state()` already as compact JSON
+(`dump_state_json`), spliced from the fragment each resident row keeps
+(the pod as last journaled for that row, as bytes: the cache's made
+where the pod was journaled, a queue entry's by the first compaction
+that meets it), so a compaction serialises the queue entries it has
+never met, and any row a restore left without a fragment, and joins the
+rest. (With `snapshot_interval_seconds` 0, journal only, the cache makes
+none at entry: the seal serialises every row.) `last_snapshot` says how
+many (`rows`, `rows_encoded`) and where the time went (`dump_s`,
+`write_s`, `flush_s`, `prune_s`). The file, the cut, the fsyncs and
+their order are what they were.
+
 Snapshot consistency: the dump and the journal cut happen while holding
 BOTH the queue and cache locks (lock order queue -> cache -> journal
 buffer; no other code path takes two of these at once), so the cut is
@@ -36,6 +49,7 @@ import time as _time
 from typing import Callable
 
 from .codec import (
+    json_bytes,
     node_from_state,
     pod_from_state,
 )
@@ -52,7 +66,7 @@ from .snapshot import (
     prune_snapshots,
     read_latest_snapshot,
     snapshot_indices,
-    write_snapshot,
+    write_snapshot_body,
 )
 
 log = logging.getLogger("k8s_scheduler_tpu.state")
@@ -110,6 +124,9 @@ class DurableState:
         self._cache = None
         self._last_snapshot_at = now()
         self.last_snapshot: dict = {}
+        # pod rows serialised inside compactions since the process
+        # began (the flight records carry it as `snapshot_rows_encoded`)
+        self.rows_encoded = 0
         self.last_restore: dict = {}
         # per-op Counter children memoized so the hot emit path does one
         # dict hit, not a labels() lookup
@@ -137,7 +154,7 @@ class DurableState:
         self._cache = cache
         stats = self.restore_into(queue, cache)
         queue.set_journal(self._emit)
-        cache.set_journal(self._emit)
+        cache.set_journal(self._emit, compacts=self.snapshot_interval > 0)
         return stats
 
     def _emit(self, op: str, t: float, data: dict) -> None:
@@ -391,7 +408,11 @@ class DurableState:
 
     def snapshot(self, clean_shutdown: bool = False) -> str:
         """Dump queue+cache at a journal cut, write durably, prune the
-        compacted segments and older snapshots."""
+        compacted segments and older snapshots. The dump is spliced from
+        the rows' kept fragments (`dump_state_json` of both classes), so
+        what is serialised here is what entered the queue since the last
+        one; the file is the one `write_snapshot` would make of
+        `dump_state()` at this cut."""
         if self._queue is None or self._cache is None:
             raise StateCorruption("snapshot before attach()")
         t0 = _time.perf_counter()
@@ -408,23 +429,26 @@ class DurableState:
                 # buffer between this flush and the cut.
                 with self._batch_lock:
                     self._flush_batch_locked()
-                qstate = self._queue.dump_state()
-                cstate = self._cache.dump_state()
+                qbody, qrows, qenc = self._queue.dump_state_json()
+                cbody, crows, cenc = self._cache.dump_state_json()
                 tail_from = self.journal.cut()
                 t_mono = (
                     self._queue._now()
                     if callable(self._queue._now) else _time.monotonic()
                 )
-        payload = {
+        t_dump = _time.perf_counter()
+        head = json_bytes({
             "format_version": 1,
             "taken_mono": t_mono,
             "taken_wall": _time.time(),
             "clean_shutdown": bool(clean_shutdown),
             "journal_from": tail_from,
-            "queue": qstate,
-            "cache": cstate,
-        }
-        path, nbytes = write_snapshot(self.dir, payload)
+        })
+        path, nbytes = write_snapshot_body(self.dir, tail_from, (
+            head[:-1], b',"queue":', qbody,
+            b',"cache":', cbody, b"}",
+        ))
+        t_write = _time.perf_counter()
         # drain the writer before pruning: records for pre-cut segments
         # may still sit in its buffer, and pruning first would let it
         # recreate a just-deleted segment file (harmless for restore —
@@ -435,10 +459,14 @@ class DurableState:
             self.journal.flush()
         except StateError:
             pass
+        t_flush = _time.perf_counter()
         # only after the snapshot is durable may its inputs disappear
         self.journal.prune(tail_from)
         prune_snapshots(self.dir, tail_from)
-        seconds = _time.perf_counter() - t0
+        t_end = _time.perf_counter()
+        seconds = t_end - t0
+        rows, encoded = qrows + crows, qenc + cenc
+        self.rows_encoded += encoded  # schedlint: disable=TR001 -- same single writer as the two stores below
         self._last_snapshot_at = self._now()  # schedlint: disable=TR001 -- httpserver reaches snapshot() only through the by-name fallback on 'snapshot' (the debug routes call FlightRecorder.snapshot); the sole real caller is the serve loop via maybe_snapshot/seal
         self.last_snapshot = {  # schedlint: disable=TR001 -- same fallback inventory as the line above; single-writer in practice
             "path": path,
@@ -446,12 +474,25 @@ class DurableState:
             "journal_from": tail_from,
             "seconds": round(seconds, 6),
             "clean_shutdown": bool(clean_shutdown),
+            # pod rows in the file, and those of them serialised inside
+            # this compaction (the rest went in as kept fragments)
+            "rows": rows,
+            "rows_encoded": encoded,
+            # the compaction's parts: both locks held (batch flush,
+            # splice, cut); CRC + write + fsync + rename; the journal
+            # writer's barrier; the unlinks
+            "dump_s": round(t_dump - t0, 6),
+            "write_s": round(t_write - t_dump, 6),
+            "flush_s": round(t_flush - t_write, 6),
+            "prune_s": round(t_end - t_flush, 6),
         }
         m = self._metrics
         if m is not None:
             m.snapshot_writes.inc()
             m.snapshot_duration.observe(seconds)
             m.snapshot_bytes.set(nbytes)
+            m.snapshot_rows.labels(source="kept").inc(rows - encoded)
+            m.snapshot_rows.labels(source="encoded").inc(encoded)
         return path
 
     def ack_barrier(self, timeout: float = 10.0) -> bool:

@@ -2,7 +2,10 @@
 
 A snapshot file is the full durable state (queue tiers + cache) at a
 journal cut, so restore = load snapshot + replay segments
-`>= journal_from`. Format:
+`>= journal_from`. The file is whole every time; what a compaction
+serialises is only the rows it has not met before (the body arrives
+spliced from the fragments the cache and the queue keep:
+`write_snapshot_body`). Format:
 
     [8s magic "TPUSSNP\\0"][u32 format_version][u32 crc32(payload)]
     [u32 payload_len][payload JSON]
@@ -20,6 +23,7 @@ import os
 import re
 import struct
 import zlib
+from typing import Sequence
 
 from .journal import (
     FORMAT_VERSION,
@@ -52,16 +56,35 @@ def snapshot_indices(directory: str) -> list[int]:
 def write_snapshot(directory: str, payload: dict) -> tuple[str, int]:
     """Serialize + write the snapshot durably; returns (path, bytes).
     `payload["journal_from"]` names the first journal segment NOT
-    compacted into this snapshot (the replay tail's start)."""
+    compacted into this snapshot (the replay tail's start). The form
+    that defines the file: every snapshot before PR 45 came from here,
+    and the tests hold `write_snapshot_body`'s file to this one's;
+    DurableState.snapshot calls that one."""
     body = json.dumps(payload, separators=(",", ":")).encode()
-    head = _HEAD.pack(
-        SNAPSHOT_MAGIC, FORMAT_VERSION, zlib.crc32(body), len(body)
+    return write_snapshot_body(
+        directory, int(payload["journal_from"]), (body,)
     )
-    final = snapshot_path(directory, int(payload["journal_from"]))
+
+
+def write_snapshot_body(
+    directory: str, journal_from: int, parts: Sequence[bytes]
+) -> tuple[str, int]:
+    """Write a payload that is already compact JSON, handed over in the
+    pieces it was assembled from (their concatenation is the body:
+    DurableState.snapshot splices kept fragments and copies the ~40 MB
+    no second time). Same file as write_snapshot makes of the payload
+    the body parses to."""
+    crc = length = 0
+    for part in parts:
+        crc = zlib.crc32(part, crc)
+        length += len(part)
+    head = _HEAD.pack(SNAPSHOT_MAGIC, FORMAT_VERSION, crc, length)
+    final = snapshot_path(directory, journal_from)
     tmp = final + ".tmp"
     with open(tmp, "wb") as f:
         f.write(head)
-        f.write(body)
+        for part in parts:
+            f.write(part)
         f.flush()
         os.fsync(f.fileno())
     os.replace(tmp, final)
@@ -70,7 +93,7 @@ def write_snapshot(directory: str, payload: dict) -> tuple[str, int]:
         os.fsync(dfd)
     finally:
         os.close(dfd)
-    return final, len(head) + len(body)
+    return final, len(head) + length
 
 
 def read_snapshot(path: str) -> dict:

@@ -209,6 +209,49 @@ def test_cycle_snapshot_appears_only_in_a_cycle_that_compacted(
     st.seal()
 
 
+def test_cycle_snapshot_says_what_it_spliced_and_the_records_keep_the_total(
+        armed, tmp_path):
+    """`cycle.snapshot` carries the pod rows of its file, those of them
+    the compaction serialised itself (the rest went in as kept
+    fragments) and the file's bytes; every flight record of a scheduler
+    with durable state carries the running total of the second as
+    `snapshot_rows_encoded` (a record is committed before the
+    compaction that follows its cycle)."""
+    clock = [0.0]
+    st = DurableState(str(tmp_path), snapshot_interval_seconds=15,
+                      now=lambda: clock[0])
+    svc = service(st)
+    in_records, spans = [], []
+    for i, (pods, nodes) in enumerate(((4, 3), (2, 0), (0, 0), (3, 0))):
+        clock[0] = 16.0 * (i + 1)
+        svc.Update(cluster_request(n_nodes=nodes, n_pods=pods,
+                                   tag=f"t{i}-"), None)
+        before = len(armed.snapshot())
+        svc.Cycle(pb.CycleRequest(), None)
+        (span,) = [s for s in armed.snapshot()[before:]
+                   if s.name == "cycle.snapshot"]
+        spans.append(span.attrs)
+        if pods:  # a cycle that pops nothing commits no record
+            in_records.append(svc.scheduler.flight.last_record().counts[
+                "snapshot_rows_encoded"])
+        assert span.attrs == {k: st.last_snapshot[k] for k in (
+            "rows", "rows_encoded", "bytes")}
+    # each compaction met the queue's in-flight entries of its cycle
+    # and nothing older (the cache's rows came serialised); the cycle
+    # that popped nothing dropped the in-flight set
+    assert [a["rows_encoded"] for a in spans] == [4, 2, 0, 3]
+    assert [a["rows"] for a in spans] == [8, 8, 6, 12]
+    assert in_records == [0, 4, 6]
+    assert st.rows_encoded == 9
+    st.seal()
+    # no durable state, no count
+    plain = service()
+    plain.Update(cluster_request(n_pods=1), None)
+    plain.Cycle(pb.CycleRequest(), None)
+    assert "snapshot_rows_encoded" not in (
+        plain.scheduler.flight.last_record().counts)
+
+
 def test_the_loser_loop_is_two_spans_a_mark_and_three_running_counts(
         armed):
     """A cycle that refuses a pod stamps the wait for the preemption
