@@ -66,6 +66,10 @@ class CycleResult:
     # refused whatever else the cycle placed, and kept out of the
     # compacted window from the round that judged them (ops/rounds.py;
     # 0 in scan mode)
+    round_cap_hit: jnp.ndarray  # i32 [] 1 where the rounds ended at
+    # `max_rounds` with claimants still unjudged (0 in scan mode)
+    spread_revoked: jnp.ndarray  # i32 [] claims the rounds' spread guard
+    # revoked, summed over the rounds (0 in scan mode)
 
 
 @jax.tree_util.register_dataclass
@@ -647,6 +651,11 @@ def _make_cycle_body(
                 closed_for_cycle_fn=lambda vs, vmp, vsm, pf: (
                     fw.closed_for_cycle(view_ctx(vs, vmp), vsm, pf)
                 ),
+                reach_mask_fn=lambda vs, vmp, nr, ex, vsm, pf, act: (
+                    fw.reach_mask_batched(
+                        view_ctx(vs, vmp), nr, ex, vsm, pf, act
+                    )
+                ),
                 **(rounds_kw or {}),
             )
             narrowed = rres.sample_narrowed
@@ -709,6 +718,8 @@ def _make_cycle_body(
             accepted_per_round = rres.accepted_per_round
             diag_per_round = rres.diag_per_round
             rounds_parked = rres.parked
+            round_cap_hit = rres.round_cap_hit
+            spread_revoked = rres.spread_revoked
         else:
             def dyn_fn(p, node_req, ext, static_row):
                 out = fw.dyn(ctx, p, node_req, ext, static_row)
@@ -721,6 +732,7 @@ def _make_cycle_body(
                 return fw.extra_update(ctx, ext, p, node, ok)
 
             rounds_used = rounds_parked = jnp.int32(0)
+            round_cap_hit = spread_revoked = jnp.int32(0)
             accepted_per_round = jnp.zeros((max_rounds,), jnp.int32)
             diag_per_round = jnp.zeros((max_rounds, 3), jnp.int32)
             order = jnp.argsort(snap.pod_order)
@@ -755,7 +767,7 @@ def _make_cycle_body(
                 snap, ctx, result.extra, result.assignment, dropped
             ),
             rounds_used, accepted_per_round, diag_per_round,
-            rounds_parked,
+            rounds_parked, round_cap_hit, spread_revoked,
         ), snap, sample, narrowed)
 
     return cycle
@@ -1431,6 +1443,11 @@ def build_packed_cycle_carry_fn(
             closed_for_cycle_fn=lambda vs, vmp, vsm, pf: (
                 fw.closed_for_cycle(view_ctx(vs, vmp), vsm, pf)
             ),
+            reach_mask_fn=lambda vs, vmp, nr, ex, vsm, pf, act: (
+                fw.reach_mask_batched(
+                    view_ctx(vs, vmp), nr, ex, vsm, pf, act
+                )
+            ),
             **(rounds_kw or {}),
         )
         result = commit_ops.CommitResult(
@@ -1450,7 +1467,7 @@ def build_packed_cycle_carry_fn(
                 snap, ctx, rres.extra, result.assignment, dropped
             ),
             rres.rounds_used, rres.accepted_per_round, rres.diag_per_round,
-            rres.parked,
+            rres.parked, rres.round_cap_hit, rres.spread_revoked,
         ), snap, sample, rres.sample_narrowed)
 
     return _jit(

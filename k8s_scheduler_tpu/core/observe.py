@@ -34,6 +34,9 @@ rebuilt TPU-natively on top of the recorder:
   * `wedge_precursor`— `_Resilient` absorbed new retry strikes this
     cycle (core/cycle.py): the strike classes that precede the rig's
     executable-cache wedge;
+  * `round_cap_hit`  — a cycle's commit rounds ended at `max_rounds`
+    with claimants still unjudged (ops/rounds.py): pods may have been
+    refused beside open nodes, which no latency shows;
   * `degraded`       — a degradation-ladder rung transition
     (core/degrade.py), raised externally via `raise_anomaly` with the
     from/to rung names and the triggering reason in the detail.
@@ -117,6 +120,9 @@ ANOMALY_CLASSES = (
     "recompile",
     "fold_miss",
     "wedge_precursor",
+    # the flight records' running `round_cap_hits` rose: this cycle's
+    # commit rounds ended at max_rounds with claimants unjudged
+    "round_cap_hit",
     # a degradation-ladder rung transition (core/degrade.py): raised
     # externally via raise_anomaly — both directions, with the from/to
     # rung names and the triggering reason in the detail
@@ -704,6 +710,18 @@ class CycleObserver:
                 ]
                 if delta > 0:
                     raise_anomaly("wedge_precursor", strikes=delta)
+            if "round_cap_hits" in counts:
+                # the scheduler's running total over every profile
+                prev_v = self._global_counts.get("round_cap_hits")
+                self._global_counts["round_cap_hits"] = counts[
+                    "round_cap_hits"
+                ]
+                if prev_v is not None and counts["round_cap_hits"] > prev_v:
+                    raise_anomaly(
+                        "round_cap_hit", phase="device",
+                        value_s=phases.get("device", 0.0),
+                        commit_rounds=counts.get("commit_rounds"),
+                    )
 
             # -- speculation thrash: EWMA of the abandon rate over
             # speculated batches (one sample per speculation — the

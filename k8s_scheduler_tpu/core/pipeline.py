@@ -241,9 +241,10 @@ class CycleHandle:
             if hasattr(result, "sample_k") else None
         )
         # ... and a program that reports its commit rounds (a
-        # CycleResult; the latency subset carries none) two more
+        # CycleResult; the latency subset carries none) four more
         self._rounds = (
-            (result.rounds_used, result.rounds_parked)
+            (result.rounds_used, result.rounds_parked,
+             result.round_cap_hit, result.spread_revoked)
             if hasattr(result, "rounds_parked") else None
         )
         self._wbuf = wbuf
@@ -300,6 +301,8 @@ class CycleHandle:
                 st["fetch_bytes"] += sum(int(v.nbytes) for v in rounds)
                 st["commit_rounds"] = int(rounds[0])
                 st["rounds_parked"] = int(rounds[1])
+                st["round_cap_hits"] = int(rounds[2])
+                st["spread_revoked"] = int(rounds[3])
             # what the un-slimmed fetch of the same fields would move
             st["fetch_bytes_full"] = int(a.shape[0] * (4 + 1 + 1))
             self._pipe._fetch_bytes_total += st["fetch_bytes"]

@@ -224,9 +224,12 @@ class Scheduler:
         self._pod_spans = False
         self._cycle_trace = None
         self.last_cycle_seqs: list[int] = []
-        # percentageOfNodesToScore's two counts over the same records
-        # (empty when no program of the cycle sampled): the RPC span's
-        self.last_cycle_sample: dict[str, int] = {}
+        # what the cycle's programs counted over the same records, for
+        # the RPC span: percentageOfNodesToScore's two counts (absent
+        # when no program of the cycle sampled) and the commit rounds'
+        # `round_cap_hits` and `spread_revoked` (absent when none
+        # reported its rounds)
+        self.last_cycle_counts: dict[str, int] = {}
         # durable state (state/ package): restore-then-journal. Attach
         # happens here — after queue/cache exist, before any cycle — so
         # a standby that just won the FileLease resumes with the exact
@@ -416,9 +419,12 @@ class Scheduler:
         self._fold_seen: dict[str, tuple[int, int]] = {}
         # running totals every flight record carries (_commit_record):
         # the commit rounds the cycle programs used, the pods those
-        # rounds parked (ops/rounds.py) and the pods refused
+        # rounds parked, the cycles whose rounds ended at `max_rounds`
+        # with claimants unjudged, the claims the spread guard revoked
+        # (ops/rounds.py) and the pods refused
         self._round_totals = {
-            "commit_rounds": 0, "rounds_parked": 0, "refusals": 0,
+            "commit_rounds": 0, "rounds_parked": 0, "round_cap_hits": 0,
+            "spread_revoked": 0, "refusals": 0,
         }
         if self.extenders:
             # extender verdicts are consulted per HOST cycle; inner
@@ -997,7 +1003,7 @@ class Scheduler:
         self._cycle_fault = False
         self._cycle_trace = trace
         self.last_cycle_seqs = []
-        self.last_cycle_sample = {}
+        self.last_cycle_counts = {}
         t_entry = _spans.now() if trace is not None else 0.0
         # the per-pod stamp sites run only while some pod is bound to a
         # trace (Submit registers them; the agent path never does)
@@ -2746,7 +2752,7 @@ class Scheduler:
             # with the decisions (core/pipeline.CycleHandle)
             k, narrowed = st["sample_k"], st["sample_narrowed_pods"]
             rec.counts.update(sample_k=k, sample_narrowed_pods=narrowed)
-            seen = self.last_cycle_sample
+            seen = self.last_cycle_counts
             seen["sample_k"] = k
             seen["sample_narrowed_pods"] = (
                 seen.get("sample_narrowed_pods", 0) + narrowed
@@ -2754,12 +2760,21 @@ class Scheduler:
         tot = self._round_totals
         if "commit_rounds" in st:
             # fetched with the decisions too: the rounds this cycle's
-            # program ran and the pods it parked as refused for the
-            # cycle. The records keep running totals, as `full_encodes`
-            tot["commit_rounds"] += st["commit_rounds"]
-            tot["rounds_parked"] += st["rounds_parked"]
-            self.metrics.commit_rounds.inc(st["commit_rounds"])
-            self.metrics.rounds_parked_pods.inc(st["rounds_parked"])
+            # program ran, the pods it parked as refused for the cycle,
+            # whether `max_rounds` ended its loop and the claims its
+            # spread guard revoked. The records keep running totals, as
+            # `full_encodes`; the RPC span carries the cycle's own
+            for key, counter in (
+                ("commit_rounds", self.metrics.commit_rounds),
+                ("rounds_parked", self.metrics.rounds_parked_pods),
+                ("round_cap_hits", self.metrics.round_cap_hits),
+                ("spread_revoked", self.metrics.spread_revoked_claims),
+            ):
+                tot[key] += st[key]
+                counter.inc(st[key])
+            seen = self.last_cycle_counts
+            for key in ("round_cap_hits", "spread_revoked"):
+                seen[key] = seen.get(key, 0) + st[key]
         tot["refusals"] += stats.unschedulable - before[1]
         # rows the existing-set fold built in Python since the last
         # record: bound pods the native row writer does not cover

@@ -140,6 +140,21 @@ class PluginBase:
         (Framework.closed_for_cycle)."""
         return None
 
+    def dyn_mask_reach_batched(self, ctx: CycleContext, node_requested,
+                               extra, shared: dict,
+                               active) -> tuple | None:
+        """(mask bool [P, N], share f32 [P, N], domain i32 [P, N]) as
+        the rounds engine lets the `active` (bool [P]) pods CLAIM within
+        one round, or None where claims go by `dyn_mask_batched` as it
+        stands (the default). `mask` is that mask plus the nodes that
+        acceptances of the same round can open; `domain` groups the
+        nodes (-1: ungrouped) and `share` says how much of the group's
+        claims each domain should draw. A plugin that answers must have
+        a guard in the round's sweep that holds every acceptance to its
+        rule (ops/rounds.py: the spread guard's level-fill); what it
+        returns here only says where claims go."""
+        return None
+
     def dyn_score_batched(self, ctx: CycleContext, node_requested, extra,
                           feasible, shared: dict) -> jnp.ndarray | None:
         """`feasible` is the full [P, N] feasibility (static & dynamic)

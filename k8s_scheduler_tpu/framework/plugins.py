@@ -499,6 +499,23 @@ class PodTopologySpread(PluginBase):
             ctx.snap, state, cbn, shared["spread_minc"]
         )
 
+    def dyn_mask_reach_batched(self, ctx: CycleContext, node_requested,
+                               extra, shared, active):
+        # a round's own acceptances raise the minimum: claims may go to
+        # every domain the group's claimants can lift it to
+        if not ctx.snap.has_topology_spread:
+            return None
+        state = _affinity_state(ctx, extra)
+        cbn = _shared_cbn(ctx, state, shared)
+        reach = interpod_ops.spread_reach(
+            ctx.snap, state, interpod_ops.spread_minc(ctx.snap, state),
+            active,
+        )
+        return (
+            interpod_ops.spread_mask_batched(ctx.snap, state, cbn, reach),
+            *interpod_ops.spread_claim_share(ctx.snap, state, cbn, reach),
+        )
+
     def dyn_mask_reopens(self, ctx: CycleContext):
         # a DoNotSchedule constraint lets a domain in again once the
         # minimum over the domains has risen

@@ -220,6 +220,29 @@ class Framework:
                 score = score + w * v
         return mask, score, per_filter
 
+    def reach_mask_batched(self, ctx: CycleContext, node_requested, extra,
+                           static_mask, per_filter, active):
+        """(mask, share, domain) the `active` pods CLAIM by within one
+        round: `dyn_batched`'s mask with the filter that can say where
+        the round's own acceptances open a node read that way, and that
+        filter's share and domain (PluginBase.dyn_mask_reach_batched;
+        the first filter that answers, today the only one). None when
+        no filter does, so a cycle without such a constraint traces
+        nothing here."""
+        shared: dict = {}
+        mask, steer = static_mask, None
+        for f, m in zip(self.filters, per_filter):
+            w = (
+                f.dyn_mask_reach_batched(
+                    ctx, node_requested, extra, shared, active
+                ) if steer is None else None
+            )
+            if w is not None:
+                m, steer = w[0], w[1:]
+            if m is not None:
+                mask = mask & m
+        return None if steer is None else (mask, *steer)
+
     def closed_for_cycle(self, ctx: CycleContext, static_mask,
                          per_filter):
         """bool [P]: the pods that no placement made later in this cycle

@@ -76,7 +76,8 @@ streaming consumer of every flight record):
   the observer's streaming histograms, evaluated at scrape time
 - scheduler_anomalies_total{class} — typed anomaly detections
   (tunnel_stall | fetch_stall | recompile | fold_miss |
-  wedge_precursor | degraded | speculation_thrash); each increment has
+  wedge_precursor | round_cap_hit | degraded | speculation_thrash);
+  each increment has
   a matching structured event in /debug/anomalies carrying the cycle
   seq
 - scheduler_slo_burn_rate{window} — latency-SLO burn rate over the
@@ -121,6 +122,14 @@ round trip):
   no placement later in the cycle could have given them a node, so
   they were refused for the cycle the round that judged them and kept
   out of the rounds' compacted window
+- scheduler_round_cap_hits_total — cycles whose commit rounds ended at
+  `max_rounds` with claimants still unjudged (ops/rounds.py
+  `round_cap_hit`): pods may have been refused beside open nodes; 0 in
+  a sound run
+- scheduler_spread_revoked_claims_total — claims the commit rounds'
+  spread guard revoked (a DoNotSchedule constraint's domain stood above
+  the level the round's arrivals lifted the minimum to; the pod claims
+  again next round), summed over each cycle's rounds
 
 Multi-chip serving families (shardDevices + parallel/audit.py — the
 sharded carry path with shard-invariant tie-breaking):
@@ -441,6 +450,18 @@ class SchedulerMetrics:
             "have given them a node.",
             registry=r,
         )
+        self.round_cap_hits = Counter(
+            "scheduler_round_cap_hits_total",
+            "Cycles whose commit rounds ended at max_rounds with "
+            "claimants still unjudged (0 in a sound run).",
+            registry=r,
+        )
+        self.spread_revoked_claims = Counter(
+            "scheduler_spread_revoked_claims_total",
+            "Claims the commit rounds' spread guard revoked, summed over "
+            "each cycle's rounds.",
+            registry=r,
+        )
         self.fold_fallback_pods = Counter(
             "scheduler_encode_fold_fallback_pods_total",
             "Newly bound pods whose existing-set row the incremental "
@@ -532,7 +553,8 @@ class SchedulerMetrics:
             "scheduler_anomalies_total",
             "Typed anomaly detections from the cycle observer "
             "(tunnel_stall | fetch_stall | recompile | fold_miss | "
-            "wedge_precursor | degraded | speculation_thrash); each "
+            "wedge_precursor | round_cap_hit | degraded | "
+            "speculation_thrash); each "
             "has a structured /debug/anomalies event carrying the "
             "cycle seq.",
             ["class"],

@@ -434,6 +434,12 @@ def affinity_score_batched(snap, state: AffinityState, m_pending, cbn,
     return jnp.where(hi > 0, raw / hi * 100.0, 0.0)
 
 
+def _eligible(snap, k: int) -> jnp.ndarray:  # bool [D]
+    """The domains of topology key k that hold a node: the ones a spread
+    group's minimum is taken over."""
+    return (snap.domain_key == k) & (snap.domain_node_count > 0)
+
+
 def spread_minc(snap, state: AffinityState) -> jnp.ndarray:  # f32 [K*S]
     """min matching-pod count over eligible domains, per (key, selector) —
     the `minc` of the spread rule, shared by all pods."""
@@ -441,12 +447,90 @@ def spread_minc(snap, state: AffinityState) -> jnp.ndarray:  # f32 [K*S]
     S, D = state.counts.shape
     outs = []
     for k in range(K):
-        eligible = (snap.domain_key == k) & (snap.domain_node_count > 0)  # [D]
+        eligible = _eligible(snap, k)
         m = jnp.min(
             jnp.where(eligible[None, :], state.counts, jnp.inf), axis=1
         )  # [S]
         outs.append(jnp.where(jnp.isfinite(m), m, 0.0))
     return jnp.concatenate(outs, axis=0)  # schedlint: disable=SH002 -- per-key [S] minima on the replicated selector axis; never pods-sharded
+
+
+def spread_reach(snap, state: AffinityState, minc,
+                 active) -> jnp.ndarray:  # f32 [K*S]
+    """The level one commit round can lift a (key, selector) group's
+    minimum to: pour the group's `n` claimants (the `active` pods that
+    carry a DoNotSchedule constraint on it) into its eligible domains,
+    lowest first, and read the water line, i.e. the largest T with
+    sum_d max(0, T - count_d) <= n. It is `minc` where nobody claims.
+    `spread_mask_batched` given this level in place of `minc` admits
+    every domain the round's own acceptances can open, which is what
+    the rounds engine lets pods CLAIM (ops/rounds.py: the guard sweep,
+    not this mask, then holds every acceptance to the skew)."""
+    K = snap.node_domains.shape[1]
+    S, D = state.counts.shape
+    k = snap.pod_tsc[..., 0]
+    hard = (k >= 0) & (
+        snap.pod_tsc[..., 2] == enc.WHEN_DO_NOT_SCHEDULE
+    ) & active[:, None]
+    row = jnp.clip(k, 0, K - 1) * S + jnp.clip(snap.pod_tsc[..., 1], 0, S - 1)
+    n = jnp.zeros((K * S,), jnp.float32).at[row.reshape(-1)].add(
+        hard.reshape(-1).astype(jnp.float32)
+    )
+    eligible = [_eligible(snap, kk) for kk in range(K)]
+
+    def halve(_, lh):
+        lo, hi = lh
+        mid = jnp.floor((lo + hi + 1.0) * 0.5)
+        need = jnp.concatenate([  # schedlint: disable=SH002 -- per-key [S] sums on the replicated selector axis; never pods-sharded
+            jnp.sum(jnp.where(
+                eligible[kk][None, :],
+                jnp.maximum(mid[kk * S:(kk + 1) * S, None] - state.counts,
+                            0.0),
+                0.0,
+            ), axis=1)
+            for kk in range(K)
+        ])
+        fits = need <= n
+        return jnp.where(fits, mid, lo), jnp.where(fits, hi, mid - 1.0)
+
+    # the line lies in [minc, minc + n] and n <= P: bisect to a unit
+    steps = int(snap.pod_tsc.shape[0]).bit_length() + 1
+    lo, _ = jax.lax.fori_loop(0, steps, halve, (minc, minc + n))
+    return lo
+
+
+def spread_claim_share(snap, state: AffinityState, cbn, reach):
+    """(share f32 [P, N], domain i32 [P, N]) for the rounds engine's
+    claims under `spread_reach`'s level: under each pod's FIRST
+    DoNotSchedule constraint, how many pods node n's domain takes
+    before it stands at the level (what pouring gives the domain; 0 at
+    or above the line), and that domain's id (-1: the pod has no such
+    constraint, or the node lacks the key). A group whose claimants
+    pick their domain with odds as these shares arrive as the pouring
+    would place them, whatever the nodes' scores say."""
+    P, N = snap.P, snap.N
+    K = snap.node_domains.shape[1]
+    S = state.counts.shape[0]
+    share = jnp.zeros((P, N), jnp.float32)
+    domain = jnp.full((P, N), -1, jnp.int32)
+    taken = jnp.zeros((P,), bool)
+    for c in range(snap.pod_tsc.shape[1]):
+        k = snap.pod_tsc[:, c, 0]
+        sel = snap.pod_tsc[:, c, 1]
+        first = (k >= 0) & (
+            snap.pod_tsc[:, c, 2] == enc.WHEN_DO_NOT_SCHEDULE
+        ) & ~taken
+        taken |= first
+        kcl = jnp.clip(k, 0, K - 1)
+        cnt = _term_counts(snap, cbn, sel, k)  # [P, N]
+        line = reach[kcl * S + jnp.clip(sel, 0, S - 1)]  # [P]
+        share = jnp.where(
+            first[:, None], jnp.maximum(line[:, None] - cnt, 0.0), share
+        )
+        domain = jnp.where(
+            first[:, None], snap.node_domains.T[kcl], domain
+        )
+    return share, domain
 
 
 def spread_mask_batched(snap, state: AffinityState, cbn,
@@ -562,7 +646,7 @@ def spread_min2(snap, counts):
     d_ids = jnp.arange(D, dtype=jnp.int32)[None, :]
     m1s, aas, m2s = [], [], []
     for k in range(K):
-        eligible = (snap.domain_key == k) & (snap.domain_node_count > 0)
+        eligible = _eligible(snap, k)
         vals = jnp.where(eligible[None, :], counts, jnp.inf)  # [S, D]
         a1 = jnp.argmin(vals, axis=1).astype(jnp.int32)  # [S]  # schedlint: disable=SH001 -- reduce over the domain axis D, which is never mesh-sharded (MESH_AXES is pods/nodes); counts ties are broken identically on every replica
         m1 = jnp.min(vals, axis=1)

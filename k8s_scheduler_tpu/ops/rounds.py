@@ -38,7 +38,9 @@ the MXU/VPU at full width:
                   row per (claimant, constraint-role) — sorted by
                   (group, rank) and swept with segmented exclusive scans.
                   Rank order within a group decides, exactly like the
-                  sequential scan would have.
+                  sequential scan would have. The spread guard comes
+                  last and holds a domain to the level the round's own
+                  arrivals lift the group's minimum to (rounds_commit).
   3. UPDATE  — accepted placements fold into the running state in one
                batched pass (segment-adds into domain counts, scatter
                rows into the symmetric tables, port-bitmap scatter).
@@ -104,13 +106,16 @@ TIE_EPS = 0.9375  # hash spread, strictly below the integer quantum
 # package — a plain gRPC client (service/client.py) would take the chip
 _PR1 = np.uint32(2654435761)
 _PR2 = np.uint32(40503)
+_PR3 = np.uint32(0x85EBCA6B)  # with _PR4, murmur3's finishing multipliers:
+_PR4 = np.uint32(0xC2B2AE35)  # the domain draw of one_round
 _BIG = np.int32(2**31 - 1)
 
 # In the name of every program that embeds this engine (core/cycle.py
 # appends it to the name's discriminator): the executable store keys on
 # names and call conventions, not on code, and an entry built by an
-# engine that did not park (see rounds_commit) must never load.
-ENGINE_MARK = ":parks"
+# engine that did not park, or that held a spread group to the counts
+# at the round's start (see rounds_commit), must never load.
+ENGINE_MARK = ":levels"
 
 # participant role bits (packed into one sort operand)
 _RB_MATCH = 1
@@ -135,6 +140,10 @@ class RoundsResult:
     # diagnostics, negligible cost
     parked: jnp.ndarray  # i32 [] pods parked for the rest of the cycle
     # (see rounds_commit)
+    round_cap_hit: jnp.ndarray  # i32 [] 1 where the loop ended at
+    # `max_rounds` with claimants still to be judged, else 0
+    spread_revoked: jnp.ndarray  # i32 [] claims the spread guard revoked,
+    # summed over the rounds
     sample_narrowed: jnp.ndarray | None = None  # i32 [] pods whose
     # feasible nodes outnumbered the sample's k in round 1 (None: the
     # cycle does not sample)
@@ -240,6 +249,12 @@ def rounds_commit(
     #   -> bool [B]: pods no acceptance of this cycle can give a node
     #   (Framework.closed_for_cycle), from the per-filter masks the
     #   round computed anyway
+    reach_mask_fn: Callable | None = None,  # (vsnap, vmp, node_req, ext,
+    #   vsmask, per_filter, act_v) -> (mask bool, share f32, domain i32,
+    #   each [B, N]) | None: what the round's claims go by where that is
+    #   wider than the filters' own mask (Framework.reach_mask_batched:
+    #   the domains a spread group's claimants can open within the
+    #   round, and how many of them each domain should draw)
     extra: Any,
     max_rounds: int = 64,
     compact: int = 8,
@@ -318,7 +333,45 @@ def rounds_commit(
     fit hundreds of nodes were refused (PERF.md section 6, PR 36).
 
     On inputs where no pod parks, the window, the scores, the tie-break,
-    the guards and every placement are bit for bit what they were."""
+    the guards and every placement are bit for bit what they were.
+
+    **One spread group is filled level by level within a round.** For a
+    (topology key, selector) group let `cnt_d` be domain d's count at
+    the round's start and `a_d` the capacity-accepted claimants that
+    arrive in d. The level the round reaches is `m' = min over eligible
+    d of (cnt_d + a_d)` (a domain nobody claims holds it at `cnt_d`),
+    and d accepts, in rank order, the claimants with fewer than
+    `maxSkew + m' - cnt_d` matching arrivals ranked before them. Every
+    domain then ends at `m'` or above, so each accepted claim met
+    `count + 1 - minimum <= maxSkew` in the serial order that places
+    into the lowest domain first, and where every carrier has the same
+    `maxSkew` no domain a pod went to ends more than `maxSkew` above the
+    minimum. (The engine before held d to `maxSkew + minimum - cnt_d`
+    at the round's START: one acceptance a zone a round at `maxSkew` 1,
+    so a group of 2,000 ended at `max_rounds` with ~1,600 refused beside
+    open nodes, ISSUE 46.)
+    A claim another guard revokes in the same sweep arrives nowhere and
+    would lower the true level, so the OTHER GUARDS ARE SETTLED FIRST:
+    `a_d` and the places in rank order count only claims they left
+    standing, and of those only the sure ones (`spread_level_fill`: no
+    DoNotSchedule constraint, or exactly one, on this very group, that
+    passes at the level; the level is tried and, where the sure
+    arrivals do not carry it, the group falls back to the round's
+    start). For `m'` to rise, claims have to ARRIVE in every domain up
+    to it: `reach_mask_fn` lets a group's claimants claim every domain
+    below the line that pouring all of them into the group gives
+    (`interpod.spread_reach`), each within one domain drawn with odds
+    as the pouring's shares. Claims by that wider mask are only ever
+    accepted through the guard; a round of them that accepts nothing
+    proves nothing, and the window is judged again by the filters' own
+    mask before the sweep steps on (`body`).
+
+    On inputs where no spread rule binds (the wider mask is wider for
+    no row, and no claim stands at or past its domain's room),
+    placements stay bit for bit what they were; where one binds they
+    may differ, and `ENGINE_MARK` differs either way. `round_cap_hit`
+    and `spread_revoked` are the two counts by which a cycle that ends
+    at `max_rounds`, and the guard's work, are seen."""
     P, N = (sbase if sbase is not None else static_mask).shape
     S = m_pending.shape[0]
     D = snap.domain_key.shape[0]
@@ -394,23 +447,27 @@ def rounds_commit(
             static_mask, jnp.clip(static_score, -1e6, 1e6), NEG_INF
         )  # [P, N]
 
-    def guards_ok(vsnap, vrank, vsels, choice, live, ext_state):
+    def guards_ok(vsnap, vmp, vrank, vsels, choice, live, ext_state):
         """Participant-table sweep over the round's accepted claims;
-        ok bool [B]. Within a (selector/port, domain/node) group, entries
-        resolve in rank order — the same outcome a sequential pass over
-        the claims would produce."""
+        (ok bool [B], claims the spread guard revoked i32 []). Within a
+        (selector/port, domain/node) group, entries resolve in rank
+        order — the same outcome a sequential pass over the claims
+        would produce. The spread guard is settled AFTER every other
+        guard, over the claims those left standing (see
+        `spread_level_fill`)."""
         B = vrank.shape[0]
         state = _owner_state(ext_state) if has_guards else None
         if state is None and not has_port_guards and not has_pv_guards:
-            return jnp.ones((B,), bool)
+            return jnp.ones((B,), bool), jnp.int32(0)
         nsafe = jnp.clip(choice, 0, N - 1)
 
-        keys, role_ids, caps = [], [], []
+        keys, role_ids = [], []
+        spread_terms = []  # per constraint slot: (emit slot, hard bool
+        # [B], group row i32 [B], skew - count at the round's start f32 [B])
 
-        def emit(key, valid, role, cap=None):
+        def emit(key, valid, role):
             keys.append(jnp.where(valid & live, key, GK_INVALID))
             role_ids.append(role)
-            caps.append(cap)
 
         if state is not None:
             # each capability pays only for its own machinery: affinity-
@@ -431,7 +488,6 @@ def rounds_commit(
                     emit(GK_GLOBAL + scl, (sel >= 0) & boot_active[scl],
                          _RB_BOOT)
             if snap.has_topology_spread:
-                minc = interpod_ops.spread_minc(snap, state)  # [K*S]
                 for c in range(MC):
                     k = vsnap.pod_tsc[:, c, 0]
                     sel = vsnap.pod_tsc[:, c, 1]
@@ -443,13 +499,11 @@ def rounds_commit(
                         d >= 0
                     )
                     cnt = state.counts[scl, jnp.clip(d, 0, D - 1)]  # [B]
-                    mc = minc[kcl * S + scl]
-                    cap = (
-                        vsnap.pod_tsc_skew[:, c].astype(jnp.float32)
-                        - cnt + mc
-                    ).astype(jnp.int32)
-                    emit(scl * (D + 1) + (d + 1), hard, _RB_SPREAD,
-                         cap=jnp.maximum(cap, 1))
+                    spread_terms.append((
+                        len(keys), hard & live, kcl * S + scl,
+                        vsnap.pod_tsc_skew[:, c].astype(jnp.float32) - cnt,
+                    ))
+                    emit(scl * (D + 1) + (d + 1), hard, _RB_SPREAD)
             # matchers feed the anti guard AND the spread arrival counts —
             # needed whenever either capability is on
             for m in range(MS_MATCH):
@@ -488,22 +542,16 @@ def rounds_commit(
         keys_c = jnp.stack(keys, axis=0).reshape(-1)
         n_emit = len(keys)
         ranks_c = jnp.tile(vrank, n_emit)
-        # Collective-payload diet: the claimant id, role, and cap of
-        # table entry j are all FUNCTIONS of position (claimant j % B of
-        # emit slot j // B; roles are per-slot trace constants), so the
-        # sweep gathers NONE of them through the sort — the permutation
-        # alone reconstructs pods/roles, and the caps column is gathered
-        # only when a spread emit actually produced one. (The old
-        # stacked [L, 3] payload gather was the audit's s32[283136,3]
-        # all-reduce — 3.4 MB at the P=10112 shape — for data the sort
-        # result already encodes.)
+        # Collective-payload diet: the claimant id and role of table
+        # entry j are FUNCTIONS of position (claimant j % B of emit slot
+        # j // B; roles are per-slot trace constants), so the sweep
+        # gathers NEITHER through the sort — the permutation alone
+        # reconstructs pods/roles, and a spread entry's place in its
+        # domain goes back to its (slot, claimant) the same way. (The
+        # old stacked [L, 3] payload gather was the audit's
+        # s32[283136,3] all-reduce — 3.4 MB at the P=10112 shape — for
+        # data the sort result already encodes.)
         role_tab = jnp.asarray(role_ids, jnp.int32)  # [n_emit] constant
-        needs_caps = any(c is not None for c in caps)
-        if needs_caps:
-            caps_c = jnp.stack([  # stack, not concatenate (see keys_c)
-                c if c is not None else jnp.full((B,), 2**30, jnp.int32)
-                for c in caps
-            ], axis=0).reshape(-1)
 
         # The participant-table sort dominates the sweep. When (key, rank)
         # fits one u32 word, sort a SINGLE packed operand plus an iota
@@ -532,7 +580,6 @@ def rounds_commit(
         slot = perm // B
         pods_s = perm - slot * B
         role_s = role_tab[slot]
-        cap_s = caps_c[perm] if needs_caps else None
         before = _seg_scan_tables(
             keys_s, pods_s,
             {
@@ -542,8 +589,6 @@ def rounds_commit(
                 "gmatch": (role_s == _RB_GMATCH).astype(jnp.int32),
                 "port": (role_s == _RB_PORT).astype(jnp.int32),
                 "pv": (role_s == _RB_PV).astype(jnp.int32),
-                "arrive": ((role_s == _RB_MATCH) | (role_s == _RB_SPREAD))
-                .astype(jnp.int32),
             },
         )
         ok_e = jnp.ones(keys_s.shape, bool)
@@ -554,24 +599,104 @@ def rounds_commit(
             (before["boot"] == 0) & (before["gmatch"] == 0),
             True,
         )
-        if needs_caps:
-            # only spread emits carry a cap, and they exist iff a cap
-            # column was built — without one no row has _RB_SPREAD
-            ok_e &= jnp.where(
-                role_s == _RB_SPREAD, before["arrive"] < cap_s, True
-            )
         ok_e &= jnp.where(role_s == _RB_PORT, before["port"] == 0, True)
         ok_e &= jnp.where(role_s == _RB_PV, before["pv"] == 0, True)
         ok_e |= keys_s == GK_INVALID
         ok_pod = (
             jnp.ones((B,), jnp.int32).at[pods_s].min(ok_e.astype(jnp.int32))
+        ) > 0
+        if not spread_terms:
+            return ok_pod, jnp.int32(0)
+        # a spread claimant's place in its (selector, domain): the
+        # matching arrivals ranked before it that the other guards left
+        # standing (a claim they revoked arrives nowhere), sent back
+        # from the sorted table to its (slot, claimant)
+        ahead_s = _seg_scan_tables(
+            keys_s, pods_s,
+            {"m": ((role_s == _RB_MATCH) & ok_pod[pods_s])
+             .astype(jnp.int32)},
+        )["m"]
+        ahead = (
+            jnp.zeros(keys_s.shape, jnp.int32)
+            .at[perm].set(ahead_s, unique_indices=True)
+            .reshape(n_emit, B)
         )
-        return ok_pod > 0
+        ok_spread = spread_level_fill(
+            vmp, node_dom, live & ok_pod, state,
+            [(hard, row, room, ahead[sl].astype(jnp.float32))
+             for sl, hard, row, room in spread_terms],
+        )
+        n_revoked = jnp.sum(live & ok_pod & ~ok_spread, dtype=jnp.int32)
+        return ok_pod & ok_spread, n_revoked
 
-    def one_round(gid, act_v, node_req, ext, passes: int,
+    def spread_level_fill(vmp, node_dom, standing, state, terms):
+        """The spread guard: ok bool [B] over the claims `standing`
+        after every other guard. `terms` holds, per constraint slot,
+        (hard bool [B], the (key, selector) row i32 [B], `room` =
+        maxSkew less the domain's count at the round's start f32 [B],
+        `ahead` = standing matching arrivals of the same (selector,
+        domain) ranked before the claimant f32 [B]).
+
+        A claimant is held to `ahead < room + level`, the level being
+        what the group's minimum is SURE to reach in this round (the
+        docstring of `rounds_commit` has the rule and why it is sound).
+        Sure arrivals of a (selector, domain) are the standing
+        claimants that match the selector and carry no DoNotSchedule
+        constraint at all, and those whose ONE such constraint is on
+        this very group and passes at the level tried. The level tried
+        is the minimum, over the group's eligible domains, of count +
+        every such claimant, passing or not; it stands if the minimum
+        over count + the sure arrivals at that level comes out no
+        lower, and else the group falls back to the minimum at the
+        round's start for this round. A claimant with two or more such
+        constraints, or one on another group than it is counted in, is
+        never a sure arrival (it may be revoked elsewhere) and is
+        itself held to the round's start: conservative, and rare."""
+        n_hard = sum(t[0].astype(jnp.int32) for t in terms)
+        free = standing & (n_hard == 0)
+        single = standing & (n_hard == 1)
+        # a single claimant's one group (garbage where not single)
+        own = sum(jnp.where(t[0], t[1], 0) for t in terms)
+        s_ids = jnp.arange(S, dtype=jnp.int32)[:, None]
+        d_ids = jnp.arange(D, dtype=jnp.int32)[None, :]
+        oh_d = [
+            (node_dom[:, k][:, None] == d_ids).astype(jnp.float32)
+            for k in range(K)
+        ]  # K x [B, D]; a node without the key (-1) lands nowhere
+
+        def level(sure_single):
+            """min over eligible domains of count + sure arrivals,
+            f32 [K*S]."""
+            arrive = jnp.zeros((S, D), jnp.float32)
+            for k in range(K):
+                mine = sure_single[None, :] & (own[None, :] == k * S + s_ids)
+                arrive = arrive + jax.lax.dot(
+                    (vmp & (free[None, :] | mine)).astype(jnp.float32),
+                    oh_d[k],
+                )
+            return interpod_ops.spread_minc(
+                snap, dataclasses.replace(state, counts=state.counts + arrive)
+            )
+
+        def passes(lvl_of):
+            ok = jnp.ones(standing.shape, bool)
+            for hard, row, room, ahead in terms:
+                ok &= ~hard | (ahead < room + lvl_of(row))
+            return ok
+
+        start = interpod_ops.spread_minc(snap, state)
+        tried = level(single)
+        holds = level(single & passes(lambda row: tried[row])) >= tried
+        lvl = jnp.where(holds, tried, start)
+        return passes(
+            lambda row: jnp.where(single, lvl[row], start[row])
+        )
+
+    def one_round(gid, act_v, node_req, ext, passes: int, reach, rnd,
                   identity_gid: bool = False):
         """One round over the pods in `gid` (global ids; `act_v` marks
-        which rows are genuinely active).
+        which rows are genuinely active; `reach` (bool []) lets claims
+        go by `reach_mask_fn`'s wider mask; `rnd` is the round's number).
 
         The round computes plugin masks/scores ONCE, then runs `passes`
         CAPACITY-ONLY acceptance passes: in each pass every
@@ -638,6 +763,47 @@ def rounds_commit(
             vsnap, vmp, vsmask, per_filter
         )
         mask = mask & vsmask & act_v[:, None]
+        # where claims may go: the filters' own mask, or with `reach`
+        # the wider one (a spread group's domains up to the level its
+        # claimants can lift the minimum to within this round; the
+        # guard sweep holds every acceptance to the skew). `reached`
+        # says the wider mask was in force AND was wider for some row:
+        # only then can a round without an acceptance have passed over
+        # a node the filters' own mask held open
+        reached = jnp.zeros((), bool)
+        wide = (
+            reach_mask_fn(vsnap, vmp, node_req, ext, vsmask, per_filter,
+                          act_v)
+            if reach_mask_fn is not None else None
+        )
+        if wide is not None:
+            wide, share, domain = wide
+            wide = wide & act_v[:, None]
+            # the rows the wider mask is wider FOR: a pod no rule holds
+            # back claims as it always did, bit for bit
+            bound = reach & jnp.any(wide & ~mask, axis=1)  # [B]
+            reached = jnp.any(bound)
+            # Such a pod claims within ONE domain, drawn with odds as
+            # the domains' shares (the largest of log(u) / share, u a
+            # hash of (pod, domain, round): weighted sampling without a
+            # table), and the best node by score within it. Left to the
+            # scores, a group's claimants crowd the domains whose nodes
+            # look best, the others draw nobody, their count holds the
+            # level down and the guard revokes nearly every claim. A
+            # fresh draw every round: a claim revoked in a domain the
+            # round could not open after all goes elsewhere next time.
+            h = (
+                gid.astype(jnp.uint32)[:, None] * _PR1
+                + (domain.astype(jnp.uint32) + 1) * _PR3
+                + (rnd.astype(jnp.uint32) + 1) * _PR4
+            )
+            h = (h ^ (h >> 16)) * _PR3
+            h = (h ^ (h >> 13)) * _PR4
+            h = h ^ (h >> 16)
+            u = ((h >> 8).astype(jnp.float32) + 0.5) * (1.0 / (1 << 24))
+            draw = jnp.where(wide, jnp.log(u) / (share + 0.5), -jnp.inf)
+            drawn = wide & (draw >= jnp.max(draw, axis=1, keepdims=True))
+            mask = jnp.where(bound[:, None], drawn, mask)
         narrowed = None
         if sample is not None:
             off, k = sample
@@ -920,7 +1086,9 @@ def rounds_commit(
         # is merely LESS full than they assumed). Revoked pods retry next
         # round; persistent violations (anti slot held by the winner) are
         # then excluded by the refreshed dyn masks.
-        g_ok = guards_ok(vsnap, vrank, vsels, acc_node, acc, ext)
+        g_ok, n_spread = guards_ok(
+            vsnap, vmp, vrank, vsels, acc_node, acc, ext
+        )
         revoked = acc & ~g_ok
         node_req = node_req.at[jnp.where(revoked, acc_node, 0)].add(
             jnp.where(revoked[:, None], -vsnap.pod_requested, 0.0)
@@ -936,13 +1104,15 @@ def rounds_commit(
         ext = local_update_fn(update_batched_view_fn)(
             vsnap, vmp, ext, acc, jnp.where(acc, acc_node, 0)
         )
-        return acc, acc_node, node_req, ext, diag, narrowed, parked
+        return (acc, acc_node, node_req, ext, diag, narrowed, parked,
+                reached, n_spread)
 
     # ---- round 1: full pending set ----
     gid0 = jnp.arange(P, dtype=jnp.int32)
-    acc0, node0, node_req, extra, diag0, narrowed0, parked0 = one_round(
+    (acc0, node0, node_req, extra, diag0, narrowed0, parked0, reached0,
+     n_spread0) = one_round(
         gid0, snap.pod_valid, snap.node_requested, extra, passes_round0,
-        identity_gid=True,
+        jnp.ones((), bool), jnp.int32(0), identity_gid=True,
     )
     placed = jnp.where(acc0, node0, -1)
     active = snap.pod_valid & ~acc0 & ~parked0
@@ -968,19 +1138,25 @@ def rounds_commit(
     # final (see the docstring), so the sweep steps on by the rows that
     # STAY active: the pods ranked after the window move up by as many
     # places as it parked, and none of them is passed over.
+    # A round whose claims went by the wider mask (`reached`) and that
+    # accepted nothing is NO such check: its pods may have claimed
+    # domains the round could not open after all, and passed over nodes
+    # the filters' own mask held open. The same window is then judged
+    # again by the filters' own mask, and so is every round after it
+    # until one accepts (`reach` off): a sweep steps on only past rounds
+    # that claimed by the filters' own mask.
     B = compact_window(P, compact)
 
     def body(carry):
         (node_req, ext, placed, active, rnd, skip, hist, dhist,
-         n_parked) = carry
+         n_parked, reach, n_spread) = carry
         key = jnp.where(active, rank_g, _BIG)
         order = jnp.argsort(key).astype(jnp.int32)
         start = jnp.minimum(skip, jnp.maximum(P - B, 0))
         gid = jax.lax.dynamic_slice(order, (start,), (B,))
         act_v = active[gid]
-        accepted, node_of, node_req, ext, diag, _, parked = one_round(
-            gid, act_v, node_req, ext, passes
-        )
+        (accepted, node_of, node_req, ext, diag, _, parked, reached,
+         n_sp) = one_round(gid, act_v, node_req, ext, passes, reach, rnd)
         placed = placed.at[gid].set(jnp.where(accepted, node_of, placed[gid]))
         active = active.at[gid].set(act_v & ~accepted & ~parked)
         n_acc = jnp.sum(accepted, dtype=jnp.int32)
@@ -988,24 +1164,32 @@ def rounds_commit(
         hist = hist.at[jnp.minimum(rnd, max_rounds - 1)].set(n_acc)
         dhist = dhist.at[jnp.minimum(rnd, max_rounds - 1)].set(diag)
         skip = jnp.where(
-            n_acc > 0, jnp.int32(0), skip + jnp.int32(B) - n_out
+            n_acc > 0, jnp.int32(0),
+            jnp.where(reached, skip, skip + jnp.int32(B) - n_out),
         )
+        reach = (n_acc > 0) | (reach & ~reached)
         return (node_req, ext, placed, active, rnd + 1, skip, hist,
-                dhist, n_parked + n_out)
+                dhist, n_parked + n_out, reach, n_spread + n_sp)
+
+    def unjudged(active, skip):
+        # actives the zero-accept sweep has not passed yet
+        return skip < jnp.sum(active, dtype=jnp.int32)
 
     def cond(carry):
-        _, _, _, active, rnd, skip, _, _, _ = carry
-        n_act = jnp.sum(active, dtype=jnp.int32)
-        return (skip < n_act) & (rnd < max_rounds)
+        _, _, _, active, rnd, skip, *_ = carry
+        return unjudged(active, skip) & (rnd < max_rounds)
 
-    # round 0 was full-width: if it accepted nothing, every pod already
-    # had its full-mask check and the sweep is complete (skip = P)
-    skip0 = jnp.where(jnp.any(acc0), jnp.int32(0), jnp.int32(P))
-    (node_req, extra, placed, active, rounds_used, _, acc_hist, diag_hist,
-     n_parked) = jax.lax.while_loop(
+    # round 0 was full-width: if it accepted nothing by the filters' own
+    # mask, every pod already had its full-mask check and the sweep is
+    # complete (skip = P)
+    any0 = jnp.any(acc0)
+    skip0 = jnp.where(any0 | reached0, jnp.int32(0), jnp.int32(P))
+    (node_req, extra, placed, active, rounds_used, skip, acc_hist,
+     diag_hist, n_parked, _, n_spread) = jax.lax.while_loop(
         cond, body,
         (node_req, extra, placed, active, jnp.int32(1), skip0,
-         acc_hist, diag_hist, jnp.sum(parked0, dtype=jnp.int32)),
+         acc_hist, diag_hist, jnp.sum(parked0, dtype=jnp.int32),
+         any0 | ~reached0, n_spread0),
     )
 
     return RoundsResult(
@@ -1016,6 +1200,10 @@ def rounds_commit(
         accepted_per_round=acc_hist,
         diag_per_round=diag_hist,
         parked=n_parked,
+        # the loop's own condition, read once more: it ended with
+        # actives unjudged, so `max_rounds` ended it
+        round_cap_hit=unjudged(active, skip).astype(jnp.int32),
+        spread_revoked=n_spread,
         # round 1 judged every valid pod; an invalid row's mask is empty
         sample_narrowed=(
             None if narrowed0 is None

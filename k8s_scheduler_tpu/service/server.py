@@ -373,7 +373,7 @@ class SchedulerService:
                     "rpc.cycle", trace, t_in, t_out, root_of=caller,
                     seqs=list(s.last_cycle_seqs), bindings=n_bind,
                     events=n_ev, evictions=len(resp.evictions),
-                    **s.last_cycle_sample,
+                    **s.last_cycle_counts,
                 )
         if armed:
             _agents_side(context, trace, caller, t_in, t_out)

@@ -303,6 +303,47 @@ def test_the_loser_loop_is_two_spans_a_mark_and_three_running_counts(
     assert rec2.counts["commit_rounds"] > first["commit_rounds"]
 
 
+ZONE_KEY = "topology.kubernetes.io/zone"
+
+
+def test_the_rounds_two_new_counts_on_the_record_metrics_and_rpc_cycle(
+        armed):
+    """One spread group over two zones, `maxSkew` 1, one zone with room
+    for a single pod: the level-fill places three (1 + 2), the spread
+    guard revokes claims on the way, and no loop ends at its cap. The
+    flight record keeps both as running totals beside `commit_rounds`,
+    `/metrics` counts them, and `rpc.cycle` carries the cycle's own."""
+    svc = service()
+    req = pb.UpdateRequest()
+    for i, cpu in enumerate(("1", "8")):
+        req.node_adds.append(convert.node_to(
+            MakeNode(f"n{i}").capacity({"cpu": cpu})
+            .labels({ZONE_KEY: f"z{i}"}).obj()))
+    for i in range(6):
+        req.pod_adds.append(pb.PodEvent(pod=convert.pod_to(
+            MakePod(f"s{i}").req({"cpu": "1"}).labels({"app": "blue"})
+            .spread(1, ZONE_KEY, {"app": "blue"}).obj())))
+    svc.Update(req, None)
+    assert len(svc.Cycle(pb.CycleRequest(), None).bindings) == 3
+    (root,) = [s for s in armed.snapshot() if s.name == "rpc.cycle"]
+    (rec,) = [r for r in svc.scheduler.flight.snapshot()
+              if r.seq in root.attrs["seqs"]]
+    assert rec.counts["round_cap_hits"] == root.attrs["round_cap_hits"] == 0
+    revoked = rec.counts["spread_revoked"]
+    assert revoked == root.attrs["spread_revoked"] > 0
+    text = svc.scheduler.metrics.expose()
+    assert b"scheduler_round_cap_hits_total 0.0" in text
+    assert (b"scheduler_spread_revoked_claims_total %.1f" % revoked) in text
+    # a second cycle: the record's totals stand or grow, the span's
+    # counts are that cycle's own
+    svc.Update(cluster_request(n_nodes=0, n_pods=1, tag="q"), None)
+    svc.Cycle(pb.CycleRequest(), None)
+    root2 = [s for s in armed.snapshot() if s.name == "rpc.cycle"][-1]
+    rec2 = svc.scheduler.flight.snapshot()[-1]
+    assert rec2.counts["spread_revoked"] == (
+        revoked + root2.attrs["spread_revoked"])
+
+
 def test_unarmed_no_span_and_no_annotation_object(monkeypatch):
     made = []
     real = jax.profiler.TraceAnnotation

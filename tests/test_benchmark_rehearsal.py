@@ -24,12 +24,14 @@ share `.bench/` (git-ignored: `cache-cpu` and the server logs, ~10 MB)
 and run one after another.
 """
 
+import dataclasses
 import json
 import os
 import shutil
 import subprocess
 import sys
 
+import numpy as np
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -239,3 +241,118 @@ def test_the_servers_count_follows_the_deletes_of_a_cycles_victims(
     # the replay took this cycle's victims out, and so has the server
     assert verdict.resident_after[-1] == drv.resident
     assert int(held) == verdict.resident_after[-1], victims
+
+
+def tight_skew_breaches(dep, pods: dict, cycles: list) -> list:
+    """The run replayed through `reference.Cluster` under the TIGHT
+    reading of DoNotSchedule skew: for every pod a cycle bound and every
+    constraint it carries, the count of its domain at the cycle's END
+    less the minimum over the domains at its END is at most `maxSkew`
+    (every carrier of the cell has one `maxSkew`, so the bound at
+    placement time shows at the end). The harness's check (c) holds the
+    count at the cycle's START + 1 against that minimum, which cannot
+    see a zone over-filled within one cycle (PERF.md section 7: the
+    tight form is owed to a `benchmark` PR)."""
+    cl = reference.Cluster(dep.nodes)
+    for pod, node in dep.init:
+        cl.add(pod, cl.index[node])
+    found = []
+    for ci, cyc in enumerate(cycles):
+        for uid in cyc.completed:
+            cl.remove(uid)
+        for uid, node in cyc.bindings:
+            cl.add(pods[uid], cl.index[node])
+        for uid, node in cyc.bindings:
+            for key, sel, skew in reference._terms(pods[uid]).spread:
+                here = cl.per_domain(key, cl.match[sel])
+                d = cl.domain(key)[0][cl.index[node]]
+                if here[d] - here.min() > skew:
+                    found.append((ci, uid, int(here[d] - here.min())))
+        for uid, _node in cyc.evictions:
+            cl.remove(uid)
+    return found
+
+
+def test_one_spread_group_holds_the_tight_skew_rule_at_every_cycles_end(
+        monkeypatch):
+    """`sp5000-spread.sat`'s rehearsal, driven as `run.py` drives it:
+    every cycle binds ~250 pods of ONE spread group (`maxSkew` 1, six
+    zones). The run passes the harness's own check and the tight rule
+    above; the same run with its last cycle's spread pods moved into the
+    zone that stood lowest at that cycle's start passes check (c), whose
+    count is taken at the cycle's START, and fails the tight rule: the
+    control that shows the tight rule sees what (c) cannot."""
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    monkeypatch.delenv("XLA_FLAGS", raising=False)  # one CPU device
+    cfg, traffic = files_of("sp5000-spread.sat")
+    cut = {k: v for k, v in cfg["rehearse"].items() if k != "server"}
+    seed = 3000000019
+    dep = generate.deployment(cfg, seed, cut)
+    depth = dep.cfg["depth"]
+    workdir = os.path.join(bench_run.SCRATCH, f"tight-{seed}")
+    cache = os.path.join(bench_run.SCRATCH, "cache-cpu")
+    shutil.rmtree(workdir, ignore_errors=True)
+    os.makedirs(workdir)
+    os.makedirs(cache, exist_ok=True)
+    server = Server(
+        ROOT, workdir, bench_run.server_yaml(cfg, True, workdir),
+        aot_dir=os.path.join(cache, "aot"),
+        jax_cache_dir=os.path.join(cache, "jax"), traced=False)
+    drv = agent.Driver(server.grpc_port, dep, traffic["completions"], seed)
+    try:
+        server.started(require_tpu=False, chips=1)
+        drv.load()
+        seconds = 2.0  # run.py's own budget for a window of that length
+        window_pods = dep.pending(int(
+            traffic["rehearse"]["pods_budget_per_s"] * (seconds + 5))
+            + depth, "pod")
+        drv.warm(dep.pending(depth, "warm"))
+        drv.run_closed(window_pods, depth, seconds)
+        server.stop()
+    finally:
+        drv.close()
+        server.kill()
+        shutil.rmtree(workdir, ignore_errors=True)
+
+    def verdict(cycles):
+        return reference.check_run(
+            dep.nodes, dep.init, drv.pods, cycles, dep.pools,
+            drv.probe_rounds, drv.resident_target)
+
+    assert len(drv.cycles) >= 4
+    sound = verdict(drv.cycles)
+    assert sound.ok, sound.problems
+    assert sound.counts["refused"] == 0
+    assert tight_skew_breaches(dep, drv.pods, drv.cycles) == []
+
+    # the control: the last cycle's spread pods, all into one zone
+    last = drv.cycles[-1]
+    spread = [u for u, _n in last.bindings
+              if reference._terms(drv.pods[u]).spread]
+    assert len(spread) > 100
+    cl = reference.Cluster(dep.nodes)
+    for pod, node in dep.init:
+        cl.add(pod, cl.index[node])
+    for cyc in drv.cycles[:-1]:
+        for uid in cyc.completed:
+            cl.remove(uid)
+        for uid, node in cyc.bindings:
+            cl.add(drv.pods[uid], cl.index[node])
+    for uid in last.completed:
+        cl.remove(uid)
+    key, sel, skew = reference._terms(drv.pods[spread[0]]).spread[0]
+    assert skew == 1
+    lowest = int(np.argmin(cl.per_domain(key, cl.watch(sel))))
+    plain = len(dep.nodes) - sum(len(p.nodes) for p in dep.pools)
+    into = [dep.nodes[i].name for i in range(plain)
+            if cl.domain(key)[0][i] == lowest]
+    moved = dict(zip(spread, (into[j % len(into)]
+                              for j in range(len(spread)))))
+    altered = drv.cycles[:-1] + [dataclasses.replace(
+        last, bindings=[(u, moved.get(u, n)) for u, n in last.bindings])]
+    loose = verdict(altered)
+    assert loose.counts["constraint_breaches"] == [0, 0], loose.problems
+    breaches = tight_skew_breaches(dep, drv.pods, altered)
+    assert breaches and {ci for ci, _u, _s in breaches} == {
+        len(altered) - 1}
+    assert max(s for _c, _u, s in breaches) > 100

@@ -569,6 +569,49 @@ def test_warm_restart_compiles_zero_programs(tmp_path):
     assert warm_s < cold_s
 
 
+def test_an_executable_of_the_engine_before_is_not_loaded(
+        tmp_path, monkeypatch):
+    """The executable store keys on program names, not on code: the
+    commit rounds' mark rides in the name of every program that embeds
+    them (`core/cycle._engine_marks`), so an entry stored by the engine
+    that held a spread group to the counts at the round's start (mark
+    ":parks", PR 36-45) is a miss for this one, which compiles its own."""
+    from k8s_scheduler_tpu.ops import rounds as rounds_ops
+
+    assert rounds_ops.ENGINE_MARK != ":parks"
+    cfg = SchedulerConfiguration(compile_cache_dir=str(tmp_path))
+    with monkeypatch.context() as m:
+        m.setattr(rounds_ops, "ENGINE_MARK", ":parks")
+        old = Scheduler(config=cfg, pad_bucket=8)
+        _mini_cluster(old)
+        old.on_pod_add(MakePod("p0").req({"cpu": "1"}).obj())
+        assert old.schedule_cycle().scheduled == 1
+        stored = old._compile_cache.misses
+        assert stored >= 5 and old._compile_cache.hits == 0
+    cc.clear_loaded_memo()
+    new = Scheduler(
+        config=SchedulerConfiguration(compile_cache_dir=str(tmp_path)),
+        pad_bucket=8,
+    )
+    _mini_cluster(new)
+    new.on_pod_add(MakePod("w0").req({"cpu": "1"}).obj())
+    assert new.schedule_cycle().scheduled == 1
+    entry = next(iter(new._packed.values()))
+    assert entry["source"] == "cold"
+    assert new._compile_cache.misses >= 1
+    # ... and under its own mark the next process loads every program
+    cc.clear_loaded_memo()
+    again = Scheduler(
+        config=SchedulerConfiguration(compile_cache_dir=str(tmp_path)),
+        pad_bucket=8,
+    )
+    _mini_cluster(again)
+    again.on_pod_add(MakePod("x0").req({"cpu": "1"}).obj())
+    assert again.schedule_cycle().scheduled == 1
+    assert again._compile_cache.misses == 0
+    assert next(iter(again._packed.values()))["source"] == "cache"
+
+
 def test_speculative_precompile_wins_the_flip(tmp_path):
     """Acceptance: with demand drifting toward the P bucket boundary,
     the warm thread pre-builds the adjacent regime; the flip then costs

@@ -360,6 +360,28 @@ def test_wedge_precursor_from_strike_deltas():
     assert obs.anomaly_counts["wedge_precursor"] == 2  # one new strike
 
 
+def test_round_cap_hit_from_the_running_count():
+    """The records' `round_cap_hits` is the scheduler's running total
+    of cycles whose commit rounds ended at `max_rounds` with claimants
+    unjudged: a cycle in which it rises is an anomaly of its own class,
+    with the rounds run so far in the detail; the first record is the
+    anchor, and a record without the count (a program that returns the
+    latency subset) raises nothing."""
+    fr, obs = _observed()
+    _commit_cycle(fr, 0.0, round_cap_hits=1, commit_rounds=64)
+    _commit_cycle(fr, 1.0, round_cap_hits=1, commit_rounds=70)
+    _commit_cycle(fr, 2.0)
+    assert obs.anomalies() == []
+    rec = _commit_cycle(fr, 3.0, round_cap_hits=2, commit_rounds=134)
+    (ev,) = obs.anomalies()
+    assert (ev["class"], ev["seq"]) == ("round_cap_hit", rec.seq)
+    assert ev["detail"] == {"commit_rounds": 134}
+    # one total for every profile: the next profile's record of the
+    # same cycle carries the same number and raises nothing
+    _commit_cycle(fr, 3.1, profile="gpu-sched", round_cap_hits=2)
+    assert obs.anomaly_counts["round_cap_hit"] == 1
+
+
 def test_anomaly_ring_is_bounded_and_last_filters():
     fr, obs = _observed(ring=8)
     for i in range(8):
