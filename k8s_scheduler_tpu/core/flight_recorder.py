@@ -211,22 +211,38 @@ class PodTimelines:
         self, uid: str, name: str, kind: str, t: float, wall: float,
         **detail: Any,
     ) -> None:
-        ev = {"t_s": t, "wall": wall, "kind": kind, **detail}
+        self.note_many(kind, ((uid, name, detail),), t, wall)
+
+    def note_many(
+        self, kind: str,
+        rows: Iterable[tuple[str, str, dict | None]],
+        t: float, wall: float,
+    ) -> None:
+        """One event of `kind` for each (uid, name, detail or None) of
+        `rows`, in their order and all at (`t`, `wall`), under one hold
+        of the lock: the entries, their order and what is evicted are
+        what a `note` a row leaves."""
+        pods, max_pods, max_events = (
+            self._pods, self._max_pods, self._max_events
+        )
         with self._lock:
-            entry = self._pods.get(uid)
-            if entry is None:
-                entry = {"uid": uid, "name": name, "events": []}
-                self._pods[uid] = entry
-                while len(self._pods) > self._max_pods:
-                    self._pods.popitem(last=False)
-            else:
-                self._pods.move_to_end(uid)
+            for uid, name, detail in rows:
+                ev = {"t_s": t, "wall": wall, "kind": kind}
+                if detail:
+                    ev.update(detail)
+                entry = pods.get(uid)
+                if entry is None:
+                    pods[uid] = {"uid": uid, "name": name, "events": [ev]}
+                    while len(pods) > max_pods:
+                        pods.popitem(last=False)
+                    continue
+                pods.move_to_end(uid)
                 if name:
                     entry["name"] = name
-            events = entry["events"]
-            events.append(ev)
-            if len(events) > self._max_events:
-                del events[: len(events) - self._max_events]
+                events = entry["events"]
+                events.append(ev)
+                if len(events) > max_events:
+                    del events[: len(events) - max_events]
 
     def get(self, uid: str) -> dict | None:
         with self._lock:
@@ -320,10 +336,15 @@ class FlightRecorder:
     def pod_event(
         self, uid: str, name: str, kind: str, **detail: Any
     ) -> None:
-        self.pods.note(
-            uid, name, kind, self.now() - self.epoch, self._wall(),
-            **detail,
-        )
+        self.pod_events(kind, ((uid, name, detail),))
+
+    def pod_events(
+        self, kind: str, rows: Iterable[tuple[str, str, dict | None]]
+    ) -> None:
+        """A timeline event of `kind` for each (uid, name, detail or
+        None) of `rows`: one pair of clock reads and one hold of the
+        timelines' lock for the list."""
+        self.pods.note_many(kind, rows, self.now() - self.epoch, self._wall())
 
     # ---- reader side -----------------------------------------------------
 

@@ -26,10 +26,11 @@ k+1's encode reads it. `forced_sync` restores sequential execution.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import logging
 import threading as _threading
 import time as _time
-from typing import Callable
+from typing import Callable, Iterable, Sequence
 
 import numpy as np
 
@@ -87,6 +88,11 @@ class CycleStats:
     victims: int = 0
     gang_dropped: int = 0
     cycle_seconds: float = 0.0
+
+
+def _is_bound(pair: "tuple[Pod, str]") -> bool:
+    """Whether a handler's (pod, node name) pair names a node."""
+    return bool(pair[1])
 
 
 def _pad(n: int, bucket: int = 64) -> int:
@@ -208,9 +214,11 @@ class Scheduler:
             initial_backoff_seconds=self.config.pod_initial_backoff_seconds,
             max_backoff_seconds=self.config.pod_max_backoff_seconds,
             now=now,
-            on_enqueue=lambda queue, event: self.metrics.queue_incoming.labels(
-                queue=queue, event=event
-            ).inc(),
+            on_enqueue=lambda queue, event, n=1: (
+                self.metrics.queue_incoming.labels(
+                    queue=queue, event=event
+                ).inc(n)
+            ),
         )
         self.binder = binder or (lambda pod, node: None)
         self.evictor = evictor or (lambda pod, node: None)
@@ -884,48 +892,106 @@ class Scheduler:
 
     # ---- informer-style event handlers (SURVEY.md §3.3) ------------------
 
+    # A pod handler takes a LIST (`on_pods_add`, `on_pods_update`,
+    # `confirm_pods`, `on_pods_delete`: what an `Update` request hands
+    # over, one call a list) and pays once for it what does not carry a
+    # pod: one hold of the cache's lock and one of the queue's, one clock
+    # read each, one queueing-hint pass, one step of `queue_incoming`
+    # for each (queue, event), one timeline call. The single-object
+    # handlers are the list forms at length 1.
+
     def on_pod_add(self, pod: Pod, node_name: str = "") -> None:
-        if node_name:
-            # observed bound: drop any stale queue entry (a late informer
-            # echo after an assumption expired must not leave the pod both
-            # pending and existing, which would double-schedule it)
-            self.queue.delete(pod.uid)
-            self.cache.add_pod(pod, node_name)
-            self.queue.move_all_to_active_or_backoff(EVENT_POD_ADD)
-            if self.flight is not None:
-                self.flight.pod_event(
-                    pod.uid, pod.name, "BoundObserved", node=node_name
-                )
-        else:
-            self.queue.add(pod)
-            if self.flight is not None:
-                self.flight.pod_event(pod.uid, pod.name, "Queued")
+        self.on_pods_add(((pod, node_name),))
 
     def on_pod_update(self, pod: Pod, node_name: str = "") -> None:
-        if node_name:
-            self.queue.delete(pod.uid)
-            self.cache.add_pod(pod, node_name)
-            self.queue.move_all_to_active_or_backoff(EVENT_POD_UPDATE)
-            if self.flight is not None:
-                self.flight.pod_event(
-                    pod.uid, pod.name, "BoundObserved", node=node_name
-                )
-        else:
-            self.queue.update(pod)
-            if self.flight is not None:
-                self.flight.pod_event(pod.uid, pod.name, "Updated")
+        self.on_pods_update(((pod, node_name),))
 
     def on_pod_delete(self, pod_uid: str) -> None:
-        self.cache.remove_pod(pod_uid)
-        self.queue.delete(pod_uid)
+        self.on_pods_delete((pod_uid,))
+
+    def on_pods_add(self, pairs: Iterable[tuple[Pod, str]]) -> None:
+        """(pod, node name or "") pairs, applied in their order."""
+        self._on_pods_upsert(
+            pairs, EVENT_POD_ADD, self.queue.add_many, "Queued"
+        )
+
+    def on_pods_update(self, pairs: Iterable[tuple[Pod, str]]) -> None:
+        self._on_pods_upsert(
+            pairs, EVENT_POD_UPDATE, self.queue.update_many, "Updated"
+        )
+
+    def _on_pods_upsert(self, pairs, event, queue_many, pending_kind):
+        """A list may mix bound and pending pods: it is applied as runs
+        of one or the other, in its order, so a uid that comes twice
+        resolves as it does pod by pod. A bound run ends with the hint
+        pass (and not the list: a pending pod that follows it must find
+        the cured pods already moved, as it does pod by pod); nothing
+        enters the unschedulable set between two cycles, so of a run's
+        per-pod passes only the first could move anything."""
+        flight = self.flight
+        for bound, run in itertools.groupby(pairs, key=_is_bound):
+            run = list(run)
+            if bound:
+                # observed bound: drop any stale queue entry (a late
+                # informer echo after an assumption expired must not
+                # leave the pod both pending and existing, which would
+                # double-schedule it)
+                self.queue.delete_many([pod.uid for pod, _ in run])
+                self.cache.add_pods(run)
+                self.queue.move_all_to_active_or_backoff(event)
+                if flight is not None:
+                    flight.pod_events("BoundObserved", [
+                        (pod.uid, pod.name, {"node": node})
+                        for pod, node in run
+                    ])
+            else:
+                queue_many([pod for pod, _ in run])
+                if flight is not None:
+                    flight.pod_events(pending_kind, [
+                        (pod.uid, pod.name, None) for pod, _ in run
+                    ])
+
+    def confirm_pods(
+        self, confirms: Sequence[tuple[str, str]]
+    ) -> list[str]:
+        """(uid, node name) of bindings confirmed by reference: the pod
+        the cache holds assumed on that node becomes bound, with what
+        `on_pod_update` does for a bound pod and nothing converted,
+        stored or journaled a second time. Returns every other uid, in
+        the list's order: its sender sends those pods in full."""
+        if not confirms:
+            return []
+        pods = self.cache.confirm_pods(confirms)
+        rows, unconfirmed = [], []
+        for (uid, node), pod in zip(confirms, pods):
+            if pod is None:
+                unconfirmed.append(uid)
+            else:
+                rows.append((uid, pod.name, {"node": node}))
+        if rows:
+            self.queue.delete_many([row[0] for row in rows])
+            # once for the list where the full path moves per pod: only
+            # a cycle fills the unschedulable set
+            self.queue.move_all_to_active_or_backoff(EVENT_POD_UPDATE)
+            if self.flight is not None:
+                self.flight.pod_events("BoundObserved", rows)
+        return unconfirmed
+
+    def on_pods_delete(self, pod_uids: Sequence[str]) -> None:
+        if not pod_uids:
+            return
+        self.cache.remove_pods(pod_uids)
+        self.queue.delete_many(pod_uids)
         if self.admission is not None:
             # a pod deleted before binding must leave the front door's
             # accepted-pending set, or its uid stays "already pending"
             # forever and a re-created pod can never be admitted
-            self.admission.note_delete(pod_uid)
+            self.admission.note_deletes(pod_uids)
         self.queue.move_all_to_active_or_backoff(EVENT_POD_DELETE)
         if self.flight is not None:
-            self.flight.pod_event(pod_uid, "", "Deleted")
+            self.flight.pod_events(
+                "Deleted", [(uid, "", None) for uid in pod_uids]
+            )
 
     def on_node_add(self, node: Node) -> None:
         self.cache.add_node(node)
@@ -2849,6 +2915,10 @@ class Scheduler:
             # (a compaction follows its cycle's record, so a record
             # carries those up to the cycle before); no state, no count
             rec.counts["snapshot_rows_encoded"] = self.state.rows_encoded
+            # journal RECORDS appended so far, a `batch` as one: the
+            # cycle's pop and its apply phase's group, and one for every
+            # `Update` request before it
+            rec.counts["journal_records"] = self.state.journal.seq()
         if self.update_rpcs is not None:
             # the Update RPCs the servicer handled before this cycle:
             # two a cycle where the agent sends each batch whole, more

@@ -24,7 +24,11 @@ across a consistent dump).
 Durability contract (state/ package): same as SchedulingQueue — each
 public mutator reads the clock once, applies, and emits one journal
 record with that clock value, so replay under a pinned clock reproduces
-assumed-pod TTL deadlines exactly.
+assumed-pod TTL deadlines exactly. A list form (`add_pods`,
+`remove_pods`, `confirm_pods`) is its single-object mutator over a
+list: one hold of the lock and one clock read for the list, the
+single's record for each pod, all with that clock value; the single
+is the list form at length 1.
 """
 
 from __future__ import annotations
@@ -32,7 +36,7 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time as _time
-from typing import Callable
+from typing import Callable, Iterable
 
 from ..models.api import Node, Pod
 
@@ -172,21 +176,42 @@ class SchedulerCache:
 
     def add_pod(self, pod: Pod, node_name: str) -> None:
         """A bound pod appeared (or an assumed pod's bind was observed)."""
+        self.add_pods(((pod, node_name),))
+
+    def add_pods(self, pairs: Iterable[tuple[Pod, str]]) -> None:
+        """`add_pod` for a list of (pod, node name), in its order: one
+        hold of the lock and one clock read for the list, a record a
+        pod."""
         with self._lock:
-            self._assumed.pop(pod.uid, None)
-            self._bound[pod.uid] = (pod, node_name)
-            state = self._pod_state(pod, node_name)
-            if state is not None:
-                self._emit("c.add_pod", {"pod": state, "node": node_name})
+            now = self._now() if self._journal is not None else 0.0
+            assumed, bound = self._assumed, self._bound
+            for pod, node_name in pairs:
+                assumed.pop(pod.uid, None)
+                bound[pod.uid] = (pod, node_name)
+                state = self._pod_state(pod, node_name)
+                if state is not None:
+                    self._journal(
+                        "c.add_pod", now, {"pod": state, "node": node_name}
+                    )
 
     def remove_pod(self, pod_uid: str) -> None:
+        self.remove_pods((pod_uid,))
+
+    def remove_pods(self, pod_uids: Iterable[str]) -> None:
+        """`remove_pod` for a list of uids, in its order: one hold of
+        the lock and one clock read for the list, a record for every
+        pod that was held."""
         with self._lock:
-            b = self._bound.pop(pod_uid, None)
-            a = self._assumed.pop(pod_uid, None)
-            if b is not None or a is not None:
-                self.departed += 1
-                self._frags.pop(pod_uid, None)
-                self._emit("c.remove_pod", {"uid": pod_uid})
+            now = self._now() if self._journal is not None else 0.0
+            assumed, bound, frags = self._assumed, self._bound, self._frags
+            for pod_uid in pod_uids:
+                b = bound.pop(pod_uid, None)
+                a = assumed.pop(pod_uid, None)
+                if b is not None or a is not None:
+                    self.departed += 1
+                    frags.pop(pod_uid, None)
+                    if self._journal is not None:
+                        self._journal("c.remove_pod", now, {"uid": pod_uid})
 
     # ---- assume lifecycle ------------------------------------------------
 
@@ -221,15 +246,30 @@ class SchedulerCache:
         node) nothing changes and None is returned. The check and the
         move are one step under the lock, so no TTL sweep falls between
         them."""
+        return self.confirm_pods(((pod_uid, node_name),))[0]
+
+    def confirm_pods(
+        self, confirms: Iterable[tuple[str, str | None]]
+    ) -> list[Pod | None]:
+        """`confirm` for a list of (uid, node name or None), in its
+        order: what each returns, under one hold of the lock and one
+        clock read for the list, a record for every pod confirmed."""
+        out = []
         with self._lock:
-            a = self._assumed.get(pod_uid)
-            if a is None or node_name not in (None, a.node_name):
-                return None
-            del self._assumed[pod_uid]
-            # the same pod on the same node: its fragment stays
-            self._bound[pod_uid] = (a.pod, a.node_name)
-            self._emit("c.confirm", {"uid": pod_uid})
-            return a.pod
+            now = self._now() if self._journal is not None else 0.0
+            assumed, bound = self._assumed, self._bound
+            for pod_uid, node_name in confirms:
+                a = assumed.get(pod_uid)
+                if a is None or node_name not in (None, a.node_name):
+                    out.append(None)
+                    continue
+                del assumed[pod_uid]
+                # the same pod on the same node: its fragment stays
+                bound[pod_uid] = (a.pod, a.node_name)
+                if self._journal is not None:
+                    self._journal("c.confirm", now, {"uid": pod_uid})
+                out.append(a.pod)
+        return out
 
     def forget(self, pod_uid: str) -> None:
         with self._lock:

@@ -31,7 +31,13 @@ helpers, and emits EXACTLY ONE journal record carrying that clock value
 — so replaying the record stream under a clock pinned to each record's
 timestamp reproduces this queue bit-identically (attempt counts, backoff
 expiries, tier membership, in-flight set). Internal helpers never emit
-and never read the clock themselves.
+and never read the clock themselves. A list form (`add_many`,
+`update_many`, `delete_many`: what an `Update` request's pod lists go
+through) is its single-object mutator over a list, in the list's order:
+one hold of the lock and one clock read for the list, the single's
+record for each pod with that clock value, one step of the intake
+observer for each (queue, event) by its count; the single is the list
+form at length 1.
 """
 
 from __future__ import annotations
@@ -118,17 +124,18 @@ class SchedulingQueue:
         max_backoff_seconds: float = 10.0,
         unschedulable_timeout_seconds: float = 300.0,
         now: Callable[[], float] = _time.monotonic,
-        on_enqueue: Callable[[str, str], None] | None = None,
+        on_enqueue: Callable[[str, str, int], None] | None = None,
         journal: Callable[[str, float, dict], None] | None = None,
     ) -> None:
         self._initial = initial_backoff_seconds
         self._max = max_backoff_seconds
         self._timeout = unschedulable_timeout_seconds
         self._now = now
-        # (queue_name, event) observer for EVERY tier entry — feeds the
-        # upstream scheduler_queue_incoming_pods_total metric; kept in the
-        # queue so no transition undercounts
-        self._on_enqueue = on_enqueue or (lambda queue, event: None)
+        # (queue_name, event, pods) observer for EVERY tier entry — feeds
+        # the upstream scheduler_queue_incoming_pods_total metric; kept
+        # in the queue so no transition undercounts. A list form steps
+        # it once by the list's count
+        self._on_enqueue = on_enqueue or (lambda queue, event, n=1: None)
         # (op, t, data) observer for the write-ahead journal (state/):
         # None = durability disabled. DurableState.attach wires it.
         self._journal = journal
@@ -156,12 +163,23 @@ class SchedulingQueue:
 
     def add(self, pod: Pod) -> None:
         """New pod (informer Add): straight to active."""
+        self.add_many((pod,))
+
+    def add_many(self, pods: Iterable[Pod]) -> None:
+        """`add` for a list of pods, in its order: one hold of the lock
+        and one clock read for the list, a record a pod, and one step
+        of the intake observer by the list's count."""
         with self._lock:
             now = self._now()
-            state = self._pod_state(pod)
-            self._add_locked(pod, now, EVENT_POD_ADD, state)
-            if state is not None:
-                self._emit("q.add", now, {"pod": state})
+            n = 0
+            for pod in pods:
+                state = self._pod_state(pod)
+                self._add_locked(pod, now, state)
+                if state is not None:
+                    self._emit("q.add", now, {"pod": state})
+                n += 1
+            if n:
+                self._on_enqueue("active", EVENT_POD_ADD, n)
 
     def _pod_state(self, pod: Pod) -> dict | None:
         """The state dict for the record a mutator is about to journal,
@@ -172,64 +190,92 @@ class SchedulingQueue:
             return None
         return _codec_pod()(pod)
 
-    def _add_locked(
-        self, pod: Pod, now: float, event: str, state: dict | None
-    ) -> None:
+    def _add_locked(self, pod: Pod, now: float, state: dict | None) -> None:
+        """The entry made and placed; its caller steps the intake
+        observer (`active`, `PodAdd`)."""
         uid = pod.uid
         self._backoff.pop(uid, None)
         self._unschedulable.pop(uid, None)
         self._active[uid] = _QueuedPod(pod, enqueued_at=now, frag=state)
-        self._on_enqueue("active", event)
 
     def update(self, pod: Pod) -> None:
         """Spec/labels changed: an update can unstick its own pod."""
+        self.update_many((pod,))
+
+    def update_many(self, pods: Iterable[Pod]) -> None:
+        """`update` for a list of pods, in its order: one hold of the
+        lock and one clock read for the list, a record a pod, and one
+        step of the intake observer for each (queue, event) by its
+        count."""
         with self._lock:
             now = self._now()
-            state = self._pod_state(pod)
-            if state is not None:
-                self._emit("q.update", now, {"pod": state})
-            uid = pod.uid
-            for tier in (self._active, self._backoff, self._unschedulable):
-                if uid in tier:
-                    entry = tier[uid]
-                    entry.pod = pod
-                    entry.frag = state
-                    if tier is self._unschedulable:
-                        # the update may cure the failure, but the pod's
-                        # backoff window still applies (upstream checks
-                        # isPodBackingOff here) — otherwise a controller
-                        # touching annotations defeats exponential backoff
-                        del tier[uid]
-                        if entry.backoff_expiry > now:
-                            self._backoff[uid] = entry
-                            self._on_enqueue("backoff", EVENT_POD_UPDATE)
-                        else:
-                            self._active[uid] = entry
-                            self._on_enqueue("active", EVENT_POD_UPDATE)
-                    return
-            if uid in self._in_flight:
-                # being scheduled right now: refresh the in-flight object so
-                # a requeue carries the new spec, but do NOT double-enqueue
-                entry = self._in_flight[uid]
+            entered: dict[tuple[str, str], int] = {}
+            for pod in pods:
+                state = self._pod_state(pod)
+                if state is not None:
+                    self._emit("q.update", now, {"pod": state})
+                into = self._update_locked(pod, now, state)
+                if into is not None:
+                    entered[into] = entered.get(into, 0) + 1
+            for (queue, event), n in entered.items():
+                self._on_enqueue(queue, event, n)
+
+    def _update_locked(
+        self, pod: Pod, now: float, state: dict | None
+    ) -> tuple[str, str] | None:
+        """One pod's update applied; the (queue, event) it entered a
+        tier under, None where it stayed where it was."""
+        uid = pod.uid
+        for tier in (self._active, self._backoff, self._unschedulable):
+            if uid in tier:
+                entry = tier[uid]
                 entry.pod = pod
                 entry.frag = state
-                return
-            self._add_locked(pod, now, EVENT_POD_ADD, state)
+                if tier is not self._unschedulable:
+                    return None
+                # the update may cure the failure, but the pod's
+                # backoff window still applies (upstream checks
+                # isPodBackingOff here) — otherwise a controller
+                # touching annotations defeats exponential backoff
+                del tier[uid]
+                if entry.backoff_expiry > now:
+                    self._backoff[uid] = entry
+                    return "backoff", EVENT_POD_UPDATE
+                self._active[uid] = entry
+                return "active", EVENT_POD_UPDATE
+        if uid in self._in_flight:
+            # being scheduled right now: refresh the in-flight object so
+            # a requeue carries the new spec, but do NOT double-enqueue
+            entry = self._in_flight[uid]
+            entry.pod = pod
+            entry.frag = state
+            return None
+        self._add_locked(pod, now, state)
+        return "active", EVENT_POD_ADD
 
     def delete(self, pod_uid: str) -> None:
+        self.delete_many((pod_uid,))
+
+    def delete_many(self, pod_uids: Iterable[str]) -> None:
+        """`delete` for a list of uids, in its order: one hold of the
+        lock and one clock read for the list, a record for every uid
+        that was queued or in flight."""
         with self._lock:
-            changed = False
-            for tier in (self._active, self._backoff, self._unschedulable):
-                if tier.pop(pod_uid, None) is not None:
+            now = self._now()
+            tiers = (self._active, self._backoff, self._unschedulable)
+            for pod_uid in pod_uids:
+                changed = False
+                for tier in tiers:
+                    if tier.pop(pod_uid, None) is not None:
+                        changed = True
+                        self.departed += 1
+                if pod_uid in self._in_flight:
+                    # mark so the cycle's requeue discards instead of
+                    # resurrecting a deleted pod
+                    self._deleted_in_flight.add(pod_uid)
                     changed = True
-                    self.departed += 1
-            if pod_uid in self._in_flight:
-                # mark so the cycle's requeue discards instead of
-                # resurrecting a deleted pod
-                self._deleted_in_flight.add(pod_uid)
-                changed = True
-            if changed:  # a no-op delete journals nothing (replay-exact)
-                self._emit("q.delete", self._now(), {"uid": pod_uid})
+                if changed:  # a no-op delete journals nothing (replay-exact)
+                    self._emit("q.delete", now, {"uid": pod_uid})
 
     # ---- cycle boundary --------------------------------------------------
 

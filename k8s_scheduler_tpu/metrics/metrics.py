@@ -260,7 +260,11 @@ Durable-state families (state/ package — write-ahead journal, snapshots,
 restore) and leader election:
 
 - scheduler_journal_appends_total{op} — journal records appended, by
-  logical operation (q.add, q.pop, c.assume, ...)
+  logical operation (q.add, q.pop, c.assume, ...); the sub-ops of a
+  `batch` record count under their own names, the record under `batch`
+- scheduler_journal_records_total — journal RECORDS appended, a `batch`
+  as one: a cycle's emissions are one, an `Update` request's are one;
+  the flight records carry the same running total as `journal_records`
 - scheduler_journal_bytes_total — encoded journal bytes written to disk
 - scheduler_journal_fsync_seconds — group-commit fsync latency (one
   fsync per drained batch, writer thread only — never the bind path)
@@ -785,6 +789,12 @@ class SchedulerMetrics:
             "scheduler_journal_appends_total",
             "Write-ahead-journal records appended, by logical op.",
             ["op"],
+            registry=r,
+        )
+        self.journal_records = Counter(
+            "scheduler_journal_records_total",
+            "Write-ahead-journal records appended, a batch record (a "
+            "cycle's or an Update request's emissions) as one.",
             registry=r,
         )
         self.journal_bytes = Counter(

@@ -567,19 +567,22 @@ class AdmissionController:
                     (uid,), "bound", latency_ms=round(lat_ms, 3)
                 )
 
-    def note_delete(self, uid: str) -> None:
-        """Called by Scheduler.on_pod_delete: a pod deleted before it
+    def note_deletes(self, uids: collections.abc.Sequence[str]) -> None:
+        """Called by Scheduler.on_pods_delete: a pod deleted before it
         bound leaves the accepted-pending set, so a re-created pod
         reusing the uid can be admitted again (without this the uid
         would answer 'already pending' until the LRU happened to evict
-        it). Must never raise — it sits on the informer path."""
+        it). One hold of the lock for the list. Must never raise — it
+        sits on the informer path."""
         with self._lock:
-            self._accept_t.pop(uid, None)
-            self._tenant_untrack(uid)
+            for uid in uids:
+                self._accept_t.pop(uid, None)
+                self._tenant_untrack(uid)
         # a deleted pod's trace is over — drop its live context (the
         # recorded spans stay in the ring for /debug queries)
         if _spans.ARMED:
-            _spans.release(uid)
+            for uid in uids:
+                _spans.release(uid)
 
     def take_bind_latency_ms(self) -> float:
         """Worst submit->bind latency among binds since the last take

@@ -32,6 +32,7 @@ grpc_tools would generate.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import uuid
 from concurrent import futures
@@ -41,7 +42,6 @@ import grpc
 from ..config import SchedulerConfiguration
 from ..core import spans as _spans
 from ..core.scheduler import Scheduler
-from ..internal.queue import EVENT_POD_UPDATE
 from ..metrics import SchedulerMetrics
 from ..models.api import PodGroup
 from . import convert
@@ -160,8 +160,18 @@ class SchedulerService:
         its API object, then the informer handlers applied. Conversion
         touches no scheduler state, so the order of effects is what one
         interleaved pass gave; a request with an unparseable object now
-        fails before any of it is applied. Armed, the RPC is one trace
-        (core/spans): `rpc.update` with `update.convert` and
+        fails before any of it is applied. The request is applied as a
+        group: each of its pod lists goes to ONE handler call
+        (`on_pods_add`, `on_pods_update`, `confirm_pods`,
+        `on_pods_delete`), and with a durable state attached the whole
+        pass runs inside its `batch()` scope, so what the request
+        journals (a sub-record a pod a store, in the order and with the
+        clock values the handlers gave them) is appended as ONE `batch`
+        record where the pass ends, before the response leaves: no new
+        op, and no flush, fsync or acknowledgement moved (`rpc.update`
+        carries the records appended as `journal_records`; the flight
+        records carry the journal's running total). Armed, the RPC is
+        one trace (core/spans): `rpc.update` with `update.convert` and
         `update.apply` as its children, four clock reads in all, and the
         child of the caller's `client.update` where the call names one
         (`_agents_side`). Each
@@ -192,70 +202,63 @@ class SchedulerService:
         pdbs = [convert.pdb_from(pdb) for pdb in request.pdb_upserts]
         if armed:
             t_converted = _spans.now()
-        for node in node_adds:
-            s.on_node_add(node)
-        for node in node_updates:
-            s.on_node_update(node)
-        for name in request.node_deletes:
-            s.on_node_delete(name)
-        for g in request.pod_groups:
-            s.add_pod_group(PodGroup(g.name, g.min_member))
-        for pod, bound_node in pod_adds:
-            self._uid_index[pod.uid] = pod
-            s.on_pod_add(pod, node_name=bound_node)
-        for pod, bound_node in pod_updates:
-            self._uid_index[pod.uid] = pod
-            s.on_pod_update(pod, node_name=bound_node)
-        # bindings of the previous Cycle, confirmed by reference: the pod
-        # the cache holds assumed on that node becomes bound, with what
-        # on_pod_update does for a bound pod and nothing converted,
-        # stored or journaled a second time. Any other uid goes back to
-        # the agent untouched, to be sent in full.
-        unconfirmed = []
-        for c in request.bind_confirms:
-            pod = s.cache.confirm(c.pod_uid, c.node_name)
-            if pod is None:
-                unconfirmed.append(c.pod_uid)
-                continue
-            s.queue.delete(c.pod_uid)
-            if s.flight is not None:
-                s.flight.pod_event(
-                    c.pod_uid, pod.name, "BoundObserved", node=c.node_name
-                )
-        confirmed = len(request.bind_confirms) - len(unconfirmed)
-        if confirmed:
-            # once for the request where the full path moves per pod:
-            # only a cycle fills the unschedulable set
-            s.queue.move_all_to_active_or_backoff(EVENT_POD_UPDATE)
-        for uid in request.pod_deletes:
-            self._uid_index.pop(uid, None)
-            s.on_pod_delete(uid)
-        for uid in request.bind_failures:
-            # agent's Binding POST failed: forget + backoff (upstream
-            # handleBindingCycleError)
-            s.cache.forget(uid)
-            pod = self._uid_index.get(uid)
-            if pod is not None:
-                s.queue.requeue_backoff(pod)
-        for pvc in pvcs:
-            s.on_pvc_upsert(pvc)
-        for key in request.pvc_deletes:
-            s.on_pvc_delete(key)
-        for pv in pvs:
-            s.on_pv_upsert(pv)
-        for name in request.pv_deletes:
-            s.on_pv_delete(name)
-        for sc in storage_classes:
-            s.on_storage_class_upsert(sc)
-        for name in request.storage_class_deletes:
-            s.on_storage_class_delete(name)
-        for pdb in pdbs:
-            s.on_pdb_upsert(pdb)
-        for key in request.pdb_deletes:
-            s.on_pdb_delete(key)
-        # /metrics follows what this request added, confirmed and
-        # deleted now, not at the next cycle's end
-        s.stamp_store_gauges()
+        state = s.state
+        records = state.journal.seq() if state is not None else 0
+        # one journal group for the request: its emissions reach the
+        # journal as ONE batch record where the scope closes, as a
+        # cycle's do
+        with state.batch() if state is not None else contextlib.nullcontext():
+            for node in node_adds:
+                s.on_node_add(node)
+            for node in node_updates:
+                s.on_node_update(node)
+            for name in request.node_deletes:
+                s.on_node_delete(name)
+            for g in request.pod_groups:
+                s.add_pod_group(PodGroup(g.name, g.min_member))
+            self._uid_index.update((pod.uid, pod) for pod, _ in pod_adds)
+            s.on_pods_add(pod_adds)
+            self._uid_index.update((pod.uid, pod) for pod, _ in pod_updates)
+            s.on_pods_update(pod_updates)
+            # bindings of the previous Cycle, confirmed by reference;
+            # any uid the cache does not hold assumed on that node goes
+            # back to the agent untouched, to be sent in full
+            unconfirmed = s.confirm_pods(
+                [(c.pod_uid, c.node_name) for c in request.bind_confirms]
+            )
+            confirmed = len(request.bind_confirms) - len(unconfirmed)
+            pod_deletes = list(request.pod_deletes)
+            for uid in pod_deletes:
+                self._uid_index.pop(uid, None)
+            s.on_pods_delete(pod_deletes)
+            for uid in request.bind_failures:
+                # agent's Binding POST failed: forget + backoff (upstream
+                # handleBindingCycleError)
+                s.cache.forget(uid)
+                pod = self._uid_index.get(uid)
+                if pod is not None:
+                    s.queue.requeue_backoff(pod)
+            for pvc in pvcs:
+                s.on_pvc_upsert(pvc)
+            for key in request.pvc_deletes:
+                s.on_pvc_delete(key)
+            for pv in pvs:
+                s.on_pv_upsert(pv)
+            for name in request.pv_deletes:
+                s.on_pv_delete(name)
+            for sc in storage_classes:
+                s.on_storage_class_upsert(sc)
+            for name in request.storage_class_deletes:
+                s.on_storage_class_delete(name)
+            for pdb in pdbs:
+                s.on_pdb_upsert(pdb)
+            for key in request.pdb_deletes:
+                s.on_pdb_delete(key)
+            # /metrics follows what this request added, confirmed and
+            # deleted now, not at the next cycle's end
+            s.stamp_store_gauges()
+        if state is not None:
+            records = state.journal.seq() - records
         if armed:
             t_out = _spans.now()
             converted = (
@@ -288,6 +291,7 @@ class SchedulerService:
                 bind_failures=len(request.bind_failures),
                 node_events=node_events, bind_confirms=confirmed,
                 confirm_fallbacks=len(unconfirmed),
+                journal_records=records,
             )
             _agents_side(context, trace, caller, t_in, t_out)
         s.update_rpcs += 1

@@ -98,7 +98,8 @@ def encode_record(op: str, t: float, data: dict) -> bytes:
 
 # Batch record: ONE journal record carrying N logical sub-operations —
 # the group-append the vectorized apply/bind fold emits per cycle
-# (core/scheduler._apply_phase under DurableState.batch()). The wire
+# (core/scheduler._apply_phase under DurableState.batch()) and the
+# servicer per `Update` request (service/server.py). The wire
 # shape is an ordinary record whose op is BATCH_OP and whose payload is
 # {"ops": [[op, t, d], ...]}: each sub-op keeps its OWN clock value, so
 # replay pins the replay clock per sub-record and reproduces the exact
@@ -320,7 +321,13 @@ class Journal:
             # GIL contention lands on the bind path (measured ~4x the
             # append cost). The writer polls on a short timeout instead,
             # so encoding happens while the scheduler waits on device
-            # transfers (GIL released). Only a deep buffer forces a wake.
+            # transfers (GIL released). Only a deep buffer forces a wake,
+            # and the served path no longer builds one: a cycle's apply
+            # phase and an `Update` request each append ONE batch record
+            # (DurableState.batch()), a handful of records an iteration
+            # where a request of 8,000 pods used to pass this depth by
+            # itself. Singles in numbers come from the front door and
+            # from callers of the single-object handlers.
             if len(self._buf) >= self._wake_depth:
                 self._cond.notify()
         return seq

@@ -41,6 +41,7 @@ snapshot or in the replay tail, never both, never neither.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import logging
 import os
@@ -203,12 +204,22 @@ class DurableState:
             return
         m = self._metrics
         if m is not None:
-            c = self._append_counters.get(op)
-            if c is None:
-                c = self._append_counters[op] = m.journal_appends.labels(
-                    op=op
-                )
-            c.inc()
+            # the records appended, a batch as one (`journal.seq()` is
+            # the same count); its logical ops are counted where it is
+            # flushed
+            m.journal_records.inc()
+            self._count_ops(op, 1)
+
+    def _count_ops(self, op: str, n: int) -> None:
+        """`scheduler_journal_appends_total{op}` stepped by `n`; the
+        labelled children are kept, so a step is one dict hit and no
+        `labels()` lookup."""
+        c = self._append_counters.get(op)
+        if c is None:
+            c = self._append_counters[op] = (
+                self._metrics.journal_appends.labels(op=op)
+            )
+        c.inc(n)
 
     # ---- batch group-append ----------------------------------------------
 
@@ -227,28 +238,27 @@ class DurableState:
         # the record's own t is the newest sub-op's clock; replay never
         # reads it (each sub-op carries its own t)
         self._append_record(BATCH_OP, ops[-1][1], encode_batch_payload(ops))
-        m = self._metrics
-        if m is not None and not self._closed:
+        if self._metrics is not None and not self._closed:
             # keep per-logical-op append counters meaningful for folded
             # ops too (op="batch" counted once by _append_record above
-            # is the record count; these are the logical-op counts)
-            for op, _t, _d in ops:
-                c = self._append_counters.get(op)
-                if c is None:
-                    c = self._append_counters[op] = (
-                        self._metrics.journal_appends.labels(op=op)
-                    )
-                c.inc()
+            # is the record count; these are the logical-op counts),
+            # one step an op name
+            for op, n in collections.Counter(
+                op for op, _t, _d in ops
+            ).items():
+                self._count_ops(op, n)
 
     @contextlib.contextmanager
     def batch(self):
-        """Group-append scope for the vectorized apply/bind fold: every
-        journal emission from the CALLING thread inside the scope
-        coalesces into ONE batch record, appended on exit — one record,
-        one buffer push, one share of the group-commit fsync per cycle
-        instead of N. Replay expands the batch with each sub-op's own
-        clock value, so restored state is bit-identical to N single
-        records (tests/test_state_journal.py asserts the digests).
+        """Group-append scope for the vectorized apply/bind fold and
+        for an `Update` request's apply pass: every journal emission
+        from the CALLING thread inside the scope coalesces into ONE
+        batch record, appended on exit — one record, one buffer push,
+        one `json.dumps`, one share of the group-commit fsync per cycle
+        or request instead of N. Replay expands the batch with each
+        sub-op's own clock value, so restored state is bit-identical to
+        N single records (tests/test_state_journal.py asserts the
+        digests).
 
         Emissions from OTHER threads (informer/admission paths) while a
         batch is open first flush the buffered prefix, preserving true
@@ -293,7 +303,7 @@ class DurableState:
         )
         queue._now = cache._now = clock
         queue._journal = cache._journal = None
-        queue._on_enqueue = lambda q, e: None
+        queue._on_enqueue = lambda q, e, n=1: None
         replayed = 0
         try:
             for op, t, data in replay_dir(self.dir, from_idx):
