@@ -867,10 +867,14 @@ class Scheduler:
         """Device-resident stable-side precomputes, rerun only when the
         encoder's stable side (nodes / existing pods / dedup tables) or
         the packed-spec regime changes. A miss costs one extra ASYNC
-        dispatch of a ~2ms device program (cheaper than the fused
-        in-cycle recompute it replaces), so even a bind-every-cycle
-        workload — whose existing-pod set changes every cycle — comes out
-        ahead; the memo is bounded like _packed for pad flip-flops."""
+        dispatch of a device program whose time follows the existing
+        pad: 112.6 ms a launch on a v5e at an E pad of 262,144 (the
+        benchmark's 5,000-node cells, where every cycle binds and so
+        every cycle misses: PERF.md section 5), a few ms at the pads
+        tests use; cheaper than the fused in-cycle recompute it
+        replaces, so even a bind-every-cycle workload — whose
+        existing-pod set changes every cycle — comes out ahead; the
+        memo is bounded like _packed for pad flip-flops."""
         # keyed on the encoder's stable-cache dict IDENTITY, with a strong
         # ref pinned in the entry: the encoder's _stable_key tuple contains
         # raw id()s whose objects older memo entries would not pin, so a
@@ -1489,6 +1493,19 @@ class Scheduler:
             except Exception as e:
                 self._cycle_failed(profile, pending, e, stats, t0, rec)
                 return
+        # the device decides from here to `decisions()`: this thread
+        # makes, meanwhile, what the bind loop would serialise a winner:
+        # the state dict each pod's in-flight queue entry keeps (what
+        # `q.add` / `q.update` / its requeue journaled, so no second
+        # `pod_to_state`) and, where the cache makes fragments at entry,
+        # the pod's half of its snapshot fragment, which does not wait
+        # for the node. One hold of the queue's lock for the list, none
+        # across the wait, no store touched; None with no journal
+        # attached. The multi-cycle paths and `Submit`'s prepare none
+        # and serialise in the loop, as a winner without a row does here
+        rows = self.cache.prepare_rows(
+            self.queue.in_flight_states(pending)
+        )
         # the ONLY blocking transfer on the bind path: the slimmed
         # decision payload (i16 assignment + u8 flags per pod). A
         # failure here — deadline expiry, transport flake past the
@@ -1498,6 +1515,8 @@ class Scheduler:
         try:
             assignment, _unsched, gang_dropped = handle.decisions()
         except Exception as e:
+            # the prepared rows go with the cycle: nothing was journaled
+            self._note_bind_rows(rec, rows, 0, 0)
             self._cycle_failed(profile, pending, e, stats, t0, rec)
             return
         assignment = assignment[: len(pending)]
@@ -1548,7 +1567,7 @@ class Scheduler:
         self._apply_phase(
             profile, framework, pending, nodes, existing, assignment,
             gang_dropped, extender_errors, reject_counts_fn, force_pre,
-            stats, t0, rec, t_device,
+            stats, t0, rec, t_device, rows,
         )
 
         # ---- flight record: assemble + commit (one list store) ----
@@ -3119,6 +3138,34 @@ class Scheduler:
             if _blackbox.ARMED:
                 _blackbox.trigger("stateless", reason)
 
+    def _note_bind_rows(
+        self, rec, rows, used: int, fallback: int
+    ) -> None:
+        """One cycle's three counts of its prepared rows, on its
+        flight record, on the `Cycle` RPC's span (summed over the
+        cycle's records) and on /metrics."""
+        prepared = (
+            len(rows) - rows.count(None) if rows is not None else 0
+        )
+        counts = dict(
+            rows_prepared=prepared,
+            rows_prepared_used=used,
+            rows_prepared_fallback=fallback,
+        )
+        if rec is not None:
+            rec.counts.update(counts)
+        seen = self.last_cycle_counts
+        for key, n in counts.items():
+            seen[key] = seen.get(key, 0) + n
+        for outcome, n in (
+            ("used", used), ("fallback", fallback),
+            ("unused", prepared - used),
+        ):
+            if n:
+                self.metrics.bind_rows_prepared.labels(
+                    outcome=outcome
+                ).inc(n)
+
     def _apply_phase(
         self,
         profile: str,
@@ -3135,6 +3182,7 @@ class Scheduler:
         t0: float,
         rec,
         t_device: float,
+        rows=None,
     ) -> None:
         """The host APPLY phase of one cycle: winner bind loop,
         preemption force, loser requeue, victim eviction — everything
@@ -3159,6 +3207,11 @@ class Scheduler:
 
         `force_pre()` forces the cycle's preemption program and
         returns `(nominated[:P_real] | None, victims[:E_real] | None)`.
+
+        `rows` (`cache.prepare_rows`, the single-cycle path): a
+        winner's row goes to `assume`, which then serialises nothing;
+        a winner without one, and every winner of a caller that
+        prepared none, is serialised there as before.
         """
         import contextlib
 
@@ -3196,6 +3249,19 @@ class Scheduler:
         a = np.asarray(assignment[: len(pending)])
         win_idx = np.flatnonzero(a >= 0)
         lose_idx = np.flatnonzero(a < 0)
+        # the hand-over of the prepared rows: one stands only if the
+        # entry it was made from still keeps that very dict for that
+        # very pod (an `Update` that came while the device ran put
+        # another pod and another dict there)
+        handed = None
+        if rows is not None:
+            kept = self.queue.in_flight_states(pending)
+            if kept is not None:
+                handed = [
+                    r if r is not None and r[0] is k else None
+                    for r, k in zip(rows, kept)
+                ]
+        rows_used = rows_fallback = 0
         # ONE journal group-append per cycle: every record the fold
         # emits (assume/bind/requeue/evict) buffers into a single
         # batch frame, flushed (and fsynced by the writer as one
@@ -3216,12 +3282,13 @@ class Scheduler:
                 node_name = nodes[int(a[i])].name
                 came_nominated = bool(pod.nominated_node_name)
                 nom_dispatched += came_nominated
+                row = handed[i] if handed is not None else None
                 try:
                     # a per-pod scheduling error (e.g. the uid raced to
                     # bound via an informer echo mid-cycle) must not
                     # kill the loop — upstream continues with the next
                     # pod
-                    self.cache.assume(pod, node_name)
+                    self.cache.assume(pod, node_name, row)
                 except ValueError:
                     stats.bind_errors += 1
                     _pev(
@@ -3231,6 +3298,10 @@ class Scheduler:
                         "error", per_pod_s(), profile
                     )
                     continue
+                if row is not None:
+                    rows_used += 1
+                elif handed is not None:
+                    rows_fallback += 1
                 # Reserve -> Permit -> PreBind host extension points
                 try:
                     run_reserve_permit_prebind(
@@ -3418,6 +3489,7 @@ class Scheduler:
                     nominated_dispatched=nom_dispatched,
                     nominated_bound=nom_bound,
                 )
+            self._note_bind_rows(rec, rows, rows_used, rows_fallback)
 
             if victims is not None and victims.any():
                 # victims belong to the preemptor nominated onto their

@@ -128,7 +128,10 @@ class SchedulerCache:
                 op, self._now(), {"node": _codec()[1](node)}
             )
 
-    def _pod_state(self, pod: Pod, node_name: str) -> dict | None:
+    def _pod_state(
+        self, pod: Pod, node_name: str,
+        row: tuple[dict, bytes | None] | None = None,
+    ) -> dict | None:
         """The state dict for the record a mutator is about to journal;
         the row's fragment is made from it here, so it is the pod as
         last journaled and never a second `pod_to_state`. A pod that
@@ -137,15 +140,21 @@ class SchedulerCache:
         entries, most of which no compaction ever meets, wait for one:
         `_QueuedPod.frag`). With no journal attached there is no record,
         no dict and no fragment, with one that never compacts no
-        fragment (and none stays from before)."""
+        fragment (and none stays from before). `row` is that dict and
+        its bytes made ahead of the call (`prepare_rows`): the same
+        record and the same fragment, with nothing serialised here."""
         if self._journal is None:
             state = None
         else:
             to_state, _, json_bytes = _codec()
-            state = to_state(pod)
+            if row is not None:
+                state, pod_json = row
+            else:
+                state, pod_json = to_state(pod), None
         if state is not None and self._frag_at_entry:
             self._frags[pod.uid] = _row_open(
-                json_bytes(state), json_bytes(node_name)
+                json_bytes(state) if pod_json is None else pod_json,
+                json_bytes(node_name),
             )
         else:
             self._frags.pop(pod.uid, None)
@@ -215,14 +224,37 @@ class SchedulerCache:
 
     # ---- assume lifecycle ------------------------------------------------
 
-    def assume(self, pod: Pod, node_name: str) -> None:
+    def prepare_rows(
+        self, states: list[dict | None] | None
+    ) -> list[tuple[dict, bytes | None] | None] | None:
+        """What `assume` would serialise for each pod of a cycle, made
+        before the cycle's decisions land: for every state dict the
+        queue's in-flight entries keep (`SchedulingQueue.in_flight_states`),
+        the dict with the pod's half of its snapshot fragment, which
+        does not depend on the node; the dict alone under a journal
+        that never compacts (no fragment at entry). Touches no store and
+        takes no lock. None with no journal attached."""
+        if states is None or self._journal is None:
+            return None
+        if not self._frag_at_entry:
+            return [None if s is None else (s, None) for s in states]
+        json_bytes = _codec()[2]
+        return [None if s is None else (s, json_bytes(s)) for s in states]
+
+    def assume(
+        self, pod: Pod, node_name: str,
+        row: tuple[dict, bytes | None] | None = None,
+    ) -> None:
+        """`row`: this pod's entry of `prepare_rows`, where the caller
+        holds one made from this very object as it stands; the record
+        and the fragment are then made from it and not from the pod."""
         with self._lock:
             if pod.uid in self._bound:
                 # raise WITHOUT emitting: a refused assume must not be
                 # replayed (replay would refuse it again and abort)
                 raise ValueError(f"pod {pod.name} already bound")
             self._assumed[pod.uid] = _AssumedPod(pod, node_name)
-            state = self._pod_state(pod, node_name)
+            state = self._pod_state(pod, node_name, row)
             if state is not None:
                 self._emit("c.assume", {"pod": state, "node": node_name})
 

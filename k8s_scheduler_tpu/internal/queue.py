@@ -113,7 +113,9 @@ class _QueuedPod:
     # journal record carried it, until a compaction has serialised it,
     # and those bytes from then on (state/codec.pod_fragment). A field
     # of the entry, so it moves and goes with it; None with no journal
-    # attached and after `load_state`
+    # attached and after `load_state`. While it is the dict, the cycle
+    # that binds the pod journals its `c.assume` from it
+    # (`in_flight_states`) and makes no second one
     frag: dict | bytes | None = None
 
 
@@ -315,6 +317,32 @@ class SchedulingQueue:
                     "q.pop", now, {"hold": True} if hold else {}
                 )
             return ready
+
+    def in_flight_states(
+        self, pods: Sequence[Pod]
+    ) -> list[dict | None] | None:
+        """For each of a cycle's popped pods, the state dict its
+        in-flight entry keeps, under one hold of the lock for the list:
+        `pod_to_state(pod)` as the entry's last record carried it, so
+        the cycle's bind loop need not make it a second time
+        (`SchedulerCache.prepare_rows`). None for a pod whose entry is
+        gone, holds another object (an `Update` refreshed it; the cycle
+        binds the one it popped) or keeps no dict (restored, or met by
+        a compaction, which left bytes); None for the list with no
+        journal attached, where no entry keeps anything."""
+        with self._lock:
+            if self._journal is None:
+                return None
+            get = self._in_flight.get
+            out = []
+            for pod in pods:
+                e = get(pod.uid)
+                out.append(
+                    e.frag
+                    if e is not None and e.pod is pod
+                    and type(e.frag) is dict else None
+                )
+            return out
 
     def retire_in_flight(self, uids: Sequence[str]) -> None:
         """A multi-cycle batch flush applied these pods' outcomes: drop

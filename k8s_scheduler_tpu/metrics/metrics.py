@@ -265,6 +265,12 @@ restore) and leader election:
 - scheduler_journal_records_total — journal RECORDS appended, a `batch`
   as one: a cycle's emissions are one, an `Update` request's are one;
   the flight records carry the same running total as `journal_records`
+- scheduler_bind_rows_prepared_total{outcome} — the journal rows a
+  cycle prepared for its bind loop while the device decided, from what
+  the queue's in-flight entries keep: `used` (a winner's `assume` took
+  its row and serialised nothing), `unused` (a loser's row, a row whose
+  entry changed meanwhile, a failed cycle's), and `fallback` (a winner
+  with no row, serialised in the loop); nothing with no journal attached
 - scheduler_journal_bytes_total — encoded journal bytes written to disk
 - scheduler_journal_fsync_seconds — group-commit fsync latency (one
   fsync per drained batch, writer thread only — never the bind path)
@@ -795,6 +801,15 @@ class SchedulerMetrics:
             "scheduler_journal_records_total",
             "Write-ahead-journal records appended, a batch record (a "
             "cycle's or an Update request's emissions) as one.",
+            registry=r,
+        )
+        self.bind_rows_prepared = Counter(
+            "scheduler_bind_rows_prepared_total",
+            "Journal rows prepared for a cycle's bind loop while the "
+            "device decided, by outcome: used by a winner's assume, "
+            "unused, or fallback (a winner that had none and was "
+            "serialised in the loop).",
+            ["outcome"],
             registry=r,
         )
         self.journal_bytes = Counter(
