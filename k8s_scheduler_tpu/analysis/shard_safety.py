@@ -5,8 +5,8 @@ device count by (a) routing every claim-path reduce through the
 shard-invariant selection primitives in ops/argsel.py (`jnp.argmax` /
 `lax.top_k` merge equal-valued entries in shard-local order under
 GSPMD), (b) eliminating the axis-0 `jnp.concatenate` of pods-sharded
-1-D vectors that this jaxlib miscompiles under SPMD (root-caused in
-AUDIT_SHARDED_r05; guarded until now only by one repro test), and
+1-D vectors that this jaxlib miscompiles under SPMD (guarded until
+now only by one repro test, tests/test_shard_invariance.py), and
 (c) centralizing every "which PartitionSpec does this array get" rule
 in `parallel/mesh.mesh_pin`. ROADMAP item 3 (multi-host mesh) rewrites
 exactly these surfaces — this pass is the static guardrail that must

@@ -90,22 +90,6 @@ def new_scheduler_command() -> argparse.ArgumentParser:
         "(MC) the same way (overrides config padMc; 0 = keep config)",
     )
     ap.add_argument(
-        "--multi-cycle-k", type=int, default=0,
-        help="multi-cycle on-device serving: coalesce up to K arrival "
-        "groups into one device dispatch running K scheduling cycles in "
-        "a device-resident loop (amortizes the dispatch round trip "
-        "K-fold for small-delta cycles; config multiCycleK; 1 disables, "
-        "0 = keep config). Workloads outside the exactness envelope "
-        "fall back to sequential single-cycle dispatches",
-    )
-    ap.add_argument(
-        "--multi-cycle-max-wait-ms", type=float, default=-1.0,
-        help="latency bound on the multi-cycle coalescing buffer: a "
-        "delta group is never held back longer than this many ms "
-        "waiting for the batch to fill (config multiCycleMaxWaitMs; "
-        "-1 = keep config)",
-    )
-    ap.add_argument(
         "--slo-p99-ms", type=float, default=-1.0,
         help="latency SLO objective: at most 1%% of cycles in the "
         "sloWindowCycles window may exceed this many milliseconds of "
@@ -145,26 +129,6 @@ def new_scheduler_command() -> argparse.ArgumentParser:
         "(config speculativeCompile; 1 on, 0 off, -1 = keep config)",
     )
     ap.add_argument(
-        "--speculative-dispatch", type=int, default=-1, choices=(-1, 0, 1),
-        help="depth-2 speculative dispatch pipelining: while multi-cycle "
-        "batch k is on device, dispatch batch k+1 against the predicted "
-        "post-k carry; adopted on a predicate match, abandoned and "
-        "re-dispatched on a mismatch — results are bit-identical either "
-        "way. Forced off under --forced-sync and at/below the ladder's "
-        "sequential rung (config speculativeDispatch; 1 on, 0 off, "
-        "-1 = keep config)",
-    )
-    ap.add_argument(
-        "--incremental-encode", type=int, default=-1, choices=(-1, 0, 1),
-        help="admission-time incremental encode: parse each buffered pod "
-        "into staged row data at multi-cycle buffer time (the ack "
-        "path's shadow) so the flush encode is an O(dirty) finalize "
-        "over pre-parsed rows; falls back to a full rebuild on "
-        "interning-table growth or a pad-regime flip, bit-identical "
-        "either way (config incrementalEncode; 1 on, 0 off, "
-        "-1 = keep config)",
-    )
-    ap.add_argument(
         "--dispatch-deadline-ms", type=float, default=-1.0,
         help="dispatch watchdog: bound on the blocking per-cycle "
         "decision fetch in milliseconds — on expiry the fetch is "
@@ -189,17 +153,17 @@ def new_scheduler_command() -> argparse.ArgumentParser:
         help="submission front door: serve the admission-controlled "
         "Submit/NodeChurn RPCs on this extra gRPC address (own accept "
         "queue + worker pool) and run the internal serve loop — "
-        "arrivals coalesce straight into the multi-cycle batcher "
-        "instead of waiting for agent-driven Cycle RPCs. Accepted "
+        "arrivals go to the queue and the loop's next cycle pops them, "
+        "with no agent-driven Cycle RPCs. Accepted "
         "pods are journaled through the WAL before the ack returns "
         "when --state-dir is set. Empty = front door disabled",
     )
     ap.add_argument(
         "--admission-queue-depth", type=int, default=-1,
-        help="bound on the admission queue (pending pods + coalescing "
-        "buffers): a Submit that would push the depth past this is "
-        "shed with RESOURCE_EXHAUSTED + retry-after instead of "
-        "buffered (config admissionQueueDepth; 0 = unbounded, "
+        help="bound on the admission queue (pending pods across the "
+        "queue's tiers): a Submit that would push the depth past this "
+        "is shed with RESOURCE_EXHAUSTED + retry-after instead of "
+        "queued (config admissionQueueDepth; 0 = unbounded, "
         "-1 = keep config)",
     )
     ap.add_argument(
@@ -286,10 +250,6 @@ def main(argv: list[str] | None = None) -> int:
         config.health_max_cycle_age_seconds = args.health_max_cycle_age
     if args.slo_p99_ms >= 0:
         config.slo_p99_ms = args.slo_p99_ms
-    if args.multi_cycle_k > 0:
-        config.multi_cycle_k = args.multi_cycle_k
-    if args.multi_cycle_max_wait_ms >= 0:
-        config.multi_cycle_max_wait_ms = args.multi_cycle_max_wait_ms
     if args.pad_hysteresis_pct >= 0:
         config.pad_hysteresis_pct = args.pad_hysteresis_pct
     if args.compile_cache_dir:
@@ -298,10 +258,6 @@ def main(argv: list[str] | None = None) -> int:
         config.shard_devices = args.shard_devices
     if args.speculative_compile >= 0:
         config.speculative_compile = bool(args.speculative_compile)
-    if args.speculative_dispatch >= 0:
-        config.speculative_dispatch = bool(args.speculative_dispatch)
-    if args.incremental_encode >= 0:
-        config.incremental_encode = bool(args.incremental_encode)
     if args.dispatch_deadline_ms >= 0:
         config.dispatch_deadline_ms = args.dispatch_deadline_ms
     if args.degrade_promote_cycles > 0:
@@ -646,10 +602,9 @@ def main(argv: list[str] | None = None) -> int:
                 print(f"black box dumped: {bpath}", flush=True)
         if front_door is not None:
             # graceful drain BEFORE anything seals: admission closes
-            # (late submits answer UNAVAILABLE "draining"), buffered
-            # multi-cycle groups flush, the active tier empties — no
-            # pod stranded between ack and dispatch — then the loop
-            # thread joins
+            # (late submits answer UNAVAILABLE "draining"), the active
+            # tier empties — no pod stranded between ack and dispatch —
+            # then the loop thread joins
             drained = front_door.stop()
             print(
                 f"front door drained: {drained} "

@@ -140,19 +140,6 @@ class SchedulerConfiguration:
     # 0 disables the objective (attribution + anomalies still run).
     slo_p99_ms: float = 0.0
     slo_window_cycles: int = 1024
-    # multi-cycle on-device serving (core/cycle.build_packed_multicycle_fn):
-    # coalesce up to K per-cycle arrival groups into ONE device dispatch
-    # running K scheduling cycles in a device-resident loop, amortizing
-    # the remote-dispatch round trip K-fold for small-delta cycles.
-    # 1 disables batching (every cycle dispatches alone). Workloads
-    # outside the exactness envelope (inter-pod affinity, topology
-    # spread, volumes, pending host ports, extenders) automatically fall
-    # back to sequential single-cycle dispatches.
-    multi_cycle_k: int = 1
-    # latency bound on the coalescing buffer: a delta group is never
-    # held back longer than this many milliseconds waiting for the
-    # batch to fill (an idle pop also flushes immediately)
-    multi_cycle_max_wait_ms: float = 5.0
     # compile-regime management (core/compile_cache.py):
     # padHysteresisPct — down-step margin for the P/N pad buckets: a
     # shrinking pending/node count only steps the pad regime DOWN when
@@ -183,27 +170,6 @@ class SchedulerConfiguration:
     # anomaly sentinel's demand EWMA drifts toward a bucket boundary;
     # a flip speculation won costs ~0 compile on the serve path.
     speculative_compile: bool = True
-    # speculativeDispatch — depth-2 speculative dispatch pipelining
-    # (core/pipeline.py + core/scheduler.py): while multi-cycle batch k
-    # is on device, speculatively dispatch batch k+1 against the
-    # predicted post-k carry (device-resident continuation chaining).
-    # When batch k's host fold lands, the speculation is adopted on a
-    # predicate-digest match (zero added latency) or abandoned and
-    # re-dispatched against the true carry — bit-identical results
-    # either way, only latency is speculative. Effective on the
-    # multi-cycle path (multiCycleK > 1); forced off under forcedSync
-    # and at/below the degradation ladder's `sequential` rung.
-    speculative_dispatch: bool = True
-    # incrementalEncode — admission-time incremental encode
-    # (models/encoding.py ingest_pod + core/scheduler.py multi-cycle
-    # flush): each pod buffered for a multi-cycle batch is parsed into
-    # staged row data at buffer time, in the ack path's shadow, so the
-    # flush-time encode is an O(dirty) finalize over pre-parsed rows
-    # instead of an O(P) re-walk. Falls back to a full rebuild whenever
-    # an interning table grows during ingest or the pad regime flips —
-    # the packed arena is bit-identical either way. Effective on the
-    # multi-cycle path (multiCycleK > 1); a no-op at K=1.
-    incremental_encode: bool = False
     # dispatch watchdog (core/pipeline.py): bound, in milliseconds, on
     # the ONE blocking device->host decision fetch. On expiry the fetch
     # is abandoned (DispatchDeadlineExceeded), the cycle's pods requeue
@@ -221,11 +187,10 @@ class SchedulerConfiguration:
     # env SCHED_FAULTS overrides when this is empty). "" disarms.
     fault_spec: str = ""
     # submission front door (service/admission.py): bound on the
-    # admission queue — pending pods (all queue tiers) plus pods
-    # coalescing in the multi-cycle buffers. A Submit that would push
-    # the depth past this bound is SHED whole (RESOURCE_EXHAUSTED +
-    # retry-after), never buffered: overload degrades to shedding, not
-    # to unbounded memory. Shedding also engages while the SLO
+    # admission queue — pending pods (all queue tiers). A Submit that
+    # would push the depth past this bound is SHED whole
+    # (RESOURCE_EXHAUSTED + retry-after): overload degrades to shedding,
+    # not to unbounded memory. Shedding also engages while the SLO
     # fast-burn gauge fires or the degradation ladder sits below rung
     # 0. 0 disables the front door's depth bound (tests only).
     admission_queue_depth: int = 65536
@@ -398,14 +363,10 @@ def load_config(source: "str | dict") -> SchedulerConfiguration:
         ),
         slo_p99_ms=float(data.get("sloP99Ms", 0.0)),
         slo_window_cycles=int(data.get("sloWindowCycles", 1024)),
-        multi_cycle_k=int(data.get("multiCycleK", 1)),
-        multi_cycle_max_wait_ms=float(data.get("multiCycleMaxWaitMs", 5.0)),
         pad_hysteresis_pct=float(data.get("padHysteresisPct", 0.0)),
         compile_cache_dir=str(data.get("compileCacheDir", "")),
         shard_devices=int(data.get("shardDevices", 0)),
         speculative_compile=bool(data.get("speculativeCompile", True)),
-        speculative_dispatch=bool(data.get("speculativeDispatch", True)),
-        incremental_encode=bool(data.get("incrementalEncode", False)),
         dispatch_deadline_ms=float(data.get("dispatchDeadlineMs", 0.0)),
         degrade_promote_cycles=int(data.get("degradePromoteCycles", 8)),
         fault_spec=str(data.get("faultSpec", "")),

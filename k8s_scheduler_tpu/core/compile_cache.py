@@ -22,7 +22,7 @@ backend wedge — ISSUE 5). Three layers attack that cost:
 - **Cache keys** — `models/packing.shape_signature(spec)` (the named
   pad regime: every SIGNATURE_DIMS dimension) + a hash of the full
   `spec.key()` + profile + program kind (cycle / stable / preempt /
-  diag / carry_init / carry_update / multicycle-K) + the program's
+  diag / carry_init / carry_update) + the program's
   deterministic build name + the jax/jaxlib/backend fingerprint. The
   literal `SIG_KEY_FIELDS`/`EXTRA_KEY_FIELDS` inventories below are
   machine-checked by schedlint ID006 against packing.SIGNATURE_DIMS and
@@ -33,8 +33,8 @@ backend wedge — ISSUE 5). Three layers attack that cost:
   speculative build jobs (never the bind path): when the sentinel's
   per-profile demand EWMA (core/observe.py) drifts toward a pad-bucket
   boundary, the ADJACENT regime's spec is derived by `packing.respec`
-  and its programs are pre-built into the scheduler's `_packed`/
-  `_mc_fns` memos and this disk cache. A flip that speculation won then
+  and its programs are pre-built into the scheduler's `_packed` memo
+  and this disk cache. A flip that speculation won then
   stamps `regime_flip` with `compile_ms~=0` and
   `compile_source="speculative"`.
 """

@@ -10,8 +10,8 @@ EXPLICIT, observable recovery ladder instead of an ad-hoc one:
                          absorb transient flakes invisibly
     rung 1  retrace      compiled-program memos cleared (the
                          clear_cache+retrace recovery, regime-wide)
-    rung 2  sequential   multi-cycle batching off — every cycle is its
-                         own dispatch (smaller blast radius per fault)
+    rung 2  sequential   every cycle dispatches alone (kept for rung
+                         numbering)
     rung 3  forced_sync  every dispatch blocks to completion (no
                          in-flight state to lose; the measurement mode,
                          now a recovery mode)

@@ -72,24 +72,9 @@ TRACE_LANE_FOR_PHASE = {
     "losers": (LANE_HOST, "losers"),
     "device": (LANE_DEVICE, "device cycle[seq]"),
     "diag_lag": (LANE_DIAG, "diag lag[seq]"),
-    # multi-cycle batched decomposition: an inner cycle's host-side
-    # coalescing wait renders on the host lane (it precedes the batch's
-    # encode), its apportioned device share inside the batch's device
-    # slice (the host cannot see per-inner-cycle device boundaries)
-    "batch_wait": (LANE_HOST, "batch wait"),
-    "device_share": (LANE_DEVICE, "device cycle[seq]"),
-    # streamed decision fetch: batch flush -> first inner cycle's
-    # decision row landed; renders inside the batch's device slice
-    # (the window ends where row 0's transfer completes)
-    "first_bind": (LANE_DEVICE, "device cycle[seq]"),
     # front door: admission accept -> bind, a host-observed end-to-end
     # window; renders on the host lane (it ends in the bind loop)
     "submit_bind": (LANE_HOST, "bind winners"),
-    # admission-time incremental encode: the ingest share was paid
-    # before the flush cycle started, but it is host encode work, so
-    # both halves render inside the flush cycle's encode slice
-    "encode_ingest": (LANE_HOST, "encode"),
-    "encode_finalize": (LANE_HOST, "encode"),
 }
 
 
@@ -128,12 +113,6 @@ class CycleRecord:
     # flip). The observer surfaces it in /debug/anomalies recompile
     # events so operators can tell a cache miss from a win.
     compile_source: str = ""
-    # depth-2 speculative dispatch outcome, stamped on the record of
-    # the batch a speculation rode (one sample per speculation):
-    # "adopted" | "abandoned" | "none" (speculation considered but not
-    # dispatched — e.g. spec mismatch), "" = no speculation involved.
-    # Feeds the observer's speculation_thrash abandon-rate EWMA.
-    speculation: str = ""
     # trace ids of the sampled pods this cycle served (core/spans):
     # the exemplar join from a flight record back to its pod traces —
     # span attrs carry the cycle `seq` for the reverse direction.
@@ -173,10 +152,6 @@ class CycleRecord:
             **(
                 {"compile_source": self.compile_source}
                 if self.compile_source else {}
-            ),
-            **(
-                {"speculation": self.speculation}
-                if self.speculation else {}
             ),
             **(
                 {"trace_ids": list(self.trace_ids)}
@@ -299,11 +274,6 @@ class FlightRecorder:
         self.observers: list[Callable[[CycleRecord], None]] = []
 
     # ---- writer side (scheduling loop only) ------------------------------
-
-    @property
-    def next_seq(self) -> int:
-        """The seq the next started record takes."""
-        return self._seq
 
     def start(self, profile: str = "default-scheduler") -> CycleRecord:
         rec = CycleRecord(

@@ -34,22 +34,6 @@ so an encode that already ran read a stale cache). Drivers that fold
 nothing (pure throughput loops, probes) opt out with
 `require_decision_fetch=False`.
 
-Depth-2 speculative dispatch (`dispatch_multi(..., speculative=True)`)
-is the one sanctioned relaxation: batch k+1 may be dispatched while
-batch k is still in flight, encoded against the PREDICTED post-k state
-(device-side carry chaining — cycle.build_packed_multicycle_fn
-`carry_in`). The guard is then "binds fold before the next ADOPTED
-encode": the speculative handle only becomes the current batch through
-`adopt_speculative()` — called after batch k's host fold landed and
-matched the speculation's predicate digest — and is otherwise abandoned
-(`abandon_speculative()`) and re-dispatched against the true carry.
-Correctness is never speculative, only latency is. Depth 2 needs a
-THIRD arena slot (`slots=3`): the two double-buffered slots assume one
-batch in flight, and with two in flight the slot-reuse release would
-otherwise overwrite a batch whose decisions were never fetched —
-`dispatch`/`dispatch_multi` refuse that loudly instead of corrupting
-an in-flight upload.
-
 `forced_sync=True` is the escape hatch for tests and latency measurement:
 every dispatch blocks to completion before returning, restoring strict
 sequential execution with identical results (the split is a scheduling
@@ -67,8 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import faults as _faults
-from . import spans as _spans
-from .cycle import CycleDecision, _jit
+from .cycle import _jit
 
 
 def _dispatch_anchor(anchor, now):
@@ -175,36 +158,6 @@ def build_decision_slim_fn(num_nodes: int):
         return a, flags
 
     return _jit(slim, "decision_slim", disc=f"narrow{int(narrow)}")
-
-
-def build_multicycle_slim_rows_fn(num_nodes: int, k: int):
-    """STREAMED variant of the multi-cycle decision slimming: the same
-    i16|u8 diet, but split K ways so each inner cycle's row is its own
-    fetchable device buffer — `(((a_0, flags_0), …, (a_{K-1},
-    flags_{K-1})), cycles_run)` instead of one stacked [K, P] pair.
-    MultiCycleHandle.decisions_row(i) then blocks on row i's transfer
-    alone, so the apply loop can bind inner cycle i's winners while
-    rows i+1…K-1 are still in flight (and, under depth-2 speculative
-    dispatch, while the NEXT batch is still running on device). Flag
-    bits: 0 = unschedulable, 1 = gang_dropped, 2 = attempted (the pod
-    was valid in that inner cycle — the host needs it to tell "not
-    this cycle's pod" from "placed at node 0")."""
-    narrow = num_nodes < (1 << 15)
-
-    def slim(assignment, unschedulable, gang_dropped, attempted,
-             cycles_run):
-        a = assignment.astype(jnp.int16) if narrow else assignment
-        flags = (
-            unschedulable.astype(jnp.uint8)
-            | (gang_dropped.astype(jnp.uint8) << 1)
-            | (attempted.astype(jnp.uint8) << 2)
-        )
-        rows = tuple((a[i], flags[i]) for i in range(k))
-        return rows, cycles_run
-
-    return _jit(
-        slim, "multicycle_slim_rows", disc=f"narrow{int(narrow)}|k{k}"
-    )
 
 
 def _cpu_safe_buffers(wbuf, bbuf):
@@ -440,272 +393,6 @@ class CycleHandle:
         self._wbuf = self._bbuf = self._stable = self._emask = None
 
 
-class MultiCycleHandle:
-    """One in-flight multi-cycle batch (K inner cycles dispatched as a
-    single device program — core/cycle.build_packed_multicycle_fn).
-    Mirrors CycleHandle's contract, streamed: the slimmed decision
-    payload is split into per-inner-cycle fetchable rows
-    (build_multicycle_slim_rows_fn), so `decisions_row(i)` blocks on
-    row i's transfer alone and the apply loop binds cycle i's winners
-    while later rows (and, under depth-2 speculation, the next batch)
-    are still in flight. The handle counts as fetched — releasing the
-    binds-fold ordering guard — once every LIVE row (`n_live`, the
-    dispatched `n_cycles`) was fetched. The per-inner-cycle deferred
-    programs (diagnosis, preemption) dispatch lazily against the
-    stacked buffers' row i and the loop's post-cycle-i
-    `node_requested`."""
-
-    def __init__(
-        self, pipe, result, slim, wbufs, bbufs, stable,
-        n_live: int, speculative: bool = False,
-    ):
-        self._pipe = pipe
-        self.result = result  # MultiCycleResult device futures
-        # (((i16|i32 [P], u8 [P]) x K), i32) futures — per-row slimmed
-        self._slim = slim
-        self._wbufs = wbufs
-        self._bbufs = bbufs
-        self._stable = stable
-        self.n_live = n_live
-        self.speculative = speculative
-        self._rows: dict[int, tuple] = {}
-        self._cycles_run: "int | None" = None
-        self._decisions = None
-        self._t_decisions = None
-        self._diag: dict[int, object] = {}
-        self._pre: dict[int, object] = {}
-        # inner cycle i -> (lag_s, t_done): deferred-diagnosis
-        # availability, stamped at first force so the scheduler can put
-        # diag_lag on inner-cycle flight records (stage_report is
-        # snapshotted BEFORE the apply loop that forces these)
-        self.diag_lag: dict[int, tuple[float, float]] = {}
-        self.fetched = False
-
-    def _consumed(self, e: BaseException) -> None:
-        """A failed fetch consumes the batch: same contract as
-        CycleHandle.decisions — the ordering guard releases, the
-        failure class is stamped before the re-raise."""
-        self._pipe.note_fetch_failure(e)
-        self.fetched = True
-        self.release()
-        self._pipe._note_inflight()
-
-    def decisions_row(self, i: int):
-        """Inner cycle i's decisions as numpy — `(assignment i32 [P],
-        unschedulable bool [P], gang_dropped bool [P], attempted bool
-        [P])` — blocking on row i's slimmed transfer only. The first
-        row fetched stamps `t_first_decision` (the scheduler's
-        `first_bind` phase anchor); fetching every live row marks the
-        handle consumed (ordering-guard release)."""
-        hit = self._rows.get(i)
-        if hit is not None:
-            return hit
-        now = self._pipe._now
-        t0 = now()
-        st = self._pipe.stats
-        st.setdefault("t_decision_start", t0)
-        try:
-            a, flags = self._pipe.fetch_decisions(
-                lambda: jax.device_get(self._slim[0][i])
-            )
-        except Exception as e:  # schedlint: disable=RB001 -- not swallowed: _consumed stamps the failure class (metric + events ring) before the re-raise — the consumed-cycle contract
-            self._consumed(e)
-            raise
-        t1 = now()
-        self._t_decisions = t1
-        st["decision_wait_ms"] = (
-            st.get("decision_wait_ms", 0.0) + (t1 - t0) * 1e3
-        )
-        st["t_decision_end"] = t1
-        st.setdefault("t_first_decision", t1)
-        if _spans.ARMED:
-            # per-row decision window for the decision.row trace span
-            # (scheduler._apply_mc_row reads it back by row index; a
-            # plain-list key, so the stage report's t_*/"*_ms" copy
-            # loops never see it and flight records stay unchanged)
-            st.setdefault("decision_rows", []).append((i, t0, t1))
-        nbytes = int(a.nbytes + flags.nbytes)
-        st["fetch_bytes"] = st.get("fetch_bytes", 0) + nbytes
-        self._pipe._fetch_bytes_total += nbytes
-        m = self._pipe._metrics
-        if m is not None:
-            m.cycle_duration.labels(phase="decision_fetch").observe(
-                t1 - t0
-            )
-            m.decision_fetch_bytes.inc(nbytes)
-        row = (
-            np.asarray(a, dtype=np.int32),
-            (flags & 1) != 0,
-            (flags & 2) != 0,
-            (flags & 4) != 0,
-        )
-        self._rows[i] = row
-        if len(self._rows) >= self.n_live and not self.fetched:
-            self.fetched = True
-            self._pipe._note_inflight()
-        return row
-
-    def cycles_run(self) -> int:
-        """Inner cycles the device loop actually executed (blocks on
-        the scalar transfer; ~free once the rows landed)."""
-        if self._cycles_run is None:
-            try:
-                cr = self._pipe.fetch_decisions(
-                    lambda: jax.device_get(self._slim[1])
-                )
-            except Exception as e:  # schedlint: disable=RB001 -- not swallowed: _consumed stamps the failure class (metric + events ring) before the re-raise
-                self._consumed(e)
-                raise
-            self._cycles_run = int(cr)
-        return self._cycles_run
-
-    def decisions(self):
-        """(assignment i32 [K, P], unschedulable bool [K, P],
-        gang_dropped bool [K, P], attempted bool [K, P], cycles_run int)
-        as numpy — the whole-batch fetch (every row + the scalar in one
-        transfer). Kept for drivers that want the stacked shape; the
-        streaming apply path uses decisions_row."""
-        if self._decisions is None:
-            now = self._pipe._now
-            t0 = now()
-            st = self._pipe.stats
-            st.setdefault("t_decision_start", t0)
-            try:
-                rows, cycles_run = self._pipe.fetch_decisions(
-                    lambda: jax.device_get(self._slim)
-                )
-            except Exception as e:  # schedlint: disable=RB001 -- not swallowed: _consumed stamps the failure class (metric + events ring) before the re-raise
-                self._consumed(e)
-                raise
-            self._t_decisions = now()
-            st["decision_wait_ms"] = (
-                st.get("decision_wait_ms", 0.0)
-                + (self._t_decisions - t0) * 1e3
-            )
-            st["t_decision_end"] = self._t_decisions
-            st.setdefault("t_first_decision", self._t_decisions)
-            nbytes = sum(
-                int(r[0].nbytes + r[1].nbytes) for r in rows
-            ) + 4
-            a = np.stack([np.asarray(r[0], dtype=np.int32)
-                          for r in rows])
-            flags = np.stack([np.asarray(r[1]) for r in rows])
-            st["fetch_bytes"] = st.get("fetch_bytes", 0) + nbytes
-            self._pipe._fetch_bytes_total += nbytes
-            m = self._pipe._metrics
-            if m is not None:
-                m.cycle_duration.labels(phase="decision_fetch").observe(
-                    self._t_decisions - t0
-                )
-                m.decision_fetch_bytes.inc(nbytes)
-            self._cycles_run = int(cycles_run)
-            self._decisions = (
-                a,
-                (flags & 1) != 0,
-                (flags & 2) != 0,
-                (flags & 4) != 0,
-                self._cycles_run,
-            )
-            self.fetched = True
-            self._pipe._note_inflight()
-        return self._decisions
-
-    def _inner_decision(self, i: int) -> CycleDecision:
-        """Inner cycle i's decision carry as the deferred programs'
-        input: stacked row i plus the loop's POST-cycle-i state."""
-        r = self.result
-        return CycleDecision(
-            assignment=r.assignment[i],
-            node_requested=r.node_requested[i],
-            unschedulable=r.unschedulable[i],
-            gang_dropped=r.gang_dropped[i],
-        )
-
-    def dispatch_preemption(self, i: int):
-        """Dispatch inner cycle i's preemption PostFilter (non-blocking);
-        returns its device-side result or None. NOTE the documented
-        multi-cycle deviation: candidates/victims are computed against
-        the BATCH-start existing set — a pod bound by an earlier inner
-        cycle is not yet evictable (it becomes so next batch)."""
-        if i not in self._pre and self._pipe._preempt_fn is not None:
-            self._pre[i] = self._pipe._preempt_fn(
-                self._wbufs[i], self._bbufs[i],
-                self._inner_decision(i), self._stable,
-            )
-        return self._pre.get(i)
-
-    def dispatch_diagnosis(self, i: int):
-        """Dispatch inner cycle i's FailedScheduling diagnosis program
-        (non-blocking); returns the device-side [P, F] handle or None.
-        Uses `pipe.multi_diag_fn` when set — the multi-cycle decisions
-        are lean (no fused reject counts), so the scheduler installs a
-        diagnosis program even for regimes whose single-cycle path runs
-        the fused full program and needs none."""
-        fn = self._pipe.multi_diag_fn or self._pipe._diag_fn
-        if i not in self._diag and fn is not None:
-            r = self.result
-            self._diag[i] = fn(
-                self._wbufs[i], self._bbufs[i], self._stable,
-                r.assignment[i], r.node_requested[i],
-            )
-            if self._pipe.forced_sync:
-                jax.block_until_ready(self._diag[i])
-                self._stamp_diag_lag(i)
-        return self._diag.get(i)
-
-    def _stamp_diag_lag(self, i: int) -> None:
-        if self._t_decisions is None or i in self.diag_lag:
-            return
-        t_done = self._pipe._now()
-        lag_s = max(0.0, t_done - self._t_decisions)
-        self.diag_lag[i] = (lag_s, t_done)
-        m = self._pipe._metrics
-        if m is not None:
-            m.cycle_duration.labels(phase="diag_lag").observe(lag_s)
-
-    def reject_counts(self, i: int):
-        """Force inner cycle i's diagnosis output (i32 [P, F]); None
-        when the pipeline has no diagnosis program. First force stamps
-        the deferred-diagnosis lag for inner cycle i — how long after
-        the batch's decision fetch the attribution became available."""
-        d = self.dispatch_diagnosis(i)
-        if d is None:
-            return None
-        arr = np.asarray(d)
-        self._stamp_diag_lag(i)
-        return arr
-
-    def reject_counts_matrix(self, i: int, n: int):
-        """Inner cycle i's per-plugin attribution as ONE forced [n, F]
-        matrix (see CycleHandle.reject_counts_matrix — same one-force
-        contract for the vectorized apply fold)."""
-        return np.asarray(self.reject_counts(i))[:n]
-
-    def block(self):
-        """Force everything in flight (the forced_sync escape hatch);
-        watchdog-bounded like CycleHandle.block."""
-        try:
-            self._pipe.fetch_decisions(
-                lambda: jax.block_until_ready((self.result, self._slim))
-            )
-        except Exception as e:
-            # consumed batch: guard releases, class stamped (see
-            # CycleHandle.block)
-            self._pipe.note_fetch_failure(e)
-            self.fetched = True
-            self.release()
-            self._pipe._note_inflight()
-            raise
-        return self
-
-    def release(self):
-        self.result = self._slim = None
-        self._wbufs = self._bbufs = self._stable = None
-        self._diag = {}
-        self._pre = {}
-        self.diag_lag = {}
-
-
 class ServingPipeline:
     """Owns the two upload slots, the in-flight handle, and the carry
     hand-off (CarryKeeper-compatible). One instance per compiled packed
@@ -723,10 +410,6 @@ class ServingPipeline:
         keeper=None,
         diag_fn=None,
         preempt_fn=None,
-        multi_fn=None,  # optional multi-cycle program
-        # (build_packed_multicycle_fn) driving dispatch_multi; the
-        # scheduler assigns it lazily (`pipe.multi_fn = ...`) when
-        # multiCycleK > 1 and the workload is in the envelope
         forced_sync: bool = False,
         require_decision_fetch: bool = True,
         donate_diagnosis: bool = False,
@@ -737,7 +420,6 @@ class ServingPipeline:
         # decision fetch (0 = unbounded); expiry raises
         # DispatchDeadlineExceeded via the _FetchWorker watchdog
         now=_time.perf_counter,
-        slots: int = 2,
     ) -> None:
         if donate_diagnosis and preempt_fn is not None:
             # a donated diagnosis consumes the slot's packed buffers; a
@@ -759,27 +441,9 @@ class ServingPipeline:
         self.dispatch_deadline_s = dispatch_deadline_s
         self._fetch_worker = _FetchWorker()  # no thread until first use
         self._now = now
-        self._slots = [None] * max(2, slots)
+        self._slots = [None, None]
         self._slim_fn = None
-        self.multi_fn = multi_fn
-        # multi-cycle diagnosis program (build_diagnosis_fn): the
-        # scheduler installs it next to multi_fn; falls back to
-        # _diag_fn (carry mode shares one) when None
-        self.multi_diag_fn = None
-        # continuation variant (build_packed_multicycle_fn carry_in):
-        # consumes a predecessor batch's device-resident carry — the
-        # program depth-2 speculative dispatches run on
-        self.multi_cont_fn = None
-        self._multi_slim_fn = None
         self._last = None
-        # the one in-flight SPECULATIVE batch (depth 2: at most one),
-        # pending adopt_speculative/abandon_speculative resolution
-        self._spec: "MultiCycleHandle | None" = None
-        # speculation ledger: outcomes of every speculative dispatch
-        # (mirrored into scheduler_speculation_total{outcome})
-        self.speculation = {
-            "adopted": 0, "abandoned": 0, "redispatched": 0,
-        }
         self._n = 0
         self._fetch_bytes_total = 0
         self._pending_encode_ms: float | None = None
@@ -854,79 +518,14 @@ class ServingPipeline:
 
     def _claim_slot(self) -> int:
         """Claim the next upload slot, releasing its previous occupant's
-        device references for arena reuse. Refuses to overwrite a slot
-        whose batch was never fetched: under depth-2 speculation two
-        batches are legitimately in flight, and silently releasing an
-        unfetched handle would corrupt an in-flight upload — the
-        slot-accounting invariant is that `slots >= in-flight + 1`
-        (three slots for depth 2), enforced here loudly."""
+        device references first, so the allocator hands back the
+        same-sized blocks (buffered arena reuse instead of per-cycle
+        growth)."""
         slot = self._n % len(self._slots)
         prev = self._slots[slot]
         if prev is not None:
-            if not prev.fetched and self.require_decision_fetch:
-                # fold-free drivers (require_decision_fetch=False) opted
-                # out of the ordering guard and may legitimately leave
-                # handles unfetched — they keep the silent release
-                raise RuntimeError(
-                    f"ServingPipeline: upload slot {slot} still holds "
-                    "an unfetched in-flight batch — dispatch depth "
-                    f"exceeds the {len(self._slots)}-slot arena "
-                    "(speculative depth-2 needs slots=3)"
-                )
-            # release the old occupant's device references BEFORE
-            # uploading so the allocator hands back the same-sized
-            # blocks (buffered arena reuse instead of per-cycle growth)
             prev.release()
         return slot
-
-    def _speculation_outcome(self, outcome: str) -> None:
-        self.speculation[outcome] += 1
-        m = self._metrics
-        counter = getattr(m, "speculation", None) if m else None
-        if counter is not None:
-            counter.labels(outcome=outcome).inc()
-
-    def adopt_speculative(self) -> "MultiCycleHandle":
-        """The host fold of the predecessor batch matched the
-        speculation's predicate: the in-flight speculative batch
-        becomes the current one (zero added latency — it has been on
-        device the whole time) and the ordering guard resumes guarding
-        it like any adopted dispatch."""
-        h = self._spec
-        if h is None:
-            raise RuntimeError("adopt_speculative: no speculation in flight")
-        self._spec = None
-        self._last = h
-        # the adopted batch's dispatch marks become the current stage
-        # report (its rows' fetch stats land on top as they stream in)
-        self.stats = dict(getattr(h, "_stats_seed", {}))
-        self._speculation_outcome("adopted")
-        return h
-
-    def abandon_speculative(self) -> None:
-        """The host fold diverged from the speculation's predicate (or
-        the predecessor batch failed outright): drop the in-flight
-        speculative batch — its results are never observed — and free
-        its arena slot. The caller re-dispatches against the true
-        carry (note_redispatch) or requeues. Idempotent/no-op when no
-        speculation is in flight, so failure paths can call it
-        unconditionally without leaking a slot."""
-        h = self._spec
-        if h is None:
-            return
-        self._spec = None
-        h.fetched = True  # consumed-without-observation: guard releases
-        h.release()
-        for i, s in enumerate(self._slots):
-            if s is h:
-                self._slots[i] = None
-        self._speculation_outcome("abandoned")
-        self._note_inflight()
-
-    def note_redispatch(self) -> None:
-        """Ledger mark: an abandoned speculation's groups were
-        re-dispatched against the true carry."""
-        self._speculation_outcome("redispatched")
 
     def dispatch(
         self,
@@ -946,12 +545,6 @@ class ServingPipeline:
         CycleHandle (unless forced_sync). Raises if the previous cycle's
         decisions were never fetched while require_decision_fetch — the
         strict-ordering guard (see module docstring)."""
-        if self._spec is not None:
-            raise RuntimeError(
-                "ServingPipeline: dispatch with an unresolved "
-                "speculative batch in flight — adopt_speculative() or "
-                "abandon_speculative() first"
-            )
         if (
             self.require_decision_fetch
             and self._last is not None
@@ -1027,136 +620,9 @@ class ServingPipeline:
             self.stats["encode_hidden_ms"] = 0.0
         return handle
 
-    def dispatch_multi(
-        self,
-        wbufs,
-        bbufs,
-        stable,
-        n_cycles: int,
-        *,
-        device_put: bool = True,
-        carry0=None,
-        speculative: bool = False,
-        anchor=None,
-    ) -> MultiCycleHandle:
-        """Upload + dispatch one MULTI-CYCLE batch (stacked [K, ...]
-        packed snapshots, one device dispatch for up to `n_cycles` inner
-        cycles — see build_packed_multicycle_fn). Shares the single-
-        dispatch ordering guard: a batch counts as the in-flight cycle,
-        so the next dispatch (single or multi) is refused until the
-        batch's decisions were fetched — binds-fold ordering holds
-        across the batch boundary exactly as it does between single
-        cycles.
-
-        `speculative=True` is the depth-2 relaxation: the batch may be
-        dispatched while its predecessor is still unfetched (the guard
-        becomes "binds fold before the next ADOPTED encode" — module
-        docstring). The handle is held aside until the caller resolves
-        it via adopt_speculative()/abandon_speculative(); at most one
-        speculation is in flight. `carry0 = (carry_node_requested,
-        carry_gplaced)` chains the predecessor's device-resident final
-        carry into this batch through `multi_cont_fn` (the carry_in
-        continuation program) — no host round trip."""
-        fn = self.multi_fn
-        if carry0 is not None:
-            fn = self.multi_cont_fn
-            if fn is None:
-                raise RuntimeError(
-                    "ServingPipeline.dispatch_multi: carry0 given but "
-                    "no continuation program (assign pipe.multi_cont_fn"
-                    " = build_packed_multicycle_fn(..., carry_in=True))"
-                )
-        if fn is None:
-            raise RuntimeError(
-                "ServingPipeline.dispatch_multi: no multi-cycle program "
-                "(assign pipe.multi_fn = build_packed_multicycle_fn(...))"
-            )
-        if self._spec is not None:
-            raise RuntimeError(
-                "ServingPipeline: dispatch_multi with an unresolved "
-                "speculative batch in flight — adopt_speculative() or "
-                "abandon_speculative() first"
-            )
-        if (
-            not speculative
-            and self.require_decision_fetch
-            and self._last is not None
-            and not self._last.fetched
-        ):
-            raise RuntimeError(
-                "ServingPipeline: multi-cycle batch dispatched before "
-                "the previous cycle's decisions were fetched — binds "
-                "cannot have folded before this batch was encoded "
-                "(speculative=True is the sanctioned depth-2 path)"
-            )
-        t0 = self._now()
-        slot = self._claim_slot()
-        if device_put:
-            wbufs = jax.device_put(wbufs)
-            bbufs = jax.device_put(bbufs)
-        else:
-            wbufs, bbufs = _cpu_safe_buffers(wbufs, bbufs)
-        with _dispatch_anchor(anchor, self._now):
-            if carry0 is not None:
-                result = fn(
-                    wbufs, bbufs, stable, np.int32(n_cycles), *carry0
-                )
-            else:
-                result = fn(wbufs, bbufs, stable, np.int32(n_cycles))
-        if self._multi_slim_fn is None:
-            self._multi_slim_fn = build_multicycle_slim_rows_fn(
-                result.node_requested.shape[1],
-                result.assignment.shape[0],
-            )
-        slim = self._multi_slim_fn(
-            result.assignment, result.unschedulable,
-            result.gang_dropped, result.attempted, result.cycles_run,
-        )
-        handle = MultiCycleHandle(
-            self, result, slim, wbufs, bbufs, stable,
-            n_live=n_cycles, speculative=speculative,
-        )
-        self._slots[slot] = handle
-        if speculative:
-            self._spec = handle
-        else:
-            self._last = handle
-        self._n += 1
-        t1 = self._now()
-        stats = {
-            "dispatch_ms": (t1 - t0) * 1e3,
-            "slot": slot,
-            "multi_cycles": n_cycles,
-            "t_dispatch_start": t0,
-            "t_dispatch_end": t1,
-        }
-        if self._pending_encode_ms is not None:
-            stats["encode_ms"] = self._pending_encode_ms
-            self._pending_encode_ms = None
-        if speculative:
-            # a speculative dispatch must not clobber the in-flight
-            # batch's stage report: its marks are held on the handle
-            # and installed by adopt_speculative — the predecessor's
-            # stats only note that a speculation was dispatched in its
-            # shadow
-            handle._stats_seed = stats
-            self.stats["spec_dispatch_ms"] = stats["dispatch_ms"]
-        else:
-            self.stats = stats
-        if self._metrics is not None:
-            self._metrics.cycle_duration.labels(phase="dispatch").observe(
-                t1 - t0
-            )
-        self._note_inflight()
-        if self.forced_sync and not speculative:
-            handle.block()
-            self.stats["encode_hidden_ms"] = 0.0
-        return handle
-
     def inflight(self) -> int:
         """Dispatched cycles whose decisions were not fetched yet (0 or
-        1 under the strict-ordering guard; up to 2 while a depth-2
-        speculative batch is in flight)."""
+        1 under the strict-ordering guard)."""
         return sum(
             1 for h in self._slots if h is not None and not h.fetched
         )

@@ -63,7 +63,6 @@ from .cycle import (
 from .degrade import (
     RUNG_FORCED_SYNC,
     RUNG_RETRACE,
-    RUNG_SEQUENTIAL,
     RUNG_STATELESS,
     DegradationLadder,
 )
@@ -278,10 +277,6 @@ class Scheduler:
                 metrics=self.metrics,
                 slo_p99_ms=self.config.slo_p99_ms,
                 slo_window_cycles=self.config.slo_window_cycles,
-                # speculation_thrash auto-disable horizon: the same
-                # clean-evidence window the degradation ladder promotes
-                # on (satisfies "re-enable after degradePromoteCycles")
-                spec_hold_cycles=self.config.degrade_promote_cycles,
             )
             self.observer.epoch = self.flight.epoch
             self.flight.observers.append(self.observer.observe)
@@ -388,39 +383,6 @@ class Scheduler:
         # bucket changes) reuse earlier compilations
         self._packed: dict = {}
         self._dev_stable: dict = {}
-        # multi-cycle serving (ROADMAP item 1): with multiCycleK > 1,
-        # per-cycle arrival groups coalesce in _mc_groups until K groups
-        # are buffered, an idle pop signals the arrival stream paused,
-        # or the oldest group ages past multiCycleMaxWaitMs — then ONE
-        # device dispatch runs all of them as inner cycles of a device-
-        # resident loop (core/cycle.build_packed_multicycle_fn),
-        # amortizing the dispatch round trip K-fold. _mc_fns memoizes
-        # the per-regime multi-cycle + diagnosis programs; _mc_off pins
-        # the profiles whose workload left the exactness envelope (the
-        # encoder's capability flags are sticky/grow-only, so a profile
-        # that left it never re-enters for this process's lifetime).
-        self._mc_k = max(int(self.config.multi_cycle_k), 1)
-        self._mc_wait_s = (
-            max(float(self.config.multi_cycle_max_wait_ms), 0.0) / 1e3
-        )
-        self._mc_groups: dict[str, list[tuple[float, list[Pod]]]] = {
-            n: [] for n in names
-        }
-        self._mc_fns: dict = {}
-        self._mc_off: dict[str, str] = {}
-        # profiles whose packed delta arena a batch dispatch left stale
-        # (the K stacked snapshots take plain encode(), not
-        # encode_packed(), so _delta_state still describes the
-        # pre-batch arena): the NEXT single-cycle record is stamped
-        # post_batch=1 so the observer can excuse its full re-encode
-        # from the fold_miss anomaly
-        self._mc_stale_arena: set[str] = set()
-        # admission-time incremental encode (incrementalEncode): per-
-        # profile accumulated ingest seconds since the last flush (the
-        # staging work hidden in the buffering pop's shadow) and the
-        # flush-time phase stamps awaiting inner record 0
-        self._ingest_s: dict[str, float] = {}
-        self._flush_phases: dict[str, dict] = {}
         # per profile, the encoder's (fold_fallback_pods,
         # fold_removed_pods) as of the last committed flight record
         # (_commit_record counts the differences)
@@ -434,11 +396,6 @@ class Scheduler:
             "commit_rounds": 0, "rounds_parked": 0, "round_cap_hits": 0,
             "spread_revoked": 0, "refusals": 0,
         }
-        if self.extenders:
-            # extender verdicts are consulted per HOST cycle; inner
-            # device cycles cannot re-consult a webhook, so batching is
-            # off for every profile from the start
-            self._mc_off = {n: "extender" for n in names}
         # regime-flip accounting for the observer: _packed_fns bumps the
         # build count on every memo miss and records how long the host-
         # side program (re)build took — the XLA compile itself rides the
@@ -561,12 +518,12 @@ class Scheduler:
             # extenders WITHOUT carry_verdicts disable the carry/latency
             # path: their verdicts may be stateful, so every cycle must
             # re-consult every pod and pay the full static [P,N] rebuild
-            # plus in-cycle attribution. Loud, because the deployments
-            # that reach for extenders are often the ones that also care
-            # about cycle latency (VERDICT r3 weak #6) — measured
-            # ~+60 ms device + full re-encode at 10k x 5k. Deterministic
-            # extenders can set carryVerdicts: true to keep the latency
-            # path (PERF.md 'Extenders and the carry path').
+            # plus in-cycle attribution (their cost is not measured on
+            # the chip). Loud, because the deployments that reach for
+            # extenders are often the ones that also care about cycle
+            # latency. Deterministic extenders can set carryVerdicts:
+            # true to keep the latency path (PERF.md 'Extenders and the
+            # carry path').
             logging.getLogger(__name__).warning(
                 "scheduler: %d HTTP extender(s) configured without "
                 "carryVerdicts - the device-carry latency path is "
@@ -687,17 +644,6 @@ class Scheduler:
             metrics=self.metrics,
             events=self.events,
             dispatch_deadline_s=self._dispatch_deadline_s,
-            # depth-2 speculation keeps TWO batches in flight: the
-            # third arena slot lets the next upload proceed without
-            # overwriting either (the 2-slot default assumes one).
-            # Only the multi-cycle path speculates, so single-cycle
-            # serving keeps the tighter double-buffered arena
-            slots=(
-                3
-                if self.config.speculative_dispatch
-                and self.config.multi_cycle_k > 1
-                else 2
-            ),
         )
         fns = (
             cyc,
@@ -833,10 +779,10 @@ class Scheduler:
 
     def _warm_regime(self, spec, profile: str) -> None:
         """Warm-thread body: pre-build one predicted regime's programs
-        into the `_packed` (and, under multi-cycle serving, `_mc_fns`)
-        memos and the persistent executable cache. Installs with
-        setdefault — if the serve loop flipped first and built its own
-        entry, this build is discarded (the disk entries still land)."""
+        into the `_packed` memo and the persistent executable cache.
+        Installs with setdefault — if the serve loop flipped first and
+        built its own entry, this build is discarded (the disk entries
+        still land)."""
         key = (spec.key(), profile)
         with self._packed_lock:
             if key in self._packed:
@@ -850,18 +796,6 @@ class Scheduler:
                 # +1: a fresh speculative entry must not evict a live
                 # regime the moment it lands, nor be evicted itself
                 self._packed.pop(next(iter(self._packed)))
-        if self._mc_k > 1 and profile not in self._mc_off:
-            with self._packed_lock:
-                if key in self._mc_fns:
-                    return
-            m_entry = self._build_mc_entry(spec, profile, aot=True)
-            m_entry["source"] = "speculative"
-            m_entry["fresh"] = True
-            with self._packed_lock:
-                self._mc_fns.setdefault(key, m_entry)
-                while len(self._mc_fns) > 4 * len(self.frameworks) + 1:
-                    self._mc_fns.pop(next(iter(self._mc_fns)))
-
 
     def _stable_state(self, spec, stable_fn, wbuf, bbuf, encoder=None):
         """Device-resident stable-side precomputes, rerun only when the
@@ -1101,46 +1035,13 @@ class Scheduler:
                 )
         self.queue.flush_unschedulable_timeout()
 
-        # multi-cycle batching is gated on the degradation ladder: at or
-        # below the `sequential` rung every cycle dispatches alone
-        mc_on = self._mc_k > 1 and self.ladder.rung < RUNG_SEQUENTIAL
-        if self._mc_k > 1 and not mc_on:
-            # the ladder stepped below `sequential` with groups still
-            # coalescing: drain them as single-cycle dispatches BEFORE
-            # this cycle's non-hold pop replaces the in-flight set (a
-            # stranded buffer's pods would be neither queued nor
-            # in-flight — lost)
-            for name in self._profile_order:
-                buf = self._mc_groups[name]
-                if not buf:
-                    continue
-                self._mc_groups[name] = []
-                for _t_enq, g in buf:
-                    stats.attempted += len(g)
-                    self._schedule_profile(name, g, stats, t0)
-                self.queue.retire_in_flight(
-                    [p.uid for _t_enq, g in buf for p in g]
-                )
-        mc_buffered = mc_on and any(
-            self._mc_groups[n] for n in self._profile_order
-        )
-        # hold-pop while groups are buffered: their in-flight entries
-        # (attempts counts, delete tombstones, crash recovery) must
-        # survive until the batch flush applies their outcomes
-        pending_all = self.queue.pop_ready(hold=mc_buffered)
+        pending_all = self.queue.pop_ready()
         if _spans.ARMED and not self._pod_spans:
             # a context is registered before its pod is queued: asked
             # again after the pop, none of this cycle's pods is missed
             self._pod_spans = _spans.any_context()
-        # attempted > 0 means the rung-gated drain above dispatched —
-        # that work must flow through the full cycle epilogue
-        nothing_to_do = (
-            not pending_all and not mc_buffered and stats.attempted == 0
-        )
         if pending_all:
-            # += not =: the rung-gated buffer drain above may already
-            # have counted its groups into this cycle's attempted
-            stats.attempted += len(pending_all)
+            stats.attempted = len(pending_all)
             self.metrics.cycle_pods.observe(len(pending_all))
 
         by_prof: dict[str, list[Pod]] = {
@@ -1168,13 +1069,12 @@ class Scheduler:
             lst.append(pod)
 
         if trace is not None:
-            # ends where the first profile's flight record starts (a
-            # rung-gated drain above, rare, ran its records inside it)
+            # ends where the first profile's flight record starts
             _spans.record_span(
                 "cycle.pop", trace, t_entry, _spans.now(),
                 pods=len(pending_all),
             )
-        if nothing_to_do:
+        if not pending_all:
             # gauges must track deletions/moves that happen between
             # non-empty cycles, so update them on the empty path too
             self._update_gauges()
@@ -1182,108 +1082,14 @@ class Scheduler:
             return stats
         for name in self._profile_order:
             group = by_prof[name]
-            if mc_on and name not in self._mc_off:
-                # multi-cycle coalescing: buffer this pop's arrival group
-                # and flush K of them as ONE device dispatch. Flush when
-                # the batch is full, the arrival stream paused (an empty
-                # pop — holding a ready group while nothing else is
-                # coming would be pure added latency), or the oldest
-                # group aged past the latency bound.
-                buf = self._mc_groups[name]
-                if group:
-                    buf.append((t0, group))
-                    if self.config.incremental_encode:
-                        # admission-time incremental encode: parse each
-                        # newly buffered pod's arena row NOW, in the
-                        # buffering pop's shadow — the first serve-
-                        # thread moment after the front door acked it
-                        # (the encoder is serve-thread-owned, so the
-                        # ack path proper never touches it). The flush
-                        # then finalizes with an O(dirty) apply.
-                        self._ingest_group(name, group)
-                if not buf:
-                    continue
-                if (
-                    len(buf) >= self._mc_k
-                    or not group
-                    or (t0 - buf[0][0]) >= self._mc_wait_s
-                ):
-                    self._mc_groups[name] = []
-                    if self._pod_spans:
-                        # mc.buffer_wait: admission-group enqueue ->
-                        # this flush, one span per sampled pod. The
-                        # wait is a scheduler-clock delta (t0/t_enq
-                        # may ride an injected test clock); the span
-                        # anchors its END at the recorder clock so it
-                        # abuts the dispatch span that follows.
-                        t_flush = _spans.now()
-                        for t_enq, g in buf:
-                            wait_s = max(t0 - t_enq, 0.0)
-                            for p in g:
-                                c = _spans.ctx_for(p.uid)
-                                if c is not None:
-                                    _spans.record_span(
-                                        "mc.buffer_wait", c,
-                                        t_flush - wait_s, t_flush,
-                                        uid=p.uid, groups=len(buf),
-                                    )
-                    # a pod is "attempted" in the cycle whose dispatch
-                    # carries it: groups popped by EARLIER buffering
-                    # cycles count NOW (their buffering cycle
-                    # subtracted them below), so per-cycle stats keep
-                    # scheduled <= attempted and a cross-cycle
-                    # sum(scheduled)/sum(attempted) rate stays honest
-                    # (this cycle's own group is already counted via
-                    # pending_all)
-                    stats.attempted += (
-                        sum(len(g) for _t, g in buf) - len(group)
-                    )
-                    if len(buf) == 1:
-                        # a lone group gains nothing from the stacked
-                        # path — keep it on the delta/carry-optimized
-                        # single-cycle encode
-                        self._schedule_profile(
-                            name, buf[0][1], stats, t0
-                        )
-                    else:
-                        self._schedule_profile_multi(
-                            name, buf, stats, t0
-                        )
-                    # outcomes applied: drop the batch's pods from the
-                    # in-flight set. Hold pops only ACCUMULATE, and
-                    # out-of-phase profile buffers can keep every pop
-                    # holding — without this, bound pods stay
-                    # "recoverable" forever (unbounded growth + a
-                    # takeover re-binding pods bound long ago)
-                    self.queue.retire_in_flight(
-                        [p.uid for _t_enq, g in buf for p in g]
-                    )
-                    if self.config.incremental_encode:
-                        # staged rows the flush did not consume (shed /
-                        # dropped pods) must not outlive their batch
-                        self._encoders[name].clear_ingest()
-                        self._ingest_s.pop(name, None)
-                else:
-                    # buffered, not dispatched: attempted at the flush
-                    stats.attempted -= len(group)
-            elif group:
+            if group:
                 self._schedule_profile(name, group, stats, t0)
-                if self._mc_k > 1:
-                    # this profile is pinned out of batching but other
-                    # profiles' buffers may be holding every pop — its
-                    # outcomes are applied, so retire explicitly too
-                    # (K=1 serving skips this: the non-hold pop's
-                    # wholesale replacement retires, and the journal
-                    # stream stays byte-identical to the seed's)
-                    self.queue.retire_in_flight(
-                        [p.uid for p in group]
-                    )
 
         stats.cycle_seconds = self._now() - t0
         self.metrics.cycle_duration.labels(phase="total").observe(
             stats.cycle_seconds
         )
-        if stats.attempted > 0 and not self._cycle_fault:
+        if not self._cycle_fault:
             # promotion bookkeeping: only cycles that actually exercised
             # the dispatch path count as evidence the fault cleared
             self.ladder.note_clean_cycle(seq=self._cycle_counter)
@@ -1501,8 +1307,7 @@ class Scheduler:
         # the pod's half of its snapshot fragment, which does not wait
         # for the node. One hold of the queue's lock for the list, none
         # across the wait, no store touched; None with no journal
-        # attached. The multi-cycle paths and `Submit`'s prepare none
-        # and serialise in the loop, as a winner without a row does here
+        # attached. A winner without a row is serialised in the loop
         rows = self.cache.prepare_rows(
             self.queue.in_flight_states(pending)
         )
@@ -1583,30 +1388,11 @@ class Scheduler:
             fold_ms = encoder.delta_profile.get("fold")
             if fold_ms:
                 extra_phases["fold_ms"] = float(fold_ms)
-            if self.config.incremental_encode:
-                # a lone buffered group flushed through the single-
-                # cycle path with staged ingest rows: its encode WAS
-                # the finalize, so the ingest/finalize split lands
-                # here too (the mc flush stamps via _flush_phases)
-                ing_s = self._ingest_s.pop(profile, 0.0)
-                if ing_s > 0.0:
-                    fin_s = max(t_encode - t_start, 0.0)
-                    extra_phases["encode_ingest_ms"] = ing_s * 1e3
-                    extra_phases["encode_finalize_ms"] = fin_s * 1e3
-                    self.metrics.encode_finalize.observe(fin_s)
             if self._packed_builds > builds_before:
                 extra_phases["compile_ms"] = self._last_build_s * 1e3
                 extra_counts["regime_flip"] = 1
                 # cold | cache | speculative — how the flip was paid
                 compile_source = self._last_compile_source
-            if profile in self._mc_stale_arena:
-                # first single-cycle dispatch after a batch: a full
-                # re-encode here is the batch's fault (its plain
-                # encodes left _delta_state stale), not a fold miss —
-                # cleared now because this encode_packed reinstalled
-                # the arena, so later full encodes are unexplained
-                self._mc_stale_arena.discard(profile)
-                extra_counts["post_batch"] = 1
             self._commit_record(
                 rec, st, spec, encoder, pending, nodes, stats,
                 _before, profile_gang_dropped,
@@ -1619,1147 +1405,6 @@ class Scheduler:
         # speculative precompilation: after the cycle's work is fully
         # committed, check whether demand is drifting toward a pad
         # boundary and pre-build the adjacent regime off-thread
-        self._maybe_speculate(profile, spec)
-
-    def _mc_programs(self, spec, profile: str):
-        """Memoized multi-cycle program pair for one packed regime:
-        (multicycle_fn, diagnosis_fn). Counted into `_packed_builds`
-        like every other program build so the observer's recompile
-        anomaly attributes the one-time compile cost of a new regime's
-        batch program. True LRU: a hit moves the entry to the end, so
-        eviction drops the coldest regime — the seed's FIFO pop could
-        evict the hottest multi-cycle regime while a cold one stayed
-        (regression-tested in tests/test_compile_cache.py)."""
-        key = (spec.key(), profile)
-        with self._packed_lock:
-            entry = self._mc_fns.get(key)
-            if entry is not None:
-                self._mc_fns.pop(key)
-                self._mc_fns[key] = entry  # move-to-end on hit
-                if entry.pop("fresh", None):
-                    self._packed_builds += 1
-                    self._last_build_s = 0.0
-                    self._last_compile_source = "speculative"
-                return entry["fns"]
-        entry = self._build_mc_entry(
-            spec, profile,
-            aot=self._compile_cache is not None and not self.extenders,
-        )
-        with self._packed_lock:
-            cur = self._mc_fns.setdefault(key, entry)
-            self._mc_fns.pop(key)
-            self._mc_fns[key] = cur
-            cur.pop("fresh", None)
-            self._packed_builds += 1
-            self._last_build_s = entry["build_s"]
-            self._last_compile_source = entry["source"]
-            while len(self._mc_fns) > 4 * len(self.frameworks):
-                self._mc_fns.pop(next(iter(self._mc_fns)))
-        return cur["fns"]
-
-    def _build_mc_entry(self, spec, profile: str, aot: bool) -> dict:
-        """Construct one regime's multi-cycle program pair (the
-        `_mc_fns` memo entry); warm-thread safe like
-        _build_packed_entry."""
-        from .cycle import (
-            build_diagnosis_fn,
-            build_packed_multicycle_fn,
-        )
-
-        t_build = _time.perf_counter()  # wall, like _build_packed_entry
-        fw = self.frameworks[profile]
-        mfn = build_packed_multicycle_fn(
-            spec, framework=fw, k=self._mc_k, **self._cycle_kw
-        )
-        # the multi-cycle decisions are lean (no fused reject
-        # counts), so every regime needs the separate diagnosis
-        # program — including scan-mode regimes whose single-cycle
-        # path runs the fused full program and has none
-        mdiag = build_diagnosis_fn(spec, fw)
-        # depth-2 speculation chains batch k+1 onto batch k's
-        # device-resident carry through the carry_in continuation
-        # variant; only built when the config can ever dispatch one
-        mcont = None
-        if self.config.speculative_dispatch:
-            mcont = build_packed_multicycle_fn(
-                spec, framework=fw, k=self._mc_k, carry_in=True,
-                **self._cycle_kw,
-            )
-        source = "cold"
-        if aot:
-            src = self._aot_install_multi(
-                spec, profile, mfn=mfn, mdiag=mdiag, mcont=mcont
-            )
-            if src is not None:
-                source = src
-        return {
-            "fns": (mfn, mdiag, mcont),
-            "build_s": _time.perf_counter() - t_build,
-            "source": source,
-        }
-
-    def _aot_install_multi(
-        self, spec, profile: str, *, mfn, mdiag, mcont=None
-    ) -> "str | None":
-        """AOT layer for the multi-cycle programs: the stacked [K, ...]
-        batch loop (kind `multicycle-K` — K is static in the program),
-        its per-row diagnosis companion (same key as the single-cycle
-        diag when the conventions match, so the disk entry is shared),
-        and — under speculativeDispatch — the carry-in continuation
-        variant (kind `multicycle-cont-K`; two extra carry arguments,
-        so it can never alias the plain entry)."""
-        import jax
-
-        from . import compile_cache as cc
-        from .cycle import build_stable_state_fn
-
-        w1 = jax.ShapeDtypeStruct((spec.n_words,), np.uint32)
-        b1 = jax.ShapeDtypeStruct((spec.n_bytes,), np.uint8)
-        wk = jax.ShapeDtypeStruct(
-            (self._mc_k, spec.n_words), np.uint32
-        )
-        bk = jax.ShapeDtypeStruct((self._mc_k, spec.n_bytes), np.uint8)
-        try:
-            stable_sds = jax.eval_shape(
-                build_stable_state_fn(spec), w1, b1
-            )
-        except Exception as e:
-            logging.getLogger(__name__).warning(
-                "multi-cycle AOT install skipped: stable-state avals "
-                "unavailable (%s); the jit path remains", e,
-            )
-            return None
-        n_sds = jax.ShapeDtypeStruct((), np.int32)
-        sources: list[str] = []
-        compiled, source, _dt, out_sds = cc.load_or_compile(
-            mfn, self._compile_cache, spec, profile,
-            f"multicycle-{self._mc_k}",
-            args=(wk, bk, stable_sds, n_sds),
-        )
-        if compiled is not None:
-            mfn.install_aot(compiled)
-            sources.append(source)
-        if mcont is not None and out_sds is not None:
-            # continuation avals: the same stacked inputs plus the
-            # predecessor's final carry (shapes straight off out_sds)
-            nr0 = jax.ShapeDtypeStruct(
-                tuple(out_sds.carry_node_requested.shape), np.float32
-            )
-            gp0 = jax.ShapeDtypeStruct(
-                tuple(out_sds.carry_gplaced.shape), np.int32
-            )
-            compiled_c, source_c, _dt, _out_c = cc.load_or_compile(
-                mcont, self._compile_cache, spec, profile,
-                f"multicycle-cont-{self._mc_k}",
-                args=(wk, bk, stable_sds, n_sds, nr0, gp0),
-            )
-            if compiled_c is not None:
-                mcont.install_aot(compiled_c)
-                sources.append(source_c)
-        if out_sds is not None:
-            a_row = jax.ShapeDtypeStruct(
-                tuple(out_sds.assignment.shape[1:]), np.int32
-            )
-            nr_row = jax.ShapeDtypeStruct(
-                tuple(out_sds.node_requested.shape[1:]), np.float32
-            )
-            compiled_d, source_d, _dt, _out = cc.load_or_compile(
-                mdiag, self._compile_cache, spec, profile, "diag",
-                args=(w1, b1, stable_sds, a_row, nr_row),
-            )
-            if compiled_d is not None:
-                mdiag.install_aot(compiled_d)
-                sources.append(source_d)
-        if not sources:
-            return None
-        return "cache" if all(s == "cache" for s in sources) else "cold"
-
-    def _ingest_group(self, profile: str, group: "list[Pod]") -> None:
-        """Stage each newly buffered pod's arena row (incrementalEncode,
-        models/encoding.SnapshotEncoder.ingest_pod) so the flush's per-
-        group delta encode skips the parse. The staging seconds
-        accumulate per profile for the flush record's encode_ingest_ms
-        phase — the host encode cost hidden from the dispatch path."""
-        enc = self._encoders[profile]
-        t_ing = self._now()
-        for p in group:
-            enc.ingest_pod(p)
-        ing_s = max(self._now() - t_ing, 0.0)
-        self._ingest_s[profile] = self._ingest_s.get(profile, 0.0) + ing_s
-        self.metrics.encode_ingest.observe(ing_s)
-        if self._pod_spans:
-            # encode.ingest: this group's admission-time row staging
-            # (scheduler-clock duration anchored at the recorder clock,
-            # same discipline as mc.buffer_wait)
-            t1 = _spans.now()
-            for p in group:
-                c = _spans.ctx_for(p.uid)
-                if c is not None:
-                    _spans.record_span(
-                        "encode.ingest", c, t1 - ing_s, t1,
-                        uid=p.uid, pods=len(group),
-                    )
-
-    def _schedule_profile_multi(
-        self,
-        profile: str,
-        groups: "list[tuple[float, list[Pod]]]",
-        stats: CycleStats,
-        t0: float,
-    ) -> None:
-        """Dispatch the buffered arrival groups as a multi-cycle
-        device batch (core/cycle.build_packed_multicycle_fn): group i
-        becomes inner cycle i of a device-resident loop, paying one
-        dispatch round trip for up to K scheduling cycles. Under
-        `speculativeDispatch` the flush splits depth-2 — row 0
-        dispatches alone and the rest ride its dispatch shadow as a
-        speculative continuation batch (_schedule_profile_multi_spec);
-        either way the decision rows stream back per inner cycle
-        (_apply_mc_rows) instead of blocking on the stacked fetch.
-
-        Semantics contract: each inner cycle's decisions are applied
-        through `_apply_phase` in batch order — binds, journal records,
-        events, and pod timelines land per cycle exactly as K sequential
-        dispatches would, so durability does not change across the
-        batch boundary. The device loop threads the post-cycle capacity
-        + gang-count carry the host fold would have produced; workloads
-        whose snapshots leave the exactness envelope
-        (`multicycle_unsupported_reason`) fall back to sequential
-        single-cycle dispatches — sticky capability reasons (affinity /
-        topology spread / volumes, grow-only encoder flags) pin the
-        profile out of batching for the process lifetime, while
-        host_ports is per-snapshot: a later port-free batch re-enters
-        the device loop."""
-        fr = self.flight
-        nodes = self.cache.nodes()
-        existing = self.cache.existing_pods()
-        kw = dict(
-            pod_groups=list(self._groups.values()),
-            pvcs=list(self._pvcs.values()),
-            pvs=list(self._pvs.values()),
-            storage_classes=list(self._storage_classes.values()),
-            pdbs=list(self._pdbs.values()),
-        )
-        from ..models import packing
-        from .cycle import multicycle_unsupported_reason
-
-        # one spec for every row: pad to the LARGEST group so all K
-        # packed snapshots stack into [K, W]/[K, B]; down-steps damped
-        # by the same hysteresis as the single-cycle path
-        mc_pods = max(len(g) for _, g in groups)
-        encoder = self._encoders[profile]
-        encoder.pad_pods = encoder.hysteresis_pad(
-            "P", _pad(mc_pods, self._pad_bucket), mc_pods
-        )
-        encoder.pad_nodes = encoder.hysteresis_pad(
-            "N", _pad(len(nodes), self._pad_bucket), len(nodes)
-        )
-        builds_before = self._packed_builds
-        t_batch = self._now()
-        t_batch_rec = fr.now() if fr is not None else 0.0
-        inc = self.config.incremental_encode
-        if not inc:
-            # the stacked snapshots below take plain encode() — the
-            # packed delta arena is bypassed and its _delta_state goes
-            # stale, so the next single-cycle encode_packed may
-            # legitimately fall back to a full encode (set even when
-            # the envelope precheck falls back: the plain encodes have
-            # run either way). Under incrementalEncode every group
-            # folds through encode_packed, so the arena stays fresh.
-            self._mc_stale_arena.add(profile)
-
-        # depth-2 speculative dispatch pipelining (speculativeDispatch):
-        # row 0 dispatches alone and the remaining rows ride its
-        # dispatch shadow as a speculative continuation batch — first
-        # bind lands after ~1 inner cycle instead of K. Forced off
-        # under forcedSync, at/below the ladder's `sequential` rung,
-        # and while the sentinel's speculation_thrash hold is active.
-        if (
-            self.config.speculative_dispatch
-            and len(groups) >= 2
-            and not self.forced_sync
-            and self.ladder.rung < RUNG_SEQUENTIAL
-            and (
-                self.observer is None
-                or self.observer.speculation_ok(profile)
-            )
-        ):
-            self._schedule_profile_multi_spec(
-                profile, groups, stats, t0, t_batch, t_batch_rec,
-                builds_before, nodes, existing, kw,
-            )
-            return
-
-        if inc:
-            rows, spec, reason = self._encode_groups_packed(
-                profile, encoder, groups, nodes, existing, kw
-            )
-            if rows is None:
-                self._mc_fall_back(profile, groups, stats, t0, reason)
-                return
-            snaps = None
-        else:
-            snaps = []
-            lens0 = None
-            ci0 = encoder._cycle_index
-            for _t_enq, g in groups:
-                snaps.append(encoder.encode(nodes, g, existing, **kw))
-                if lens0 is None:
-                    # row 0's tables are the whole batch's stable side
-                    # (_stable_state below reads wbufs[0]/bbufs[0]), so
-                    # the growth watermark starts AFTER its encode —
-                    # anything a later group interns past this point is
-                    # invisible to the tables every inner cycle reads
-                    lens0 = encoder._table_lens()
-                reason = multicycle_unsupported_reason(snaps[-1])
-                if reason is not None:
-                    self._mc_fall_back(
-                        profile, groups, stats, t0, reason
-                    )
-                    return
-            specs = [packing.make_spec(s) for s in snaps]
-            if (
-                encoder._table_lens() != lens0
-                or any(sp.key() != specs[0].key() for sp in specs[1:])
-            ):
-                # a later group grew an interning structure — either
-                # past row 0's padded regime (spec keys diverge) or
-                # WITHIN the padding (keys still match, but row 0's
-                # stable tables lack the new entries and a later row's
-                # reference to them would dangle): re-encode the whole
-                # batch once against the now-grown (grow-only) tables
-                # so every row shares the final spec AND row 0 carries
-                # the full tables. The retry is a host-side do-over of
-                # the SAME logical cycles: rewind the sampling rotation
-                # so each group re-stamps the cycle_index its first
-                # encode used (otherwise the retry would skew the
-                # rotation vs a batch that needed only one pass)
-                encoder._cycle_index = ci0
-                snaps = [
-                    encoder.encode(nodes, g, existing, **kw)
-                    for _t_enq, g in groups
-                ]
-                specs = [packing.make_spec(s) for s in snaps]
-                if any(sp.key() != specs[0].key() for sp in specs[1:]):
-                    # cannot happen with grow-only tables; refuse to
-                    # guess
-                    self._mc_fall_back(profile, groups, stats, t0, None)
-                    return
-            spec = specs[0]
-        (
-            _pcycle, ppreempt, stable_fn, _keeper, _diag, _ek, pipe,
-        ) = self._packed_fns(spec, profile)
-        mfn, mdiag, mcont = self._mc_programs(spec, profile)
-        pipe.multi_fn = mfn
-        pipe.multi_diag_fn = mdiag
-        pipe.multi_cont_fn = mcont
-
-        n = len(groups)
-        if inc:
-            wbufs, bbufs = self._pack_stack_rows(rows, spec)
-        else:
-            wbufs, bbufs = self._pack_stack(snaps, spec)
-        batch_pods = [p for _t_enq, g in groups for p in g]
-        try:
-            stable = self._stable_state(
-                spec, stable_fn, wbufs[0], bbufs[0], encoder
-            )
-        except Exception as e:
-            self._cycle_failed(profile, batch_pods, e, stats, t0, None)
-            return
-        t_encode = self._now()
-        self.metrics.cycle_duration.labels(phase="encode").observe(
-            t_encode - t_batch
-        )
-        if inc:
-            self._stamp_finalize(
-                profile, t_encode - t_batch, pods=batch_pods
-            )
-        pipe.forced_sync = (
-            self.forced_sync or self.ladder.rung >= RUNG_FORCED_SYNC
-        )
-        pipe.dispatch_deadline_s = self._dispatch_deadline_s
-        pipe.note_encode(t_encode - t_batch)
-        # a failed batch dispatch consumes the WHOLE batch before any
-        # bind: every group's pods requeue (the caller's
-        # retire_in_flight after this return drops only pods the
-        # requeue did not re-track)
-        try:
-            handle = pipe.dispatch_multi(
-                wbufs, bbufs, stable, n, device_put=False,
-                anchor=self._anchor(),
-            )
-        except Exception as e:
-            self._cycle_failed(profile, batch_pods, e, stats, t0, None)
-            return
-        self.metrics.multicycle_batch.observe(n)
-        applied, exc = self._apply_mc_rows(
-            profile, handle, groups, spec, encoder, stats, t0, t_batch,
-            t_batch_rec, nodes, existing, ppreempt, builds_before,
-            batch_n=n, stamp_first_bind=True, stamp_compile=True,
-        )
-        self.metrics.multicycle_cycles.inc(applied)
-        if exc is not None:
-            # a mid-stream fetch failure: groups already applied are
-            # bound and folded (exactly as sequential dispatches would
-            # be); only the unapplied tail requeues through the ladder
-            rest = [p for _t_enq, g in groups[applied:] for p in g]
-            self._cycle_failed(profile, rest, exc, stats, t0, None)
-            return
-        self._maybe_speculate(profile, spec)
-
-    def _pack_stack(self, snaps, spec):
-        """Stack packed snapshot rows into the [K, W]/[K, B] multi-
-        cycle arenas (zero-padded past the real rows) and device_put
-        them unless K8S_TPU_NO_DEVICE_PUT=1 — the one upload
-        convention every multi-cycle dispatch shape (combined batch,
-        depth-2 row 0, speculative continuation) shares."""
-        import os as _os
-
-        from ..models import packing
-
-        wbufs = np.zeros((self._mc_k, spec.n_words), np.uint32)
-        bbufs = np.zeros((self._mc_k, spec.n_bytes), np.uint8)
-        for i, s in enumerate(snaps):
-            wbufs[i], bbufs[i] = packing.pack(s, spec)
-        if _os.environ.get("K8S_TPU_NO_DEVICE_PUT") != "1":
-            import jax as _jax
-
-            wbufs = _jax.device_put(wbufs)
-            bbufs = _jax.device_put(bbufs)
-        return wbufs, bbufs
-
-    def _encode_groups_packed(
-        self, profile, encoder, groups, nodes, existing, kw
-    ):
-        """Encode a flush's groups through the packed delta arena
-        (incrementalEncode): each group folds via encode_packed —
-        staged ingest rows make it an O(dirty) apply — and its
-        wbuf/bbuf row is copied out immediately, before the next
-        group's encode rewrites the arena in place. When a later group
-        grows an interning dimension, the growing group full-encodes
-        against the grown tables and ONE delta re-encode pass re-rows
-        the earlier groups (delta_hits, not a second round of full
-        encodes — ingest already grew the tables before the flush, so
-        the whole-batch double re-encode disappears). Returns
-        (rows, spec, None) on success, (None, None, reason|None) to
-        fall back sequential."""
-        from .cycle import multicycle_unsupported_reason
-
-        mut = frozenset(self._nominated_mut[profile])
-
-        def one_pass():
-            rows, specs = [], []
-            lens0 = None
-            for _t_enq, g in groups:
-                f = encoder.encode_packed(
-                    nodes, g, existing, mutated_ids=mut, **kw
-                )
-                if lens0 is None:
-                    # growth watermark starts after row 0's encode:
-                    # its tables are the batch's stable side, so later
-                    # interning (even within the padded regime — spec
-                    # keys unchanged) leaves dangling row references
-                    lens0 = encoder._table_lens()
-                reason = multicycle_unsupported_reason(f.snap)
-                if reason is not None:
-                    return None, None, reason, lens0
-                rows.append((f.wbuf.copy(), f.bbuf.copy()))
-                specs.append(f.spec)
-            return rows, specs, None, lens0
-
-        ci0 = encoder._cycle_index
-        rows, specs, reason, lens0 = one_pass()
-        if rows is None:
-            return None, None, reason
-        if (
-            encoder._table_lens() != lens0
-            or any(sp.key() != specs[0].key() for sp in specs[1:])
-        ):
-            # host-side do-over of the same logical cycles: rewind the
-            # sampling rotation so the retry stamps the same
-            # cycle_index values as the first pass
-            encoder._cycle_index = ci0
-            rows, specs, reason, lens0 = one_pass()
-            if rows is None:
-                return None, None, reason
-            if (
-                encoder._table_lens() != lens0
-                or any(sp.key() != specs[0].key() for sp in specs[1:])
-            ):
-                # cannot happen with grow-only tables; refuse to guess
-                return None, None, None
-        self._nominated_mut[profile].clear()
-        return rows, specs[0], None
-
-    def _pack_stack_rows(self, rows, spec):
-        """_pack_stack for already-packed arena rows (the
-        incrementalEncode flush path): stack the copied wbuf/bbuf rows
-        into the [K, W]/[K, B] multi-cycle arenas and device_put them
-        under the same convention."""
-        import os as _os
-
-        wbufs = np.zeros((self._mc_k, spec.n_words), np.uint32)
-        bbufs = np.zeros((self._mc_k, spec.n_bytes), np.uint8)
-        for i, (wr, br) in enumerate(rows):
-            wbufs[i] = wr
-            bbufs[i] = br
-        if _os.environ.get("K8S_TPU_NO_DEVICE_PUT") != "1":
-            import jax as _jax
-
-            wbufs = _jax.device_put(wbufs)
-            bbufs = _jax.device_put(bbufs)
-        return wbufs, bbufs
-
-    def _stamp_finalize(
-        self, profile: str, fin_s: float, pods=(),
-    ) -> None:
-        """Observe the flush's finalize window (encode_finalize
-        histogram) and park the ingest/finalize phase stamps for the
-        batch's inner record 0 (_apply_mc_row picks them up)."""
-        fin_s = max(fin_s, 0.0)
-        ing_s = self._ingest_s.pop(profile, 0.0)
-        self.metrics.encode_finalize.observe(fin_s)
-        self._flush_phases[profile] = {
-            "encode_finalize_ms": fin_s * 1e3,
-            "encode_ingest_ms": ing_s * 1e3,
-        }
-        if self._pod_spans and pods:
-            # flush.finalize: the O(dirty) flush apply this batch paid
-            # (scheduler-clock duration, recorder-clock anchor)
-            t1 = _spans.now()
-            for p in pods:
-                c = _spans.ctx_for(p.uid)
-                if c is not None:
-                    _spans.record_span(
-                        "flush.finalize", c, t1 - fin_s, t1,
-                        uid=p.uid,
-                    )
-
-    def _mc_fall_back(
-        self, profile: str, groups, stats: CycleStats, t0: float,
-        reason: "str | None",
-    ) -> None:
-        """Dispatch `groups` as sequential single-cycle dispatches
-        because the batch left the multi-cycle exactness envelope
-        (`reason`), pinning sticky capability reasons out of batching
-        for the process lifetime (host_ports stays per-snapshot)."""
-        log = logging.getLogger(__name__)
-        if reason == "host_ports":
-            # per-SNAPSHOT reason, not a sticky capability: only a
-            # PENDING pod that requests a port leaves the envelope
-            # (cycle.multicycle_unsupported_reason), so a later
-            # port-free batch is exact again — fall back for THIS
-            # batch without pinning the profile
-            log.info(
-                "multi-cycle batch for profile %r fell back to "
-                "sequential dispatches: pending set carries host "
-                "ports (batching resumes on port-free batches)",
-                profile,
-            )
-        elif reason is not None and profile not in self._mc_off:
-            # sticky encoder capability flags (affinity / topology
-            # spread / volumes / extender) are grow-only: once a
-            # profile's workload shows them, it never re-enters
-            self._mc_off[profile] = reason
-            log.warning(
-                "multi-cycle serving disabled for profile %r: "
-                "workload left the exactness envelope (%s); "
-                "falling back to sequential single-cycle "
-                "dispatches", profile, reason,
-            )
-        for _t_enq, g in groups:
-            self._schedule_profile(profile, g, stats, t0)
-
-    @staticmethod
-    def _fold_digest(
-        scheduled: int, unschedulable: int, bind_errors: int,
-        victims: int,
-    ) -> tuple:
-        """Digest of one host fold's observable cache effects — the
-        part of the post-fold state a speculative continuation batch
-        conditioned on. The speculation's PREDICATE is this digest
-        computed from the predecessor's device decisions (every winner
-        binds, nothing else changes: zero bind errors, zero
-        evictions); the fold's ACTUAL digest is computed from what the
-        apply loop really did. Equal digests mean the cache mutated
-        exactly as the speculative encode+carry assumed, so adoption
-        is bit-identical to a sequential re-dispatch; anything else
-        (a bind error, a host-plugin veto, a preemption eviction)
-        abandons. A named tuple of the four counts, not a hash: on an
-        abandon the log must say WHICH count diverged — that is the
-        datum an operator debugging speculation_thrash needs."""
-        return (
-            ("scheduled", scheduled),
-            ("unschedulable", unschedulable),
-            ("bind_errors", bind_errors),
-            ("victims", victims),
-        )
-
-    def _apply_mc_rows(
-        self,
-        profile: str,
-        handle,
-        group_slice,
-        spec,
-        encoder,
-        stats: CycleStats,
-        t0: float,
-        t_batch: float,
-        t_batch_rec: float,
-        nodes,
-        existing,
-        ppreempt,
-        builds_before: int,
-        batch_n: int,
-        stamp_first_bind: bool = False,
-        stamp_compile: bool = False,
-        resolve_after_first=None,
-    ) -> "tuple[int, BaseException | None]":
-        """STREAMED apply of one dispatched multi-cycle batch: fetch
-        decision row i (`MultiCycleHandle.decisions_row`), apply group
-        i through `_apply_phase`, commit its flight record — so inner
-        cycle i's winners bind while rows i+1… (and, under depth-2
-        speculation, the NEXT batch) are still on device, instead of
-        blocking on the whole stacked fetch.
-
-        `group_slice` is this handle's `[(t_enq, pods), …]` in row
-        order. `resolve_after_first(a_row, before)` — the speculation
-        predicate hook — runs after group 0's apply and returns the
-        speculation tag for its record. Returns `(applied, exc)`:
-        `applied` groups were fully applied; `exc` is the fetch
-        failure that stopped the walk (None when every row landed —
-        the caller requeues the unapplied tail). Rows the device loop
-        never executed (early exit on a non-empty group: a driver
-        bug) requeue loudly here with `MultiCycleUnran`."""
-        fr = self.flight
-        log = logging.getLogger(__name__)
-        framework = self.frameworks[profile]
-        pipe = handle._pipe
-        st: dict = {}
-        device_win_s = 0.0
-        total_attempted = sum(len(g) for _t, g in group_slice) or 1
-        applied = 0
-        exc: "BaseException | None" = None
-        for gi, (t_enq, pending) in enumerate(group_slice):
-            try:
-                a_full, _u_full, gd_full, att_full = (
-                    handle.decisions_row(gi)
-                )
-            except Exception as e:  # schedlint: disable=RB001 -- not swallowed: decisions_row already attributed it (note_fetch_failure: metric + events ring) and the caller routes it through _cycle_failed's ladder step + requeue
-                exc = e
-                break
-            if gi == 0:
-                # the dispatch's stage report as of its first landed
-                # row: batch-wide marks (encode/dispatch/decision
-                # fetch) come from here and land only on record 0
-                st = pipe.stage_report()
-                device_win_s = max(
-                    st.get("t_decision_end", 0.0)
-                    - st.get("t_dispatch_end", 0.0),
-                    0.0,
-                )
-                self.metrics.cycle_duration.labels(
-                    phase="device"
-                ).observe(device_win_s)
-            if pending and not att_full[: len(pending)].any():
-                # drain early-exit cannot fire on non-empty groups, so
-                # an unran row is a driver bug: stop and requeue below
-                break
-            rec = fr.start(profile) if fr is not None else None
-            _before = (
-                stats.scheduled, stats.unschedulable, stats.bind_errors,
-                stats.preemptors, stats.victims,
-            )
-            if rec is not None:
-                # the record's window opens at the batch flush, not at
-                # this inner cycle's apply: its `total` is the latency
-                # the inner cycle's pods actually experienced
-                rec.t_start = t_batch_rec
-                rec.mark("encode_start", t_batch_rec)
-            try:
-                self._apply_mc_row(
-                    profile, handle, gi, pending, a_full, gd_full,
-                    spec, encoder, stats, t0, t_batch, t_batch_rec,
-                    nodes, existing, ppreempt, builds_before, batch_n,
-                    stamp_first_bind, stamp_compile,
-                    resolve_after_first, rec, st, device_win_s,
-                    total_attempted, t_enq, _before,
-                )
-            except Exception:  # schedlint: disable=RB001 -- not swallowed: the guard-release is the recovery (old stacked-fetch parity); the error re-raises to the cycle driver with its story intact
-                # a NON-fetch failure mid-apply (a deferred diagnosis/
-                # preemption force, a host-plugin bug): the stacked
-                # fetch of the old path had already marked the handle
-                # consumed before the apply loop, so the ordering guard
-                # could never be left held — restore that property
-                # before the error reaches the cycle driver, or one
-                # apply-path exception would wedge the pipeline forever
-                handle.fetched = True
-                handle.release()
-                pipe._note_inflight()
-                raise
-            applied += 1
-        if exc is None and applied < len(group_slice):
-            log.error(
-                "multi-cycle dispatch ran %d of %d inner cycles; "
-                "requeueing the unran groups", applied,
-                len(group_slice),
-            )
-            # release the guard: the unran rows will never be fetched
-            # (a distinct event name keeps the recovery honest — these
-            # pods never reached a bind attempt; bind_errors still
-            # counts them, the closest CycleStats bucket for "cycle
-            # failed through no fault of the pod")
-            handle.fetched = True
-            handle.release()
-            pipe._note_inflight()
-            for _t_enq, g in group_slice[applied:]:
-                for pod in g:
-                    self.queue.requeue_backoff(
-                        pod, event="MultiCycleUnran"
-                    )
-                    stats.bind_errors += 1
-        return applied, exc
-
-    def _apply_mc_row(
-        self, profile, handle, gi, pending, a_full, gd_full, spec,
-        encoder, stats, t0, t_batch, t_batch_rec, nodes, existing,
-        ppreempt, builds_before, batch_n, stamp_first_bind,
-        stamp_compile, resolve_after_first, rec, st, device_win_s,
-        total_attempted, t_enq, before,
-    ) -> None:
-        """One inner cycle's apply + record commit (the body of
-        _apply_mc_rows' walk, split out so its guard-release failure
-        handling stays readable)."""
-        framework = self.frameworks[profile]
-        a_i = a_full[: len(pending)]
-        gd_i = gd_full[: len(pending)]
-        profile_gang_dropped = int(gd_i.sum())
-        stats.gang_dropped += profile_gang_dropped
-        self.metrics.decisions.inc(len(pending) * len(nodes))
-
-        if (a_i < 0).any():
-            handle.dispatch_diagnosis(gi)
-        _rej_box: list = []
-
-        def reject_counts_fn(
-            gi=gi, pending=pending, _rej_box=_rej_box
-        ):
-            # ONE force of inner cycle gi's [P, F] attribution matrix
-            if not _rej_box:
-                _rej_box.append(
-                    handle.reject_counts_matrix(gi, len(pending))
-                )
-            return _rej_box[0]
-
-        pre_handle = None
-        if ppreempt is not None and (a_i < 0).any():
-            self.metrics.preemption_attempts.inc()
-            pre_handle = handle.dispatch_preemption(gi)
-
-        def force_pre(pre_handle=pre_handle, pending=pending):
-            if pre_handle is None:
-                return None, None
-            return (
-                np.asarray(pre_handle.nominated)[: len(pending)],
-                np.asarray(pre_handle.victims)[: len(existing)],
-            )
-
-        self._apply_phase(
-            profile, framework, pending, nodes, existing, a_i,
-            gd_i, {}, reject_counts_fn, force_pre,
-            stats, t0, rec, self._now(),
-        )
-        speculation = ""
-        if gi == 0 and resolve_after_first is not None:
-            # the speculation predicate: group 0's fold just landed —
-            # adopt or abandon the in-flight continuation before any
-            # record of this batch publishes
-            speculation = resolve_after_first(a_i, before)
-
-        if rec is not None:
-            # batched decomposition (observe.PHASES): how long this
-            # group waited for the batch to fill, and its share of
-            # the batch's device window apportioned by attempted-pod
-            # counts (no clock runs under jit). multi_cycle_k marks
-            # this record as an inner cycle of an n-cycle batch —
-            # the observer reads it to excuse the full (non-delta)
-            # per-group encodes from fold_miss
-            extra_phases: dict = {
-                "batch_wait_ms": max(t_batch - t_enq, 0.0) * 1e3,
-                "device_share_ms": (
-                    device_win_s * len(pending)
-                    / total_attempted * 1e3
-                ),
-            }
-            extra_marks: dict = {}
-            extra_counts: dict = {"multi_cycle_k": batch_n}
-            if gi == 0:
-                # incrementalEncode flush stamps (encode_ingest /
-                # encode_finalize): batch-wide, so they land only on
-                # the dispatch's record — same rule as the pipeline
-                # marks below
-                extra_phases.update(self._flush_phases.pop(profile, {}))
-            if (
-                gi == 0 and stamp_first_bind
-                and "t_first_decision" in st
-                and t_batch_rec
-            ):
-                # streamed-fetch headline: batch flush -> the first
-                # decision row landed (both on the recorder clock)
-                extra_phases["first_bind_ms"] = max(
-                    st["t_first_decision"] - t_batch_rec, 0.0
-                ) * 1e3
-            dl = handle.diag_lag.get(gi)
-            if dl is not None:
-                lag_s, t_done = dl
-                extra_phases["diag_lag_ms"] = lag_s * 1e3
-                extra_marks["diag_done"] = t_done
-                self.metrics.diag_lag.observe(lag_s)
-            compile_source = ""
-            if (
-                gi == 0 and stamp_compile
-                and self._packed_builds > builds_before
-            ):
-                extra_phases["compile_ms"] = (
-                    self._last_build_s * 1e3
-                )
-                extra_counts["regime_flip"] = 1
-                compile_source = self._last_compile_source
-            # batch-wide pipeline marks/phases (encode, dispatch,
-            # device window, decision fetch) land ONLY on inner
-            # record 0 — the one representing the dispatch. Copying
-            # them onto all K records would feed the streaming
-            # phase histograms K observations of ONE batch window
-            # (~K-fold inflated attribution) and let a single slow
-            # batch raise K duplicate stall anomalies; records i>0
-            # carry the apportioned decomposition instead
-            # (device_share/batch_wait), same spirit as zeroing
-            # their fetch_bytes
-            st_i = st if gi == 0 else {"slot": st.get("slot", -1)}
-            # armed-only: this inner cycle's streamed decision-row
-            # window (pipeline.decisions_row stamps it per row) — the
-            # decision.row span override for records of a batch
-            row_window = None
-            if self._pod_spans:
-                row_window = dict(
-                    (ri, (rt0, rt1))
-                    for ri, rt0, rt1 in st.get("decision_rows", ())
-                ).get(gi)
-            self._commit_record(
-                rec, st_i, spec, encoder, pending, nodes, stats,
-                before, profile_gang_dropped,
-                fetch_bytes=(
-                    int(st.get("fetch_bytes", 0)) if gi == 0 else 0
-                ),
-                extra_phases=extra_phases,
-                extra_marks=extra_marks,
-                extra_counts=extra_counts,
-                compile_source=compile_source,
-                speculation=speculation,
-                row_window=row_window,
-            )
-
-    def _schedule_profile_multi_spec(
-        self,
-        profile: str,
-        groups: "list[tuple[float, list[Pod]]]",
-        stats: CycleStats,
-        t0: float,
-        t_batch: float,
-        t_batch_rec: float,
-        builds_before: int,
-        nodes,
-        existing,
-        kw: dict,
-    ) -> None:
-        """The depth-2 speculative split of one flushed batch
-        (ROADMAP item 2 / ISSUE 13 tentpole): batch A = row 0 alone,
-        batch B = the remaining rows, dispatched SPECULATIVELY against
-        A's predicted post-fold state while A is still on device.
-
-        Timeline (device never idles, first bind never waits K
-        cycles):
-
-            encode row 0 -> dispatch A (1 inner cycle)
-            encode rows 1..n-1          | A on device
-            dispatch B (carry0 = A's    |
-              device-resident carry)    |
-            fetch A row 0, bind, fold   | B on device
-            predicate digest match?     |
-              yes -> adopt B: stream B's rows, apply (zero added
-                     latency — B has been on device the whole time)
-              no  -> abandon B, re-dispatch rows 1..n-1 against the
-                     TRUE post-fold state (correctness never rides
-                     the speculation, only latency does)
-
-        The predicate (`_fold_digest`) covers exactly what B's encode
-        + device-carry assumed about A's fold: every device winner
-        binds, no bind errors, no host-plugin vetoes, no preemption
-        evictions. B's rows were encoded against the same pre-batch
-        cache state the combined [A;B] batch would use and chained
-        through the carry_in continuation program, so adoption is
-        bit-identical to the combined batch — and, inside the
-        envelope, to sequential dispatches with host folding
-        (tests/test_speculative.py asserts all three)."""
-        from ..models import packing
-        from .cycle import multicycle_unsupported_reason
-
-        log = logging.getLogger(__name__)
-        encoder = self._encoders[profile]
-        n = len(groups)
-        rest_groups = groups[1:]
-        batch_pods = [p for _t_enq, g in groups for p in g]
-
-        inc = self.config.incremental_encode
-        mut = frozenset(self._nominated_mut[profile]) if inc else None
-        if inc:
-            f0 = encoder.encode_packed(
-                nodes, groups[0][1], existing, mutated_ids=mut, **kw
-            )
-            snap0 = f0.snap
-        else:
-            snap0 = encoder.encode(nodes, groups[0][1], existing, **kw)
-        reason = multicycle_unsupported_reason(snap0)
-        if reason is not None:
-            self._mc_fall_back(profile, groups, stats, t0, reason)
-            return
-        # growth watermark: A's stable side is row 0's tables; if B's
-        # encodes below intern anything new — even within the padded
-        # regime — B's rows would reference entries A's tables lack
-        lens0 = encoder._table_lens()
-        spec = f0.spec if inc else packing.make_spec(snap0)
-        (
-            _pcycle, ppreempt, stable_fn, _keeper, _diag, _ek, pipe,
-        ) = self._packed_fns(spec, profile)
-        mfn, mdiag, mcont = self._mc_programs(spec, profile)
-        pipe.multi_fn = mfn
-        pipe.multi_diag_fn = mdiag
-        pipe.multi_cont_fn = mcont
-
-        if inc:
-            # the arena is rewritten by B's encodes below while A is
-            # still on device: stack a copy of row 0 now
-            wa, ba = self._pack_stack_rows([(f0.wbuf, f0.bbuf)], spec)
-        else:
-            wa, ba = self._pack_stack([snap0], spec)
-        try:
-            stable = self._stable_state(
-                spec, stable_fn, wa[0], ba[0], encoder
-            )
-        except Exception as e:
-            self._cycle_failed(profile, batch_pods, e, stats, t0, None)
-            return
-        t_encode = self._now()
-        self.metrics.cycle_duration.labels(phase="encode").observe(
-            t_encode - t_batch
-        )
-        # the speculative gate already excluded forcedSync and the
-        # degraded rungs; refresh the pipeline's knobs regardless
-        pipe.forced_sync = False
-        pipe.dispatch_deadline_s = self._dispatch_deadline_s
-        pipe.note_encode(t_encode - t_batch)
-        try:
-            handle_a = pipe.dispatch_multi(
-                wa, ba, stable, 1, device_put=False,
-                anchor=self._anchor(),
-            )
-        except Exception as e:
-            self._cycle_failed(profile, batch_pods, e, stats, t0, None)
-            return
-
-        # rows 1..n-1 encode in A's dispatch shadow — the host work
-        # depth-2 hides behind device time (effective cycle tends to
-        # max(device_ms, encode_ms) instead of their sum)
-        t_enc_b0 = self._now()
-        snaps_b = []
-        rows_b = []
-        specs_b = []
-        bad_reason: "str | None" = None
-        for _t_enq, g in rest_groups:
-            if inc:
-                fb_ = encoder.encode_packed(
-                    nodes, g, existing, mutated_ids=mut, **kw
-                )
-                s = fb_.snap
-            else:
-                s = encoder.encode(nodes, g, existing, **kw)
-            bad_reason = multicycle_unsupported_reason(s)
-            if bad_reason is not None:
-                break
-            if inc:
-                rows_b.append((fb_.wbuf.copy(), fb_.bbuf.copy()))
-                specs_b.append(fb_.spec)
-            else:
-                snaps_b.append(s)
-        if inc:
-            if bad_reason is None:
-                # every buffered group folded with `mut` in scope; an
-                # incomplete pass keeps the set so the fall-back
-                # encodes still rewrite the mutated slots
-                self._nominated_mut[profile].clear()
-            self._stamp_finalize(
-                profile,
-                (t_encode - t_batch) + (self._now() - t_enc_b0),
-                pods=batch_pods,
-            )
-        handle_b = None
-        if bad_reason is None:
-            if encoder._table_lens() != lens0 or any(
-                (specs_b[j] if inc else packing.make_spec(s)).key()
-                != spec.key()
-                for j, s in enumerate(specs_b if inc else snaps_b)
-            ):
-                # a later group grew an interning structure — past row
-                # 0's regime (carry shapes no longer line up) or within
-                # its padding (B's rows reference table entries A's
-                # stable side lacks) — so B cannot chain: it
-                # re-dispatches after A's fold instead (counted as
-                # speculation="none": nothing was ever speculated)
-                log.info(
-                    "speculative batch for profile %r skipped: rows "
-                    "1..%d grew the interning tables past row 0's",
-                    profile, n - 1,
-                )
-            else:
-                if inc:
-                    wb, bb = self._pack_stack_rows(rows_b, spec)
-                else:
-                    wb, bb = self._pack_stack(snaps_b, spec)
-                pipe.note_encode(self._now() - t_enc_b0)
-                try:
-                    handle_b = pipe.dispatch_multi(
-                        wb, bb, stable, n - 1, device_put=False,
-                        carry0=(
-                            handle_a.result.carry_node_requested,
-                            handle_a.result.carry_gplaced,
-                        ),
-                        speculative=True,
-                        # row 0's one record starts before B's first
-                        anchor=self._anchor(ahead=1),
-                    )
-                except Exception as e:
-                    # the speculation itself failing must never fail
-                    # the batch: B simply re-dispatches sequentially
-                    # after A's fold
-                    log.warning(
-                        "speculative dispatch failed for profile %r "
-                        "(%s); re-dispatching sequentially", profile, e,
-                    )
-                    handle_b = None
-
-        outcome: dict = {}
-
-        def resolve(a_row, before):
-            # predicted fold: every device winner binds, nothing else
-            # mutates the cache — vs what the apply loop actually did
-            wins = int((a_row >= 0).sum())
-            predicted = self._fold_digest(
-                wins, len(a_row) - wins, 0, 0
-            )
-            sb, ub, bb_, _pb, vb = before
-            actual = self._fold_digest(
-                stats.scheduled - sb,
-                stats.unschedulable - ub,
-                stats.bind_errors - bb_,
-                stats.victims - vb,
-            )
-            outcome["predicted"] = predicted
-            outcome["actual"] = actual
-            if handle_b is None:
-                outcome["tag"] = "none"
-            elif actual == predicted:
-                pipe.adopt_speculative()
-                outcome["tag"] = "adopted"
-            else:
-                pipe.abandon_speculative()
-                outcome["tag"] = "abandoned"
-            return outcome["tag"]
-
-        self.metrics.multicycle_batch.observe(n)
-        try:
-            applied_a, exc_a = self._apply_mc_rows(
-                profile, handle_a, groups[:1], spec, encoder, stats,
-                t0, t_batch, t_batch_rec, nodes, existing, ppreempt,
-                builds_before, batch_n=n, stamp_first_bind=True,
-                stamp_compile=True, resolve_after_first=resolve,
-            )
-        except BaseException:  # schedlint: disable=RB001 -- not swallowed: purely a leak guard (the speculation slot must not outlive the batch) — the original error re-raises with its story intact
-            # a non-fetch apply failure escaped with the speculation
-            # possibly unresolved: free its slot before the error
-            # reaches the cycle driver (no-op if already resolved)
-            pipe.abandon_speculative()
-            raise
-        if exc_a is not None:
-            # A's fetch failed with the speculation (if any) still in
-            # flight: abandon it so its arena slot cannot leak, then
-            # consume the whole batch through the ladder — nothing was
-            # bound, every pod requeues
-            pipe.abandon_speculative()
-            self._cycle_failed(
-                profile, batch_pods, exc_a, stats, t0, None
-            )
-            return
-        if applied_a == 0:
-            # row 0 never executed (driver bug; A's group was requeued
-            # by _apply_mc_rows) — the speculation conditioned on a
-            # fold that never happened
-            pipe.abandon_speculative()
-            for _t_enq, g in rest_groups:
-                for pod in g:
-                    self.queue.requeue_backoff(
-                        pod, event="MultiCycleUnran"
-                    )
-                    stats.bind_errors += 1
-            return
-
-        tag = outcome.get("tag", "none")
-        if tag == "adopted":
-            applied_b, exc_b = self._apply_mc_rows(
-                profile, handle_b, rest_groups, spec, encoder, stats,
-                t0, t_batch, t_batch_rec, nodes, existing, ppreempt,
-                builds_before, batch_n=n,
-            )
-            self.metrics.multicycle_cycles.inc(applied_a + applied_b)
-            if exc_b is not None:
-                rest = [
-                    p for _t_enq, g in rest_groups[applied_b:]
-                    for p in g
-                ]
-                self._cycle_failed(
-                    profile, rest, exc_b, stats, t0, None
-                )
-                return
-        else:
-            self.metrics.multicycle_cycles.inc(applied_a)
-            if tag == "abandoned":
-                pipe.note_redispatch()
-                diverged = [
-                    f"{name} {pv}->{av}"
-                    for (name, pv), (_n2, av) in zip(
-                        outcome["predicted"], outcome["actual"]
-                    )
-                    if pv != av
-                ]
-                log.info(
-                    "speculative batch abandoned for profile %r (host "
-                    "fold diverged from the predicate digest: %s); "
-                    "re-dispatching %d group(s) against the true "
-                    "carry", profile, ", ".join(diverged),
-                    len(rest_groups),
-                )
-            if bad_reason is not None:
-                self._mc_fall_back(
-                    profile, rest_groups, stats, t0, bad_reason
-                )
-            elif len(rest_groups) == 1:
-                self._schedule_profile(
-                    profile, rest_groups[0][1], stats, t0
-                )
-            else:
-                self._schedule_profile_multi(
-                    profile, rest_groups, stats, t0
-                )
         self._maybe_speculate(profile, spec)
 
     def _commit_record(
@@ -2775,20 +1420,12 @@ class Scheduler:
         gang_dropped: int,
         fetch_bytes: int,
         extra_phases: "dict | None" = None,
-        extra_marks: "dict | None" = None,
         extra_counts: "dict | None" = None,
         compile_source: str = "",
-        speculation: str = "",
-        row_window: "tuple | None" = None,
     ) -> None:
         """Assemble + commit one cycle flight record (one list store):
         pipeline stage marks/phases, pad-regime signature, queue
-        depths, and the per-profile outcome deltas. Shared by the
-        single-cycle path and the multi-cycle batch path so a field
-        added to one cannot silently go missing from the other; the
-        paths differ only through the extra_* parameters (fold_ms /
-        compile_ms / post_batch vs batch_wait / device_share /
-        multi_cycle_k)."""
+        depths, and the per-profile outcome deltas."""
         from ..models import packing as _packing
         from .cycle import RESILIENT_STRIKES
 
@@ -2809,8 +1446,6 @@ class Scheduler:
                 if k.endswith("_ms")
             }
         )
-        for k, v in (extra_marks or {}).items():
-            rec.mark(k, v)
         rec.phases.update(extra_phases or {})
         if self.admission is not None:
             # front door: worst admission-accept -> bind latency among
@@ -2826,11 +1461,6 @@ class Scheduler:
             # regime-flip cycles only: how the (re)build was paid —
             # cold compile, persistent-cache load, or a speculation win
             rec.compile_source = compile_source
-        if speculation:
-            # depth-2 dispatch speculation outcome (adopted | abandoned
-            # | none), one sample per speculation — feeds the
-            # observer's speculation_thrash abandon-rate EWMA
-            rec.speculation = speculation
         if "sample_k" in st:
             # the cycle program sampled nodes (core/cycle.node_sample):
             # the k in force and the pods it cost a candidate, as fetched
@@ -2902,10 +1532,6 @@ class Scheduler:
             # full encode beside a rise of this one as explained
             fold_declined=int(encoder.fold_declined),
             **tot,
-            # admission-time incremental encode: dirty slots whose
-            # flush-time parse was skipped (a staged ingest row was
-            # waiting) — the bench's encode_hidden evidence
-            ingest_hits=int(getattr(encoder, "ingest_hits", 0)),
             queue_active=qc.get("active", 0),
             queue_backoff=qc.get("backoff", 0),
             queue_unschedulable=qc.get("unschedulable", 0),
@@ -2944,21 +1570,18 @@ class Scheduler:
             # where batched() flushed it in chunks
             rec.counts["update_rpcs"] = self.update_rpcs
         if self._pod_spans:
-            self._emit_cycle_spans(rec, pending, speculation, row_window)
+            self._emit_cycle_spans(rec, pending)
         self._commit_traced(rec)
 
-    def _anchor(self, rec=None, ahead: int = 0):
+    def _anchor(self, rec):
         """What the pipeline's `sched.dispatch` trace annotation carries
         (core/pipeline._dispatch_anchor): the seq of the flight record
-        the dispatch belongs to — `rec`'s, or on the multi-cycle path,
-        whose records start after the dispatch, the seq the batch's
-        first record will take — and the recorder's epoch. None while
+        the dispatch belongs to and the recorder's epoch. None while
         tracing is unarmed or the recorder is off."""
         fr = self.flight
         if fr is None or not _spans.ARMED:
             return None
-        seq = rec.seq if rec is not None else fr.next_seq + ahead
-        return seq, fr.epoch
+        return rec.seq, fr.epoch
 
     def _commit_traced(self, rec) -> None:
         """Commit `rec`, joined both ways to the `Cycle` RPC it ran
@@ -2970,18 +1593,12 @@ class Scheduler:
         self.last_cycle_seqs.append(rec.seq)
         self.flight.commit(rec)
 
-    def _emit_cycle_spans(
-        self, rec, pending, speculation: str,
-        row_window: "tuple | None",
-    ) -> None:
+    def _emit_cycle_spans(self, rec, pending) -> None:
         """Armed-only: emit this record's serve-side spans for every
         sampled pod it carried and stamp the record's `trace_ids`
         exemplar join. All windows come from the record's own marks
         (recorder perf_counter clock — the same base the span ring
-        uses), so span slices and cycle lanes rebase identically;
-        `row_window` overrides the decision window for an inner cycle
-        of a multi-cycle batch (its streamed row, not the batch-wide
-        fetch envelope)."""
+        uses), so span slices and cycle lanes rebase identically."""
         ctxs = []
         for p in pending:
             c = _spans.ctx_for(p.uid)
@@ -2991,24 +1608,12 @@ class Scheduler:
             return
         m = rec.marks
         d0, d1 = m.get("dispatch_start"), m.get("dispatch_end")
-        r0, r1 = row_window or (
-            m.get("decision_start"), m.get("decision_end")
-        )
+        r0, r1 = m.get("decision_start"), m.get("decision_end")
         a0, a1 = m.get("apply_start"), m.get("winners_end")
         for uid, c in ctxs:
             if d0 is not None and d1 is not None:
                 _spans.record_span(
                     "dispatch", c, d0, d1, uid=uid, seq=rec.seq,
-                )
-            if speculation in ("adopted", "abandoned"):
-                # the speculative continuation this batch resolved:
-                # anchor it on the dispatch window (the speculation
-                # rode that dispatch's shadow)
-                _spans.record_span(
-                    "dispatch.speculative", c,
-                    d0 if d0 is not None else rec.t_start,
-                    d1 if d1 is not None else rec.t_start,
-                    uid=uid, seq=rec.seq, outcome=speculation,
                 )
             if r0 is not None and r1 is not None:
                 _spans.record_span(
@@ -3100,7 +1705,6 @@ class Scheduler:
             # next rung's retry.
             with self._packed_lock:
                 self._packed.clear()
-                self._mc_fns.clear()
             self._dev_stable.clear()
         if (
             new >= RUNG_STATELESS
@@ -3187,12 +1791,6 @@ class Scheduler:
         """The host APPLY phase of one cycle: winner bind loop,
         preemption force, loser requeue, victim eviction — everything
         between "decisions in hand" and "flight record assembled".
-        Shared verbatim by the single-cycle path (_schedule_profile)
-        and the multi-cycle batch path (_schedule_profile_multi), which
-        invokes it once per INNER cycle in batch order, so binds,
-        journal records, events, and timelines are applied per cycle
-        exactly as sequential dispatches would — durability semantics
-        do not change across the batch boundary.
 
         Vectorized fold: winners/losers are classified once with
         numpy, the per-plugin attribution is forced ONCE as a matrix
@@ -3208,10 +1806,9 @@ class Scheduler:
         `force_pre()` forces the cycle's preemption program and
         returns `(nominated[:P_real] | None, victims[:E_real] | None)`.
 
-        `rows` (`cache.prepare_rows`, the single-cycle path): a
-        winner's row goes to `assume`, which then serialises nothing;
-        a winner without one, and every winner of a caller that
-        prepared none, is serialised there as before.
+        `rows` (`cache.prepare_rows`): a winner's row goes to `assume`,
+        which then serialises nothing; a winner without one, and every
+        winner of a caller that prepared none, is serialised there.
         """
         import contextlib
 
@@ -3611,22 +2208,6 @@ class Scheduler:
                 self.admission.queue_depth()
             )
 
-    def speculation_ledger(self) -> dict:
-        """Aggregate depth-2 speculation ledger: {'adopted',
-        'abandoned', 'redispatched'} counts. Read from this
-        scheduler's scheduler_speculation_total{outcome} counters, not
-        the per-pipeline dicts — a retrace rung (or plain LRU
-        eviction) drops regime pipelines along with their ledgers,
-        while the metric registry survives every memo clear. Soaks and
-        the fuzz differential read this to assert the speculative path
-        actually exercised (and abandoned without leaking a slot)."""
-        return {
-            o: int(
-                self.metrics.speculation.labels(outcome=o)._value.get()
-            )
-            for o in ("adopted", "abandoned", "redispatched")
-        }
-
     def pod_timeline(self, uid: str) -> dict | None:
         """The per-pod scheduling timeline: the flight recorder's pod
         events (queued -> attempts -> bound/evicted) joined with
@@ -3709,12 +2290,5 @@ class Scheduler:
         while max_cycles is None or cycles < max_cycles:
             stats = self.schedule_cycle()
             cycles += 1
-            if stats.attempted == 0 and not (
-                self._mc_k > 1
-                and any(self._mc_groups.values())
-            ):
-                # buffered groups are waiting on the NEXT pop to
-                # detect a paused arrival stream (the flush trigger) —
-                # sleeping here would stretch every batch by
-                # idle_sleep; a truly idle loop still backs off
+            if stats.attempted == 0:
                 _time.sleep(idle_sleep)

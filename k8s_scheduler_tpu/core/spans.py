@@ -3,9 +3,8 @@
 Every other observability surface is cycle-centric (flight records,
 phase histograms, the anomaly sentinel); since the front door landed,
 the unit of work users experience is a POD REQUEST: Submit ->
-admission -> WAL ack barrier -> mc-group buffering -> (speculative)
-dispatch -> inner-cycle decision row -> bind fold -> confirm. This
-module makes that whole life one trace:
+admission -> WAL ack barrier -> dispatch -> decision row -> bind fold
+-> confirm. This module makes that whole life one trace:
 
 - `SpanRecorder` — a bounded ring of `Span`s with the same
   seqlock-style publication discipline as the cycle flight recorder
@@ -87,13 +86,8 @@ from typing import Any, Callable, Iterable
 #                   group-commit fsync wait; one span PER SUBMITTER,
 #                   all joined to the shared flush seq via the
 #                   `flush_seq` attr)
-#   serve thread:   mc.buffer_wait (admission -> multi-cycle flush),
-#                   encode.ingest (admission-time incremental row
-#                   staging), flush.finalize (the O(dirty) flush
-#                   apply), dispatch (device dispatch window),
-#                   dispatch.speculative (the depth-2 continuation;
-#                   attr `outcome`: adopted | abandoned),
-#                   decision.row (the inner cycle's slimmed row
+#   serve thread:   dispatch (device dispatch window),
+#                   decision.row (the cycle's slimmed decision
 #                   transfer), apply.fold (winner bind loop ->
 #                   postfilter), bind.confirm (the pod's bind),
 #                   preempt.victim (an eviction this pod's nomination
@@ -145,11 +139,7 @@ SPAN_NAMES = (
     "submit.validate",
     "submit.journal",
     "ack.barrier",
-    "mc.buffer_wait",
-    "encode.ingest",
-    "flush.finalize",
     "dispatch",
-    "dispatch.speculative",
     "decision.row",
     "apply.fold",
     "bind.confirm",

@@ -1,11 +1,12 @@
 """Scenario fuzzer + trace-level differential oracle (ISSUE 11).
 
 The correctness backstop for every scale item: a seeded generator
-(`trace.py`) emits multi-cycle cluster traces — pod arrivals/deletions,
-node add/drain/churn, gangs, priority bands with preemption pressure,
-taints/tolerations, PV topology, zone spreads, disruption budgets —
+(`trace.py`) emits cluster traces of several cycles — pod
+arrivals/deletions, node add/drain/churn, gangs, priority bands with
+preemption pressure, taints/tolerations, PV topology, zone spreads,
+disruption budgets —
 which `replay.py` drives through BOTH the live `Scheduler` (the real
-dispatch path, multi-cycle and sharded variants included) and the slow
+dispatch path, the sharded variant included) and the slow
 sequential oracle extended with trace semantics
 (`oracle.schedule_cycle_trace`), asserting bit-equal bind streams plus
 standing per-cycle invariants. `shrink.py` reduces failing traces to

@@ -13,10 +13,8 @@ reasons, nominations, evictions, gang drops, PDB overruns); after each
 cycle the harness plays the informer back — bind confirmations
 (`on_pod_add(pod, node)`) and eviction deletes (`on_pod_delete`) — and
 ticks the clock past the max backoff, so requeued pods return
-deterministically. `compare()` asserts the two streams bit-equal:
-per-cycle for single-cycle serving, as flattened streams for
-multi-cycle coalescing (whose ONLY legal difference is when outcomes
-land, never what they are — PR 6's contract).
+deterministically. `compare()` asserts the two streams bit-equal,
+cycle by cycle.
 
 Standing invariants checked engine-side every cycle (chaos traces,
 where faults make the queues legitimately diverge from the oracle's,
@@ -59,8 +57,6 @@ from .trace import (
     Trace,
     materialize,
     materialize_event,
-    trace_from_dict,
-    trace_to_dict,
 )
 
 
@@ -86,9 +82,6 @@ class ReplayResult:
     failures: list  # list[Failure] (invariants; chaos checks)
     binds: list  # flattened [(uid, node), ...] in bind order
     stats: dict
-
-    def stream(self, key: str) -> list:
-        return [x for r in self.records for x in r[key]]
 
 
 def _require_scan_mode(cfgd: dict) -> None:
@@ -192,8 +185,8 @@ def replay_engine(
     trace: Trace, *, state_dir: str = "", via_api: bool = False
 ) -> ReplayResult:
     """Drive the trace through a LIVE Scheduler — the real dispatch
-    path (split-phase pipeline, multi-cycle coalescing and sharded
-    serving included, per the trace config). Chaos traces arm the
+    path (split-phase pipeline, and sharded serving where the trace's
+    config asks for it). Chaos traces arm the
     trace's FaultPlan for the duration.
 
     `via_api` (the ISSUE 14 `arrivals_via_api` variant) routes every
@@ -224,20 +217,6 @@ def replay_engine(
     cfg = SchedulerConfiguration(
         commit_mode=cfgd.get("commit_mode", "scan"),
         gang_scheduling=bool(cfgd.get("gang_scheduling", True)),
-        multi_cycle_k=int(cfgd.get("multi_cycle_k", 1)),
-        multi_cycle_max_wait_ms=float(
-            cfgd.get("multi_cycle_max_wait_ms", 1e12)
-        ),
-        # depth-2 speculative dispatch (default OFF for traces: the
-        # committed corpus predates the key and must replay unchanged;
-        # generate_trace(speculative=True) turns the variant on)
-        speculative_dispatch=bool(
-            cfgd.get("speculative_dispatch", False)
-        ),
-        # admission-time incremental encode (default OFF for the same
-        # corpus-stability reason; generate_trace(incremental=True)
-        # turns the variant on)
-        incremental_encode=bool(cfgd.get("incremental_encode", False)),
         shard_devices=devices,
         dispatch_deadline_ms=float(cfgd.get("dispatch_deadline_ms", 0.0)),
         degrade_promote_cycles=int(cfgd.get("degrade_promote_cycles", 2)),
@@ -461,24 +440,6 @@ def replay_engine(
             "fired_points": sorted(
                 faults.plan().fired_points()
             ) if faults.plan() is not None else [],
-            # depth-2 speculation outcomes (all zero when the trace
-            # runs without speculativeDispatch): the variant tests
-            # assert the speculative path actually exercised AND that
-            # no slot leaked (pipeline inflight drained)
-            "speculation": sched.speculation_ledger(),
-            # admission-time incremental encode ledger (all zero when
-            # the trace runs without incrementalEncode): the variant
-            # asserts staged rows were actually consumed at flush
-            "ingest": {
-                "hits": sum(
-                    int(getattr(e, "ingest_hits", 0))
-                    for e in sched._encoders.values()
-                ),
-                "misses": sum(
-                    int(getattr(e, "ingest_misses", 0))
-                    for e in sched._encoders.values()
-                ),
-            },
         }
     finally:
         from k8s_scheduler_tpu.core import faults as _faults
@@ -752,136 +713,20 @@ _PER_CYCLE_KEYS = (
 )
 
 
-def compare(trace: Trace, eng: ReplayResult, orc: ReplayResult) -> list[Failure]:
-    """Bit-equality of the two decision streams. Single-cycle serving
-    compares cycle by cycle (first diverging cycle + field named);
-    multi-cycle serving compares the flattened streams — coalescing
-    legitimately moves WHEN outcomes land (to the flush cycle), never
-    what they are or their order."""
+def compare(eng: ReplayResult, orc: ReplayResult) -> list[Failure]:
+    """Bit-equality of the two decision streams, cycle by cycle (first
+    diverging cycle + field named)."""
     out: list[Failure] = []
-    if int(trace.config.get("multi_cycle_k", 1)) <= 1:
-        for er, orr in zip(eng.records, orc.records):
-            for key in _PER_CYCLE_KEYS:
-                if er[key] != orr[key]:
-                    out.append(Failure(
-                        f"divergence/{key}", er["cycle"],
-                        f"engine={er[key]!r} oracle={orr[key]!r}",
-                    ))
-            if out:
-                return out
-        return out
-    for key in ("binds", "unschedulable", "nominated", "evicted",
-                "gang_dropped"):
-        e, o = eng.stream(key), orc.stream(key)
-        if key == "gang_dropped":
-            # sorted per RECORD, and a flush record merges K inner
-            # cycles — order across the merge is presentation, not
-            # semantics (the ordered truth rides the unschedulable
-            # stream as ("Coscheduling",) entries); compare the multiset
-            e, o = sorted(e), sorted(o)
-        if e != o:
-            i = next(
-                (j for j, (a, b) in enumerate(zip(e, o)) if a != b),
-                min(len(e), len(o)),
-            )
-            out.append(Failure(
-                f"divergence/{key}", -1,
-                f"stream differs from element {i}: "
-                f"engine={e[i:i+3]!r} oracle={o[i:i+3]!r} "
-                f"(lengths {len(e)}/{len(o)})",
-            ))
-            return out
-    return out
-
-
-def compare_speculative(
-    eng_on: ReplayResult, eng_off: ReplayResult
-) -> list[Failure]:
-    """Per-cycle bit-equality of the speculative engine against the
-    NON-speculative engine on the same trace. This — not the oracle —
-    is depth-2 speculation's contract: adoption/abandonment must not
-    change WHAT is decided, WHEN it lands, or in what order (the two
-    engines share the exact batching cadence, so even the cycle
-    placement must match). The oracle differential is defined against
-    sequential serving, where coalescing's documented legal
-    batch-window shifts (an unschedulable pod's re-activation moving
-    to the flush cycle) would read as divergence."""
-    out: list[Failure] = []
-    for er, orr in zip(eng_on.records, eng_off.records):
-        for key in _PER_CYCLE_KEYS + ("requeues", "rung"):
+    for er, orr in zip(eng.records, orc.records):
+        for key in _PER_CYCLE_KEYS:
             if er[key] != orr[key]:
                 out.append(Failure(
-                    f"speculation/{key}", er["cycle"],
-                    f"spec-on={er[key]!r} spec-off={orr[key]!r}",
+                    f"divergence/{key}", er["cycle"],
+                    f"engine={er[key]!r} oracle={orr[key]!r}",
                 ))
         if out:
             return out
     return out
-
-
-def compare_incremental(
-    eng_on: ReplayResult, eng_off: ReplayResult
-) -> list[Failure]:
-    """Per-cycle bit-equality of the incremental-encode engine against
-    the rebuild engine on the same trace. This — not the oracle — is
-    admission-time ingest's contract: staging row data at buffer time
-    must not change WHAT is encoded or decided, only WHEN the parse
-    cost is paid (the two engines share the exact coalescing cadence,
-    so even cycle placement must match). The dispatched packed arenas
-    are additionally compared byte for byte by run_case via
-    _capture_arenas — the decision streams could mask a compensating
-    arena difference, the arena bytes cannot."""
-    out: list[Failure] = []
-    for er, orr in zip(eng_on.records, eng_off.records):
-        for key in _PER_CYCLE_KEYS + ("requeues", "rung"):
-            if er[key] != orr[key]:
-                out.append(Failure(
-                    f"incremental/{key}", er["cycle"],
-                    f"inc-on={er[key]!r} inc-off={orr[key]!r}",
-                ))
-        if out:
-            return out
-    return out
-
-
-@contextlib.contextmanager
-def _capture_arenas(out: list):
-    """Record the packed-arena bytes of every dispatch (single and
-    multi-cycle) issued inside the scope: `out` collects
-    `(kind, words_bytes, bytes_bytes)` tuples in dispatch order, pulled
-    to host before the upload so device placement cannot launder a
-    difference. Class-level patch — replays are sequential, and the
-    finally-restore keeps it scoped."""
-    import numpy as _np
-
-    from ..core.pipeline import ServingPipeline
-
-    orig_d = ServingPipeline.dispatch
-    orig_m = ServingPipeline.dispatch_multi
-
-    def dispatch(self, wbuf, bbuf, *a, **kw):
-        out.append((
-            "1",
-            _np.asarray(wbuf).tobytes(),
-            _np.asarray(bbuf).tobytes(),
-        ))
-        return orig_d(self, wbuf, bbuf, *a, **kw)
-
-    def dispatch_multi(self, wbufs, bbufs, *a, **kw):
-        out.append((
-            "K",
-            _np.asarray(wbufs).tobytes(),
-            _np.asarray(bbufs).tobytes(),
-        ))
-        return orig_m(self, wbufs, bbufs, *a, **kw)
-
-    ServingPipeline.dispatch = dispatch
-    ServingPipeline.dispatch_multi = dispatch_multi
-    try:
-        yield
-    finally:
-        ServingPipeline.dispatch = orig_d
-        ServingPipeline.dispatch_multi = orig_m
 
 
 def compare_via_api(
@@ -889,11 +734,10 @@ def compare_via_api(
 ) -> list[Failure]:
     """Per-cycle bit-equality of the arrivals-via-API engine against
     the direct-enqueue engine on the same trace. Both engines share
-    the exact coalescing cadence (same trace, same K, same frozen
-    clock — the coalescing-window legalities of the PR 10 generator
-    notes therefore cancel out), so even cycle placement must match:
-    any difference is the Submit/NodeChurn path perturbing state —
-    conversion loss, ordering drift, or admission side effects."""
+    the exact cadence (same trace, same clock), so even cycle
+    placement must match: any difference is the Submit/NodeChurn path
+    perturbing state — conversion loss, ordering drift, or admission
+    side effects."""
     out: list[Failure] = []
     for er, orr in zip(eng_api.records, eng_direct.records):
         for key in _PER_CYCLE_KEYS + ("requeues", "rung"):
@@ -1031,80 +875,19 @@ def run_case(
     traces — the differential divergences. `bug` injects a deliberate
     engine mutation (see `engine_bug`) for harness self-tests.
 
-    Speculative-dispatch traces differentially compare the engine
-    against ITSELF with speculation off (see compare_speculative) and
-    additionally fail when the trace never actually speculated —
-    a variant that silently stopped exercising the depth-2 path would
-    otherwise be a permanent green. Decision correctness is still
-    oracle-checked through the non-speculative variants (a shared
-    engine bug cancels out of an engine-vs-engine comparison, so this
-    variant hunts speculation bugs specifically).
-
-    Incremental-encode traces likewise compare the engine against
-    ITSELF with admission-time ingest off (compare_incremental), and
-    additionally require the dispatched packed arenas byte-identical
-    and the ingest path actually exercised (staged rows consumed at
-    flush) — a variant that silently fell back to full rebuilds every
-    flush would otherwise be a permanent green.
-
     Multi-tenant traces (config["tenancy"]) route to the arena-vs-
     sequential differential instead (run_tenant_case) — same plain-data
     trace format, same shrinker, same corpus, different oracle."""
     if trace.config.get("tenancy"):
         return run_tenant_case(trace, bug=bug)
-    inc = bool(trace.config.get("incremental_encode")) and not trace.chaos
-    arenas_on: list = []
-    cap = _capture_arenas(arenas_on) if inc else contextlib.nullcontext()
-    with engine_bug(bug), cap:
+    with engine_bug(bug):
         eng = replay_engine(trace, state_dir=state_dir)
     failures = list(eng.failures)
     if trace.chaos:
         return failures
-    if inc:
-        off = trace_from_dict(trace_to_dict(trace))
-        off.config["incremental_encode"] = False
-        arenas_off: list = []
-        with engine_bug(bug), _capture_arenas(arenas_off):
-            eng_off = replay_engine(off)
-        failures.extend(eng_off.failures)
-        failures.extend(compare_incremental(eng, eng_off))
-        if arenas_on != arenas_off:
-            i = next(
-                (j for j, (a, b) in enumerate(zip(arenas_on, arenas_off))
-                 if a != b),
-                min(len(arenas_on), len(arenas_off)),
-            )
-            failures.append(Failure(
-                "incremental/arena", -1,
-                f"dispatched packed arenas diverge at dispatch {i} "
-                f"(counts {len(arenas_on)}/{len(arenas_off)})",
-            ))
-        ing = eng.stats.get("ingest", {})
-        if not ing.get("hits", 0):
-            failures.append(Failure(
-                "incremental/never_exercised", -1,
-                f"incrementalEncode trace consumed no staged row at "
-                f"flush (ledger {ing})",
-            ))
-        return failures
-    if trace.config.get("speculative_dispatch"):
-        off = trace_from_dict(trace_to_dict(trace))
-        off.config["speculative_dispatch"] = False
-        with engine_bug(bug):
-            eng_off = replay_engine(off)
-        failures.extend(eng_off.failures)
-        failures.extend(compare_speculative(eng, eng_off))
-        led = eng.stats.get("speculation", {})
-        if not (led.get("adopted", 0) + led.get("abandoned", 0)):
-            failures.append(Failure(
-                "speculation/never_exercised", -1,
-                f"speculativeDispatch trace dispatched no speculative "
-                f"batch (ledger {led})",
-            ))
-        return failures
     orc = replay_oracle(trace)
     failures.extend(orc.failures)
-    failures.extend(compare(trace, eng, orc))
+    failures.extend(compare(eng, orc))
     return failures
 
 

@@ -232,23 +232,14 @@ def _gen_pod(
     *,
     groups: list,
     claims: list,
-    churn_ok: bool,
     heavy: bool = False,
-    flat_priority: bool = False,
-    envelope_only: bool = False,
 ) -> dict:
     app = rng.choice(APPS)
     if heavy:
         cpu_m = rng.choice((2000, 3000, 4000))
     else:
         cpu_m = rng.choice((250, 500, 1000))
-    if flat_priority:
-        # uniform priorities make preemption structurally impossible
-        # (no victim can rank below a preemptor) — multi-cycle traces
-        # need that, see generate_trace
-        pri = 0
-    else:
-        pri = rng.choice((0, 0, 5, 10)) if not heavy else 100
+    pri = rng.choice((0, 0, 5, 10)) if not heavy else 100
     b = (
         MakePod(name)
         .req({"cpu": f"{cpu_m}m", "memory": f"{rng.choice((256, 512))}Mi"})
@@ -260,28 +251,20 @@ def _gen_pod(
         b.node_selector({"node-type": rng.choice(NODE_TYPES)})
     if rng.random() < 0.30:
         b.toleration("dedicated", "special", "NoSchedule")
-    # envelope_only (speculative depth-2 traces): the capability draws
-    # are still consumed — the stamp's spec flag must not shift the rng
-    # stream — but the envelope-leaving features (affinity / spread /
-    # volumes / host ports, cycle.multicycle_unsupported_reason) are
-    # not applied, so the trace actually exercises the device loop the
-    # variant pipelines instead of pinning the profile out of batching
-    # on its first affinity pod. Plain multi-cycle traces keep drawing
-    # them: the envelope-exit fallback is itself a fuzzed path.
-    if rng.random() < 0.25 and not envelope_only:
+    if rng.random() < 0.25:
         b.pod_affinity("topology.kubernetes.io/zone", {"app": app})
-    if rng.random() < 0.25 and not envelope_only:
+    if rng.random() < 0.25:
         b.pod_affinity("kubernetes.io/hostname", {"app": app}, anti=True)
-    if rng.random() < 0.20 and not envelope_only:
+    if rng.random() < 0.20:
         b.spread(rng.choice((1, 2)), "topology.kubernetes.io/zone",
                  {"app": app},
                  when_unsatisfiable=rng.choice(
                      (api.DO_NOT_SCHEDULE, api.SCHEDULE_ANYWAY)))
-    if churn_ok and rng.random() < 0.08:
+    if rng.random() < 0.08:
         b.host_port(8000 + rng.randrange(4))
     if groups and rng.random() < 0.30:
         b.group(rng.choice(groups)["n"])
-    if claims and rng.random() < 0.5 and not envelope_only:
+    if claims and rng.random() < 0.5:
         b.volume(claims.pop(0)["n"])
     if rng.random() < 0.08:
         b.preemption_policy("Never")
@@ -403,43 +386,16 @@ def generate_trace(
     *,
     devices: int = 1,
     chaos: bool = False,
-    multi_cycle: "bool | None" = None,
-    speculative: bool = False,
-    incremental: bool = False,
 ) -> Trace:
     """One random scenario. `devices` > 1 turns on sharded serving
     (`shardDevices`; placements must stay bit-identical — PR 9's
-    contract). `multi_cycle` forces the K=4 coalescing path (None =
-    seeded coin); multi-cycle traces are ARRIVALS-ONLY, FROZEN-CLOCK
-    (tick_s=0), and PREEMPTION-FREE (uniform priorities, so no victim
-    can ever rank below a preemptor) — churn between buffered groups,
-    backoff retries whose re-activation shifts to the flush cycle, and
-    eviction informer echoes that land after the flush instead of
-    between inner cycles are all legitimate semantic differences of
-    the batch window, not engine bugs, so the generator keeps those
-    traces inside the exactness envelope the PR 6 equivalence suite
-    defines (whose own drive freezes the clock for the same reason).
-    `chaos` fuses a random `FaultPlan` over the trace (engine side
-    only) and appends a recovery tail so the ladder invariants are
-    decidable. `speculative` turns on the depth-2 speculative dispatch
-    variant (speculativeDispatch; forces the K=4 coalescing path it
-    pipelines) — a pure config switch drawing nothing from the rng, so
-    a stamp's spec=<0|1> reproduces the identical trace either way.
-    `incremental` turns on admission-time incremental encode
-    (incrementalEncode; forces the K=4 coalescing path it feeds) —
-    like `speculative`, a pure config switch drawing nothing from the
-    rng, so a stamp's inc=<0|1> reproduces the identical trace."""
+    contract). `chaos` fuses a random `FaultPlan` over the trace (engine
+    side only) and appends a recovery tail so the ladder invariants are
+    decidable."""
     rng = random.Random(seed)
-    # the coin is drawn UNCONDITIONALLY so an explicit multi_cycle flag
-    # (replaying a FUZZ-FAIL stamp's mc=<0|1>) consumes the same rng
-    # stream as the seeded coin did — the stamp must reproduce the
-    # identical trace, not a shifted one
-    mc_coin = rng.random() < 0.25
-    if speculative or incremental:
-        multi_cycle = True
-    elif multi_cycle is None:
-        multi_cycle = mc_coin
-    churn_ok = not multi_cycle
+    # one draw that chooses nothing: it keeps every later draw where a
+    # seed's trace (the corpus, a FUZZ-FAIL stamp) has always had it
+    rng.random()
     uniform = rng.random() < 0.5  # identical nodes -> score ties abound
     n_nodes = rng.randint(4, 10)
     nodes = [
@@ -521,7 +477,7 @@ def generate_trace(
 
     for _c in range(n_cycles):
         evs: list[dict] = []
-        n_heavy = 1 if (churn_ok and rng.random() < 0.3) else 0
+        n_heavy = 1 if rng.random() < 0.3 else 0
         n_arrive = rng.randint(1, 5)
         for ai in range(n_arrive + n_heavy):
             heavy = n_heavy > 0 and ai == n_arrive  # last arrival
@@ -531,48 +487,41 @@ def generate_trace(
                 "op": "add_pod",
                 "pod": _gen_pod(
                     rng, name, created, groups=pod_groups,
-                    claims=claims, churn_ok=churn_ok, heavy=heavy,
-                    flat_priority=multi_cycle,
-                    # envelope_only for the same reason as speculative:
-                    # the incremental variant tests the coalescing
-                    # flush's encode, so the trace must actually stay
-                    # on the multi-cycle path
-                    envelope_only=speculative or incremental,
+                    claims=claims, heavy=heavy,
                 ),
             })
             created += 1.0
             live_uids.append(f"default/{name}")
-        if churn_ok:
-            if live_uids and rng.random() < 0.3:
-                u = live_uids.pop(rng.randrange(len(live_uids)))
-                evs.append({"op": "delete_pod", "uid": u})
-            r = rng.random()
-            if r < 0.10:
-                nm = f"nx{uid_counter}"
-                evs.append({
-                    "op": "add_node",
-                    "node": _gen_node(rng, nm, uniform=uniform,
-                                      taint_p=0.2),
-                })
-                churn_nodes.append(nm)
-            elif r < 0.18:
-                # drain: re-deliver an initial node as unschedulable
-                nd = node_from_state(rng.choice(nodes))
-                nd.spec.unschedulable = True
-                evs.append({"op": "update_node",
-                            "node": node_to_state(nd)})
-            elif r < 0.24 and churn_nodes:
-                evs.append({
-                    "op": "delete_node",
-                    "name": churn_nodes.pop(
-                        rng.randrange(len(churn_nodes))
-                    ),
-                })
+        if live_uids and rng.random() < 0.3:
+            u = live_uids.pop(rng.randrange(len(live_uids)))
+            evs.append({"op": "delete_pod", "uid": u})
+        r = rng.random()
+        if r < 0.10:
+            nm = f"nx{uid_counter}"
+            evs.append({
+                "op": "add_node",
+                "node": _gen_node(rng, nm, uniform=uniform,
+                                  taint_p=0.2),
+            })
+            churn_nodes.append(nm)
+        elif r < 0.18:
+            # drain: re-deliver an initial node as unschedulable
+            nd = node_from_state(rng.choice(nodes))
+            nd.spec.unschedulable = True
+            evs.append({"op": "update_node",
+                        "node": node_to_state(nd)})
+        elif r < 0.24 and churn_nodes:
+            evs.append({
+                "op": "delete_node",
+                "name": churn_nodes.pop(
+                    rng.randrange(len(churn_nodes))
+                ),
+            })
         cycles.append(evs)
 
-    # drain tail: empty pops flush any coalescing buffer; under chaos a
-    # recovery tail with trivial arrivals (promotion only counts cycles
-    # that exercised the dispatch path) lets the ladder walk back to 0
+    # under chaos a recovery tail with trivial arrivals (promotion only
+    # counts cycles that exercised the dispatch path) lets the ladder
+    # walk back to 0
     fault_spec = ""
     if chaos:
         rules = []
@@ -612,20 +561,7 @@ def generate_trace(
     config = {
         "commit_mode": "scan",
         "gang_scheduling": True,
-        "multi_cycle_k": 4 if multi_cycle else 1,
-        # never the flush trigger: the ticking trace clock would trip a
-        # real-units bound every cycle — batches flush on K or idle pops
-        "multi_cycle_max_wait_ms": 1e12,
         "shard_devices": devices if devices > 1 else 0,
-        # depth-2 speculative dispatch pipelining over the coalesced
-        # batches: the differential asserts the adopted/abandoned/
-        # re-dispatched streams stay bit-equal to the oracle's
-        "speculative_dispatch": bool(speculative),
-        # admission-time incremental encode over the coalesced batches:
-        # the differential asserts the packed arenas stay byte-identical
-        # and the decision/journal/event streams bit-equal to the
-        # rebuild engine's
-        "incremental_encode": bool(incremental),
         "pad_bucket": 8,
         "dispatch_deadline_ms": 300.0 if chaos else 0.0,
         "degrade_promote_cycles": 2,
@@ -634,8 +570,4 @@ def generate_trace(
         seed=seed, config=config, nodes=nodes, pod_groups=pod_groups,
         pvcs=pvcs, pvs=pvs, storage_classes=classes, pdbs=pdbs,
         cycles=cycles, fault_spec=fault_spec,
-        # frozen clock under coalescing: backoff re-activation times
-        # shift to the flush cycle, a legal batch-window difference the
-        # differential must not read as divergence
-        tick_s=0.0 if multi_cycle else 16.0,
     )

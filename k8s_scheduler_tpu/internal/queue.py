@@ -285,15 +285,12 @@ class SchedulingQueue:
         """Drain the active tier — the whole next cycle's pending set.
         Flushes expired backoff first so a ready pod is never left behind.
 
-        `hold=True` is the multi-cycle coalescing variant: groups popped
-        by EARLIER cycles are still buffered scheduler-side (their
-        outcomes apply at the batch flush), so this pop ACCUMULATES into
-        the in-flight set instead of replacing it, and keeps the
-        deleted-in-flight tombstones — otherwise a buffered pod would
-        lose its attempts count, its delete tombstone, and its crash
-        recovery (recover_in_flight) the moment the next group was
-        popped. The flag is journaled: replay must reproduce the exact
-        in-flight set a takeover recovers."""
+        `hold=True` ACCUMULATES into the in-flight set instead of
+        replacing it, and keeps the deleted-in-flight tombstones. No
+        serving code passes it; a journal written by an older process
+        may hold `q.pop {"hold": true}` records, and the replayer
+        (state/manager.py) must reproduce the exact in-flight set a
+        takeover recovers (ROADMAP D16)."""
         with self._lock:
             now = self._now()
             # journal only a pop that changes SOMETHING: drains pods,
@@ -345,16 +342,14 @@ class SchedulingQueue:
             return out
 
     def retire_in_flight(self, uids: Sequence[str]) -> None:
-        """A multi-cycle batch flush applied these pods' outcomes: drop
-        them (and their delete tombstones) from the in-flight set.
+        """Drop these pods (and their delete tombstones) from the
+        in-flight set: their outcomes were applied.
 
-        Single-cycle serving retires implicitly — the next non-hold
-        pop replaces the whole set — but hold pops only ever
-        ACCUMULATE, and out-of-phase profile buffers can keep every
-        pop holding, so without an explicit retire a bound pod would
-        stay "recoverable" forever: unbounded in-flight growth, and a
-        leader takeover re-scheduling (re-binding) pods bound
-        arbitrarily long ago. Pods the failure paths already requeued
+        Serving retires implicitly — the next pop replaces the whole
+        set — but hold pops only ever ACCUMULATE, so a journal that
+        holds them also holds `q.retire` records, which the replayer
+        (state/manager.py) applies through this method; it is its one
+        caller (ROADMAP D16). Pods the failure paths already requeued
         are not in the set — the membership filter skips them."""
         with self._lock:
             live = [

@@ -55,17 +55,8 @@ streaming consumer of every flight record):
 
 - scheduler_cycle_phase_seconds{phase} — streaming per-phase latency
   attribution of every committed cycle record; phases: total, encode,
-  fold, encode_ingest, encode_finalize, dispatch, device,
-  decision_fetch, bind, postfilter, losers, diag_lag,
-  compile, batch_wait, device_share, first_bind, submit_bind
-  (encode_ingest / encode_finalize are the admission-time incremental
-  encode split: the per-group ingest cost paid in the ack path's
-  shadow, and the flush-time finalize residue; batch_wait and
-  device_share are the multi-cycle batched decomposition: an inner
-  cycle's host-side coalescing wait and its apportioned share of the
-  batch's device window; first_bind is the streamed-fetch window from
-  batch flush to the FIRST inner cycle's decisions landing — the
-  latency a row-0 pod actually waits before its bind; submit_bind is
+  fold, dispatch, device, decision_fetch, bind, postfilter, losers,
+  diag_lag, compile, submit_bind (submit_bind is
   the front door's end-to-end window from admission accept to the
   pod's bind, stamped per cycle as the worst such latency among that
   cycle's binds; the inventory is
@@ -76,8 +67,7 @@ streaming consumer of every flight record):
   the observer's streaming histograms, evaluated at scrape time
 - scheduler_anomalies_total{class} — typed anomaly detections
   (tunnel_stall | fetch_stall | recompile | fold_miss |
-  wedge_precursor | round_cap_hit | degraded | speculation_thrash);
-  each increment has
+  wedge_precursor | round_cap_hit | degraded); each increment has
   a matching structured event in /debug/anomalies carrying the cycle
   seq
 - scheduler_slo_burn_rate{window} — latency-SLO burn rate over the
@@ -86,26 +76,9 @@ streaming consumer of every flight record):
 - scheduler_slo_budget_remaining — fraction of the slow window's
   violation budget left (1.0 = untouched, negative = overspent)
 
-Multi-cycle serving families (core/scheduler.py _schedule_profile_multi
-— K scheduling cycles per device dispatch, amortizing the dispatch
-round trip):
+Encode-fold and commit-round families (models/encoding.py, ops/rounds.py
+— counted as each cycle's flight record commits):
 
-- scheduler_multicycle_batch_cycles — inner scheduling cycles per
-  multi-cycle device dispatch (1 = a degenerate single-cycle batch)
-- scheduler_multicycle_inner_cycles_total — scheduling cycles served
-  through multi-cycle dispatches (vs one dispatch per cycle)
-- scheduler_speculation_total{outcome} — depth-2 speculative dispatch
-  outcomes (adopted | abandoned | redispatched): a batch dispatched
-  against the predicted post-predecessor carry is adopted when the
-  host fold matches the speculation's predicate digest (zero added
-  latency), abandoned on a mismatch, and its groups then re-dispatched
-  against the true carry — correctness is never speculative
-- scheduler_encode_ingest_seconds — admission-time incremental encode:
-  per-group cost of parsing acked pods into staged row data in the ack
-  path's shadow (work moved OFF the flush critical path)
-- scheduler_encode_finalize_seconds — flush-time residue of the
-  incremental encode: folding staged rows into the packed arena when
-  the multi-cycle buffer flushes (what is left of the old O(P) rebuild)
 - scheduler_encode_fold_fallback_pods_total — newly bound pods whose
   existing-set row the incremental fold built in Python because the
   native row writer does not cover them (volumes / nodeAffinity): the
@@ -180,8 +153,7 @@ harness that drives them):
   (RESOURCE_EXHAUSTED + retry-after) from a full admission queue, an
   SLO fast-burn, or a degraded ladder rung — never silent loss
 - scheduler_admission_queue_depth — admission queue depth (pending
-  pods across all queue tiers plus pods coalescing in the multi-cycle
-  buffers) as of the last submit or cycle
+  pods across all queue tiers) as of the last submit or cycle
 - scheduler_submit_ack_seconds — submit-to-ack latency of ACCEPTED
   submissions, including the WAL-before-ack group-fsync barrier (the
   durability contract's cost, paid off the scheduling hot path)
@@ -206,8 +178,7 @@ cmd/main.py startup stamp):
 
 - scheduler_trace_spans_total{name} — trace spans recorded, by span
   name: a pod's life through the front door (submit.validate |
-  submit.journal | ack.barrier | mc.buffer_wait | encode.ingest |
-  flush.finalize | dispatch | dispatch.speculative | decision.row |
+  submit.journal | ack.barrier | dispatch | decision.row |
   apply.fold | bind.confirm | preempt.victim) and the agent path's
   RPCs, per RPC and per phase (rpc.update | update.convert |
   update.apply | rpc.cycle | cycle.lock_wait | cycle.pop |
@@ -486,23 +457,6 @@ class SchedulerMetrics:
             "flight-record commit.",
             registry=r,
         )
-        # ---- admission-time incremental encode (models/encoding.py) ----
-        self.encode_ingest = Histogram(
-            "scheduler_encode_ingest_seconds",
-            "Admission-time incremental encode: per-group cost of parsing "
-            "acked pods into staged row data in the ack path's shadow "
-            "(work moved off the flush critical path).",
-            buckets=_DURATION_BUCKETS,
-            registry=r,
-        )
-        self.encode_finalize = Histogram(
-            "scheduler_encode_finalize_seconds",
-            "Flush-time residue of the incremental encode: folding staged "
-            "rows into the packed arena at multi-cycle flush (what is "
-            "left of the old O(P) rebuild).",
-            buckets=_DURATION_BUCKETS,
-            registry=r,
-        )
         # ---- flight-recorder derived gauges (core/flight_recorder.py) ----
         self.pipeline_overlap = Gauge(
             "scheduler_pipeline_overlap_ratio",
@@ -563,8 +517,7 @@ class SchedulerMetrics:
             "scheduler_anomalies_total",
             "Typed anomaly detections from the cycle observer "
             "(tunnel_stall | fetch_stall | recompile | fold_miss | "
-            "wedge_precursor | round_cap_hit | degraded | "
-            "speculation_thrash); each "
+            "wedge_precursor | round_cap_hit | degraded); each "
             "has a structured /debug/anomalies event carrying the "
             "cycle seq.",
             ["class"],
@@ -581,28 +534,6 @@ class SchedulerMetrics:
             "scheduler_slo_budget_remaining",
             "Fraction of the slow-window SLO violation budget left "
             "(1.0 = untouched, negative = overspent).",
-            registry=r,
-        )
-        # ---- multi-cycle serving (core/scheduler.py) ----
-        self.multicycle_batch = Histogram(
-            "scheduler_multicycle_batch_cycles",
-            "Inner scheduling cycles per multi-cycle device dispatch "
-            "(multiCycleK coalescing; 1 = a degenerate batch).",
-            buckets=(1, 2, 4, 8, 16, 32),
-            registry=r,
-        )
-        self.multicycle_cycles = Counter(
-            "scheduler_multicycle_inner_cycles_total",
-            "Scheduling cycles served through multi-cycle dispatches "
-            "(each paid dispatch_rt/K instead of a full round trip).",
-            registry=r,
-        )
-        self.speculation = Counter(
-            "scheduler_speculation_total",
-            "Depth-2 speculative dispatch outcomes (adopted | abandoned"
-            " | redispatched): batches dispatched against the predicted"
-            " post-predecessor carry while it was still on device.",
-            ["outcome"],
             registry=r,
         )
         # ---- multi-chip serving (ops/argsel.py + parallel/) ----
@@ -681,8 +612,7 @@ class SchedulerMetrics:
         self.admission_queue_depth = Gauge(
             "scheduler_admission_queue_depth",
             "Admission queue depth (pending pods across all queue "
-            "tiers + pods coalescing in the multi-cycle buffers) as of "
-            "the last submit or cycle.",
+            "tiers) as of the last submit or cycle.",
             registry=r,
         )
         self.submit_ack = Histogram(

@@ -499,17 +499,6 @@ class SnapshotEncoder:
         self.fold_declined = 0
         # per-segment ms of the LAST delta encode (see _encode_delta)
         self.delta_profile: dict[str, float] = {}
-        # admission-time incremental encode (ingest/finalize split, PR 16):
-        # rows parsed ahead of the flush, keyed by id(pod) with a strong
-        # pod ref pinned so the id cannot be recycled. `ingest_hits` counts
-        # dirty slots whose flush-time parse was skipped because a staged
-        # row was waiting; `ingest_misses` counts ingest_pod calls that
-        # could not stage (no delta state yet, or no rowdata closure).
-        self._staged: dict[int, tuple[Any, dict]] = {}
-        self._staged_grew = False  # ingest grew an interning table
-        self.ingest_hits = 0
-        self.ingest_misses = 0
-        self._ingest_ms = 0.0  # accumulated staging ms since last flush
 
     def hysteresis_pad(self, dim: str, candidate: int, real: int) -> int:
         """Regime hysteresis for the externally-bucketed P/N pads: the
@@ -1859,48 +1848,6 @@ class SnapshotEncoder:
         for name, pad in self._PEND_SCALAR_PAD.items():
             A[name][sl] = pad
 
-    def ingest_pod(self, pod: Pod) -> bool:
-        """Admission-time incremental encode: parse `pod`'s arena row
-        NOW — in the shadow of the buffer/ack path — so the flush-time
-        delta encode finds it staged and skips the parse (the `ingest`
-        segment of delta_profile becomes hidden host time and the flush
-        is an O(dirty) apply instead of an O(P) parse+apply).
-
-        Interning growth caused by the staging parse is recorded in
-        `_staged_grew`: the delta path's table-stability invariant is
-        checked against the LAST stash, so the next encode_packed must
-        take the full path ONCE to give the stable-side tables their
-        new entries — after which every later group in a multi-cycle
-        batch deltas against the grown tables instead of triggering the
-        whole-batch double re-encode.
-
-        Serve-thread only (the encoder is not thread-safe). Returns
-        True if a row was staged."""
-        ds = self._delta_state
-        if ds is None:
-            self.ingest_misses += 1
-            return False
-        import time as _time
-
-        t0 = _time.perf_counter()
-        lens0 = self._table_lens()
-        try:
-            d = ds["pod_rowdata"](pod)
-        except Exception:
-            self.ingest_misses += 1
-            return False
-        if self._table_lens() != lens0:
-            self._staged_grew = True
-        self._staged[id(pod)] = (pod, d)
-        self._ingest_ms += (_time.perf_counter() - t0) * 1e3
-        return True
-
-    def clear_ingest(self) -> None:
-        """Drop staged rows the flush did not consume (pods dropped or
-        shed between buffer and flush). Called at flush end so staging
-        memory is bounded by one buffered batch."""
-        self._staged.clear()
-
     def encode_packed(
         self,
         nodes: Sequence[Node],
@@ -1926,12 +1873,6 @@ class SnapshotEncoder:
         array fields are views into the buffers, and `dirty` names the
         rewritten pod slots (None = full rebuild)."""
         ds = self._delta_state
-        if self._staged_grew:
-            # an ingest parse grew an interning table: the stable-side
-            # tables need the new entries, so rebuild once (later groups
-            # in the same flush delta against the grown tables)
-            self._staged_grew = False
-            ds = None
         if ds is not None and self._arena_spec is not None:
             ok = self._delta_precheck(
                 ds, nodes, existing, pvcs, pvs, storage_classes, pdbs
@@ -2345,12 +2286,6 @@ class SnapshotEncoder:
         if fold_ms is not None:
             _prof["fold"] = fold_ms
             self._fold_ms = None
-        if self._ingest_ms:
-            # staging time already spent in ingest_pod's shadow — kept
-            # as its own segment so encode-budget attribution shows the
-            # parse cost that the flush no longer pays
-            _prof["ingest"] = self._ingest_ms
-            self._ingest_ms = 0.0
 
         def _mark(name):
             nonlocal _t0
@@ -2395,22 +2330,9 @@ class SnapshotEncoder:
         fb_slots = []  # their arena slots
         port_set = ds["port_set"]
         creation = ds["creation"]
-        # ingest split: dirty slots whose row was staged by ingest_pod
-        # skip the flush-time parse entirely — their cached rowdata dict
-        # goes through the batched apply below, so the flush pays only
-        # the arena write
-        staged = self._staged
-        ing_slots: set[int] = set()
-        if staged:
-            for i in dirty:
-                p = pending[i]
-                ent = staged.get(id(p))
-                if ent is not None and ent[0] is p:
-                    ing_slots.add(i)
-        fd = [i for i in dirty if i not in ing_slots] if ing_slots else dirty
         fused = native.pod_rows_into
         fused_res = None
-        if fused is not None and fd:
+        if fused is not None and dirty:
             # fused fast path (PERF.md round-5): ONE native call parses
             # every dirty pod and writes its arena row + creation column
             # directly — no 26-key rowdata dict, no apply_rows re-read.
@@ -2435,28 +2357,17 @@ class SnapshotEncoder:
                 }
                 ds["into_limits"] = limits
             guard_ok, fused_res = fused(
-                [pending[i] for i in fd], self._native_ctx(),
-                np.asarray(fd, np.int64), specs2, limits,
+                [pending[i] for i in dirty], self._native_ctx(),
+                np.asarray(dirty, np.int64), specs2, limits,
             )
             if not guard_ok:
                 return None  # arena dims too small: full re-encode
-        fused_map: dict[int, Any] = {}
-        if fused_res is not None:
-            for j, i in enumerate(fd):
-                fused_map[i] = fused_res[j]
-        for i in dirty:
+        for j, i in enumerate(dirty):
             p = pending[i]
             ids[i] = id(p)
             refs[i] = p
-            if i in ing_slots:
-                # staged at ingest: rowdata is a _pod_cache hit (the
-                # parse already ran in the buffer path's shadow)
-                staged.pop(id(p), None)
-                self.ingest_hits += 1
-                r = None
-            else:
-                r = fused_map.get(i)
-            if r is None:  # staged, no native builder, or dict-path pod
+            r = fused_res[j] if fused_res is not None else None
+            if r is None:  # no native builder, or pod needs dict path
                 d = rowdata(p)
                 new_rows.append(d)
                 fb_slots.append(i)

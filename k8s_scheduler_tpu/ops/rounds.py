@@ -296,9 +296,10 @@ def rounds_commit(
     # views carry an explicit with_sharding_constraint over the mesh
     # axes (parallel/mesh.MESH_AXES), so the one-hot compaction's psum
     # lowers to a reduce-scatter of the PARTITIONED view instead of
-    # all-reducing a replicated [B, N] (the single largest collective in
-    # AUDIT_SHARDED_r05: 23.6 MB of 43.2 MB total). None (the default,
-    # and every single-device build) changes nothing.
+    # all-reducing a replicated [B, N] (the largest single collective of
+    # the sharded cycle before the diet; its cost on the chip is not
+    # measured). None (the default, and every single-device build)
+    # changes nothing.
     sample=None,  # (off i32 [P], k i32 []) | None — percentageOfNodesTo-
     # Score (core/cycle.node_sample): each round, a pod's candidates are
     # the first k nodes FEASIBLE FOR IT IN THAT ROUND'S STATE, in its
@@ -744,8 +745,9 @@ def rounds_commit(
                 # contraction over the pods axis then lowers to a
                 # reduce-scatter of the partitioned [B, N] view instead
                 # of all-reducing a replicated one — at the audit shape
-                # that single collective was 23.6 MB of the 43.2 MB
-                # per-cycle total (AUDIT_SHARDED_r05)
+                # that single collective was over half the cycle's
+                # payload (scripts/audit_sharded.py counts it from the
+                # compiled HLO; not measured on the chip)
                 vsbase = shard_view(vsbase)
             else:
                 vsbase = sbase[gid]

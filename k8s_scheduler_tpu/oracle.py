@@ -1554,8 +1554,8 @@ def schedule_cycle_trace(
     """One full scheduling cycle under trace semantics: sequential
     greedy scheduling, gang unwind, FailedScheduling attribution, and
     the preemption pass — the oracle half of the fuzz differential.
-    Callers that replay multi-cycle traces own the queue/cache state
-    between cycles (fuzz/replay.py drives the SAME SchedulingQueue /
+    Callers that replay traces of several cycles own the queue/cache
+    state between cycles (fuzz/replay.py drives the SAME SchedulingQueue /
     SchedulerCache classes the live Scheduler uses, so the differential
     isolates the decision engine, not the host bookkeeping)."""
     weights = weights or OracleWeights()

@@ -2,9 +2,10 @@
 payload accounting (ISSUE 10 / ROADMAP item 3).
 
 At the "millions of users" cluster sizes the north star names, the
-carry cycle's cross-device traffic — not FLOPs — is the cycle floor:
-AUDIT_SHARDED_r05 measured ~43.2 MB of collectives per carry cycle at
-the P=10112/N=5120 audit shape, 23.6 MB of it one all-reduce of the
+carry cycle's cross-device traffic — not FLOPs — is expected to be the
+cycle floor (not measured on the chip): before the payload diet the
+compiled cycle at the P=10112/N=5120 audit shape moved over ten times
+the bytes it moves now, more than half of them in one all-reduce of the
 replicated compacted [B, N] static base. This module turns that
 accounting into a COMMITTED, compile-only gate:
 
@@ -12,9 +13,9 @@ accounting into a COMMITTED, compile-only gate:
   one record per collective op (all-reduce / all-gather / reduce-
   scatter / all-to-all / collective-permute, sync and `-start` async
   forms, tuple-shaped results included) with element counts and bytes
-  under two payload models: real dtype widths (`bytes`) and the r05
-  artifact's flat 4-bytes-per-element model (`flat4` — kept so new
-  audits stay comparable with the committed AUDIT_SHARDED_r05 total).
+  under two payload models: real dtype widths (`bytes`) and a flat
+  4-bytes-per-element model (`flat4`, the first audit's, kept so
+  audits stay comparable across rounds).
 - `classify` buckets each record into the budget classes of
   `COLLECTIVE_BUDGETS` — the committed allowlist `scripts/
   audit_sharded.py` asserts against, pinned by schedlint ID008 to the
@@ -36,21 +37,21 @@ import re
 
 # Budget classes x per-cycle budgets (MB, REAL dtype widths) for the
 # carry-cycle program at the audit shape (P=10112, N=5120, 8-device
-# 1-D pods mesh — the AUDIT_SHARDED_r05 geometry). schedlint ID008
+# 1-D pods mesh). schedlint ID008
 # pins every class name here to a row of the README "## Multi-chip and
 # multi-host" budget table; scripts/audit_sharded.py asserts the
 # measured per-class totals against these numbers and the grand total
-# against TOTAL_BUDGET_MB. Calibration: measured post-diet values plus
-# ~25% headroom, far below the 43.2 MB r05 baseline the acceptance
-# criterion bounds (>= 30% reduction).
+# against TOTAL_BUDGET_MB. Calibration: the post-diet values the
+# audit counts from the compiled HLO plus ~25% headroom.
 COLLECTIVE_BUDGETS = {
     # f32 planes of the [B, N]/[S, N] class: the compacted static-base
     # transport and the affinity-state count tables. Post-diet this is
     # ZERO — the compacted view stays sharded end-to-end (shard_view)
     # and the state update runs device-local (local_update_fn), where
-    # r05 paid a 23.6 MB replicated-view all-reduce here. The budget is
-    # small headroom, not an allowance: any [.,N]-wide f32 collective
-    # reappearing is a diet regression and should trip this row.
+    # the cycle before the diet paid a replicated-view all-reduce. The
+    # budget is small headroom, not an allowance: any [.,N]-wide f32
+    # collective reappearing is a diet regression and should trip this
+    # row.
     "static_base": 2.0,
     # claim/participant-table sort operands (packed u32 keys + index
     # permutations + per-claim key vectors) gathered across the pods
@@ -69,10 +70,9 @@ COLLECTIVE_BUDGETS = {
     # cannot hide here
     "other": 1.0,
 }
-# grand total (real dtype widths). Measured 3.62 MB post-diet at the
-# audit shape vs AUDIT_SHARDED_r05's 43.2 MB (-91%); the ISSUE 10
-# acceptance bound is <= 30.2 MB (a 30% reduction) — this budget holds
-# the diet at ~2x measured, an order of magnitude tighter.
+# grand total (real dtype widths). The audit counts 3.62 MB post-diet
+# at the audit shape (compiled HLO, not a chip run); this budget holds
+# the diet at ~2x that.
 TOTAL_BUDGET_MB = 8.0
 
 _COLL_RE = re.compile(

@@ -1,8 +1,8 @@
 """The submission front door: admission control, WAL-before-ack, drain.
 
-ROADMAP item 1's serving edge. Everything below the queue is fast
-(multi-cycle batching, depth-2 speculation), shard-exact, and
-chaos-hardened — this module is where live traffic meets it. Two pieces:
+ROADMAP item 1's serving edge. Everything below the queue is fast,
+shard-exact, and chaos-hardened — this module is where live traffic
+meets it. Two pieces:
 
 - `AdmissionController` — the admission layer behind the Submit /
   NodeChurn RPCs (service/server.py) and the debug server's thin
@@ -16,8 +16,8 @@ chaos-hardened — this module is where live traffic meets it. Two pieces:
     journaled.
   * **shed** — explicit backpressure, RESOURCE_EXHAUSTED with a
     retry-after hint, when admitting the request would push the
-    admission queue (pending pods across all tiers + pods coalescing in
-    the multi-cycle buffers) past `admissionQueueDepth`, when the SLO
+    admission queue (pending pods across all the queue's tiers) past
+    `admissionQueueDepth`, when the SLO
     fast-burn gauge fires (core/observe.SloEngine.degraded), or when
     the degradation ladder sits below rung 0. Overload degrades to
     shedding — never to unbounded memory, never to silent latency.
@@ -42,8 +42,8 @@ chaos-hardened — this module is where live traffic meets it. Two pieces:
   `Cycle` RPC has no caller when arrivals come over the wire). Its
   `stop()` is the graceful-drain contract: admission closes (late
   submits get UNAVAILABLE "draining"), the loop keeps cycling until
-  the active tier and every multi-cycle coalescing buffer are empty —
-  no pod stranded between ack and dispatch — and only then does the
+  the active tier is empty — no pod stranded between ack and
+  dispatch — and only then does the
   caller seal durable state.
 
 Thread model: `submit`/`node_churn` run on gRPC/HTTP worker threads;
@@ -162,18 +162,9 @@ class AdmissionController:
     # ---- depth ------------------------------------------------------------
 
     def queue_depth(self) -> int:
-        """Pending pods across all queue tiers plus pods buffered in
-        the multi-cycle coalescing groups (popped but not dispatched).
-        Approximate by design — the serve loop mutates the buffers
-        concurrently — which is fine for a shed bound: the queue's own
-        lock makes each component read consistent, and the bound is a
-        memory guard, not an exactness contract."""
-        s = self.scheduler
-        n = len(s.queue)
-        for bufs in s._mc_groups.values():
-            for _t, group in bufs:
-                n += len(group)
-        return n
+        """Pending pods across all the queue's tiers: the shed
+        bound's memory guard."""
+        return len(self.scheduler.queue)
 
     # ---- admission history (the /debug/explain join) ----------------------
 
@@ -345,9 +336,8 @@ class AdmissionController:
             now = self.scheduler._now()
             for p in pods:
                 # bind the trace context BEFORE the enqueue: the serve
-                # loop can pop and flush the pod the instant queue.add
-                # releases, and its mc.buffer_wait/dispatch spans join
-                # the trace by uid lookup
+                # loop can pop the pod the instant queue.add releases,
+                # and its dispatch spans join the trace by uid lookup
                 if _spans.ARMED:
                     c = _spans.register(
                         p.uid, traceparent,
@@ -673,10 +663,6 @@ class FrontDoor:
         )
         self._thread.start()
 
-    def _buffered(self) -> bool:
-        s = self.scheduler
-        return any(s._mc_groups.values())
-
     def _run(self) -> None:
         try:
             self._run_loop()
@@ -724,18 +710,15 @@ class FrontDoor:
                 self._stop.wait(self._failure_backoff)
                 continue
             if self._draining.is_set():
-                # drain condition: nothing ready AND nothing coalescing
-                # (backoff/unschedulable pods are durable in the sealed
-                # state and legitimately outlive the drain — they are
-                # parked, not stranded between ack and dispatch)
-                if (
-                    s.queue.pending_counts().get("active", 0) == 0
-                    and not self._buffered()
-                ):
+                # drain condition: nothing ready (backoff/unschedulable
+                # pods are durable in the sealed state and legitimately
+                # outlive the drain — they are parked, not stranded
+                # between ack and dispatch)
+                if s.queue.pending_counts().get("active", 0) == 0:
                     self._drained.set()
                     return
                 continue  # drain at full cadence, no idle sleep
-            if stats.attempted == 0 and not self._buffered():
+            if stats.attempted == 0:
                 self._stop.wait(self._idle_sleep)
 
     def begin_drain(self) -> None:
@@ -744,8 +727,8 @@ class FrontDoor:
         self._draining.set()
 
     def stop(self, drain: bool = True, timeout: float = 30.0) -> bool:
-        """Graceful shutdown: close admission, flush every buffered
-        group, stop the loop, join the thread. Returns True when the
+        """Graceful shutdown: close admission, empty the active tier,
+        stop the loop, join the thread. Returns True when the
         drain completed (False = timeout; the journal tail still holds
         every acked pod, so nothing is lost either way)."""
         drained = True
@@ -755,13 +738,12 @@ class FrontDoor:
             if not drained:
                 log.warning(
                     "front door drain did not complete within %.1fs "
-                    "(active=%d, buffered=%s) — stopping anyway; the "
+                    "(active=%d) — stopping anyway; the "
                     "journal tail covers the remainder",
                     timeout,
                     self.scheduler.queue.pending_counts().get(
                         "active", 0
                     ),
-                    self._buffered(),
                 )
         self._stop.set()
         thread = self._thread

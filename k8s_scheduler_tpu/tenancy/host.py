@@ -80,7 +80,6 @@ class TenantFrontHost:
         )
         self.queue = _ArenaQueueView(registry)
         self.cache = _ArenaCacheView(registry)
-        self._mc_groups: dict = {}  # no multi-cycle buffers in arena mode
         self.ladder = _NoLadder()
         self.state = state  # DurableState-shaped ack-barrier provider
         self.admission = None  # AdmissionController installs itself

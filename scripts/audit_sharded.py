@@ -8,20 +8,19 @@ budget allowlist).
     python scripts/audit_sharded.py --devices 8 --pods 10112 --nodes 5120
 
 Builds the production carry-cycle program at the AUDIT SHAPE
-(P=10112 x N=5120, the BENCH config-4 padded geometry AUDIT_SHARDED_r05
-measured 43.2 MB/cycle on) over an N-device 1-D ('pods',) virtual CPU
-mesh, compiles it with the carry partitioned — NO execution, so the
-[P, N] arrays are never materialized — and parses every collective out
-of the compiled HLO. The per-class totals are asserted against
+(P=10112 x N=5120, a 10,000 x 5,000 cluster's padded geometry) over
+an N-device 1-D ('pods',) virtual CPU mesh, compiles it with the carry
+partitioned — NO execution, so the [P, N] arrays are never
+materialized — and parses every collective out of the compiled HLO. The per-class totals are asserted against
 `parallel/audit.COLLECTIVE_BUDGETS` and the grand total against
 `TOTAL_BUDGET_MB`; schedlint ID008 pins those class names to the README
 budget table and the mesh-axis names, so the allowlist can only move
 together with its documentation.
 
-Output format follows the AUDIT_SHARDED_r05 artifact (shape counts,
-payload totals under BOTH the real-dtype-width model and r05's flat
-4-bytes-per-element model, budget verdict, rc) so rounds stay
-diffable. Exit: 0 within budget, 1 over budget, 2 build error.
+Output: shape counts, payload totals under BOTH the real-dtype-width
+model and the flat 4-bytes-per-element model, budget verdict, rc — one
+format from round to round, so audits stay diffable. Exit: 0 within
+budget, 1 over budget, 2 build error.
 """
 
 from __future__ import annotations

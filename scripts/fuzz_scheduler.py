@@ -13,10 +13,9 @@ trace; PR 8 soak invariants) across device counts {1, 4}:
     python scripts/fuzz_scheduler.py --seed 1 --inject-bug tiebreak
 
 Every failure is stamped `FUZZ-FAIL seed=<s> devices=<d> chaos=<0|1>
-mc=<0|1> bug=<name> fault_spec=<spec> class=<cls>` — the run is
+api=<0|1> bug=<name> fault_spec=<spec> class=<cls>` — the run is
 reproducible from that log line alone (`--seed/--devices/--chaos/
---multi-cycle/--speculative/--incremental/--inject-bug` re-derive the
-identical trace) — then
+--via-api/--inject-bug` re-derive the identical trace) — then
 shrunk to a minimal repro and written as a corpus artifact
 (fuzz/corpus.py format) under --artifact-dir for triage or promotion
 into tests/corpus/.
@@ -51,9 +50,6 @@ def _stamp(trace, bug, failure, via_api=False) -> str:
         f"FUZZ-FAIL seed={trace.seed} "
         f"devices={max(int(trace.config.get('shard_devices', 0)), 1)} "
         f"chaos={int(trace.chaos)} "
-        f"mc={int(int(trace.config.get('multi_cycle_k', 1)) > 1)} "
-        f"spec={int(bool(trace.config.get('speculative_dispatch')))} "
-        f"inc={int(bool(trace.config.get('incremental_encode')))} "
         f"api={int(via_api)} "
         f"bug={bug or '-'} fault_spec={trace.fault_spec or '-'} "
         f"class={failure.cls}"
@@ -78,9 +74,8 @@ def _run_with_tmp_state(trace, bug, via_api=False):
         return run_case(trace, state_dir=sd, bug=bug)
 
 
-def run_one(seed, *, devices, chaos, multi_cycle, bug, artifact_dir,
-            shrink, shrink_evals, speculative=False, incremental=False,
-            via_api=False) -> "tuple[int, str | None]":
+def run_one(seed, *, devices, chaos, bug, artifact_dir, shrink,
+            shrink_evals, via_api=False) -> "tuple[int, str | None]":
     """Returns (n_failures, artifact_path | None)."""
     from k8s_scheduler_tpu.fuzz import (
         generate_trace,
@@ -88,10 +83,7 @@ def run_one(seed, *, devices, chaos, multi_cycle, bug, artifact_dir,
         shrink_trace,
     )
 
-    trace = generate_trace(
-        seed, devices=devices, chaos=chaos, multi_cycle=multi_cycle,
-        speculative=speculative, incremental=incremental,
-    )
+    trace = generate_trace(seed, devices=devices, chaos=chaos)
     failures = _run_with_tmp_state(trace, bug, via_api=via_api)
     if not failures:
         return 0, None
@@ -133,16 +125,6 @@ def main() -> int:
     ap.add_argument("--devices", type=int, default=0,
                     help="shardDevices for --seed runs (soak mixes 1/4)")
     ap.add_argument("--chaos", action="store_true")
-    ap.add_argument("--multi-cycle", action="store_true")
-    ap.add_argument("--speculative", action="store_true",
-                    help="depth-2 speculative dispatch pipelining over "
-                    "the coalesced batches (forces --multi-cycle)")
-    ap.add_argument("--incremental", action="store_true",
-                    help="admission-time incremental encode variant "
-                    "(forces --multi-cycle): the same trace runs with "
-                    "incrementalEncode on AND off and must produce "
-                    "byte-identical packed arenas and bit-equal "
-                    "decision streams")
     ap.add_argument("--via-api", action="store_true",
                     help="arrivals_via_api variant: route every pod "
                     "arrival through a real gRPC Submit round trip and "
@@ -207,23 +189,18 @@ def main() -> int:
     if args.seed is not None:
         n, _p = run_one(
             args.seed, devices=args.devices, chaos=args.chaos,
-            multi_cycle=args.multi_cycle or None,
-            speculative=args.speculative,
-            incremental=args.incremental, via_api=args.via_api, **kw,
+            via_api=args.via_api, **kw,
         )
         print(json.dumps({"seed": args.seed, "failures": n}), flush=True)
         return 1 if n else 0
 
-    # the soak: plain, chaos, speculative-depth-2, incremental-encode,
-    # and arrivals-via-API cases interleaved, devices {1, 4} —
-    # (seed, devices, chaos, speculative, incremental, via_api)
+    # the soak: plain, chaos and arrivals-via-API cases interleaved,
+    # devices {1, 4} — (seed, devices, chaos, via_api)
     seeds = (
-        [(s, 1, False, False, False, False) for s in range(100, 103)]
-        + [(110, 4, False, False, False, False),
-           (111, 1, True, False, False, False),
-           (112, 1, False, True, False, False),
-           (114, 1, False, False, True, False),
-           (113, 1, False, False, False, True)]
+        [(s, 1, False, False) for s in range(100, 103)]
+        + [(110, 4, False, False),
+           (111, 1, True, False),
+           (113, 1, False, True)]
     ) if args.smoke else None
     deadline = None if args.smoke else time.time() + args.minutes * 60
     total = failures_n = cases = 0
@@ -233,8 +210,7 @@ def main() -> int:
         if seeds is not None:
             if cases >= len(seeds):
                 break
-            (s, devices, chaos, speculative, incremental,
-             via_api) = seeds[cases]
+            s, devices, chaos, via_api = seeds[cases]
         else:
             if time.time() >= deadline or failures_n >= 5:
                 break
@@ -242,22 +218,12 @@ def main() -> int:
             seed += 1
             devices = 4 if s % 4 == 3 else 1
             chaos = s % 5 == 2
-            # every seventh case pipelines depth-2 over the coalesced
-            # batches (forces mc; disjoint from nothing — it composes
-            # with chaos and sharding alike)
-            speculative = s % 7 == 1
-            # every thirteenth non-chaos case runs the same trace with
-            # incrementalEncode on AND off (chaos traces return before
-            # the on/off comparison, so they would not exercise it)
-            incremental = s % 13 == 2 and not chaos
             # every eleventh plain case routes arrivals through the
             # real Submit/NodeChurn RPCs (engine-vs-engine; chaos and
             # bug injection stay with the oracle differential)
-            via_api = s % 11 == 4 and not chaos and not speculative
+            via_api = s % 11 == 4 and not chaos
         n, path = run_one(
-            s, devices=devices, chaos=chaos, multi_cycle=None,
-            speculative=speculative, incremental=incremental,
-            via_api=via_api, **kw
+            s, devices=devices, chaos=chaos, via_api=via_api, **kw
         )
         cases += 1
         total += n

@@ -58,7 +58,6 @@ def front_door_drive(
     state_dir: str = "",
     fault_spec: str = "",
     deadline_ms: float = 0.0,
-    multi_cycle_k: int = 4,
     drain_timeout_s: float = 60.0,
     promote_cycles: int = 4,
     name_prefix: str = "ld",
@@ -97,8 +96,6 @@ def front_door_drive(
         state = DurableState(state_dir, snapshot_interval_seconds=0)
     cfg_obj = SchedulerConfiguration(
         admission_queue_depth=queue_depth,
-        multi_cycle_k=multi_cycle_k,
-        multi_cycle_max_wait_ms=5.0,
         dispatch_deadline_ms=deadline_ms,
         degrade_promote_cycles=promote_cycles,
         fault_spec=fault_spec,

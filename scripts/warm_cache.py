@@ -10,7 +10,7 @@ rig; ~100 s historical worst case on a regime flip).
 
     python scripts/warm_cache.py --cache-dir /var/lib/sched/compile_cache \
         --pods 10000 --nodes 5000 [--config scheduler.yaml] \
-        [--adjacent 1] [--multi-cycle-k 8]
+        [--adjacent 1]
 
 `--adjacent N` also pre-builds N pad-bucket regimes above the given pod
 count — the regimes churn would otherwise flip into mid-serve.
@@ -41,8 +41,6 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--pad-bucket", type=int, default=64)
     ap.add_argument("--adjacent", type=int, default=1,
                     help="extra P pad buckets above --pods to pre-build")
-    ap.add_argument("--multi-cycle-k", type=int, default=0,
-                    help="also warm the multi-cycle batch program for K")
     args = ap.parse_args(argv)
 
     cache_dir = args.cache_dir or (
@@ -69,8 +67,6 @@ def main(argv: list[str] | None = None) -> int:
     )
     config.compile_cache_dir = cache_dir
     config.speculative_compile = False  # builds run HERE, synchronously
-    if args.multi_cycle_k > 1:
-        config.multi_cycle_k = args.multi_cycle_k
     sched = Scheduler(config=config, pad_bucket=args.pad_bucket)
     nodes = make_cluster(args.nodes)
     pending = make_pods(args.pods, seed=1)
@@ -86,8 +82,6 @@ def main(argv: list[str] | None = None) -> int:
             spec = packing.make_spec(snap)
             t0 = time.perf_counter()
             sched._packed_fns(spec, profile)
-            if config.multi_cycle_k > 1:
-                sched._mc_programs(spec, profile)
             total += 1
             print(
                 f"profile={profile} P={enc.pad_pods} "

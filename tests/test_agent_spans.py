@@ -493,8 +493,8 @@ def test_agent_rpcs_render_on_one_lane(armed):
     assert pod_ev["pid"] == _spans.TRACE_TRACK_PID
 
 
-def test_span_inventory_has_twenty_nine_names():
-    assert len(SPAN_NAMES) == len(set(SPAN_NAMES)) == 29
+def test_span_inventory_has_twenty_five_names():
+    assert len(SPAN_NAMES) == len(set(SPAN_NAMES)) == 25
     # the agent's own six (service/client.py) have a lane to themselves
     assert set(_spans.CLIENT_SPAN_NAMES) == {
         n for n in SPAN_NAMES if n.startswith("client.")}

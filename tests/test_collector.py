@@ -506,8 +506,28 @@ def test_gc_sweeps_deferred_per_cycle_names_every_cell_and_a_kept_count():
         assert 'rec.counts["%s"]' % spec["select"][0] in f.read()
 
 
+@pytest.fixture()
+def measured_shape_run(svc):
+    """Throw-away cycles of the shape `[freeze]` below measures, run
+    before the policy is installed (its departures are not the
+    policy's): a process's first such cycle compiles its pad regime
+    and its second, the first to encode by delta, the carry's update
+    program. Either allocates enough for the interpreter to start full
+    passes of its own, which a measured cycle would stamp `auto_full`
+    beside the one pass it expects."""
+    add_pods(svc, 2 * PER_ROUND)
+    cycle_and_confirm(svc, leak=None)
+    for _ in range(2):
+        add_pods(svc)
+        delete_pods(svc, [b.pod_uid for b in svc.Cycle(
+            pb.CycleRequest(), None).bindings])
+    delete_pods(svc, [
+        p.uid for p, _n in svc.scheduler.cache.existing_pods()])
+
+
 @pytest.mark.parametrize("kind", ["freeze", "sweep", "auto_full"])
-def test_each_operation_stamps_one_pass_when_armed(svc, policy, armed, kind):
+def test_each_operation_stamps_one_pass_when_armed(
+        svc, measured_shape_run, policy, armed, kind):
     add_pods(svc, 2 * PER_ROUND)
     uids = cycle_and_confirm(svc)
     before = len(passes(armed))

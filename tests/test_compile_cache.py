@@ -4,7 +4,7 @@ fingerprint mismatches are refused loudly and recompiled, never crashed
 on), atomic concurrent writes, the AOT load-or-compile path, the
 adjacent-regime spec rewrite (packing.respec) against real encodes, pad
 hysteresis (an oscillating workload holds the larger regime), the
-_mc_fns LRU eviction regression, and the slow-tier end-to-end proofs:
+program memo's LRU eviction regression, and the slow-tier end-to-end proofs:
 warm restart with zero cold compiles, and a speculation-won flip with
 compile_ms ~= 0."""
 
@@ -406,7 +406,7 @@ def test_hysteresis_holds_regime_under_oscillating_trace():
     assert held[1:] == [held[1]] * 7  # larger regime held throughout
 
 
-# ---- _mc_fns LRU eviction regression ------------------------------------
+# ---- program-memo LRU eviction regression -------------------------------
 
 
 class _FakeSpec:
@@ -415,38 +415,6 @@ class _FakeSpec:
 
     def key(self):
         return self._k
-
-
-def test_mc_fns_eviction_is_true_lru(monkeypatch):
-    """Satellite regression: `next(iter(...))` popped FIFO insertion
-    order, so the HOTTEST multi-cycle regime could be evicted while a
-    cold one stayed. A hit must move the entry to the end."""
-    from k8s_scheduler_tpu.core import cycle as cycle_mod
-
-    monkeypatch.setattr(
-        cycle_mod, "build_packed_multicycle_fn",
-        lambda spec, **kw: ("mfn", spec.key()),
-    )
-    monkeypatch.setattr(
-        cycle_mod, "build_diagnosis_fn",
-        lambda spec, fw=None, **kw: ("diag", spec.key()),
-    )
-    s = Scheduler(
-        config=SchedulerConfiguration(
-            multi_cycle_k=4, flight_recorder_size=0
-        )
-    )
-    cap = 4 * len(s.frameworks)
-    profile = s._profile_order[0]
-    for i in range(cap):
-        s._mc_programs(_FakeSpec(f"regime{i}"), profile)
-    # regime0 is the FIFO-oldest; a HIT must make it the LRU-newest
-    s._mc_programs(_FakeSpec("regime0"), profile)
-    s._mc_programs(_FakeSpec(f"regime{cap}"), profile)  # evicts one
-    keys = {k[0] for k in s._mc_fns}
-    assert "regime0" in keys       # hot regime survived the eviction
-    assert "regime1" not in keys   # the actually-coldest one went
-    assert len(s._mc_fns) == cap
 
 
 def test_packed_memo_eviction_is_true_lru(monkeypatch):

@@ -218,10 +218,9 @@ def test_scheduler_sticky_retrace_reclears_program_memos():
     sched = Scheduler(binder=lambda p, n: None)
     bottom = len(RUNGS) - 1
     sched._packed[("stale-regime", "default-scheduler")] = {"fns": ()}
-    sched._mc_fns[("stale-regime", "default-scheduler")] = {"fns": ()}
     sched._dev_stable[("stale", 0, 0)] = (None, None)
     sched._on_rung_transition(bottom, bottom, "still failing")
-    assert not sched._packed and not sched._mc_fns
+    assert not sched._packed
     assert not sched._dev_stable
     # ...and a promotion (new < old) must NOT clear a live regime
     sched._packed[("live-regime", "default-scheduler")] = {"fns": ()}
@@ -427,44 +426,6 @@ def test_wedge_degrades_but_transport_and_corrupt_are_absorbed():
     assert sched.ladder.degradations == 1
     # wedge_precursor anomalies recorded the absorbed strikes
     assert sched.observer.anomaly_counts["wedge_precursor"] >= 1
-
-
-def test_sequential_rung_drains_buffered_multicycle_groups():
-    """Degrading to the `sequential` rung while multi-cycle groups are
-    still coalescing must DRAIN them as single-cycle dispatches — a
-    stranded buffer's pods would be neither queued nor in-flight."""
-    from k8s_scheduler_tpu.config import SchedulerConfiguration
-    from k8s_scheduler_tpu.core.degrade import RUNG_SEQUENTIAL
-    from k8s_scheduler_tpu.core.scheduler import Scheduler
-    from k8s_scheduler_tpu.utils.synth import make_cluster, make_pods
-
-    binds: list[str] = []
-    sched = Scheduler(
-        config=SchedulerConfiguration(
-            multi_cycle_k=4,
-            multi_cycle_max_wait_ms=10_000.0,  # only K or idle flushes
-            pad_existing=256, pad_pods_per_node=128,
-            speculative_compile=False,
-        ),
-        binder=lambda p, n: binds.append(p.uid),
-    )
-    for nd in make_cluster(4):
-        sched.on_node_add(nd)
-    added: set[str] = set()
-    for p in make_pods(3, seed=41, name_prefix="b1-"):
-        sched.on_pod_add(p)
-        added.add(p.uid)
-    sched.schedule_cycle()  # group pops and BUFFERS (k=4 not reached)
-    assert not binds and any(sched._mc_groups.values())
-    while sched.ladder.rung < RUNG_SEQUENTIAL:
-        sched.ladder.degrade("forced by test")
-    for p in make_pods(2, seed=42, name_prefix="b2-"):
-        sched.on_pod_add(p)
-        added.add(p.uid)
-    stats = sched.schedule_cycle()  # drains the buffer sequentially
-    assert not any(sched._mc_groups.values())
-    assert set(binds) == added, "buffered pods were stranded"
-    assert stats.attempted == len(added)
 
 
 # ---- journal ENOSPC -> stateless degrade ----------------------------------

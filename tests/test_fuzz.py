@@ -9,9 +9,8 @@ Four layers:
 - corpus replay (fast tier): every committed minimal repro under
   tests/corpus/ replays CLEAN against the current engine — each file
   is the regression test for a bug class the differential once caught;
-- smoke (slow tier): live differential cases across the axes (plain /
-  gangs+PDBs / sharded / chaos / multi-cycle), plus the harness
-  self-test — a deliberately seeded engine bug (mutated claim-path
+- smoke: live differential cases across the axes (plain / gangs+PDBs /
+  sharded / chaos), plus the harness self-test — a deliberately seeded engine bug (mutated claim-path
   tie-break) must be CAUGHT, and the corpus repro must reproduce its
   recorded class when the bug is re-injected.
 """
@@ -50,8 +49,8 @@ def test_generator_is_deterministic():
     assert a != trace_to_dict(generate_trace(8))
     # kwargs are part of the stamp: the same seed with different axes
     # must still be reproducible, not equal
-    c = trace_to_dict(generate_trace(7, devices=4, multi_cycle=True))
-    assert c == trace_to_dict(generate_trace(7, devices=4, multi_cycle=True))
+    c = trace_to_dict(generate_trace(7, devices=4))
+    assert c == trace_to_dict(generate_trace(7, devices=4))
     assert c != a
 
 
@@ -83,15 +82,13 @@ def test_generator_covers_the_plugin_inventory():
             seen.add("pod_churn")
         if '"delete_node"' in blob or '"update_node"' in blob:
             seen.add("node_churn")
-        if int(t.config["multi_cycle_k"]) > 1:
-            seen.add("multi_cycle")
     t = generate_trace(3, chaos=True)
     if t.fault_spec:
         seen.add("chaos")
     assert seen == {
         "gangs", "pdbs", "pv_topology", "tolerations", "spread",
         "affinity", "preemption_pressure", "pod_churn", "node_churn",
-        "multi_cycle", "chaos",
+        "chaos",
     }
 
 
@@ -102,25 +99,6 @@ def test_trace_roundtrips(tmp_path):
     p = str(tmp_path / "t.json")
     save_trace(p, t)
     assert trace_to_dict(load_trace(p)) == d
-
-
-def test_multicycle_traces_stay_in_the_exactness_envelope():
-    """Coalescing traces must be arrivals-only and frozen-clock — churn
-    or ticking backoffs across the batch window are legal semantic
-    differences the differential must never be exposed to."""
-    for seed in range(20):
-        t = generate_trace(seed, multi_cycle=True)
-        assert t.tick_s == 0.0
-        ops = {e["op"] for evs in t.cycles for e in evs}
-        assert not ops & {"delete_pod", "add_node", "update_node",
-                          "delete_node"}
-        # preemption-free: uniform priorities — an eviction's informer
-        # echo lands after the flush, a legal batch-window difference
-        pris = {
-            e["pod"].get("s", {}).get("pri", 0)
-            for evs in t.cycles for e in evs if "pod" in e
-        }
-        assert pris <= {0}
 
 
 # ---- shrinker units (synthetic checkers: no engine, no compile) ---------
@@ -141,7 +119,7 @@ def _poison_check(trace):
 
 def _seeded_poisoned_trace():
     for seed in range(100):
-        t = generate_trace(seed, multi_cycle=False)
+        t = generate_trace(seed)
         if _poison_check(t) is not None:
             return t
     raise AssertionError("no seed in range produced a priority-10 pod")
@@ -242,22 +220,14 @@ def test_corpus_replays_clean(path):
     assert not failures, [str(f) for f in failures]
 
 
-# ---- live differential smoke (slow tier) --------------------------------
+# ---- live differential smoke ---------------------------------------------
 
 
 def test_fuzz_differential_plain_seed():
     """One full plain case: random trace (churn, priorities, taints,
     spreads) through the live engine and the trace oracle — bit-equal
     streams, zero invariant violations."""
-    failures = run_case(generate_trace(2, multi_cycle=False))
-    assert not failures, [str(f) for f in failures]
-
-
-def test_fuzz_differential_multicycle_seed():
-    """The K=4 coalescing path against the sequential oracle: the
-    flattened outcome streams must be identical (PR 6's contract,
-    now fuzz-checked rather than only equivalence-suite-checked)."""
-    failures = run_case(generate_trace(1, multi_cycle=True))
+    failures = run_case(generate_trace(2))
     assert not failures, [str(f) for f in failures]
 
 
@@ -265,73 +235,8 @@ def test_fuzz_differential_sharded_seed():
     """Sharded serving (shardDevices=4 on the virtual CPU mesh) must
     stay bit-identical to the oracle — PR 9's shard-invariant
     tie-breaking is what makes this assertion exact."""
-    failures = run_case(generate_trace(31, devices=4, multi_cycle=False))
+    failures = run_case(generate_trace(31, devices=4))
     assert not failures, [str(f) for f in failures]
-
-
-def test_fuzz_differential_speculative_seed():
-    """The depth-2 pipelining variant (speculativeDispatch over
-    multiCycleK=4): the speculative engine must be per-cycle
-    bit-identical to the non-speculative engine on the same trace —
-    adoption/abandonment may never change what is decided, when it
-    lands, or its order — and the case fails if the trace never
-    actually dispatched a speculation (a silently-vacuous variant
-    would be a permanent green)."""
-    t = generate_trace(1, speculative=True)
-    assert t.config["speculative_dispatch"] is True
-    assert t.config["multi_cycle_k"] == 4
-    failures = run_case(t)
-    assert not failures, [str(f) for f in failures]
-
-
-def test_fuzz_differential_incremental_seed():
-    """The admission-time incremental encode variant (incrementalEncode
-    over multiCycleK=4): the same trace runs with ingest-at-ack on AND
-    off and must produce byte-identical dispatched packed arenas plus
-    bit-equal decision / journal / event streams — and the case fails
-    if the on-run never folded a staged row (a variant whose ingest
-    always misses would be a permanent vacuous green)."""
-    t = generate_trace(1, incremental=True)
-    assert t.config["incremental_encode"] is True
-    assert t.config["multi_cycle_k"] == 4
-    failures = run_case(t)
-    assert not failures, [str(f) for f in failures]
-
-
-def test_speculative_traces_stay_in_the_exactness_envelope():
-    """Speculative traces must actually exercise the device loop they
-    pipeline: the envelope-leaving capabilities (affinity / spread /
-    volumes / host ports) are drawn but not applied, and the mc
-    invariants (arrivals-only, frozen clock, flat priority) hold."""
-    import json
-
-    for seed in range(10):
-        t = generate_trace(seed, speculative=True)
-        assert t.tick_s == 0.0
-        blob = json.dumps(t.cycles)
-        for key in ('"af"', '"tsc"', '"vol"'):
-            assert key not in blob, (seed, key)
-
-
-def test_fuzz_chaos_fetch_hang_mid_speculation(tmp_path):
-    """Chaos fused with speculation: fetch_hang fires on the first
-    bounded fetch of a flush — AFTER the continuation batch was
-    speculatively dispatched — so the watchdog must bound it, the
-    abandoned dispatch must not leak an arena slot (the trace keeps
-    serving flushes through the same 3-slot pipeline), and the PR 8
-    soak invariants hold: no lost/duplicate binds, ladder recovered,
-    digest-verified restore."""
-    from k8s_scheduler_tpu.fuzz.replay import replay_engine
-
-    t = generate_trace(30, chaos=True, speculative=True)
-    t.fault_spec = "seed=30;fetch_hang@cycle=2..40:ms=15000:n=1"
-    eng = replay_engine(t, state_dir=str(tmp_path / "state"))
-    assert not eng.failures, [str(f) for f in eng.failures]
-    led = eng.stats["speculation"]
-    # the hang hit the predecessor's fetch mid-speculation: the
-    # in-flight continuation was abandoned (slot freed), and later
-    # flushes kept speculating (adoptions after the recovery)
-    assert led["abandoned"] >= 1, led
 
 
 def test_fuzz_chaos_seed(tmp_path):
@@ -349,7 +254,7 @@ def test_fuzz_catches_seeded_tiebreak_bug():
     mutated (first-max -> last-max), the differential must report a
     bind-stream divergence — the exact silent-wrongness class PR 9
     eliminated and the reason bit-equality is assertable at all."""
-    failures = run_case(generate_trace(1, multi_cycle=False), bug="tiebreak")
+    failures = run_case(generate_trace(1), bug="tiebreak")
     assert any(f.cls == "divergence/binds" for f in failures), (
         [str(f) for f in failures]
     )

@@ -151,6 +151,31 @@ def test_pipeline_ordering_guard_and_slim_matches_result():
     pipe2.dispatch(wbuf, bbuf, stable).decisions()
 
 
+def test_fold_free_driver_keeps_silent_slot_release():
+    """require_decision_fetch=False (fold-free probes/throughput loops)
+    opted out of the ordering guard: a dispatch that wraps onto a slot
+    whose handle was never fetched releases it silently."""
+    nodes = [MakeNode("n0").capacity({"cpu": "4"}).obj()]
+    pods = [MakePod("p0").req({"cpu": "1"}).obj()]
+    enc = SnapshotEncoder(pad_pods=8, pad_nodes=4)
+    wbuf, bbuf, spec, _snap, _dirty = enc.encode_packed(nodes, pods)
+    from k8s_scheduler_tpu.core.cycle import (
+        build_packed_cycle_fn,
+        build_stable_state_fn,
+    )
+
+    cyc = build_packed_cycle_fn(spec, commit_mode="scan")
+    stable = build_stable_state_fn(spec)(wbuf, bbuf)
+    pipe = ServingPipeline(cyc, require_decision_fetch=False)
+    first = pipe.dispatch(wbuf, bbuf, stable)
+    pipe.dispatch(wbuf, bbuf, stable)
+    # the third dispatch wraps onto the first's slot, still unfetched
+    last = pipe.dispatch(wbuf, bbuf, stable)
+    assert first.result is None  # released for the arena's reuse
+    assignment, _unsched, _dropped = last.decisions()
+    assert assignment[0] == 0
+
+
 def test_donate_diagnosis_refuses_preemption_consumer():
     # a donated diagnosis consumes the slot's packed buffers; a
     # preemption program dispatched after it would read freed memory
@@ -238,6 +263,41 @@ def test_binds_fold_before_next_cycle_encodes():
     assert binds_c1 <= set(encodes[1][1]), (
         "cycle 2 encoded before cycle 1's binds folded into the cache"
     )
+
+
+def test_apply_failure_releases_guard_and_next_cycle_dispatches():
+    """A failure inside the apply phase that is not the device's (here:
+    a host plugin raising a plain exception from `reserve`) leaves the
+    ordering guard released — the decisions were fetched before the
+    bind loop began — so later cycles dispatch and bind."""
+    from k8s_scheduler_tpu.framework.host import HostPlugin
+
+    class Boom(HostPlugin):
+        name = "Boom"
+        fired = False
+
+        def reserve(self, pod, node_name):
+            if not Boom.fired:
+                Boom.fired = True
+                raise RuntimeError("induced host-plugin failure")
+            return None
+
+    binds = []
+    s = Scheduler(
+        binder=lambda p, n: binds.append(p.name), pad_bucket=8,
+        host_plugins=[Boom()],
+    )
+    s.on_node_add(MakeNode("n0").capacity({"cpu": "64"}).obj())
+    s.on_pod_add(MakePod("p0").req({"cpu": "1"}).obj())
+    with pytest.raises(RuntimeError, match="induced host-plugin"):
+        s.schedule_cycle()
+    for i in range(1, 3):
+        s.on_pod_add(MakePod(f"p{i}").req({"cpu": "1"}).obj())
+        s.schedule_cycle()
+    assert "p1" in binds and "p2" in binds
+    assert all(p.inflight() == 0 for *_fns, p in (
+        e["fns"] for e in s._packed.values()
+    ))
 
 
 def test_forced_sync_produces_identical_bindings():

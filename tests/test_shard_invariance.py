@@ -14,7 +14,7 @@ Three layers:
   onehot compaction) places a contended guard-heavy trace bit-
   identically at devices ∈ {1, 2, 4, 8};
 - serving: two Schedulers — shardDevices=0 and shardDevices=4 —
-  driven through the same multi-cycle trace produce identical bind
+  driven through the same trace of several cycles produce identical bind
   streams and state digests, and the sharded one stamps
   n_devices/collective metadata on flight records, the
   scheduler_shard_devices gauge, and /debug/state.

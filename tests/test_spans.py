@@ -261,14 +261,12 @@ def test_unarmed_overhead_below_one_percent():
 
 def test_cross_thread_trace_join_submit_to_bind(tmp_path):
     """Spans stamped on the submit thread (validate/journal/ack), the
-    serve thread (buffer wait, dispatch, decision row, apply fold,
-    bind confirm) and the WAL writer's barrier must all land in ONE
+    serve thread (dispatch, decision row, apply fold, bind confirm)
+    and the WAL writer's barrier must all land in ONE
     trace — the caller's, when an explicit traceparent rode the
     Submit — with the registration span id as every span's parent."""
     st = DurableState(str(tmp_path), snapshot_interval_seconds=0)
-    sched, binds = _sched(
-        state=st, multi_cycle_k=4, multi_cycle_max_wait_ms=1e6
-    )
+    sched, binds = _sched(state=st)
     adm = AdmissionController(sched, queue_depth=100)
     adm.node_churn(adds=make_cluster(4))
     fd = FrontDoor(adm)
@@ -307,8 +305,7 @@ def test_cross_thread_trace_join_submit_to_bind(tmp_path):
     names = {s.name for s in spans}
     assert {
         "submit.validate", "submit.journal", "ack.barrier",
-        "mc.buffer_wait", "dispatch", "decision.row", "apply.fold",
-        "bind.confirm",
+        "dispatch", "decision.row", "apply.fold", "bind.confirm",
     } <= names, f"missing lifecycle spans, got {sorted(names)}"
     # every pod's life is individually complete
     for p in pods:

@@ -28,6 +28,14 @@ from k8s_scheduler_tpu.state.journal import (
 )
 
 
+class FakeClock:
+    def __init__(self, t: float = 1000.0) -> None:
+        self.t = t
+
+    def __call__(self) -> float:
+        return self.t
+
+
 def _drain(journal):
     journal.flush()
     journal.close()
@@ -552,3 +560,68 @@ def test_debug_state_status_shape(tmp_path):
     assert s["last_snapshot"]["bytes"] > 0
     st.seal()
     assert st.status()["sealed"]
+
+
+# ---- records of an older process: q.pop {"hold": true}, q.retire -----
+# no serving code emits them now (ROADMAP D16); a journal may hold them
+
+
+def test_hold_pop_keeps_buffered_groups_recoverable(tmp_path):
+    """A crash while K groups are coalescing must recover EVERY
+    buffered group, not just the last pop's: the journaled hold-pop
+    accumulates the in-flight set instead of replacing it."""
+    clock = FakeClock()
+    q = SchedulingQueue(now=clock)
+    c = SchedulerCache(now=clock)
+    st = DurableState(str(tmp_path / "wal"), snapshot_interval_seconds=0)
+    st.attach(q, c)
+    q.add(MakePod("p0").req({"cpu": "1"}).obj())
+    assert [p.uid for p in q.pop_ready()] == ["default/p0"]
+    q.add(MakePod("p1").req({"cpu": "1"}).obj())
+    # the second group's pop HOLDS the first group's in-flight entry
+    assert [p.uid for p in q.pop_ready(hold=True)] == ["default/p1"]
+    # a delete tombstone for a buffered pod must survive the hold-pop
+    q.delete("default/p0")
+    st.journal.flush()
+    st.journal.close()
+
+    q2 = SchedulingQueue(now=clock)
+    c2 = SchedulerCache(now=clock)
+    st2 = DurableState(
+        str(tmp_path / "wal"), snapshot_interval_seconds=0
+    )
+    st2.attach(q2, c2)
+    assert q2.recover_in_flight() == 1  # p1 requeued; p0's tombstone held
+    assert [p.uid for p in q2.pop_ready()] == ["default/p1"]
+    st2.journal.close()
+
+
+def test_retire_in_flight_bounds_hold_accumulation(tmp_path):
+    """Hold pops only ACCUMULATE the in-flight set; the batch flush
+    must retire the pods whose outcomes it applied (journaled, so a
+    replayed takeover recovers the same bounded set) — otherwise bound
+    pods stay "recoverable" forever and a failover re-binds them."""
+    clock = FakeClock()
+    q = SchedulingQueue(now=clock)
+    c = SchedulerCache(now=clock)
+    st = DurableState(str(tmp_path / "wal"), snapshot_interval_seconds=0)
+    st.attach(q, c)
+    q.add(MakePod("p0").req({"cpu": "1"}).obj())
+    q.pop_ready(hold=True)
+    q.add(MakePod("p1").req({"cpu": "1"}).obj())
+    q.pop_ready(hold=True)
+    assert set(q._in_flight) == {"default/p0", "default/p1"}
+    # flush applied p0's bind; p1 is still buffered — p0 retires, p1
+    # stays recoverable
+    q.retire_in_flight(["default/p0", "default/never-in-flight"])
+    assert set(q._in_flight) == {"default/p1"}
+    st.journal.flush()
+    st.journal.close()
+
+    q2 = SchedulingQueue(now=clock)
+    c2 = SchedulerCache(now=clock)
+    st2 = DurableState(str(tmp_path / "wal"), snapshot_interval_seconds=0)
+    st2.attach(q2, c2)
+    assert set(q2._in_flight) == {"default/p1"}  # replay reproduces it
+    assert q2.recover_in_flight() == 1  # only p1 — p0 is NOT re-bound
+    st2.journal.close()
